@@ -9,7 +9,7 @@
 #   docker build -t gym-tpu .
 #   docker run --rm gym-tpu pytest tests/ -q          # CPU mesh tests
 #   docker run --rm --privileged --net=host \
-#     -e JAX_PLATFORMS=tpu gym-tpu python bench.py     # on a TPU VM
+#     -e JAX_PLATFORMS=tpu gym-tpu python chip_smoke.py   # on a TPU VM
 
 FROM python:3.12-slim
 
@@ -30,7 +30,7 @@ COPY gym_tpu/ gym_tpu/
 COPY tests/ tests/
 COPY examples/ examples/
 COPY benchmarks/ benchmarks/
-COPY bench.py .
+COPY bench.py chip_smoke.py ./
 RUN pip install --no-cache-dir -e .
 
 # default: prove the build works (8 virtual CPU devices, same as CI)

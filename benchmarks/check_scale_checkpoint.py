@@ -4,8 +4,7 @@ Trains GPT-2 base (124M params + DiLoCo inner AdamW + outer
 master/momentum — ~2.5 GB of state) for 4 steps on the chip with
 Orbax checkpoints every 2 steps, then calls ``fit`` again with
 ``max_steps=8``: the second run must restore from step 4 and continue
-the loss trajectory at steps 4..7. Takes ~25 min end-to-end on the
-remote-transport chip (the async saves dominate).
+the loss trajectory at steps 4..7.
 
 Usage: python benchmarks/check_scale_checkpoint.py
 """

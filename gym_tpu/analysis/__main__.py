@@ -115,7 +115,7 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
-    # the suite only traces — force the cheap backend so a CI host
-    # without an accelerator (or with a sick transport) never blocks
+    # the suite only traces — force the cheap backend, with or without
+    # an accelerator on the host
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
     sys.exit(main())

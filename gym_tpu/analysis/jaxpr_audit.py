@@ -43,7 +43,8 @@ import numpy as np
 # registry and this auditor compute THE SAME key from the same function
 # — re-exported here for existing importers
 from ..programs.keys import program_key  # noqa: F401  (re-export)
-from .jaxpr_tools import trace_with_axis_env, walk_jaxpr
+from .jaxpr_tools import (eval_shape_with_axis_env, trace_with_axis_env,
+                          walk_jaxpr)
 
 PyTree = Any
 
@@ -221,7 +222,6 @@ def trainer_step_specs(num_nodes: int = 4, n_micro: int = 1,
     over the real GPT loss model), with the runtime's donation
     convention (``donate_state=True`` → arg 0, the TrainState)."""
     import jax.numpy as jnp
-    from jax import core
 
     from ..models.base import LossModel
     from ..models.nanogpt import GPT
@@ -246,9 +246,8 @@ def trainer_step_specs(num_nodes: int = 4, n_micro: int = 1,
         axis_sizes = dict(zip(ctx.axes, ctx.sizes))
         init_fn = make_init_fn(loss_model, strategy, example_micro,
                                seed=0, ctx=ctx)
-        with core.extend_axis_env_nd(list(axis_sizes.items())):
-            state_tpl = jax.eval_shape(
-                init_fn, jax.ShapeDtypeStruct((), np.int32))
+        state_tpl = eval_shape_with_axis_env(
+            init_fn, (jax.ShapeDtypeStruct((), np.int32),), axis_sizes)
         node_step = make_train_step(loss_model, strategy, ctx)
         specs.append(ProgramSpec(
             name=f"trainer.step[{name}]", fn=node_step,

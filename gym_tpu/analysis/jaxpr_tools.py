@@ -8,7 +8,7 @@ Two capabilities:
    host doesn't have (the 2-core container folds K nodes onto one CPU
    device via a vmapped ``'vnode'`` axis, which ERASES the collectives
    from the jaxpr: vmap's batching rules turn a vnode psum into a dense
-   sum at trace time). ``jax.core.extend_axis_env_nd`` binds the axis
+   sum at trace time). ``jax.make_jaxpr(axis_env=...)`` binds the axis
    names *abstractly* instead, so ``jax.make_jaxpr`` of the raw node
    function stages every ``psum``/``all_gather``/``reduce_scatter`` as a
    first-class equation over the full K-sized axis — the honest
@@ -18,7 +18,7 @@ Two capabilities:
 2. **Constant-folding jaxpr walk** (``walk_jaxpr``): an abstract
    interpreter over a ClosedJaxpr that (a) collects every collective
    equation over the node axes into a ``CollectiveSite`` inventory,
-   descending through ``pjit``/``cond``/``scan``/``shard_map``/custom-
+   descending through ``jit``/``cond``/``scan``/``shard_map``/custom-
    derivative sub-jaxprs; (b) flags host callbacks and f64-producing
    equations; and (c) *partially evaluates* the program: any equation
    whose inputs are all known constants is executed eagerly on the host.
@@ -40,7 +40,8 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import core
+from jax.core import DropVar
+from jax.extend import core
 
 from ..parallel.axis import AxisCtx
 
@@ -75,14 +76,13 @@ COLLECTIVE_PRIM_OPS = {
 # trip per dispatch; on TPU it also forces a tuplized transfer that
 # breaks async dispatch).
 CALLBACK_PRIMS = {
-    "pure_callback", "io_callback", "debug_callback", "outside_call",
-    "host_callback_call",
+    "pure_callback", "io_callback", "debug_callback", "debug_print",
 }
 
 # Call-like primitives: one sub-jaxpr, semantics = inline call, so known
 # inputs propagate to known outputs.
 _CALL_PRIMS = {
-    "pjit", "closed_call", "core_call", "call", "remat", "remat2",
+    "jit", "closed_call", "core_call", "call", "remat", "remat2",
     "checkpoint", "custom_jvp_call", "custom_jvp_call_jaxpr",
     "custom_vjp_call", "custom_vjp_call_jaxpr",
 }
@@ -160,9 +160,18 @@ def trace_with_axis_env(fn: Callable, example_args: Sequence[Any],
     as jaxpr equations instead of failing with an unbound-axis error.
     ``example_args`` may be ``ShapeDtypeStruct`` pytrees — nothing is
     materialized or executed."""
-    pairs = list((axis_sizes or {}).items())
-    with core.extend_axis_env_nd(pairs):
-        return jax.make_jaxpr(fn)(*example_args)
+    return jax.make_jaxpr(
+        fn, axis_env=list((axis_sizes or {}).items()))(*example_args)
+
+
+def eval_shape_with_axis_env(fn: Callable, example_args: Sequence[Any],
+                             axis_sizes: Optional[Dict[str, int]] = None):
+    """``jax.eval_shape(fn, *example_args)`` with the named axes bound
+    abstractly, as in ``trace_with_axis_env``."""
+    _, shapes = jax.make_jaxpr(
+        fn, axis_env=list((axis_sizes or {}).items()),
+        return_shape=True)(*example_args)
+    return shapes
 
 
 def _eqn_axes(eqn) -> Tuple[str, ...]:
@@ -215,7 +224,7 @@ class _Walker:
 
     @staticmethod
     def _write(env, var, val):
-        if not isinstance(var, core.DropVar):
+        if not isinstance(var, DropVar):
             env[var] = val
 
     # -- main walk --------------------------------------------------------

@@ -61,12 +61,12 @@ from typing import Any, Dict, List, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import core
 
 from ..parallel.axis import AxisCtx
 from ..strategy.base import Strategy
 from .jaxpr_tools import (UNKNOWN, CollectiveSite, WalkReport,
-                          abstract_node_ctx, walk_jaxpr)
+                          abstract_node_ctx, eval_shape_with_axis_env,
+                          trace_with_axis_env, walk_jaxpr)
 
 PyTree = Any
 
@@ -161,10 +161,10 @@ def extract_step_inventory(strategy: Strategy, params_template: PyTree,
         # params/state ride along so no equation is dead-code ambiguous
         return metrics["comm_bytes"], p, st
 
-    with core.extend_axis_env_nd(list(axis_sizes.items())):
-        state_tpl = jax.eval_shape(strategy.init, params_template)
-        closed = jax.make_jaxpr(fn)(params_template, params_template,
-                                    state_tpl)
+    state_tpl = eval_shape_with_axis_env(
+        strategy.init, (params_template,), axis_sizes)
+    closed = trace_with_axis_env(
+        fn, (params_template, params_template, state_tpl), axis_sizes)
     return walk_jaxpr(closed, node_axes=ctx.axes, axis_sizes=axis_sizes)
 
 

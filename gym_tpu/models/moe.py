@@ -90,22 +90,11 @@ def _grouped_dot(x, w, sorted_e, chunk_rows: int):
     return h.reshape(-1, w.shape[-1])[:n]
 
 
-def _no_ambient_mesh() -> bool:
-    """Is NO mesh context bound? jax >= 0.6 exposes
-    ``jax.sharding.get_abstract_mesh``; 0.4.x keeps the resource env on
-    ``thread_resources`` (the ``with mesh:`` context manager's state)."""
-    gam = getattr(jax.sharding, "get_abstract_mesh", None)
-    if gam is not None:
-        return gam().empty
-    from jax.interpreters import pxla
-    return pxla.thread_resources.env.physical_mesh.empty
-
-
 def _constrain(x, spec):
     """``with_sharding_constraint`` that is a no-op under mesh-less tracing
     (unit tests without a mesh context) but fails loudly on a real
     misconfiguration (e.g. an axis name missing from the mesh)."""
-    if _no_ambient_mesh():
+    if jax.sharding.get_abstract_mesh().empty:
         return x
     return jax.lax.with_sharding_constraint(
         x, jax.sharding.PartitionSpec(*spec)

@@ -26,7 +26,6 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from ..parallel.axis import axis_size as _axis_size
 import numpy as np
 import optax
 
@@ -699,7 +698,7 @@ def slice_seq_chunk(idx, targets, seq_axis: str, axis: int = 1,
     psum'd sum/count reduction). Falls back to contiguous when ``Tl`` is
     odd — the same static condition the attention dispatch tests, so the
     two sides can never disagree."""
-    sp = _axis_size(seq_axis)
+    sp = jax.lax.axis_size(seq_axis)
     t = idx.shape[axis]
     if t % sp != 0:
         raise ValueError(f"seq len {t} not divisible by cp={sp}")
@@ -812,11 +811,33 @@ def make_adamw(lr, betas=(0.9, 0.95), weight_decay=0.1, params=None):
                        mask=decay_mask(params) if params is not None else None)
 
 
+#: Peak dense bf16 FLOP/s of ONE chip, keyed by the ``device_kind`` JAX
+#: reports. Source: Google Cloud documentation, "TPU v5e" (197 TFLOP/s
+#: bf16 per chip). A device that is not listed has no peak: ``fit``
+#: reports ``mfu=None`` there and measuring scripts fail
+#: (``device_peak_flops``) — the reference's A100 constant (``:394-408``)
+#: and a v5e default for whatever is attached were both wrong answers.
+PEAK_BF16_FLOPS = {"TPU v5 lite": 197e12}
+
+
+def device_peak_flops(device=None) -> float:
+    """Peak bf16 FLOP/s of ``device`` (default: the first attached one);
+    ``KeyError`` naming the device when it is not in the table — for
+    scripts that were asked to measure utilization."""
+    kind = (device or jax.devices()[0]).device_kind
+    if kind not in PEAK_BF16_FLOPS:
+        raise KeyError(
+            f"no peak FLOP/s known for device_kind {kind!r} (table: "
+            f"{sorted(PEAK_BF16_FLOPS)}) — utilization cannot be "
+            f"measured on this device")
+    return PEAK_BF16_FLOPS[kind]
+
+
 def estimate_mfu(config: GPTConfig, params: Any, fwdbwd_per_iter: float,
-                 dt: float, peak_flops: float = 197e12,
+                 dt: float, peak_flops: float,
                  n_params: Optional[int] = None) -> float:
-    """Model FLOPs utilization. Default peak is TPU v5e bf16 (197 TFLOP/s)
-    rather than the reference's A100 312 TFLOPS (``:394-408``).
+    """Model FLOPs utilization against ``peak_flops`` (chips × the
+    per-chip entry of ``PEAK_BF16_FLOPS``).
     ``n_params`` overrides the parameter count — used for MoE, where only
     the routed top-k fraction of expert params does FLOPs per token
     (``models.moe.moe_active_params``)."""
@@ -830,7 +851,7 @@ def estimate_mfu(config: GPTConfig, params: Any, fwdbwd_per_iter: float,
 
 
 def node_mfu(config: GPTConfig, node_params: Any, seqs_per_iter: float,
-             dt: float, peak_flops: float = 197e12) -> float:
+             dt: float, peak_flops: float) -> float:
     """MFU from a *node-stacked* param tree (leading [K] axis, as held by
     the runtime/bench/trainer): strips the axis to shapes and delegates to
     ``estimate_mfu``. Single place for the MFU convention. MoE configs

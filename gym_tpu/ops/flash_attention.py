@@ -17,6 +17,7 @@ we fall back to dense in that case too.
 from __future__ import annotations
 
 import functools
+import logging
 from typing import Optional
 
 import jax
@@ -24,13 +25,20 @@ import jax.numpy as jnp
 
 from .attention import dense_causal_attention
 
+_log = logging.getLogger(__name__)
+
+
+def _on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
+
 
 @functools.cache
-def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:  # pragma: no cover
-        return False
+def _report(path: str, shape: tuple, dtype: str) -> None:
+    """Log which implementation ``attn_impl='flash'`` resolved to, once
+    per (path, shape, dtype) per process: off-TPU and untileable shapes
+    take the dense XLA path by design, and that choice must be visible
+    (``chip_smoke.py`` reads these records to assert the kernel ran)."""
+    _log.info("attention path %s for q%s %s", path, shape, dtype)
 
 
 def _flash_ok(q: jnp.ndarray) -> bool:
@@ -50,6 +58,7 @@ def flash_causal_attention(
 ) -> jnp.ndarray:
     use_dropout = dropout_rate > 0.0 and not deterministic
     if not _on_tpu() or use_dropout or not _flash_ok(q):
+        _report("dense", q.shape, str(q.dtype))
         return dense_causal_attention(
             q, k, v, dropout_rate=dropout_rate, dropout_rng=dropout_rng,
             deterministic=deterministic,
@@ -58,7 +67,9 @@ def flash_causal_attention(
     if fused_supported(q):
         # whole-context fused kernel: fastest at the reference's shapes
         # (T ≤ 1024), probs never touch HBM in fwd or bwd
+        _report("pallas_fused", q.shape, str(q.dtype))
         return fused_causal_attention(q, k, v)
+    _report("pallas_flash", q.shape, str(q.dtype))
     from jax.experimental.pallas.ops.tpu.flash_attention import (
         BlockSizes, flash_attention,
     )
@@ -105,4 +116,5 @@ def packed_flash_attention_or_none(q, k, v, n_head: int):
                                   packed_supported)
     if not _on_tpu() or not packed_supported(q, n_head):
         return None
+    _report("pallas_packed", q.shape, str(q.dtype))
     return fused_causal_attention_packed(q, k, v, n_head)

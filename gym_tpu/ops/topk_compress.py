@@ -41,8 +41,7 @@ def topk_compress(c: jnp.ndarray, k: int) -> Tuple[jnp.ndarray, jnp.ndarray]:
     n = c.shape[-1]
     k = max(1, min(int(k), n))
     nbits = max(1, (n - 1).bit_length())
-    if (c.dtype == jnp.float32 and nbits <= 16
-            and hasattr(lax, "approx_max_k")):
+    if c.dtype == jnp.float32 and nbits <= 16:
         mask = (1 << nbits) - 1
         bits = lax.bitcast_convert_type(c, jnp.int32) & jnp.int32(0x7FFFFFFF)
         # Nonfinite coefficients: |Inf|'s bit pattern OR'd with an index

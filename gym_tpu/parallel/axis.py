@@ -27,16 +27,6 @@ from jax import lax
 PyTree = Any
 
 
-def axis_size(name) -> int:
-    """Portable ``jax.lax.axis_size`` (absent before jax 0.5): size of a
-    bound mapped axis (or tuple of axes) from inside the program. The
-    ``psum(1, name)`` fallback is the classic idiom — a literal reduces
-    statically, so the result is a Python int under tracing."""
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(name)
-    return lax.psum(1, name)
-
-
 NODE_AXIS = "node"
 VNODE_AXIS = "vnode"
 SEQ_AXIS = "seq"

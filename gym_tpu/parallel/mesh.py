@@ -24,28 +24,6 @@ from .axis import (EXPERT_AXIS, MODEL_AXIS, NODE_AXIS, PIPE_AXIS, SEQ_AXIS,
 
 PyTree = Any
 
-# shard_map moved from jax.experimental to the jax namespace (and renamed
-# its kwargs: auto= complement became axis_names=, check_rep= became
-# check_vma=). Support both so the runtime tracks whichever jax the
-# environment ships.
-_NEW_SHARD_MAP = hasattr(jax, "shard_map")
-if not _NEW_SHARD_MAP:
-    from jax.experimental.shard_map import shard_map as _legacy_shard_map
-
-
-def _shard_map(f, *, mesh, in_specs, out_specs, manual_axes):
-    """Version-portable shard_map: ``manual_axes`` is the set of mesh axes
-    the body is manual over; the rest stay GSPMD-auto."""
-    if _NEW_SHARD_MAP:
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, axis_names=manual_axes,
-                             check_vma=False)
-    auto = frozenset(mesh.axis_names) - frozenset(manual_axes)
-    return _legacy_shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_rep=False,
-                             auto=auto)
-
-
 def _largest_divisor_at_most(n: int, cap: int) -> int:
     for d in range(min(n, cap), 0, -1):
         if n % d == 0:
@@ -200,13 +178,14 @@ class NodeRuntime:
         def program(*args):
             n_in = len(args)
             ins = in_specs if in_specs is not None else (P(NODE_AXIS),) * n_in
-            return _shard_map(
+            return jax.shard_map(
                 block_fn,
                 mesh=self.mesh,
                 in_specs=ins,
                 out_specs=(out_specs if out_specs is not None
                            else P(NODE_AXIS)),
-                manual_axes=manual,
+                axis_names=manual,
+                check_vma=False,
             )(*args)
 
         donate = tuple(range(n_state_args)) if donate_state else ()
@@ -215,24 +194,7 @@ class NodeRuntime:
             # XLA alias their buffers trims peak HBM while the prefetcher
             # keeps the next batch already resident
             donate = donate + tuple(range(n_state_args, n_state_args + 1))
-        jitted = jax.jit(program, donate_argnums=donate)
-        if _NEW_SHARD_MAP:
-            return jitted
-        # jax 0.4.x: with_sharding_constraint over bare PartitionSpecs (the
-        # tp/ep constraint trees) resolves axis names against the ambient
-        # resource env, so tracing must happen inside the mesh context
-        mesh = self.mesh
-
-        def call_in_mesh(*args):
-            with mesh:
-                return jitted(*args)
-
-        def lower(*args, **kw):  # used by HLO-inspection tests
-            with mesh:
-                return jitted.lower(*args, **kw)
-
-        call_in_mesh.lower = lower
-        return call_in_mesh
+        return jax.jit(program, donate_argnums=donate)
 
     def init_state(self, init_fn: Callable[[jnp.ndarray], PyTree],
                    state_specs=None) -> PyTree:

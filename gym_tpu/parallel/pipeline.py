@@ -30,7 +30,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from .axis import axis_size
 
 PIPE_AXIS = "pipe"
 
@@ -73,9 +72,9 @@ def pipeline_apply(
     double-counting the tied embedding: see
     ``train_node.make_pipeline_train_step``).
     """
-    if axis_size(axis_name) != n_stages:
+    if lax.axis_size(axis_name) != n_stages:
         raise ValueError(
-            f"pipe axis '{axis_name}' has size {axis_size(axis_name)} "
+            f"pipe axis '{axis_name}' has size {lax.axis_size(axis_name)} "
             f"but n_stages={n_stages}: a mismatch would make the is_last "
             f"mask never fire and the masked psum return silent zeros")
     m = xs.shape[0]
@@ -109,16 +108,10 @@ def pipeline_apply(
 
     # the carry is stage-varying (each stage holds different activations):
     # mark the zero init as varying over the pipe axis or the scan's carry
-    # typing rejects it (lax.pvary deprecated in favor of pcast)
-    if hasattr(lax, "pcast"):
-        def _vary(x):
-            return lax.pcast(x, (axis_name,), to="varying")
-    elif hasattr(lax, "pvary"):  # pragma: no cover — pre-pcast JAX
-        def _vary(x):
-            return lax.pvary(x, (axis_name,))
-    else:  # jax 0.4.x: no VMA typing — the annotation is a no-op
-        def _vary(x):
-            return x
+    # typing rejects it
+    def _vary(x):
+        return lax.pcast(x, (axis_name,), to="varying")
+
     out0 = _vary(jnp.zeros_like(xs))
     inbox0 = _vary(jnp.zeros_like(xs[0]))
     aux0 = _vary(jnp.zeros((), jnp.float32))

@@ -31,7 +31,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from .axis import axis_size
 
 NEG_INF = float(jnp.finfo(jnp.float32).min)
 
@@ -106,7 +105,7 @@ def _ring_kernel_blocks_zigzag(q, k, v, axis_name: str) -> jnp.ndarray:
     Differentiable end-to-end (fused kernels expose lse cotangents)."""
     from ..ops.fused_attention import fused_block_attention
 
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     my = lax.axis_index(axis_name)
     perm = [(i, (i + 1) % n) for i in range(n)]
     h = q.shape[-2] // 2
@@ -160,7 +159,7 @@ def _ring_dense_zigzag(q, k, v, axis_name: str, dropout_rate: float,
     a gated block contributes via ``m = -1e30`` ⇒ weight 0. Dropout draws
     one fold per (ring step, block) — statistically equivalent to, but not
     bitwise the same as, the contiguous schedule's draws."""
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     my = lax.axis_index(axis_name)
     perm = [(i, (i + 1) % n) for i in range(n)]
     h = q.shape[-2] // 2
@@ -230,7 +229,7 @@ def _ring_kernel_blocks(q, k, v, axis_name: str) -> jnp.ndarray:
     merge is the exact ring backward."""
     from ..ops.fused_attention import fused_block_attention
 
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     my = lax.axis_index(axis_name)
     perm = [(i, (i + 1) % n) for i in range(n)]
 
@@ -294,7 +293,7 @@ def ring_causal_attention(
     into zig-zag halves and falls back to the contiguous schedule — the
     slicing side makes the same static decision.
     """
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     drop = dropout_rate > 0.0 and not deterministic
     if n == 1:
         from ..ops.flash_attention import flash_causal_attention
@@ -348,17 +347,10 @@ def ring_causal_attention(
     b_, h_, _, d_ = q.shape
 
     # mark the fresh accumulators as device-varying over the ring axis so
-    # the scan carry type matches its output (shard_map VMA rule);
-    # lax.pvary is deprecated in favor of pcast(..., to='varying')
-    if hasattr(lax, "pcast"):
-        def _vary(x):
-            return lax.pcast(x, (axis_name,), to="varying")
-    elif hasattr(lax, "pvary"):  # pragma: no cover — pre-pcast JAX
-        def _vary(x):
-            return lax.pvary(x, (axis_name,))
-    else:  # jax 0.4.x: no VMA typing — the annotation is a no-op
-        def _vary(x):
-            return x
+    # the scan carry type matches its output (shard_map VMA rule)
+    def _vary(x):
+        return lax.pcast(x, (axis_name,), to="varying")
+
     o0 = _vary(jnp.zeros((b_, h_, tl, d_), jnp.float32))
     m0 = _vary(jnp.full((b_, h_, tl, 1), -1e30, jnp.float32))
     l0 = _vary(jnp.zeros((b_, h_, tl, 1), jnp.float32))

@@ -19,7 +19,7 @@ logits) are inserted by the partitioner and ride ICI.
 This composes with the simulator conceptually (a future mesh
 ('node','data','model')); here it stands alone for big-model training,
 exposed as ``fit_tensor_parallel`` below and exercised by
-``__graft_entry__.dryrun_multichip`` / ``tests/test_tensor_parallel.py``.
+``tests/test_tensor_parallel.py``.
 """
 
 from __future__ import annotations

@@ -14,13 +14,15 @@ from .keys import program_key
 from .registry import (DEFAULT_CACHE_DIR, Program, ProgramDef,
                        ProgramRegistry, compile_counter,
                        default_registry, disk_event_counters,
-                       enable_disk_tier, xla_compile_counter)
+                       enable_disk_tier, resolve_cache_dir,
+                       xla_compile_counter)
 from .warmup import WarmupThread, warm_engine_programs
 
 __all__ = [
     "program_key", "ProgramDef", "Program", "ProgramRegistry",
     "default_registry", "compile_counter", "xla_compile_counter",
-    "enable_disk_tier", "disk_event_counters", "DEFAULT_CACHE_DIR",
+    "enable_disk_tier", "resolve_cache_dir", "disk_event_counters",
+    "DEFAULT_CACHE_DIR",
     "WarmupThread", "warm_engine_programs",
     "elastic_program_defs", "reshard_flat_def", "replicate_rows_def",
     "unshard_params_def",

@@ -62,8 +62,12 @@ PyTree = Any
 
 # -- disk tier (persistent XLA executable cache) ---------------------------
 
+#: where the cache lives when nothing places it: ONE fixed path inside
+#: the checkout (the path is part of JAX's cache key — a directory that
+#: moves with the home, the pid, the time or an output dir never hits)
 DEFAULT_CACHE_DIR = os.path.join(
-    os.path.expanduser("~"), ".cache", "gym_tpu", "xla_cache")
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
 
 #: global persistent-cache event counters, fed by jax.monitoring. The
 #: events are process-global (jax has one compilation cache), so the
@@ -112,48 +116,49 @@ def disk_event_counters() -> Dict[str, int]:
     return {"xla_cache_hits": h, "xla_cache_misses": m}
 
 
+def _enabled_cache_dir() -> Optional[str]:
+    """The directory an earlier ``enable_disk_tier`` call turned on."""
+    import jax
+    return (jax.config.jax_compilation_cache_dir
+            if _LISTENER_INSTALLED else None)
+
+
+def resolve_cache_dir(cache_dir: Optional[str] = None) -> str:
+    """Where the persistent compile cache goes: ``JAX_COMPILATION_CACHE_DIR``
+    (where it is set, that directory is used and nothing in this repo
+    sets another) > explicit argument > the directory an earlier call
+    already enabled > ``DEFAULT_CACHE_DIR`` inside the checkout."""
+    return (os.environ.get("JAX_COMPILATION_CACHE_DIR") or cache_dir
+            or _enabled_cache_dir() or DEFAULT_CACHE_DIR)
+
+
 def enable_disk_tier(cache_dir: Optional[str] = None, *,
                      min_compile_time_secs: Optional[float] = 0.0) -> str:
-    """Point JAX's persistent compilation cache at ``cache_dir`` and
-    install the hit/miss listener the registry's compile counters read.
-
-    Resolution order: explicit argument > ``GYM_TPU_PROGRAM_CACHE_DIR``
-    > ``JAX_COMPILATION_CACHE_DIR`` > the gym-tpu default under
-    ``~/.cache``.  ``min_compile_time_secs`` defaults to 0 (persist even
+    """Point JAX's persistent compilation cache at
+    ``resolve_cache_dir(cache_dir)`` and install the hit/miss listener
+    the registry's compile counters read.
+    ``min_compile_time_secs`` defaults to 0 (persist even
     sub-second compiles — the serving programs on small models compile
     fast but a cold start pays all of them at once; ``None`` leaves
     JAX's own ~1 s threshold untouched, the trainer-path default).
     Idempotent; returns the resolved directory."""
     import jax
+    from jax.experimental.compilation_cache import compilation_cache
 
-    cache_dir = (cache_dir
-                 or os.environ.get("GYM_TPU_PROGRAM_CACHE_DIR")
-                 or os.environ.get("JAX_COMPILATION_CACHE_DIR")
-                 or DEFAULT_CACHE_DIR)
-    os.makedirs(cache_dir, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
-    jax.config.update("jax_enable_compilation_cache", True)
+    cache_dir = resolve_cache_dir(cache_dir)
     if min_compile_time_secs is not None:
         jax.config.update("jax_persistent_cache_min_compile_time_secs",
                           float(min_compile_time_secs))
-    # jax 0.4.x initializes the persistent cache AT MOST ONCE per
-    # process, at the first compile. A server restores its checkpoint
-    # (which compiles) before this function runs, so without a reset the
-    # dir-less initialization is latched and the tier is silently dead —
-    # the ci_serve restart drill caught exactly that. reset_cache()
-    # clears the latch; the next compile re-initializes against
-    # ``cache_dir``.
-    try:
-        from jax.experimental.compilation_cache import (
-            compilation_cache as _cc)
-        _cc.reset_cache()
-    except Exception as e:  # noqa: BLE001 — experimental API; degrade
-        # loudly rather than crash server startup
-        warnings.warn(f"program registry: could not reset jax's "
-                      f"compilation-cache latch ({type(e).__name__}: "
-                      f"{e}); the disk tier may be inert if anything "
-                      f"compiled before enable_disk_tier()")
-    _install_listener()
+    if cache_dir != _enabled_cache_dir():
+        os.makedirs(cache_dir, exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
+        jax.config.update("jax_enable_compilation_cache", True)
+        # JAX binds its cache object to a directory once per process;
+        # the reset makes the next compile re-initialize against
+        # ``cache_dir`` (a server restores its checkpoint, which
+        # compiles, before this function runs)
+        compilation_cache.reset_cache()
+        _install_listener()
     return cache_dir
 
 
@@ -304,6 +309,21 @@ class ProgramRegistry:
     def __len__(self) -> int:
         with self._lock:
             return len(self._store)
+
+    def lowered_text(self, name: str) -> str:
+        """StableHLO of the newest registered program called ``name``,
+        lowered again from the argument templates it was registered
+        with.  Lets a caller that did not build the program read what
+        went to the compiler: whether the Pallas kernel is in the
+        trainer step (``tpu_custom_call``), whether the collectives are
+        (``all_reduce``)."""
+        with self._lock:
+            pdef = next((e.pdef for e in reversed(self._store.values())
+                         if e.name == name and e.pdef is not None), None)
+        if pdef is None:
+            raise KeyError(f"no program named {name!r} is registered "
+                           f"with a definition")
+        return pdef.builder().lower(*pdef.args).as_text()
 
     # -- registration / acquisition ---------------------------------------
 

@@ -198,19 +198,18 @@ def _build_parser() -> argparse.ArgumentParser:
     p.add_argument("--metrics_dir", default=None,
                    help="serve.csv location (default: <RUN_DIR>/serve)")
     p.add_argument("--program-cache-dir", default=None,
-                   help="enable the device-program registry's persistent "
-                        "executable tier at this directory (or set "
-                        "GYM_TPU_PROGRAM_CACHE_DIR): a restart against "
-                        "the same config deserializes every program "
-                        "instead of compiling — /stats "
-                        "programs_compiled stays 0")
+                   help="place the device-program registry's persistent "
+                        "executable tier (default: .jax_cache in the "
+                        "checkout; JAX_COMPILATION_CACHE_DIR, where "
+                        "set, wins): a restart against the same "
+                        "config deserializes every program instead of "
+                        "compiling — /stats programs_compiled stays 0")
     p.add_argument("--no-warmup", action="store_true",
                    help="skip the background AOT program warmup at "
                         "startup (cold requests then pay compiles "
                         "on-path — the pre-registry behavior)")
     p.add_argument("--device", default=None,
-                   help="'cpu' pins the CPU backend (skips accelerator "
-                        "plugin init)")
+                   help="'cpu' pins the CPU backend")
     return p
 
 
@@ -304,11 +303,12 @@ def create_server(params, cfg, *, host: str = "127.0.0.1", port: int = 0,
     COMPLETE program family (all power-of-two prefill buckets + the
     decode/admit or paged/spec programs) through the device-program
     registry before traffic needs them — cold-start p99 TTFT pays no
-    compiles.  ``program_cache_dir`` (or ``GYM_TPU_PROGRAM_CACHE_DIR``)
-    additionally enables the registry's persistent executable tier: a
-    restart against the same config deserializes every program instead
-    of compiling (``/stats`` → ``programs_compiled`` stays 0, pinned by
-    the ``scripts/ci_serve.sh`` restart drill)."""
+    compiles.  The registry's persistent executable tier is always on
+    (``program_cache_dir`` places it; ``programs.resolve_cache_dir``
+    decides): a restart against the same config
+    deserializes every program instead of compiling (``/stats`` →
+    ``programs_compiled`` stays 0, pinned by the ``scripts/ci_serve.sh``
+    restart drill)."""
     from ..data.build_dataset import CHAR_VOCAB
     from ..utils.checkpoint import CheckpointNotFoundError
     from ..utils.resilience import fault_point
@@ -346,10 +346,9 @@ def create_server(params, cfg, *, host: str = "127.0.0.1", port: int = 0,
             "(--page_size > 0) — speculative decoding disabled\n")
 
     from .. import programs as programs_mod
-    if program_cache_dir or os.environ.get("GYM_TPU_PROGRAM_CACHE_DIR"):
-        resolved = programs_mod.enable_disk_tier(program_cache_dir)
-        sys.stderr.write(
-            f"gym_tpu.serve: program registry disk tier at {resolved}\n")
+    resolved = programs_mod.enable_disk_tier(program_cache_dir)
+    sys.stderr.write(
+        f"gym_tpu.serve: program registry disk tier at {resolved}\n")
 
     metrics = ServeMetrics(metrics_dir)
     weights_tag = (f"step-{info['step']}"
@@ -983,29 +982,39 @@ def main(argv=None) -> int:
                   file=sys.stderr)
             return 1
 
+    from .router import ChipHeldByParentError
     stop = threading.Event()
-    handle = create_server(
-        params, cfg, host=args.host, port=args.port,
-        num_slots=args.num_slots, decode_chunk=args.decode_chunk,
-        max_queue=args.max_queue, request_timeout=args.request_timeout,
-        default_deadline=getattr(args, "default_deadline"),
-        dispatch_timeout=getattr(args, "dispatch_timeout"),
-        max_restarts=getattr(args, "max_restarts"),
-        metrics_dir=args.metrics_dir or os.path.join(args.ckpt, "serve"),
-        info=info, stop_event=stop, page_size=args.page_size,
-        kv_pages=args.kv_pages, spec_tokens=args.spec_tokens,
-        replicas=args.replicas,
-        failover_retries=getattr(args, "failover_retries"),
-        reload_source=reload_source,
-        warmup=not getattr(args, "no_warmup"),
-        program_cache_dir=getattr(args, "program_cache_dir"),
-        out_of_process=getattr(args, "out_of_process"),
-        autoscale=getattr(args, "autoscale"),
-        min_replicas=getattr(args, "min_replicas"),
-        max_replicas=getattr(args, "max_replicas"),
-        autoscale_interval_s=getattr(args, "autoscale_interval"),
-        worker_startup_timeout_s=getattr(args, "worker_startup_timeout"),
-        quotas=quotas, preempt=getattr(args, "preempt"))
+    try:
+        handle = create_server(
+            params, cfg, host=args.host, port=args.port,
+            num_slots=args.num_slots, decode_chunk=args.decode_chunk,
+            max_queue=args.max_queue,
+            request_timeout=args.request_timeout,
+            default_deadline=getattr(args, "default_deadline"),
+            dispatch_timeout=getattr(args, "dispatch_timeout"),
+            max_restarts=getattr(args, "max_restarts"),
+            metrics_dir=(args.metrics_dir
+                         or os.path.join(args.ckpt, "serve")),
+            info=info, stop_event=stop, page_size=args.page_size,
+            kv_pages=args.kv_pages, spec_tokens=args.spec_tokens,
+            replicas=args.replicas,
+            failover_retries=getattr(args, "failover_retries"),
+            reload_source=reload_source,
+            warmup=not getattr(args, "no_warmup"),
+            program_cache_dir=getattr(args, "program_cache_dir"),
+            out_of_process=getattr(args, "out_of_process"),
+            autoscale=getattr(args, "autoscale"),
+            min_replicas=getattr(args, "min_replicas"),
+            max_replicas=getattr(args, "max_replicas"),
+            autoscale_interval_s=getattr(args, "autoscale_interval"),
+            worker_startup_timeout_s=getattr(args,
+                                             "worker_startup_timeout"),
+            quotas=quotas, preempt=getattr(args, "preempt"))
+    except ChipHeldByParentError as e:
+        # --out-of-process from a parent that holds the TPU: refuse in
+        # one line instead of spawning workers that wait for the chip
+        print(f"gym_tpu.serve: {e}", file=sys.stderr)
+        return 1
     httpd, metrics, router = handle.httpd, handle.metrics, handle.router
 
     watcher = None

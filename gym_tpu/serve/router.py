@@ -91,6 +91,20 @@ class NoHealthyReplicaError(RuntimeError):
         self.retry_after_s = float(retry_after_s)
 
 
+class ChipHeldByParentError(RuntimeError):
+    """A process fleet was asked for by a parent whose params sit on a
+    TPU while its workers are not pinned to another backend. A chip
+    belongs to one process: the workers would hang or fail at backend
+    init. Raised before anything is spawned."""
+
+
+def _params_on_tpu(params: Any) -> bool:
+    import jax
+    return any(isinstance(x, jax.Array)
+               and any(d.platform == "tpu" for d in x.devices())
+               for x in jax.tree.leaves(params))
+
+
 class FleetReloadError(RuntimeError):
     """A rolling weight reload could not proceed: one is already in
     flight (``retry_after_s`` is None → HTTP 409), or a replica failed
@@ -752,6 +766,16 @@ class WorkerSpawner:
                  env: Optional[Dict[str, str]] = None,
                  quotas_json: Optional[str] = None,
                  preempt: bool = False):
+        worker_platforms = ("cpu" if device == "cpu" else (env or {}).get(
+            "JAX_PLATFORMS", os.environ.get("JAX_PLATFORMS", "")))
+        if _params_on_tpu(params) and (not worker_platforms
+                                or "tpu" in worker_platforms.split(",")):
+            raise ChipHeldByParentError(
+                "this process holds the TPU (the params it restored live "
+                "there) and the workers it would spawn are not pinned to "
+                "another backend, so each would wait for the same chip. "
+                "Serve in-process (drop --out-of-process), or pin the "
+                "workers elsewhere (worker_env={'JAX_PLATFORMS': 'cpu'})")
         self.base_dir = os.path.abspath(base_dir)
         os.makedirs(self.base_dir, exist_ok=True)
         self.params_file: Optional[str] = None

@@ -132,11 +132,11 @@ def _frame_crc(frame: Dict[str, Any]) -> int:
     settings — both ends must agree on the bytes being summed, and a
     decoded dict no longer remembers the wire bytes it came from.
 
-    zlib's C crc32 rather than the sidecars' pure-Python crc32c: this
-    runs per frame on the token streaming hot path, where the Python
-    table walk (~12 µs/frame, measured) cost the subprocess fleet its
-    throughput edge over the thread fleet. Checkpoint sidecars keep
-    crc32c — they hash megabytes once per save, not bytes per token."""
+    zlib's C crc32 rather than the sidecars' crc32c, whose C
+    implementation is not installed everywhere: this runs per frame on
+    the token streaming hot path, where the pure-Python table walk
+    (~12 µs/frame, measured) cost the subprocess fleet its throughput
+    edge over the thread fleet."""
     body = {k: v for k, v in frame.items() if k != "crc"}
     return zlib.crc32(json.dumps(
         body, sort_keys=True, separators=(",", ":")).encode())

@@ -463,12 +463,10 @@ def main(argv=None) -> int:
             f"with faults: {per_replica}\n")
 
     from .. import programs as programs_mod
-    if args.program_cache_dir or os.environ.get(
-            "GYM_TPU_PROGRAM_CACHE_DIR"):
-        resolved = programs_mod.enable_disk_tier(args.program_cache_dir)
-        sys.stderr.write(
-            f"gym_tpu.serve.worker: program registry disk tier at "
-            f"{resolved}\n")
+    resolved = programs_mod.enable_disk_tier(args.program_cache_dir)
+    sys.stderr.write(
+        f"gym_tpu.serve.worker: program registry disk tier at "
+        f"{resolved}\n")
 
     from ..models.nanogpt import GPTConfig
     from .engine import InferenceEngine

@@ -78,8 +78,7 @@ def train_segment(out: str, max_steps: int) -> None:
         val_interval=0, show_progress=False, seed=1,
         checkpoint_interval=_CKPT_INTERVAL,
         save_dir=os.path.join(out, "ckpts"), run_name="drill",
-        log_dir=os.path.join(out, "logs"), resume="auto",
-        compilation_cache_dir=os.path.join(out, "xla_cache"))
+        log_dir=os.path.join(out, "logs"), resume="auto")
 
 
 def _spawn_trainer(out: str, steps: int) -> subprocess.Popen:
@@ -165,7 +164,6 @@ def run_drill(out: str, *, replicas: int = 2,
         params, cfg, host="127.0.0.1", port=0, num_slots=2,
         replicas=replicas, metrics_dir=os.path.join(out, "serve"),
         info=info, reload_source=reload_source,
-        program_cache_dir=os.path.join(out, "progcache"),
         out_of_process=out_of_process,
         fleet_dir=os.path.join(out, "fleet"),
         worker_startup_timeout_s=startup_timeout_s)

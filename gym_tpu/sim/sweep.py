@@ -327,7 +327,6 @@ def _run_event_cell(cell: Cell, cfg: SweepConfig) -> Dict[str, Any]:
         run_name=cell.cell_id, log_dir=os.path.join(cfg.out, "logs"),
         save_dir=os.path.join(cfg.out, "ckpt", cell.cell_id),
         checkpoint_interval=cfg.checkpoint_interval, resume="auto",
-        compilation_cache_dir=os.path.join(cfg.out, "xla_cache"),
     )
 
     def _seg(num_nodes, max_steps):
@@ -447,7 +446,6 @@ def run_cell(cell: Cell, cfg: SweepConfig) -> Dict[str, Any]:
         save_dir=os.path.join(cfg.out, "ckpt", cell.cell_id),
         checkpoint_interval=cfg.checkpoint_interval,
         resume="auto",
-        compilation_cache_dir=os.path.join(cfg.out, "xla_cache"),
     )
     if res.preempted:
         raise KeyboardInterrupt(
@@ -755,10 +753,9 @@ def main(argv: Optional[List[str]] = None) -> int:
     p.add_argument("--out", default=os.path.join("logs", "sim_sweep"))
     p.add_argument("--device", default="cpu",
                    help="jax platform for the measured fits (default cpu: "
-                        "the sweep workload is host-sized, and pinning "
-                        "the platform list avoids hanging on a dead "
-                        "accelerator transport; pass 'auto' to use the "
-                        "default backend)")
+                        "the sweep workload is host-sized and leaves the "
+                        "chip to whoever holds it; pass 'auto' to use "
+                        "the default backend)")
     args = p.parse_args(argv)
 
     if args.device and args.device != "auto":

@@ -177,7 +177,7 @@ def make_multi_train_step(loss_model: LossModel, strategy: Strategy,
     leading [S] axis.
 
     TPU-native throughput lever with no reference analog: host→device
-    dispatch latency (significant over remote transports) is amortized over
+    dispatch latency is amortized over
     S compiled steps chained by ``lax.scan``, keeping the chip busy
     back-to-back. Semantics are identical to S single dispatches — the
     per-step strategy schedule (H gates, step counter) advances inside the

@@ -50,6 +50,14 @@ import sys
 import zlib
 from typing import Any, Dict, List, Optional, Tuple
 
+try:
+    # C implementation, ~4 GB/s; installed with orbax's storage stack
+    import google_crc32c
+except ImportError:
+    # same values from the pure-Python loop below at ~4 MB/s: enough
+    # for tests, not for a checkpoint of a real model
+    google_crc32c = None
+
 PyTree = Any
 
 SIDECAR_NAME = "integrity.json"
@@ -78,9 +86,18 @@ _T = _build_tables()
 
 
 def crc32c(data: bytes, crc: int = 0) -> int:
-    """crc32c of ``data`` (chainable via ``crc``). Slicing-by-8: 8 bytes
-    per loop iteration keeps pure-Python hashing usable on multi-MB
-    checkpoint shards."""
+    """crc32c of ``data`` (chainable via ``crc``). The first chip run at
+    GPT-2 base width spent 22 minutes hashing one 9.3 GB checkpoint in
+    the Python loop, so the C implementation is used where it is
+    installed; ``crc32c_reference`` is what it must equal."""
+    if google_crc32c is not None:
+        return google_crc32c.extend(crc, bytes(data))
+    return crc32c_reference(data, crc)
+
+
+def crc32c_reference(data: bytes, crc: int = 0) -> int:
+    """Pure-Python crc32c, slicing-by-8: the reference the C path is
+    tested against and the fallback where that is not installed."""
     t0, t1, t2, t3, t4, t5, t6, t7 = _T
     crc = (crc ^ 0xFFFFFFFF) & 0xFFFFFFFF
     mv = memoryview(data)

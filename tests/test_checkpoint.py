@@ -187,11 +187,6 @@ def test_cross_topology_restore_pp2_tp2_to_pp1(tmp_path):
 
     if len(jax.devices()) < 8:
         pytest.skip("needs 8 devices (node=2 x model=2 x pipe=2)")
-    if not hasattr(jax, "shard_map"):
-        # jax 0.4.x: the manual('pipe') x GSPMD-auto('model') composition
-        # trips "PartitionId instruction is not supported for SPMD
-        # partitioning" in the legacy partial-auto shard_map partitioner
-        pytest.skip("pp x tp partial-auto shard_map needs jax >= 0.5")
 
     rng = np.random.default_rng(8)
     data = rng.integers(0, 32, 4096, dtype=np.int64)

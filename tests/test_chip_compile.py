@@ -1,0 +1,136 @@
+"""The main path's kernels, compiled by the chip's own compiler at real
+widths for a TPU v5e that is described, not attached
+(``jax.experimental.topologies``; ``on-chip-measurement`` guide §2.3).
+
+Interpret-mode tests cannot see what the chip's compiler refuses: a slice
+that is not aligned to the tiling, a kernel that wants more scoped VMEM
+than it may use, a program that does not fit 16 GB. These compiles can,
+at no chip time, a second or two each. Nothing runs: a compile that
+passes is not a chip run.
+
+The platform checks inside the ops (``flash_attention._on_tpu``) still see
+the CPU here, so the tests call the kernels themselves or steer that one
+check with ``monkeypatch``.
+"""
+
+import functools
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from gym_tpu.ops import flash_attention, fused_attention
+from gym_tpu.ops.dct import codec_for, sparse_decode_chunks
+from gym_tpu.ops.grouped_matmul import quant_tile_for, quantized_dot
+from gym_tpu.ops.topk_compress import topk_compress
+
+HBM_BYTES = 16 * 1024 ** 3
+
+
+@pytest.fixture(scope="module")
+def v5e_chip():
+    """Sharding on one chip of a described ``v5e:2x2`` host. The compile
+    cache is off around the module: a compile for a described device is
+    written to it but cannot be read back without a chip."""
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler installed
+        pytest.skip(f"cannot describe a v5e:2x2 topology here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _compile(fn, chip, *shapes):
+    args = [jax.ShapeDtypeStruct(s, d, sharding=chip) for s, d in shapes]
+    compiled = jax.jit(fn).lower(*args).compile()
+    mem = compiled.memory_analysis()
+    assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes) < HBM_BYTES
+    return compiled.as_text()
+
+
+def _fwd_bwd(attend):
+    """Forward and backward of ``attend(q, k, v)`` in one program."""
+    def fn(q, k, v):
+        return jax.value_and_grad(
+            lambda *a: attend(*a).astype(jnp.float32).sum(),
+            argnums=(0, 1, 2))(q, k, v)
+    return fn
+
+
+def _block(causal):
+    def attend(q, k, v):
+        o, lse = fused_attention.fused_block_attention(q, k, v, causal)
+        return o.astype(jnp.float32) + lse      # both outputs carry grads
+    return attend
+
+
+# GPT-2 base trains at [B=16, H=12, T=1024, D=64]; a ring step at cp=2
+# sees half that sequence per device; the toy cell is 4 heads of 32.
+BASE = (16, 12, 1024, 64)
+KERNELS = {
+    "fused_per_head_bf16": (
+        _fwd_bwd(fused_attention.fused_causal_attention),
+        [(BASE, jnp.bfloat16)] * 3),
+    "fused_per_head_f32": (
+        _fwd_bwd(fused_attention.fused_causal_attention),
+        [(BASE, jnp.float32)] * 3),
+    "ring_block_full_bf16": (
+        _fwd_bwd(_block(False)), [((4, 12, 512, 64), jnp.bfloat16)] * 3),
+    "ring_block_causal_bf16": (
+        _fwd_bwd(_block(True)), [((4, 12, 512, 64), jnp.bfloat16)] * 3),
+    "fused_packed_toy_bf16": (
+        _fwd_bwd(functools.partial(
+            fused_attention.fused_causal_attention_packed, n_head=4)),
+        [((16, 256, 128), jnp.bfloat16)] * 3),
+}
+
+
+@pytest.mark.parametrize("name", sorted(KERNELS))
+def test_pallas_attention_kernel_compiles_for_v5e(v5e_chip, name):
+    fn, shapes = KERNELS[name]
+    assert "tpu_custom_call" in _compile(fn, v5e_chip, *shapes)
+
+
+def test_flash_dispatch_compiles_tuned_blocks_at_2048(v5e_chip,
+                                                      monkeypatch):
+    """T=2048 is past the whole-context kernel, so the ``flash`` entry
+    takes JAX's bundled kernel with the block sizes tuned in
+    ``ops/flash_attention.py`` — forward and backward."""
+    monkeypatch.setattr(flash_attention, "_on_tpu", lambda: True)
+    hlo = _compile(_fwd_bwd(flash_attention.flash_causal_attention),
+                   v5e_chip, *[((2, 12, 2048, 64), jnp.bfloat16)] * 3)
+    assert "tpu_custom_call" in hlo
+
+
+def test_quantized_dot_compiles_at_gpt2_base_mlp(v5e_chip):
+    """The int8 MLP up-projection of GPT-2 base (768 x 3072) under a
+    decode batch: the dequantize must fuse, not overflow anything."""
+    tile = quant_tile_for((768, 3072), 256)
+    _compile(quantized_dot, v5e_chip, ((8, 768), jnp.float32),
+             ((768, 3072), jnp.int8), ((768 * 3072 // tile,), jnp.float32))
+
+
+@pytest.mark.parametrize("shape", [(768, 3072), (50304, 768)])
+def test_demo_dct_topk_compiles_at_gpt2_base_leaves(v5e_chip, shape):
+    """DeMo's per-leaf path at its defaults (chunk 64, top 32): chunked
+    DCT, top-k of every chunk, sparse decode — at GPT-2 base's MLP
+    kernel and its embedding table."""
+    codec = codec_for(shape, 64)
+
+    def fn(x):
+        idx, val = topk_compress(codec.encode(x), 32)
+        tiles = sparse_decode_chunks(idx, val, codec.d_a, codec.d_b)
+        return codec.from_chunks(tiles)
+
+    _compile(fn, v5e_chip, (shape, jnp.float32))

@@ -22,6 +22,7 @@ import jax
 from gym_tpu.utils import integrity
 from gym_tpu.utils.checkpoint import CheckpointManager, restore_params
 from gym_tpu.utils.integrity import (ChecksumMismatchError, crc32c,
+                                     crc32c_reference,
                                      tree_fingerprint,
                                      tree_fingerprint_host,
                                      verify_sidecar, write_sidecar)
@@ -41,21 +42,39 @@ def _clean_faults():
 # -- crc32c ----------------------------------------------------------------
 
 
-def test_crc32c_reference_vector():
+# both implementations: the one in use (C where installed) and the
+# pure-Python reference it must equal
+_CRCS = pytest.mark.parametrize("crc", [crc32c, crc32c_reference])
+
+
+@_CRCS
+def test_crc32c_reference_vector(crc):
     # the canonical Castagnoli check value (RFC 3720 B.4)
-    assert crc32c(b"123456789") == 0xE3069283
-    assert crc32c(b"") == 0
+    assert crc(b"123456789") == 0xE3069283
+    assert crc(b"") == 0
     # chaining == one-shot (streamed file hashing depends on it)
     data = bytes(range(256)) * 41  # deliberately not 8-aligned
-    assert crc32c(data) == crc32c(data[100:], crc32c(data[:100]))
+    assert crc(data) == crc(data[100:], crc(data[:100]))
 
 
-def test_crc32c_detects_single_bitflip():
+@_CRCS
+def test_crc32c_detects_single_bitflip(crc):
     data = os.urandom(4096)
-    ref = crc32c(data)
+    ref = crc(data)
     flipped = bytearray(data)
     flipped[1234] ^= 0x10
-    assert crc32c(bytes(flipped)) != ref
+    assert crc(bytes(flipped)) != ref
+
+
+def test_crc32c_in_use_equals_the_reference():
+    """Whatever computes the sidecars' crc32c (the C implementation
+    where it is installed) must agree with the reference, one-shot and
+    chained, on bytes and on other buffers."""
+    data = os.urandom(50_001)
+    want = crc32c_reference(data)
+    assert crc32c(data) == want
+    assert crc32c(memoryview(data)) == crc32c(bytearray(data)) == want
+    assert crc32c(data[777:], crc32c(data[:777])) == want
 
 
 # -- checkpoint sidecars ---------------------------------------------------

@@ -274,11 +274,7 @@ def test_moe_expert_parallel_matches_single_device():
     def loss_ep(p):
         return model_ep.apply({"params": p}, batch, train=False)
 
-    # jax >= 0.6 spells the ambient-mesh context jax.sharding.set_mesh;
-    # on 0.4.x entering the Mesh itself binds the resource env that
-    # with_sharding_constraint resolves axis names against
-    _set_mesh = getattr(jax.sharding, "set_mesh", None)
-    with (_set_mesh(mesh) if _set_mesh is not None else mesh):
+    with jax.sharding.set_mesh(mesh):
         ep = float(jax.jit(loss_ep)(sharded_params))
     # rtol 2e-5: the EP partition reduces the combine in a different
     # order than the unsharded program; the drift is reduction-order
@@ -288,7 +284,6 @@ def test_moe_expert_parallel_matches_single_device():
 
 import functools
 
-from conftest import needs_partial_auto
 
 
 @functools.lru_cache(maxsize=8)  # the (1,1,1) baseline is shared by cases
@@ -326,7 +321,6 @@ def _fit_moe_losses(tp: int, ep: int, cp: int = 1):
 @pytest.mark.parametrize("tp,ep,cp", [(1, 2, 1), (2, 2, 1), (1, 2, 2),
                                       (2, 2, 2)])  # 4-axis: needs 16 devs
 @pytest.mark.slow
-@needs_partial_auto
 def test_moe_fit_sharded_matches_unsharded(tp, ep, cp):
     """Trainer-level expert parallelism — fit(ep=2) on a ('node','expert')
     mesh — plus the hybrid TP×EP ('node','model','expert'), CP×EP

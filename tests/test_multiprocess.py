@@ -23,15 +23,6 @@ import sys
 import numpy as np
 import pytest
 
-# jax 0.4.x's CPU backend has no multi-process array support at all
-# ("Multiprocess computations aren't implemented on the CPU backend" at
-# the first non-addressable device_put) — these worlds need jax >= 0.5.
-import jax
-
-needs_multiprocess_cpu = pytest.mark.skipif(
-    not hasattr(jax, "shard_map"),
-    reason="multi-process CPU arrays need jax >= 0.5")
-
 
 def _free_port() -> int:
     with socket.socket() as s:
@@ -171,7 +162,6 @@ def _reference_fit_histories(tmp: str):
 
 
 @pytest.mark.slow
-@needs_multiprocess_cpu
 def test_two_process_trainer_fit_matches_single_process(tmp_path):
     """VERDICT r3 #1: ``Trainer.fit`` ITSELF runs in a multi-process
     world — both processes call fit() unmodified and must reproduce the
@@ -227,7 +217,6 @@ def test_two_process_trainer_fit_matches_single_process(tmp_path):
 
 
 @pytest.mark.slow
-@needs_multiprocess_cpu
 def test_two_process_world_matches_single_process():
     port = _free_port()
     env = dict(os.environ)

@@ -13,16 +13,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:  # jax >= 0.6 promoted shard_map out of experimental
-    shard_map = jax.shard_map
-except AttributeError:  # jax 0.4.x (whose check_rep chokes on scan carries)
-    from jax.experimental.shard_map import shard_map as _shard_map_legacy
+shard_map = jax.shard_map
 
-    def shard_map(f, **kw):
-        kw.pop("check_vma", None)  # the new-API spelling of check_rep
-        return _shard_map_legacy(f, check_rep=False, **kw)
-
-from conftest import needs_partial_auto
 
 from gym_tpu.parallel.pipeline import (apply_stage_layers, pipeline_apply,
                                        stack_stage_params, take_stage)
@@ -479,7 +471,6 @@ def test_fit_pp2_moe_matches_pp1():
 
 
 @pytest.mark.slow
-@needs_partial_auto
 def test_fit_pp2_ep2_matches_unsharded():
     """pp x ep: a ('node','expert','pipe') mesh — GPipe stages manual
     over 'pipe' while the GSPMD-auto 'expert' axis shards each stage's
@@ -508,7 +499,6 @@ def test_fit_pp_rejects_stage_misaligned_moe():
 
 
 @pytest.mark.slow
-@needs_partial_auto
 def test_fit_pp2_tp2_matches_unsharded():
     """pp x tp: a ('node','model','pipe') mesh — GPipe stages manual over
     'pipe' while GSPMD Megatron-shards each stage's matmuls over the auto

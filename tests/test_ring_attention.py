@@ -13,14 +13,7 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:  # jax >= 0.6 promoted shard_map out of experimental
-    shard_map = jax.shard_map
-except AttributeError:  # jax 0.4.x (whose check_rep chokes on scan carries)
-    from jax.experimental.shard_map import shard_map as _shard_map_legacy
-
-    def shard_map(f, **kw):
-        kw.pop("check_vma", None)  # the new-API spelling of check_rep
-        return _shard_map_legacy(f, check_rep=False, **kw)
+shard_map = jax.shard_map
 
 from gym_tpu import Trainer
 from gym_tpu.data import ArrayDataset
