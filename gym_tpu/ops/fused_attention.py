@@ -189,6 +189,7 @@ def _blk_fwd(q, k, v, scale, causal):
             jax.ShapeDtypeStruct((b, h, t, 1), jnp.float32),
         ],
         interpret=INTERPRET,
+        name="attn_fwd_blk",
     )(q, k, v)
 
 
@@ -202,6 +203,7 @@ def _blk_bwd(q, k, v, o, do, lse, dlse, scale, causal):
         out_specs=[_bh_spec(bc, t, d)] * 3,
         out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype)] * 3,
         interpret=INTERPRET,
+        name="attn_bwd_blk",
     )(q, k, v, o, do, lse, dlse)
 
 
@@ -325,6 +327,7 @@ def _fwd_packed(q, k, v, scale, nh):
             jax.ShapeDtypeStruct((b, t, nh), jnp.float32),
         ],
         interpret=INTERPRET,
+        name="attn_fwd",
     )(q, k, v)
 
 
@@ -339,6 +342,7 @@ def _bwd_packed(q, k, v, o, do, lse, scale, nh):
         out_specs=[blk] * 3,
         out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype)] * 3,
         interpret=INTERPRET,
+        name="attn_bwd",
     )(q, k, v, o, do, lse)
 
 

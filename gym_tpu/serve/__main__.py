@@ -311,6 +311,7 @@ def create_server(params, cfg, *, host: str = "127.0.0.1", port: int = 0,
     restart drill)."""
     from ..data.build_dataset import CHAR_VOCAB
     from ..utils.checkpoint import CheckpointNotFoundError
+    from ..utils import trace
     from ..utils.resilience import fault_point
     from .autoscale import AutoscalePolicy, Autoscaler
     from .engine import SamplingParams
@@ -560,6 +561,10 @@ def create_server(params, cfg, *, host: str = "127.0.0.1", port: int = 0,
                 # THE restart-drill observable (0 across a restart with
                 # a warm disk tier) — plus background-warmup progress
                 "programs_compiled": programs_mod.xla_compile_counter(),
+                # the program's spans so far: {name: [count, total_s,
+                # max_s]} (utils/trace.py; names in PERF.md §3)
+                "spans": trace.totals(),
+                "readback_bytes": sum(s.readback_bytes for s in stats),
                 "warmup": (warm_thread.stats()
                            if warm_thread is not None else None),
                 # multi-tenant serving (ISSUE 17): live quota fill,
@@ -626,6 +631,11 @@ def create_server(params, cfg, *, host: str = "127.0.0.1", port: int = 0,
             if self.path != "/generate":
                 self._reply(404, {"error": f"unknown path {self.path}"})
                 return
+            # to the last byte written; `request` joins it once known
+            with trace.span("http.generate") as http_span:
+                self._generate(http_span)
+
+        def _generate(self, http_span):
             try:
                 fault_point("serve.http")
                 n = int(self.headers.get("Content-Length", 0))
@@ -717,6 +727,7 @@ def create_server(params, cfg, *, host: str = "127.0.0.1", port: int = 0,
                 self._reply(503, {"error": f"{type(e).__name__}: {e}"},
                             retry_after_s=1.0)
                 return
+            http_span.ids["request"] = req.id
             # the handler's own wait honors the request deadline: even if
             # the driver is wedged (the watchdog will reap it), the
             # client gets its typed answer within deadline + grace

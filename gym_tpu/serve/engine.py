@@ -74,6 +74,7 @@ from ..programs.serve_defs import (cow_def, paged_decode_def,
                                    slot_admit_def, slot_decode_def,
                                    spec_decode_def)
 from ..utils.resilience import fault_point
+from ..utils.trace import span
 
 PyTree = Any
 
@@ -161,6 +162,8 @@ class EngineStats:
     #                                      work-elision observable
     active_slots: int = 0
     num_slots: int = 0
+    readback_bytes: int = 0              # cumulative bytes decode steps read
+    #                                      back from the device
     quarantined: int = 0                 # slots shut down on NaN/Inf logits
     # paged-KV observables (0 on an unpaged engine)
     kv_blocks_in_use: int = 0            # pages referenced by live slots
@@ -760,36 +763,40 @@ class InferenceEngine:
         Returns ``(slot, event)``; when the first token already finishes
         the request (``max_new_tokens == 1`` or instant EOS) the slot is
         released before returning."""
-        prompt = np.asarray(prompt, np.int32).reshape(-1)
-        self.validate(prompt, sp)
-        free = self.free_slots()
-        if not free:
-            raise NoFreeSlotError(
-                "no free slot — admit() requires one (scheduler bug: "
-                "check free_slots() first)")
-        slot = free[0]
-        fault_point("serve.prefill")
-        n = len(prompt)
-        base_key = np.asarray(jax.random.PRNGKey(sp.seed), np.uint32)
-        top_k = (self.config.vocab_size if sp.top_k is None
-                 else int(sp.top_k))
-        top_p = 1.0 if sp.top_p is None else float(sp.top_p)
+        with span("serve.prefill.args"):
+            prompt = np.asarray(prompt, np.int32).reshape(-1)
+            self.validate(prompt, sp)
+            free = self.free_slots()
+            if not free:
+                raise NoFreeSlotError(
+                    "no free slot — admit() requires one (scheduler bug: "
+                    "check free_slots() first)")
+            slot = free[0]
+            fault_point("serve.prefill")
+            n = len(prompt)
+            base_key = np.asarray(jax.random.PRNGKey(sp.seed), np.uint32)
+            top_k = (self.config.vocab_size if sp.top_k is None
+                     else int(sp.top_k))
+            top_p = 1.0 if sp.top_p is None else float(sp.top_p)
         if self.paged:
             first = self._prefill_paged(slot, prompt, sp, base_key,
                                         top_k, top_p)
         else:
-            bucket = prompt_bucket(n, self.block_size)
-            self._seen_buckets.add(bucket)
-            prefill = self._prefill_prog(bucket)
-            padded = np.zeros((1, bucket), np.int32)
-            padded[0, :n] = prompt
-            tok, row_cache = prefill(
-                self.params, jnp.asarray(padded), np.int32(n),
-                jnp.asarray(base_key), np.float32(sp.temperature),
-                np.int32(top_k), np.float32(top_p))
-            self._cache = self._admit_prog(self._cache, row_cache,
-                                           np.int32(slot), np.int32(n))
-            first = int(np.asarray(tok)[0])
+            with span("serve.prefill.args"):
+                bucket = prompt_bucket(n, self.block_size)
+                self._seen_buckets.add(bucket)
+                prefill = self._prefill_prog(bucket)
+                padded = np.zeros((1, bucket), np.int32)
+                padded[0, :n] = prompt
+                args = (self.params, jnp.asarray(padded), np.int32(n),
+                        jnp.asarray(base_key), np.float32(sp.temperature),
+                        np.int32(top_k), np.float32(top_p))
+            with span("serve.prefill.dispatch"):
+                tok, row_cache = prefill(*args)
+                self._cache = self._admit_prog(self._cache, row_cache,
+                                               np.int32(slot), np.int32(n))
+            with span("serve.prefill.readback"):
+                first = int(np.asarray(tok)[0])
             self.stats.prefill_tokens += bucket
         self.stats.prefills += 1
         self.stats.tokens_generated += 1
@@ -832,55 +839,58 @@ class InferenceEngine:
         n = len(prompt)
         page, al = self.page_size, self._alloc
         full = n // page
-        hit_pages, chain, cow_src, cid, start, suffix, bucket, n_new, \
-            need = self._plan_paged(prompt, sp.max_new_tokens)
         # `held` tracks every page reference this admission currently
         # owns; ANY failure past this point (capacity shortfall, a
         # compile/dispatch error in CoW or prefill) unwinds it exactly —
         # an admission that fails its request must not shrink the pool
         held: List[int] = []
         try:
-            # pin before the capacity check: a pinned page is neither
-            # evictable nor double-counted as supply
-            for pg in hit_pages:
-                al.incref(pg)
-                held.append(pg)
-            if cow_src is not None:
-                al.incref(cow_src)
-                held.append(cow_src)
-            if al.available() < need:
-                raise NoFreeBlocksError(
-                    f"paged KV pool cannot supply {need} blocks right "
-                    f"now — retry after running requests release")
-            row = np.zeros(self.max_blocks, np.int32)
-            row[:len(hit_pages)] = hit_pages
-            next_b = len(hit_pages)
-            if cow_src is not None:
-                dst = al.alloc()
-                held.append(dst)
-                row[next_b] = dst
-                next_b += 1
-                self._cache = self._cow_prog(
-                    self._cache, np.int32(cow_src), np.int32(dst))
-                al.decref(cow_src)       # pinned only for the copy
-                held.remove(cow_src)
-            for k in range(n_new):
-                pg = al.alloc()
-                held.append(pg)
-                row[next_b + k] = pg
-            self._bt[slot] = 0
-            self._bt[slot, :next_b + n_new] = row[:next_b + n_new]
-            self._seen_buckets.add(bucket)
-            prefill = self._prefill_prog(bucket)
-            padded = np.zeros((1, bucket), np.int32)
-            padded[0, :suffix] = prompt[start:]
-            tok, self._cache = prefill(
-                self.params, self._cache,
-                jnp.asarray(self._bt[slot][None]),
-                jnp.asarray(np.asarray([start], np.int32)),
-                jnp.asarray(padded), np.int32(suffix),
-                jnp.asarray(base_key), np.float32(sp.temperature),
-                np.int32(top_k), np.float32(top_p))
+            with span("serve.prefill.args"):
+                hit_pages, chain, cow_src, cid, start, suffix, bucket, \
+                    n_new, need = self._plan_paged(prompt,
+                                                   sp.max_new_tokens)
+                # pin before the capacity check: a pinned page is neither
+                # evictable nor double-counted as supply
+                for pg in hit_pages:
+                    al.incref(pg)
+                    held.append(pg)
+                if cow_src is not None:
+                    al.incref(cow_src)
+                    held.append(cow_src)
+                if al.available() < need:
+                    raise NoFreeBlocksError(
+                        f"paged KV pool cannot supply {need} blocks right "
+                        f"now — retry after running requests release")
+                row = np.zeros(self.max_blocks, np.int32)
+                row[:len(hit_pages)] = hit_pages
+                next_b = len(hit_pages)
+                if cow_src is not None:
+                    dst = al.alloc()
+                    held.append(dst)
+                    row[next_b] = dst
+                    next_b += 1
+                    self._cache = self._cow_prog(
+                        self._cache, np.int32(cow_src), np.int32(dst))
+                    al.decref(cow_src)       # pinned only for the copy
+                    held.remove(cow_src)
+                for k in range(n_new):
+                    pg = al.alloc()
+                    held.append(pg)
+                    row[next_b + k] = pg
+                self._bt[slot] = 0
+                self._bt[slot, :next_b + n_new] = row[:next_b + n_new]
+                self._seen_buckets.add(bucket)
+                prefill = self._prefill_prog(bucket)
+                padded = np.zeros((1, bucket), np.int32)
+                padded[0, :suffix] = prompt[start:]
+                args = (jnp.asarray(self._bt[slot][None]),
+                        jnp.asarray(np.asarray([start], np.int32)),
+                        jnp.asarray(padded), np.int32(suffix),
+                        jnp.asarray(base_key), np.float32(sp.temperature),
+                        np.int32(top_k), np.float32(top_p))
+            with span("serve.prefill.dispatch"):
+                tok, self._cache = prefill(self.params, self._cache,
+                                           *args)
         except BaseException:
             for pg in held:
                 al.decref(pg)
@@ -909,7 +919,8 @@ class InferenceEngine:
         self.stats.prefill_tokens += bucket
         self.stats.kv_blocks_in_use = al.in_use()
         self.stats.kv_blocks_cached = al.cached()
-        return int(np.asarray(tok)[0])
+        with span("serve.prefill.readback"):
+            return int(np.asarray(tok)[0])
 
     def _release_pages(self, slot: int) -> None:
         """Drop this slot's block-table references (idempotent: an
@@ -1056,98 +1067,110 @@ class InferenceEngine:
         # hit-counted AFTER the idle early-out so hit N is the Nth REAL
         # decode dispatch — "hang at dispatch 2" reproduces exactly
         fault_point("serve.decode")
-        was_active = self._active.copy()
-        remaining = (self._max_new - self._generated).astype(np.int32)
-        tail = (jnp.asarray(self._next_tok), jnp.asarray(self._active),
-                jnp.asarray(self._base_keys), jnp.asarray(self._gen_idx),
-                jnp.asarray(remaining),
-                jnp.asarray(self._eos.astype(np.int32)),
-                jnp.asarray(self._temp), jnp.asarray(self._top_k),
-                jnp.asarray(self._top_p))
-        if self.paged:
-            head = (self.params, self._cache, jnp.asarray(self._bt))
-            if spec_run:
-                head += (jnp.asarray(self._hist),)
-            tok_a, act_a, keys_a, gidx_a, rem_a, eos_a, t_a, k_a, p_a = \
-                tail
-            toks, emitted, lg, final_tok, final_active, final_pos, \
-                nan_seen, cache = prog(*head, tok_a, act_a,
-                                       jnp.asarray(self._pos), keys_a,
-                                       gidx_a, rem_a, eos_a, t_a, k_a,
-                                       p_a)
-            self._pos = np.asarray(final_pos).astype(np.int32).copy()
-            nan_seen = np.asarray(nan_seen)
-        else:
-            toks, emitted, lg, final_tok, final_active, cache = prog(
-                self.params, self._cache, *tail)
-            nan_seen = None
-        self._cache = cache
-        toks = np.asarray(toks)
-        emitted = np.asarray(emitted)
-        if toks.ndim == 2:
-            # non-speculative programs emit one token per scanned step;
-            # widen to the speculative [chunk, S, γ+1] layout so ONE host
-            # replay path routes both
-            toks = toks[..., None]
-            emitted = emitted[..., None]
-        self.last_logits = np.asarray(lg)
-        self._next_tok = np.asarray(final_tok).astype(np.int32).copy()
-        self._active = np.asarray(final_active).copy()
-        # numerical quarantine: non-finite logits fail ONLY their own
-        # slot — the model's per-row cache math keeps rows isolated (and
-        # the decode attends NaN-poison an overflowing row/position on
-        # purpose, so this is the designated catch point). Unpaged, the
-        # check reads the LAST scanned step's logits for every slot
-        # that emitted anywhere in this chunk: a poisoned slot that
-        # finishes mid-chunk goes inactive but keeps attending its own
-        # NaN cache rows, so the poison stays visible in the final
-        # logits. Paged, that witness FAILS — a finished row's table is
-        # redirected to the null page, so its later iterations read
-        # clean garbage — and the programs instead LATCH non-finite
-        # logits per iteration while the row is active (`nan_seen`).
-        if nan_seen is not None:
-            bad = nan_seen
-        else:
-            bad = emitted.any(axis=(0, 2)) & ~np.isfinite(
-                self.last_logits).all(axis=1)
-        for slot in np.nonzero(bad)[0]:
-            self._active[slot] = False           # quarantine = evict
-            self.stats.quarantined += 1
-        events: List[TokenEvent] = []
-        n_steps = toks.shape[0]
-        for k in range(n_steps):
-            for slot in np.nonzero(emitted[k].any(axis=1))[0]:
+        with span("serve.decode.args"):
+            was_active = self._active.copy()
+            remaining = (self._max_new - self._generated).astype(np.int32)
+            tail = (jnp.asarray(self._next_tok), jnp.asarray(self._active),
+                    jnp.asarray(self._base_keys),
+                    jnp.asarray(self._gen_idx), jnp.asarray(remaining),
+                    jnp.asarray(self._eos.astype(np.int32)),
+                    jnp.asarray(self._temp), jnp.asarray(self._top_k),
+                    jnp.asarray(self._top_p))
+            if self.paged:
+                head = (self.params, self._cache, jnp.asarray(self._bt))
                 if spec_run:
-                    # acceptance accounting: γ drafted per active slot
-                    # per iteration; all emitted beyond the one
-                    # guaranteed token were accepted drafts
-                    self.stats.spec_drafted += self.spec_tokens
-                    self.stats.spec_accepted += int(
-                        emitted[k, slot].sum()) - 1
-                for j in np.nonzero(emitted[k, slot])[0]:
-                    tok = int(toks[k, slot, j])
-                    if self.paged:
-                        hl = (int(self._prompt_len[slot])
-                              + int(self._generated[slot]))
-                        if hl < self.block_size:
-                            self._hist[slot, hl] = tok
-                    self._gen_idx[slot] += 1
-                    self._generated[slot] += 1
-                    # finished iff the device stopped emitting for this
-                    # slot (its last emitted token) and it came back
-                    # inactive
-                    last_emit = (not emitted[k, slot, j + 1:].any()
-                                 and not emitted[k + 1:, slot].any())
-                    finished = bool(last_emit and not self._active[slot])
-                    events.append(TokenEvent(int(slot), tok, finished,
-                                             poisoned=bool(bad[slot])))
-        if self.paged:
-            # blocks of slots that finished (or were quarantined) this
-            # chunk go back to the allocator; shared prefix blocks stay
-            # resident for future hits
-            for slot in np.nonzero(was_active & ~self._active)[0]:
-                self._release_pages(slot)
-        self.stats.tokens_generated += len(events)
-        self.stats.decode_steps += int(was_active.any()) * n_steps
-        self.stats.active_slots = int(self._active.sum())
+                    head += (jnp.asarray(self._hist),)
+                tail = tail[:2] + (jnp.asarray(self._pos),) + tail[2:]
+            else:
+                head = (self.params, self._cache)
+        with span("serve.decode.dispatch"):
+            out = prog(*head, *tail)
+        with span("serve.decode.readback") as rb:
+            # the first read waits for the step; the logits are the bulk
+            if self.paged:
+                toks, emitted, lg, final_tok, final_active, final_pos, \
+                    nan_seen, cache = out
+                final_pos = np.asarray(final_pos)
+                nan_seen = np.asarray(nan_seen)
+                self._pos = final_pos.astype(np.int32).copy()
+                read = final_pos.nbytes + nan_seen.nbytes
+            else:
+                toks, emitted, lg, final_tok, final_active, cache = out
+                nan_seen, read = None, 0
+            self._cache = cache
+            toks = np.asarray(toks)
+            emitted = np.asarray(emitted)
+            self.last_logits = np.asarray(lg)
+            final_tok = np.asarray(final_tok)
+            final_active = np.asarray(final_active)
+            read += sum(a.nbytes for a in (toks, emitted, self.last_logits,
+                                           final_tok, final_active))
+            rb.ids["bytes"] = read
+            self.stats.readback_bytes += read
+        with span("serve.decode.events"):
+            if toks.ndim == 2:
+                # non-speculative programs emit one token per scanned step;
+                # widen to the speculative [chunk, S, γ+1] layout so ONE host
+                # replay path routes both
+                toks = toks[..., None]
+                emitted = emitted[..., None]
+            self._next_tok = final_tok.astype(np.int32).copy()
+            self._active = final_active.copy()
+            # numerical quarantine: non-finite logits fail ONLY their own
+            # slot — the model's per-row cache math keeps rows isolated (and
+            # the decode attends NaN-poison an overflowing row/position on
+            # purpose, so this is the designated catch point). Unpaged, the
+            # check reads the LAST scanned step's logits for every slot
+            # that emitted anywhere in this chunk: a poisoned slot that
+            # finishes mid-chunk goes inactive but keeps attending its own
+            # NaN cache rows, so the poison stays visible in the final
+            # logits. Paged, that witness FAILS — a finished row's table is
+            # redirected to the null page, so its later iterations read
+            # clean garbage — and the programs instead LATCH non-finite
+            # logits per iteration while the row is active (`nan_seen`).
+            if nan_seen is not None:
+                bad = nan_seen
+            else:
+                bad = emitted.any(axis=(0, 2)) & ~np.isfinite(
+                    self.last_logits).all(axis=1)
+            for slot in np.nonzero(bad)[0]:
+                self._active[slot] = False           # quarantine = evict
+                self.stats.quarantined += 1
+            events: List[TokenEvent] = []
+            n_steps = toks.shape[0]
+            for k in range(n_steps):
+                for slot in np.nonzero(emitted[k].any(axis=1))[0]:
+                    if spec_run:
+                        # acceptance accounting: γ drafted per active slot
+                        # per iteration; all emitted beyond the one
+                        # guaranteed token were accepted drafts
+                        self.stats.spec_drafted += self.spec_tokens
+                        self.stats.spec_accepted += int(
+                            emitted[k, slot].sum()) - 1
+                    for j in np.nonzero(emitted[k, slot])[0]:
+                        tok = int(toks[k, slot, j])
+                        if self.paged:
+                            hl = (int(self._prompt_len[slot])
+                                  + int(self._generated[slot]))
+                            if hl < self.block_size:
+                                self._hist[slot, hl] = tok
+                        self._gen_idx[slot] += 1
+                        self._generated[slot] += 1
+                        # finished iff the device stopped emitting for this
+                        # slot (its last emitted token) and it came back
+                        # inactive
+                        last_emit = (not emitted[k, slot, j + 1:].any()
+                                     and not emitted[k + 1:, slot].any())
+                        finished = bool(last_emit and not self._active[slot])
+                        events.append(TokenEvent(int(slot), tok, finished,
+                                                 poisoned=bool(bad[slot])))
+            if self.paged:
+                # blocks of slots that finished (or were quarantined) this
+                # chunk go back to the allocator; shared prefix blocks stay
+                # resident for future hits
+                for slot in np.nonzero(was_active & ~self._active)[0]:
+                    self._release_pages(slot)
+            self.stats.tokens_generated += len(events)
+            self.stats.decode_steps += int(was_active.any()) * n_steps
+            self.stats.active_slots = int(self._active.sum())
         return events

@@ -51,6 +51,7 @@ from typing import Any, Dict, List, Optional, Tuple, Union
 import numpy as np
 
 from ..utils.resilience import fault_point
+from ..utils.trace import record, span
 from .engine import InferenceEngine, NoFreeBlocksError, SamplingParams
 
 
@@ -243,6 +244,7 @@ class Request:
     error: Optional[str] = None
     exception: Optional[BaseException] = None
     submit_t: float = 0.0
+    admit_t: Optional[float] = None       # popped from the queue to prefill
     first_token_t: Optional[float] = None
     done_t: Optional[float] = None
     preemptions: int = 0                  # times parked mid-decode
@@ -402,6 +404,7 @@ class Scheduler:
         self._accepting = True
         self._shutdown_done = False
         self._epoch = 0
+        self._round = 0                    # the `round` id of step's spans
         # weight hot-swap support (serve/router.py): while paused, step()
         # keeps decoding the running slots but admits nothing new, so a
         # draining replica quiesces under sustained queued traffic
@@ -757,7 +760,7 @@ class Scheduler:
                           engine: InferenceEngine) -> int:
         admitted = 0
         while engine.free_slots():
-            with self._drained:
+            with span("serve.pick"), self._drained:
                 # _admission_paused re-checked HERE, not just in step()'s
                 # snapshot: it shares this lock with pause_admission, so
                 # once the router has paused and observed inflight()==0,
@@ -777,77 +780,86 @@ class Scheduler:
                 if req.deadline_s is not None:
                     self._queued_deadlines -= 1
                 self._admitting = req
+                req.admit_t = time.perf_counter()
                 self._drained.notify_all()
-            dl = req.deadline_t
-            if dl is not None and time.perf_counter() > dl:
-                # expired between the shed sweep and this pop
+            with span("serve.admit", request=req.id,
+                      prompt_tokens=int(req.prompt.size)) as admit_span:
+                dl = req.deadline_t
+                if dl is not None and time.perf_counter() > dl:
+                    # expired between the shed sweep and this pop
+                    with self._lock:
+                        if self._admitting is req:
+                            self._admitting = None
+                    self._fail(req, DeadlineExceededError(
+                        f"deadline_s={req.deadline_s:.3g} elapsed in queue "
+                        f"— shed before prefill"))
+                    continue
+                try:
+                    padded = engine.stats.prefill_tokens
+                    slot, ev = engine.admit(req.prompt, req.sampling)
+                    # the padded tokens this prefill dispatched
+                    admit_span.ids["bucket"] = (
+                        engine.stats.prefill_tokens - padded)
+                    record("request.queue", req.submit_t, req.admit_t,
+                           request=req.id)
+                except NoFreeBlocksError:
+                    # transient paged-pool shortage that appeared between the
+                    # capacity probe and admit — reinsert at the ORIGINAL
+                    # queue position (the request is fine; the blocks aren't
+                    # there yet; jumping older requests would also perturb
+                    # the starvation guard's head tracking). Positions ahead
+                    # of idx only ever shrink via this driver thread, so the
+                    # clamp preserves relative order. Skipped when a
+                    # failover raced us: fail_inflight already owns the
+                    # in-admission request's resolution.
+                    with self._drained:
+                        mine = self._admitting is req
+                        if mine:
+                            self._admitting = None
+                        if mine and self._epoch == epoch:
+                            self._queue.insert(min(idx, len(self._queue)),
+                                               req)
+                            if req.deadline_s is not None:
+                                self._queued_deadlines += 1
+                    break
+                except Exception as e:  # noqa: BLE001 — a bad request must
+                    # fail ITSELF, not tear the serving loop down
+                    with self._lock:
+                        if self._admitting is req:
+                            self._admitting = None
+                    self._fail(req, e)
+                    continue
                 with self._lock:
+                    # clear only OUR marker: a stale waking driver must not
+                    # wipe the live generation's in-admission request
                     if self._admitting is req:
                         self._admitting = None
-                self._fail(req, DeadlineExceededError(
-                    f"deadline_s={req.deadline_s:.3g} elapsed in queue — "
-                    f"shed before prefill"))
-                continue
-            try:
-                slot, ev = engine.admit(req.prompt, req.sampling)
-            except NoFreeBlocksError:
-                # transient paged-pool shortage that appeared between the
-                # capacity probe and admit — reinsert at the ORIGINAL
-                # queue position (the request is fine; the blocks aren't
-                # there yet; jumping older requests would also perturb
-                # the starvation guard's head tracking). Positions ahead
-                # of idx only ever shrink via this driver thread, so the
-                # clamp preserves relative order. Skipped when a
-                # failover raced us: fail_inflight already owns the
-                # in-admission request's resolution.
-                with self._drained:
-                    mine = self._admitting is req
-                    if mine:
-                        self._admitting = None
-                    if mine and self._epoch == epoch:
-                        self._queue.insert(min(idx, len(self._queue)),
-                                           req)
-                        if req.deadline_s is not None:
-                            self._queued_deadlines += 1
-                break
-            except Exception as e:  # noqa: BLE001 — a bad request must
-                # fail ITSELF, not tear the serving loop down
-                with self._lock:
-                    if self._admitting is req:
-                        self._admitting = None
-                self._fail(req, e)
-                continue
-            with self._lock:
-                # clear only OUR marker: a stale waking driver must not
-                # wipe the live generation's in-admission request
-                if self._admitting is req:
-                    self._admitting = None
-                stale = self._epoch != epoch
-                # a failover/shutdown may have failed this request while
-                # we were inside admit — never resurrect a resolved one
-                resolved = req.status in (RequestStatus.DONE,
-                                          RequestStatus.FAILED)
+                    stale = self._epoch != epoch
+                    # a failover/shutdown may have failed this request while
+                    # we were inside admit — never resurrect a resolved one
+                    resolved = req.status in (RequestStatus.DONE,
+                                              RequestStatus.FAILED)
+                    if not stale and not resolved:
+                        req.status = RequestStatus.RUNNING
+                        req.first_token_t = time.perf_counter()
+                        req.tokens.append(ev.token)
+                        admitted += 1
+                        if not ev.finished:
+                            self._by_slot[slot] = req
                 if not stale and not resolved:
-                    req.status = RequestStatus.RUNNING
-                    req.first_token_t = time.perf_counter()
-                    req.tokens.append(ev.token)
-                    admitted += 1
-                    if not ev.finished:
-                        self._by_slot[slot] = req
-            if not stale and not resolved:
-                req._notify_progress()     # first token: wake streamers
-            if resolved and not stale:
-                engine.release(slot)   # same engine; free the row
-                continue
-            if stale:
-                # the engine was replaced mid-admit (supervisor failover):
-                # this prefill went into the DEAD engine
-                self._fail(req, EngineFailedError(
-                    "engine replaced during admission (supervisor "
-                    "failover) — retry"))
-                break
-            if ev.finished:
-                self._complete(req)
+                    req._notify_progress()     # first token: wake streamers
+                if resolved and not stale:
+                    engine.release(slot)   # same engine; free the row
+                    continue
+                if stale:
+                    # the engine was replaced mid-admit (supervisor failover):
+                    # this prefill went into the DEAD engine
+                    self._fail(req, EngineFailedError(
+                        "engine replaced during admission (supervisor "
+                        "failover) — retry"))
+                    break
+                if ev.finished:
+                    self._complete(req)
         return admitted
 
     # -- preemptible decode (driver side) ---------------------------------
@@ -936,22 +948,43 @@ class Scheduler:
         slot turns around within one round. Epoch-guarded: a stale driver
         (one that wedged, was failed over past, and finally woke) discards
         its events instead of touching the rebuilt engine's requests."""
-        now0 = time.perf_counter()
-        for req in self._shed_expired_queued(now0):
-            self._fail(req, DeadlineExceededError(
-                f"deadline_s={req.deadline_s:.3g} elapsed in queue after "
-                f"{now0 - req.submit_t:.3g}s — shed before prefill"))
-        with self._lock:
-            epoch = self._epoch
-            engine = self.engine
-            paused = self._admission_paused
+        self._round += 1
+        # the round's record, and its leaves', are kept only if it
+        # produced or admitted anything: the driver polls when idle
+        with span("serve.round", annotate=False, hold=True,
+                  round=self._round) as round_span:
+            produced = self._run_round()
+            round_span.keep = produced > 0
+        return produced
+
+    def _run_round(self) -> int:
+        with span("serve.shed"):
+            now0 = time.perf_counter()
+            for req in self._shed_expired_queued(now0):
+                self._fail(req, DeadlineExceededError(
+                    f"deadline_s={req.deadline_s:.3g} elapsed in queue "
+                    f"after {now0 - req.submit_t:.3g}s — shed before "
+                    f"prefill"))
+            with self._lock:
+                epoch = self._epoch
+                engine = self.engine
+                paused = self._admission_paused
         if not paused:
             if self._parked:
-                self._resume_parked(epoch, engine)
+                with span("serve.resume"):
+                    self._resume_parked(epoch, engine)
             if self.preempt:
-                self._preempt_for_queued(epoch, engine)
+                with span("serve.preempt"):
+                    self._preempt_for_queued(epoch, engine)
         produced = 0 if paused else self._admit_from_queue(epoch, engine)
         events = engine.step()
+        with span("serve.deliver"):
+            return produced + self._deliver(events, epoch, engine)
+
+    def _deliver(self, events, epoch: int, engine: InferenceEngine) -> int:
+        """Apply a decode step's events to their requests, sweep deadlines
+        and cancellations at the chunk boundary, resolve and wake."""
+        produced = 0
         now = time.perf_counter()
         completed: List[Request] = []
         failed: List[Tuple[Request, BaseException]] = []

@@ -18,6 +18,7 @@ from __future__ import annotations
 import abc
 from typing import Any, Dict, List, Optional, Sequence, Union
 
+import jax
 import jax.numpy as jnp
 import optax
 
@@ -109,9 +110,11 @@ class CommunicateOptimizeStrategy(Strategy):
         return events
 
     def step(self, grads, params, state, step, ctx):
-        grads = self._maybe_clip(grads, ctx)
-        updates, opt_state = self.tx.update(grads, state["opt"], params)
-        params = optax.apply_updates(params, updates)
+        with jax.named_scope("optimizer"):
+            grads = self._maybe_clip(grads, ctx)
+            updates, opt_state = self.tx.update(grads, state["opt"],
+                                                params)
+            params = optax.apply_updates(params, updates)
 
         def run(params, mstates):
             total = jnp.zeros(())
@@ -126,7 +129,6 @@ class CommunicateOptimizeStrategy(Strategy):
         if gate is None:
             params, mstates, comm = run(params, state["modules"])
         else:
-            import jax
             params, mstates, comm = jax.lax.cond(
                 gate,
                 lambda p, m: run(p, m),

@@ -223,7 +223,8 @@ class DiLoCoCommunicator(CommunicationModule):
         else:
             outer = outer_sharded if self.shard_outer else outer_replicated
         do = jnp.logical_and(step % self.H == 0, step > 0)
-        params, mstate, comm = jax.lax.cond(do, outer, skip, params, mstate)
+        params, mstate, comm = jax.lax.cond(
+            do, jax.named_scope("outer")(outer), skip, params, mstate)
         if self.shard_outer:
             mstate = pipe_wrap(mstate, ctx)
         return params, mstate, comm

@@ -129,9 +129,11 @@ def make_train_step(loss_model: LossModel, strategy: Strategy, ctx: AxisCtx,
             return (new_ms, gsum, lsum + loss, i + 1), None
 
         gzero = jax.tree.map(jnp.zeros_like, state.params)
-        (model_state, gsum, lsum, _), _ = jax.lax.scan(
-            micro, (state.model_state, gzero, jnp.zeros(()), 0), batch
-        )
+        # scopes name the step's parts in a device trace (metadata only)
+        with jax.named_scope("fwd_bwd"):
+            (model_state, gsum, lsum, _), _ = jax.lax.scan(
+                micro, (state.model_state, gzero, jnp.zeros(()), 0), batch
+            )
         # Context parallelism: a seq-sharded model returns the *global* loss
         # (psum'd in-model) but each seq device's backward pass carries only
         # its chunk's gradient contribution — combine them here.
@@ -150,9 +152,10 @@ def make_train_step(loss_model: LossModel, strategy: Strategy, ctx: AxisCtx,
                 lambda g: jnp.where(ok, g, jnp.zeros_like(g)), grads
             )
 
-        params, sstate, metrics = strategy.step(
-            grads, state.params, state.strategy_state, state.step, ctx
-        )
+        with jax.named_scope("strategy"):
+            params, sstate, metrics = strategy.step(
+                grads, state.params, state.strategy_state, state.step, ctx
+            )
         params = constrain_params(params, param_specs)
         new_state = state.replace(
             params=params,
