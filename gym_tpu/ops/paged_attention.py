@@ -1,0 +1,235 @@
+"""Paged attention that reads only a row's live pages — Pallas TPU kernel.
+
+The serving engine keeps every layer's keys and values in a POOL of
+fixed-size pages, ``[kv_pages, page_size, n_embd]`` (all heads of a
+position packed on the lanes: 768 = 6 lane tiles at GPT-2 base, so a page
+is whole (8, 128) tiles and a row of one position is what a scatter
+writes). ``models/nanogpt.py:_decode_attend_paged`` writes the new
+positions into the pool and then attends. Its gather path builds a dense
+``[b, S, H, hd]`` window out of ``pool[block_table]`` for every row,
+whatever the row's cursor says; at 128 slots that window is the size of
+the whole pool, read and written again in every layer of every step.
+
+This kernel walks the pool instead: for batch row ``r`` it copies pages
+``block_table[r, 0 .. ceil((cache_pos[r] + t) / page) - 1]`` from HBM into
+a double-buffered VMEM window, 128 positions at a time, and folds them
+into an online softmax (float32 statistics and accumulator). Nothing past
+a row's cursor is read or scored, nothing pool-sized is built, and a row
+whose table points at the null page (an inactive slot) costs one page.
+
+Heads without lane slicing: a head is 64 lanes of a 768-lane row, and
+cutting 64-lane columns out of a packed block costs more than it saves
+(``flash_attention.py:packed_flash_attention_or_none`` measured that). So
+the wrapper expands the queries instead: query row ``(j, h)`` is position
+``j``'s packed query with every lane outside head ``h`` zeroed. Its
+product with a packed key row is then head ``h``'s score exactly (the
+zeros add nothing), all heads of a decode step are 12 rows of ONE matmul
+against the page, and the weighted sum over packed value rows holds head
+``h``'s output in head ``h``'s lanes, which the wrapper keeps. The matrix
+unit does ``n_head`` times the needed products; it is otherwise idle in a
+step that is bound by reading the pages.
+
+Precision: the two products take their operands as bfloat16 and
+accumulate in float32. That is what XLA's default precision makes of the
+gather path's float32 ``einsum``s on a TPU (one bf16 pass), so the kernel
+computes the same products; what differs is the order of the sums
+(blocks of 128 positions with a running maximum instead of one softmax
+over ``S``), hence a tolerance and not bit-identity against the gather
+path on the chip. (Measured there, PR 26: float32 operands at Mosaic's
+default precision give the same numbers in the same time, so the cast
+only states what the matrix unit does anyway. And XLA lowers the gather
+path's one-token products through the vector units in float32, so at
+decode the kernel is the one bf16 pass of the default precision and the
+gather path is better than that: attention outputs 2.6e-3 apart on
+unit-variance inputs.) Under the interpreter (``INTERPRET``, CPU tests)
+the operands stay float32, as the gather path's are on the CPU.
+"""
+
+from __future__ import annotations
+
+import functools
+import logging
+import math
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from .flash_attention import _on_tpu  # noqa: F401 — steered here by tests
+
+_log = logging.getLogger(__name__)
+
+NEG = -1e30
+# Set True (e.g. from tests) to run the kernel in the Pallas interpreter
+# on any backend; lane and sublane tiling is then not required.
+INTERPRET = False
+_CHUNK = 128        # key/value positions folded per inner step
+_ROWS = 256         # expanded query rows (position x head) per program
+
+KERNEL = "pallas_paged"
+GATHER = "gather"
+
+
+def paged_attend_path(n_embd: int, page_size: int, dtype, kv_dtype) -> str:
+    """Which implementation the paged attend takes, from what the code
+    can observe: ``KERNEL`` on a TPU when the packed row fills whole lane
+    tiles, a page whole sublane tiles and a 128-position chunk whole
+    pages, and both the queries and the pool are float32; else ``GATHER``
+    (off the TPU: all of tier-1; an int8 pool: by its dtype). THE
+    dispatch point — the model and the engine's counters both ask here."""
+    f32 = (jnp.dtype(dtype) == jnp.float32
+           and jnp.dtype(kv_dtype) == jnp.float32)
+    if INTERPRET:
+        return KERNEL if f32 else GATHER
+    tiles = (n_embd % 128 == 0 and page_size % 8 == 0
+             and _CHUNK % page_size == 0)
+    return KERNEL if (_on_tpu() and f32 and tiles) else GATHER
+
+
+@functools.cache
+def report_path(path: str, shape: tuple, dtype: str) -> None:
+    """Log which implementation a paged attend resolved to, once per
+    (path, shape, dtype) per process: ``attention path pallas_paged for
+    q(128, 1, 768) float32`` — the training kernels' line
+    (``flash_attention._report``) on this module's logger, which
+    ``chip_smoke.py`` reads to assert the kernel ran."""
+    _log.info("attention path %s for q%s %s", path, shape, dtype)
+
+
+def _kernel(bt_ref, pos_ref, q_ref, k_hbm, v_hbm, o_ref,
+            kbuf, vbuf, sem, m_ref, l_ref, acc_ref, *,
+            heads, t, page, ppc, mb, scale, mxu_dtype):
+    r, rb = pl.program_id(0), pl.program_id(1)
+    tr, ch = q_ref.shape[1], ppc * page
+    pos0 = pos_ref[r]
+    n0 = rb * tr
+    # expanded row n is (position j, head h) = divmod(n, heads); the last
+    # position of this block bounds the pages it may see
+    j_hi = jnp.minimum((n0 + tr - 1) // heads, t - 1)
+    kv_len = jnp.minimum(pos0 + j_hi + 1, mb * page)
+    # a row redirected to the null page (inactive slot) reads one page
+    kv_len = jnp.where(bt_ref[r * mb] == 0, jnp.minimum(kv_len, page),
+                       kv_len)
+    n_pages = pl.cdiv(kv_len, page)
+    n_chunks = pl.cdiv(kv_len, ch)
+
+    def page_copies(c, slot):
+        for p in range(ppc):
+            pg = c * ppc + p
+            phys = bt_ref[r * mb + jnp.minimum(pg, mb - 1)]
+            dst = pl.ds(p * page, page)
+            yield pg, pltpu.make_async_copy(
+                k_hbm.at[phys], kbuf.at[slot, dst], sem.at[0, slot])
+            yield pg, pltpu.make_async_copy(
+                v_hbm.at[phys], vbuf.at[slot, dst], sem.at[1, slot])
+
+    def start(c, slot):
+        for pg, cp in page_copies(c, slot):
+            pl.when(pg < n_pages)(cp.start)
+
+    def wait(c, slot):
+        for pg, cp in page_copies(c, slot):
+            pl.when(pg < n_pages)(cp.wait)
+
+    m_ref[...] = jnp.full(m_ref.shape, NEG, jnp.float32)
+    l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+    acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+    start(0, 0)
+
+    q = q_ref[0].astype(mxu_dtype)
+    n = n0 + jax.lax.broadcasted_iota(jnp.int32, (tr, 1), 0)
+    # n // heads without a vector integer division: n + 0.5 over heads is
+    # never within 0.5 / heads of a whole number, float32 is exact enough
+    j = jnp.floor((n.astype(jnp.float32) + 0.5)
+                  * (1.0 / heads)).astype(jnp.int32)
+    limit = pos0 + jnp.minimum(j, t - 1)                      # [tr, 1]
+
+    def body(c, carry):
+        slot = jax.lax.rem(c, 2)
+
+        @pl.when(c + 1 < n_chunks)
+        def _():
+            start(c + 1, 1 - slot)
+
+        wait(c, slot)
+        k = kbuf[slot].astype(mxu_dtype)                      # [ch, C]
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+        col = c * ch + jax.lax.broadcasted_iota(jnp.int32, (tr, ch), 1)
+        s = jnp.where(col <= limit, s * scale, NEG)
+        m_prev = m_ref[...]
+        m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new)
+        l_ref[...] = alpha * l_ref[...] + p.sum(axis=1, keepdims=True)
+        # positions past this block's last are stale buffer or a recycled
+        # page's old contents: 0 * NaN is NaN, so select, don't rely on p
+        vrow = c * ch + jax.lax.broadcasted_iota(jnp.int32, (ch, 1), 0)
+        v = jnp.where(vrow < kv_len, vbuf[slot], 0.0).astype(mxu_dtype)
+        acc_ref[...] = alpha * acc_ref[...] + jnp.dot(
+            p.astype(mxu_dtype), v, preferred_element_type=jnp.float32)
+        m_ref[...] = m_new
+        return carry
+
+    jax.lax.fori_loop(0, n_chunks, body, None)
+    o_ref[0] = acc_ref[...] / l_ref[...]
+
+
+def paged_attention(q, k_pool, v_pool, block_table, cache_pos, n_head):
+    """Attention of ``q`` [b, t, C] (heads packed on C) over the pages
+    ``block_table`` [b, S // page] names in the pools [P, page, C], row
+    ``r``'s query ``j`` seeing positions ``0 .. cache_pos[r] + j``. The
+    new positions are in the pool already. Returns [b, t, C] float32.
+    ``paged_attend_path`` says whether the shapes qualify."""
+    return _paged_attention(q, k_pool, v_pool, block_table, cache_pos,
+                            n_head, INTERPRET)
+
+
+# A jit of its own: a model's layers all call it with the same shapes, so
+# the kernel is traced and lowered once a program, not once a layer (the
+# Python side of twelve lowerings was 10 s of every served program's
+# build on the chip's host, cache hit or not: PERF.md §6, PR 26).
+@functools.partial(jax.jit, static_argnums=(5, 6))
+def _paged_attention(q, k_pool, v_pool, block_table, cache_pos, n_head,
+                     interpret):
+    b, t, c = q.shape
+    page, mb = k_pool.shape[1], block_table.shape[1]
+    hd = c // n_head
+    ppc = max(1, _CHUNK // page)
+    own = (jnp.arange(c)[None, :] // hd) == jnp.arange(n_head)[:, None]
+    n = t * n_head
+    tr = min(_ROWS, -(-n // 8) * 8)
+    n_pad = -(-n // tr) * tr
+    qx = jnp.where(own, q[:, :, None, :], 0.0).reshape(b, n, c)
+    qx = jnp.pad(qx, ((0, 0), (0, n_pad - n), (0, 0)))
+    kernel = functools.partial(
+        _kernel, heads=n_head, t=t, page=page, ppc=ppc, mb=mb,
+        scale=1.0 / math.sqrt(hd),
+        mxu_dtype=jnp.float32 if interpret else jnp.bfloat16)
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(b, n_pad // tr),
+            in_specs=[
+                pl.BlockSpec((1, tr, c), lambda r, rb, *_: (r, rb, 0)),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=pl.BlockSpec((1, tr, c), lambda r, rb, *_: (r, rb, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((2, ppc * page, c), k_pool.dtype),
+                pltpu.VMEM((2, ppc * page, c), v_pool.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.VMEM((tr, 1), jnp.float32),
+                pltpu.VMEM((tr, 1), jnp.float32),
+                pltpu.VMEM((tr, c), jnp.float32),
+            ]),
+        out_shape=jax.ShapeDtypeStruct((b, n_pad, c), jnp.float32),
+        interpret=interpret,
+        name="paged_attn_decode" if t == 1 else "paged_attn_prefill",
+    )(block_table.reshape(-1).astype(jnp.int32),
+      cache_pos.astype(jnp.int32), qx, k_pool, v_pool)
+    out = out[:, :n].reshape(b, t, n_head, c)
+    return jnp.where(own, out, 0.0).sum(axis=2)

@@ -565,6 +565,11 @@ def create_server(params, cfg, *, host: str = "127.0.0.1", port: int = 0,
                 # max_s]} (utils/trace.py; names in PERF.md §3)
                 "spans": trace.totals(),
                 "readback_bytes": sum(s.readback_bytes for s in stats),
+                # decode + prefill dispatches whose attend ran the Pallas
+                # page walk: equals decode dispatches + prefills on a TPU
+                # with an f32 pool, 0 on the gather path
+                "paged_kernel_dispatches": sum(
+                    s.paged_kernel_dispatches for s in stats),
                 "warmup": (warm_thread.stats()
                            if warm_thread is not None else None),
                 # multi-tenant serving (ISSUE 17): live quota fill,

@@ -35,8 +35,13 @@ cache math is the same program modulo batch width.
 **Paged KV + prefix sharing** (``paged=True``; PagedAttention, arXiv
 2309.06180): the cache becomes a POOL of fixed-size pages addressed
 through a per-slot block table (``models/nanogpt.py:_decode_attend_paged``
-— same static-[block_size] reductions and masks as the unpaged attend,
-which is what keeps paged token streams bit-identical). A ref-counted
+— off the TPU the same static-[block_size] reductions and masks as the
+unpaged attend, which keeps paged token streams bit-identical; on a TPU
+with a float32 pool a Pallas kernel that reads only each row's live pages
+in place (``ops/paged_attention.py``), the same products summed in
+another order, held to a logits tolerance instead:
+``EngineStats.paged_kernel_dispatches`` and the ``path`` id of the
+dispatch spans say which ran). A ref-counted
 ``BlockAllocator`` plus an exact-content prefix hash table admit a
 prompt whose longest block-aligned prefix is already resident WITHOUT
 re-prefilling or copying those blocks: prefill processes only the
@@ -68,6 +73,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..models.nanogpt import GPT, GPTConfig, decode_config
+from ..ops.paged_attention import KERNEL, paged_attend_path
 from ..programs import default_registry
 from ..programs.serve_defs import (cow_def, paged_decode_def,
                                    paged_prefill_def, prefill_def,
@@ -164,6 +170,10 @@ class EngineStats:
     num_slots: int = 0
     readback_bytes: int = 0              # cumulative bytes decode steps read
     #                                      back from the device
+    paged_kernel_dispatches: int = 0     # decode + prefill dispatches whose
+    #                                      attend ran the Pallas page walk
+    #                                      (ops/paged_attention.py); 0 on
+    #                                      the gather path and unpaged
     quarantined: int = 0                 # slots shut down on NaN/Inf logits
     # paged-KV observables (0 on an unpaged engine)
     kv_blocks_in_use: int = 0            # pages referenced by live slots
@@ -212,7 +222,7 @@ class BlockAllocator:
     """Host-side ref-counted page allocator + prefix hash table for the
     paged KV pool (PagedAttention, arXiv 2309.06180).
 
-    Page ids index the device pools (``[kv_pages, page_size, H, hd]``
+    Page ids index the device pools (``[kv_pages, page_size, n_embd]``
     per layer); page 0 is the reserved NULL page — never allocated,
     the write-redirect target for deactivated rows. A page's refcount
     counts ACTIVE slot users; pages holding full, block-aligned PROMPT
@@ -427,8 +437,14 @@ class InferenceEngine:
             self.config = dataclasses.replace(
                 base_cfg, page_size=self.page_size, kv_pages=self.kv_pages)
             self._alloc = BlockAllocator(self.kv_pages, self.page_size)
+            # the model's own dispatch point, asked with what it will be
+            # asked: the id on the dispatch spans and what /stats counts
+            self.attend_path = paged_attend_path(
+                base_cfg.n_embd, self.page_size, jnp.float32,
+                jnp.int8 if self.kv_dtype == "int8" else jnp.float32)
         else:
             self.page_size = 0
+            self.attend_path = "dense"
             self.max_blocks = 0
             self.kv_pages = 0
             self.config = base_cfg
@@ -596,8 +612,8 @@ class InferenceEngine:
         model = GPT(self.config)
         dummy = jnp.zeros((self.num_slots, 1), jnp.int32)
         if self.paged:
-            # the pool is batch-shape independent ([kv_pages, page, H,
-            # hd] per layer): a 1-row prefill and an S-row decode run
+            # the pool is batch-shape independent ([kv_pages, page,
+            # n_embd] per layer): a 1-row prefill and an S-row decode run
             # against the SAME buffers — that is what makes the prefix
             # blocks shareable without an admit-scatter program
             shapes = jax.eval_shape(
@@ -791,7 +807,7 @@ class InferenceEngine:
                 args = (self.params, jnp.asarray(padded), np.int32(n),
                         jnp.asarray(base_key), np.float32(sp.temperature),
                         np.int32(top_k), np.float32(top_p))
-            with span("serve.prefill.dispatch"):
+            with span("serve.prefill.dispatch", path=self.attend_path):
                 tok, row_cache = prefill(*args)
                 self._cache = self._admit_prog(self._cache, row_cache,
                                                np.int32(slot), np.int32(n))
@@ -888,9 +904,10 @@ class InferenceEngine:
                         jnp.asarray(padded), np.int32(suffix),
                         jnp.asarray(base_key), np.float32(sp.temperature),
                         np.int32(top_k), np.float32(top_p))
-            with span("serve.prefill.dispatch"):
+            with span("serve.prefill.dispatch", path=self.attend_path):
                 tok, self._cache = prefill(self.params, self._cache,
                                            *args)
+            self.stats.paged_kernel_dispatches += self.attend_path == KERNEL
         except BaseException:
             for pg in held:
                 al.decref(pg)
@@ -1083,8 +1100,9 @@ class InferenceEngine:
                 tail = tail[:2] + (jnp.asarray(self._pos),) + tail[2:]
             else:
                 head = (self.params, self._cache)
-        with span("serve.decode.dispatch"):
+        with span("serve.decode.dispatch", path=self.attend_path):
             out = prog(*head, *tail)
+        self.stats.paged_kernel_dispatches += self.attend_path == KERNEL
         with span("serve.decode.readback") as rb:
             # the first read waits for the step; the logits are the bulk
             if self.paged:

@@ -21,7 +21,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from gym_tpu.ops import flash_attention, fused_attention
+from gym_tpu.ops import flash_attention, fused_attention, paged_attention
 from gym_tpu.ops.dct import codec_for, sparse_decode_chunks
 from gym_tpu.ops.grouped_matmul import quant_tile_for, quantized_dot
 from gym_tpu.ops.topk_compress import topk_compress
@@ -111,6 +111,80 @@ def test_flash_dispatch_compiles_tuned_blocks_at_2048(v5e_chip,
     hlo = _compile(_fwd_bwd(flash_attention.flash_causal_attention),
                    v5e_chip, *[((2, 12, 2048, 64), jnp.bfloat16)] * 3)
     assert "tpu_custom_call" in hlo
+
+
+# the served cell's pool: 128 slots x 64 pages of 16 positions, plus the
+# null page and a spare, heads packed on the lanes
+@pytest.mark.parametrize("b,t,c,heads,kernel", [
+    (128, 1, 768, 12, "paged_attn_decode"),
+    (1, 128, 768, 12, "paged_attn_prefill"),
+    (1, 1024, 768, 12, "paged_attn_prefill"),
+    (128, 5, 768, 12, "paged_attn_prefill"),         # speculative verify
+    (128, 1, 1024, 16, "paged_attn_decode"),         # GPT-2 medium
+], ids=["decode", "prefill128", "prefill1024", "verify5", "medium"])
+def test_paged_attention_kernel_compiles_for_v5e(v5e_chip, b, t, c, heads,
+                                                 kernel):
+    """The page walk at the served cell's sizes: every in-kernel slice on
+    a tile boundary, the scratch inside scoped VMEM, and nothing in the
+    program the size of a pool array but the two pools themselves."""
+    pages, page, mb = 8194, 16, 64
+    hlo = _compile(
+        functools.partial(paged_attention.paged_attention, n_head=heads),
+        v5e_chip, ((b, t, c), jnp.float32),
+        ((pages, page, c), jnp.float32), ((pages, page, c), jnp.float32),
+        ((b, mb), jnp.int32), ((b,), jnp.int32))
+    assert "tpu_custom_call" in hlo and kernel in hlo
+    pool = f"f32[{pages},{page},{c}]"
+    moved = [line for line in hlo.splitlines()
+             if pool in line.split("(")[0] and "parameter(" not in line]
+    assert not moved, moved[:3]
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill256"])
+def test_served_programs_move_nothing_pool_sized(v5e_chip, monkeypatch,
+                                                 program):
+    """``serve.paged_decode[slots=128,chunk=1]`` and a paged prefill as
+    the served cell's engine compiles them: besides the in-place scatter
+    of the new positions, no instruction's result is the size of a pool
+    array (403 MB) or of a ``[b, S, H, hd]`` window, and the temporaries
+    stay far under the 5 GiB the gathered windows took."""
+    import dataclasses
+    import re
+    from gym_tpu.models.nanogpt import GPTConfig, decode_config
+    from gym_tpu.programs import serve_defs
+    monkeypatch.setattr(paged_attention, "_on_tpu", lambda: True)
+    slots, page = 128, 16
+    cfg = dataclasses.replace(
+        decode_config(GPTConfig(block_size=1024, vocab_size=50304,
+                                n_layer=12, n_head=12, n_embd=768,
+                                dropout=0.0)),
+        page_size=page, kv_pages=2 + slots * (1024 // page))
+    cfg_tuple = dataclasses.astuple(cfg)
+    pdef = (serve_defs.paged_decode_def(cfg_tuple, slots, 1)
+            if program == "decode"
+            else serve_defs.paged_prefill_def(cfg_tuple, 256))
+    args = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=v5e_chip),
+        pdef.args)
+    compiled = pdef.builder().lower(*args).compile()
+    hlo = compiled.as_text()
+    assert ("paged_attn_decode" if program == "decode"
+            else "paged_attn_prefill") in hlo
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 30
+    pool_bytes = cfg.kv_pages * page * 768 * 4
+    big = []
+    for m in re.finditer(
+            r"= (\w+)\[([\d,]+)\]\S* (copy|gather|transpose|"
+            r"dynamic-update-slice|dynamic-slice|convert)\(", hlo):
+        n = 1
+        for d in m.group(2).split(","):
+            n *= int(d)
+        if n * 4 >= pool_bytes // 2:
+            big.append(m.group(0))
+    assert not big, big[:3]
+    # the write of the new positions is a scatter into the donated pool
+    assert hlo.count(f"f32[{cfg.kv_pages},{page},768]") > 24
+    assert re.search(r"input_output_alias=\{.*may-alias", hlo)
 
 
 def test_quantized_dot_compiles_at_gpt2_base_mlp(v5e_chip):

@@ -110,6 +110,17 @@ def test_smoke_rehearsal_runs_every_phase_and_still_fails(tmp_path):
         assert phases[name]["ok"], phases[name]
     assert phases["serve"]["checks"]["all_equal_generate_fast"]
     assert any(r["streamed"] for r in phases["serve"]["requests"])
+    # off the TPU the paged attend is the gather path: streams and logits
+    # bit-identical to the unpaged engine's, no kernel dispatch counted
+    engines = phases["serve"]["engines"]
+    assert engines["paged_attend_path"] == "gather"
+    assert engines["paged_logit_gap_max"] == 0.0 and engines["steps"] > 1
+    assert phases["serve"]["stats"]["paged_kernel_dispatches"] == 0
+    assert phases["serve"]["streams_equal_generate_fast"] == len(
+        phases["serve"]["requests"])
+    assert phases["wrap_up"]["paged_attention_paths"] and all(
+        "gather" in path
+        for path in phases["wrap_up"]["paged_attention_paths"])
     assert phases["wrap_up"]["threads_alive"] == []
     assert all("dense" in path
                for path in phases["wrap_up"]["attention_paths"])
