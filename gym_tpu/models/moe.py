@@ -369,3 +369,150 @@ def moe_param_specs(params: PyTree, base_specs: PyTree = None,
         else:
             out.append(b)
     return jax.tree_util.tree_unflatten(treedef, out)
+
+
+# -- a chip's share of a sigmoid-routed expert layer (serving) -------------
+
+
+def _gated(x, w_gate, w_up, w_down, dot):
+    """``(silu(x Wg) * (x Wu)) Wd`` with ``dot`` as the product; the gate
+    in float32, the products' operands in ``x``'s dtype."""
+    g = dot(x, w_gate).astype(jnp.float32)
+    u = dot(x, w_up).astype(jnp.float32)
+    return dot((jax.nn.silu(g) * u).astype(x.dtype), w_down)
+
+
+class HeldExperts(nn.Module):
+    """The expert layer of a deployment that shares each layer's experts
+    over several chips, as ONE of those chips runs it (inference only).
+
+    Routing runs over all ``n_experts``: ``sigmoid`` scores in float32,
+    the ``topk`` largest, their weights renormalised to sum to one
+    (``norm_topk``). The chip holds the routed experts ``held = (lo,
+    hi)`` and computes their part of the sum alone: token-picks are
+    sorted by expert, the picks on held experts run through
+    ``ops.grouped_matmul.grouped_dot`` (groups = the held experts, none
+    dropped, no capacity), picks on absent experts add nothing. Nothing
+    stands in for the other chips or for the exchange with them.
+
+    ``n_shared`` shared experts of the same gated form run on every
+    token and are averaged (every chip computes them alike).
+
+    The sorted picks are taken ``chunk_rows`` at a time, and a block
+    wholly past the last held pick is skipped: a 16k-token prefill has
+    131k picks of which an eighth are held, and only those blocks gather
+    rows and run products.
+
+    ``__call__(h [S, C] float32) -> (routed, shared)`` float32. Sows
+    into the ``counters`` collection (when mutable): ``picks`` [held]
+    token-picks on each held expert from the rows marked ``live``,
+    ``hit`` the held experts any row picked, ``tokens`` the live rows.
+    """
+
+    hidden: int
+    width: int
+    n_experts: int
+    topk: int
+    held: Tuple[int, int]
+    n_shared: int
+    norm_topk: bool = True
+    chunk_rows: int = 8192
+    param_dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, h, live=None):
+        from ..ops.grouped_matmul import grouped_dot
+        C, F, E, K = self.hidden, self.width, self.n_experts, self.topk
+        lo, hi = self.held
+        nh = hi - lo
+        if not 0 <= lo < hi <= E:
+            raise ValueError(f"held experts {self.held} not within "
+                             f"[0, {E})")
+        S = h.shape[0]
+        dt = self.param_dtype
+        init = _init_normal(0.02)
+        router = self.param("router", init, (C, E), dt)
+        wg = self.param("gate_proj", init, (nh, C, F), dt)
+        wu = self.param("up_proj", init, (nh, C, F), dt)
+        wd = self.param("down_proj", init, (nh, F, C), dt)
+        hb = h.astype(dt)
+
+        with jax.named_scope("moe.router"):
+            scores = jax.nn.sigmoid(jnp.dot(
+                h, router.astype(jnp.float32),
+                precision=jax.lax.Precision.HIGHEST))
+            top_s, top_i = jax.lax.top_k(scores, K)              # [S, K]
+            w = (top_s / top_s.sum(-1, keepdims=True) if self.norm_topk
+                 else top_s)
+            local = top_i - lo
+            # absent experts sort last, as one key past the held ones
+            key = jnp.where((local >= 0) & (local < nh), local,
+                            nh).reshape(-1)                      # [S*K]
+            order = jnp.argsort(key)       # stable: ties keep token order
+            onehot = key[:, None] == jnp.arange(nh)[None, :]
+            counts = onehot.sum(0, dtype=jnp.int32)              # [held]
+            offs = jnp.concatenate([jnp.zeros((1,), jnp.int32),
+                                    jnp.cumsum(counts)])
+            n_rows = offs[-1]              # picks on held experts
+            w_flat = w.reshape(-1)
+        if live is None:
+            live = jnp.ones((S,), bool)
+        self.sow("counters", "picks",
+                 (onehot & jnp.repeat(live, K)[:, None]).sum(
+                     0, dtype=jnp.int32),
+                 reduce_fn=jnp.add, init_fn=lambda: jnp.zeros((nh,),
+                                                              jnp.int32))
+        self.sow("counters", "hit", (counts > 0).sum(dtype=jnp.int32),
+                 reduce_fn=jnp.add, init_fn=lambda: jnp.zeros((),
+                                                              jnp.int32))
+        self.sow("counters", "tokens", live.sum(dtype=jnp.int32),
+                 reduce_fn=jnp.add, init_fn=lambda: jnp.zeros((),
+                                                              jnp.int32))
+
+        R = min(S * K, int(self.chunk_rows))
+        n_chunks = -(-(S * K) // R)
+        order = jnp.pad(order, (0, n_chunks * R - S * K))
+
+        def block(c, out):
+            rows = jax.lax.dynamic_slice(order, (c * R,), (R,))
+            tok = rows // K
+            first = c * R
+            gs = (jnp.clip(offs[1:], first, first + R)
+                  - jnp.clip(offs[:-1], first, first + R))
+            y = _gated(hb[tok], wg, wu, wd,
+                       lambda a, b: grouped_dot(a, b, gs))
+            # rows past the held picks belong to no group: whatever the
+            # grouped product left there is selected away, not scaled
+            held_row = (first + jnp.arange(R)) < n_rows
+            y = jnp.where(held_row[:, None],
+                          y.astype(jnp.float32) * w_flat[rows][:, None],
+                          0.0)
+            return out.at[tok].add(y)
+
+        with jax.named_scope("moe.routed"):
+            routed = jnp.zeros((S, C), jnp.float32)
+            if n_chunks == 1:
+                routed = block(0, routed)
+            else:
+                routed = jax.lax.fori_loop(
+                    0, n_chunks,
+                    lambda c, out: jax.lax.cond(
+                        c * R < n_rows, lambda o: block(c, o),
+                        lambda o: o, out),
+                    routed)
+
+        with jax.named_scope("moe.shared"):
+            shared = jnp.zeros((S, C), jnp.float32)
+            if self.n_shared:
+                sg = self.param("shared_gate_proj", init,
+                                (self.n_shared, C, F), dt)
+                su = self.param("shared_up_proj", init,
+                                (self.n_shared, C, F), dt)
+                sd = self.param("shared_down_proj", init,
+                                (self.n_shared, F, C), dt)
+                dot = lambda a, b: jnp.dot(          # noqa: E731
+                    a, b, preferred_element_type=jnp.float32)
+                for s in range(self.n_shared):
+                    shared = shared + _gated(hb, sg[s], su[s], sd[s], dot)
+                shared = shared / self.n_shared
+        return routed, shared

@@ -118,6 +118,47 @@ class GPTConfig:
     def is_moe_layer(self, i: int) -> bool:
         return self.n_experts > 0 and i % self.moe_every == self.moe_every - 1
 
+    # -- what the serving engine asks a model's config (models/serving.py)
+
+    def build(self) -> "GPT":
+        return GPT(self)
+
+    def program_key(self) -> tuple:
+        return dataclasses.astuple(self)
+
+    def decode_config(self) -> "GPTConfig":
+        return decode_config(self)
+
+    def program_tag(self) -> str:
+        """Name suffix for quantized-serving configs (ISSUE 11): the f32
+        default keeps its historical names (grep-stable), while a
+        quantized program's NAME carries its dtypes — the auditor's
+        recompile guard treats same-name-different-key as a collision,
+        so two dtype variants of one program must not share a name."""
+        parts = []
+        if self.weights_dtype != "f32":
+            parts.append(f"w={self.weights_dtype}"
+                         + ("+emb" if self.quant_embed else ""))
+        if self.kv_dtype != "f32":
+            parts.append(f"kv={self.kv_dtype}")
+        return ("," + ",".join(parts)) if parts else ""
+
+    def attend_paths(self) -> Tuple[str, ...]:
+        from ..ops.paged_attention import paged_attend_path
+        kv = jnp.int8 if self.kv_dtype == "int8" else jnp.float32
+        return (paged_attend_path(self.n_embd, self.page_size, jnp.float32,
+                                  kv),) * self.n_layer
+
+    def prepare_params(self, params):
+        """Quantize-at-load: accept either an f32 checkpoint tree or a
+        pre-quantized one (``load_for_serving`` quantizes once; the
+        fleet's factory rebuilds then detect and skip)."""
+        if self.weights_dtype == "f32":
+            return params
+        from ..serve.load import params_are_quantized, quantize_params
+        return (params if params_are_quantized(params)
+                else quantize_params(params, self))
+
     def without_seq_sharding(self) -> "GPTConfig":
         """Clone with the sequence sharding stripped — for tracing outside
         the mesh (shape inference, init), where ``axis_size(seq_axis)``
@@ -586,7 +627,7 @@ class GPT(nn.Module):
 
     @nn.compact
     def __call__(self, batch, train: bool = True, block_table=None,
-                 cache_pos=None):
+                 cache_pos=None, last_pos=None):
         cfg = self.config
         if cfg.weights_dtype not in ("f32", "int8", "int4"):
             raise ValueError(
@@ -680,9 +721,13 @@ class GPT(nn.Module):
         if targets is None:
             # weight tying: lm_head = wteᵀ (reference :206-208); the
             # quantized table's attend fuses its own dequant
-            if isinstance(wte, QuantEmbed):
-                return wte.attend(x)
-            return wte.attend(x.astype(wte.embedding.dtype))
+            logits = (wte.attend(x) if isinstance(wte, QuantEmbed)
+                      else wte.attend(x.astype(wte.embedding.dtype)))
+            if last_pos is None:
+                return logits
+            # one position's logits of every row (a prefill's last)
+            return jax.lax.dynamic_index_in_dim(logits, last_pos, axis=1,
+                                                keepdims=False)
         emb = (wte.materialize() if isinstance(wte, QuantEmbed)
                else wte.embedding)
         loss_sum, count = ce_sum_count(x, targets, emb, cfg.loss_chunk)

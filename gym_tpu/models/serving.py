@@ -1,0 +1,58 @@
+"""What the serving stack asks of a model, and the models that answer.
+
+The engine (``serve/engine.py``), its programs (``programs/serve_defs.py``)
+and the loaders name no model. They take a CONFIG, a dataclass that
+answers:
+
+* ``build()``            the flax module. ``apply({"params", "cache"},
+  tokens [b, t], train=False, mutable=["cache", "counters"],
+  block_table=, cache_pos=, last_pos=None)`` gives float32 logits
+  [b, t, V] ([b, V] at ``last_pos``); its ``cache`` collection is the
+  per-layer key/value state, its ``counters`` collection (it may have
+  none) small integer arrays a decode step returns beside its tokens;
+* ``decode_config()``    itself, sanitised for single-device decode;
+* ``program_key()``      a hashable tuple that ``config_from_key`` turns
+  back into the config: what device programs are keyed by;
+* ``program_tag()``      what a program's name says of its dtypes;
+* ``attend_paths()``     the paged attend's implementation a layer
+  (``ops/paged_attention.py:paged_attend_path``);
+* ``prepare_params()``   a parameter tree as this config serves it;
+* the fields ``block_size`` (positions a row may reach), ``vocab_size``,
+  ``page_size``, ``kv_pages``, ``weights_dtype``, ``kv_dtype``.
+
+A family's key starts with its ``model_type``; GPT-2's is its plain field
+tuple, as it always was (its programs' keys and names did not move).
+"""
+
+from __future__ import annotations
+
+import dataclasses
+from typing import Any, Dict
+
+from .cohere2_moe import FAMILY as COHERE2_MOE, Cohere2MoeConfig
+from .nanogpt import GPTConfig, sample_logits  # noqa: F401 — re-exported
+
+FAMILIES = {COHERE2_MOE: Cohere2MoeConfig}
+
+
+def config_from_key(key: tuple):
+    """The config a ``program_key()`` came from."""
+    if key and isinstance(key[0], str) and key[0] in FAMILIES:
+        return FAMILIES[key[0]](*key)
+    return GPTConfig(*key)
+
+
+def config_from_dict(fields: Dict[str, Any]):
+    """A config from a captured run's or a worker's JSON: the family by
+    ``model_type`` (absent: GPT-2), unknown keys ignored so that an
+    older server can read a newer snapshot."""
+    cls = FAMILIES.get(fields.get("model_type"), GPTConfig)
+    names = {f.name for f in dataclasses.fields(cls)}
+    return cls(**{k: v for k, v in fields.items() if k in names})
+
+
+def attend_path_id(config) -> str:
+    """One id for a model's paged attends: the distinct per-layer paths
+    joined by ``+`` (``pallas_paged``; ``pallas_paged+pallas_paged_window``
+    for a model that mixes full and windowed layers; ``gather``)."""
+    return "+".join(sorted(set(config.attend_paths())))

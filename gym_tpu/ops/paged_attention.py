@@ -43,6 +43,21 @@ decode the kernel is the one bf16 pass of the default precision and the
 gather path is better than that: attention outputs 2.6e-3 apart on
 unit-variance inputs.) Under the interpreter (``INTERPRET``, CPU tests)
 the operands stay float32, as the gather path's are on the CPU.
+
+Grouped heads, bfloat16 and a window (``paged_attention_gqa``): a model
+whose head dimension fills a lane tile (128) needs no lane masking. Its
+pool row is ``kv_heads * head_dim`` lanes, key-value head ``g`` the
+lane tile(s) ``g * head_dim ..``, and the ``group`` query heads that read
+it are rows of one product against that slice. One program takes a block
+of query positions with ALL heads, copies each page whole (one
+contiguous ``[page, kv_heads * head_dim]`` transfer) and walks the
+key-value heads in turn inside a chunk of 256 positions. Operands keep
+the pool's dtype (bfloat16 pools are multiplied as bfloat16, float32
+accumulation and statistics). With a ``window``, query ``i`` sees key
+``j`` iff ``0 <= i - j < window``: pages wholly older than the block's
+first query's window are neither copied nor scored, so a window layer of
+a long row reads ``window / page + 1`` pages a step, whatever the row's
+table still holds.
 """
 
 from __future__ import annotations
@@ -67,19 +82,47 @@ INTERPRET = False
 _CHUNK = 128        # key/value positions folded per inner step
 _ROWS = 256         # expanded query rows (position x head) per program
 
+_GQA_CHUNK = 256    # the same for the grouped kernel
+_GQA_TQ = 32        # query positions (all their heads) per program
+_GQA_VMEM = 64 << 20
+
 KERNEL = "pallas_paged"
+KERNEL_WINDOW = "pallas_paged_window"   # the grouped kernel with a window
 GATHER = "gather"
 
 
-def paged_attend_path(n_embd: int, page_size: int, dtype, kv_dtype) -> str:
+def paged_attend_path(n_embd: int, page_size: int, dtype, kv_dtype,
+                      head_dim: int = 0, window: int = 0) -> str:
     """Which implementation the paged attend takes, from what the code
-    can observe: ``KERNEL`` on a TPU when the packed row fills whole lane
-    tiles, a page whole sublane tiles and a 128-position chunk whole
-    pages, and both the queries and the pool are float32; else ``GATHER``
-    (off the TPU: all of tier-1; an int8 pool: by its dtype). THE
-    dispatch point — the model and the engine's counters both ask here."""
-    f32 = (jnp.dtype(dtype) == jnp.float32
-           and jnp.dtype(kv_dtype) == jnp.float32)
+    can observe. ``n_embd`` is the pool row's width (all key-value heads
+    of a position). THE dispatch point — the model and the engine's
+    counters both ask here.
+
+    A head dimension that is not whole lane tiles (GPT-2's 64; the
+    default ``head_dim=0``): ``KERNEL`` on a TPU when the packed row
+    fills whole lane tiles, a page whole sublane tiles and a 128-position
+    chunk whole pages, and both the queries and the pool are float32;
+    else ``GATHER`` (off the TPU: all of tier-1; an int8 pool: by its
+    dtype).
+
+    A ``head_dim`` given (``paged_attention_gqa``: any number of query
+    heads a key-value head, each key-value head its own lanes of the pool
+    row): ``KERNEL``, or ``KERNEL_WINDOW`` with a ``window``, on a TPU
+    when the head dimension is whole lane tiles (a multiple of 128),
+    queries and pool share one of float32 and bfloat16 and a page is
+    whole sublane tiles of it (8 rows of float32, 16 of bfloat16) that
+    divide the chunk; else ``GATHER``."""
+    dtype, kv_dtype = jnp.dtype(dtype), jnp.dtype(kv_dtype)
+    if head_dim:
+        ok = dtype == kv_dtype and dtype in (jnp.float32, jnp.bfloat16)
+        tiles = (head_dim % 128 == 0
+                 and page_size % (32 // dtype.itemsize) == 0
+                 and _GQA_CHUNK % page_size == 0)
+        kernel = KERNEL_WINDOW if window else KERNEL
+        if INTERPRET:
+            return kernel if ok else GATHER
+        return kernel if (_on_tpu() and ok and tiles) else GATHER
+    f32 = dtype == jnp.float32 and kv_dtype == jnp.float32
     if INTERPRET:
         return KERNEL if f32 else GATHER
     tiles = (n_embd % 128 == 0 and page_size % 8 == 0
@@ -233,3 +276,156 @@ def _paged_attention(q, k_pool, v_pool, block_table, cache_pos, n_head,
       cache_pos.astype(jnp.int32), qx, k_pool, v_pool)
     out = out[:, :n].reshape(b, t, n_head, c)
     return jnp.where(own, out, 0.0).sum(axis=2)
+
+
+# -- grouped heads, bfloat16, a window --------------------------------------
+
+
+def _gqa_kernel(bt_ref, pos_ref, q_ref, k_hbm, v_hbm, o_ref,
+                kbuf, vbuf, sem, m_ref, l_ref, acc_ref, *,
+                kvh, group, hd, t, tq, page, ppc, mb, window, scale):
+    r, qb = pl.program_id(0), pl.program_id(1)
+    rows, ch = tq * group, ppc * page
+    pos0 = pos_ref[r]
+    j0 = qb * tq
+    # the block's last position bounds the pages it may see, its first
+    # position's window the pages it need not
+    j_hi = jnp.minimum(j0 + tq - 1, t - 1)
+    kv_len = jnp.minimum(pos0 + j_hi + 1, mb * page)
+    lo = jnp.maximum(pos0 + j0 - window + 1, 0) if window else 0
+    # a row redirected to the null page (inactive slot) reads one page
+    null = bt_ref[r * mb] == 0
+    kv_len = jnp.where(null, jnp.minimum(kv_len, page), kv_len)
+    lo = jnp.where(null, 0, lo)
+    first_page = lo // page
+    c0 = lo // ch
+    n_pages = pl.cdiv(kv_len, page)
+    n_chunks = pl.cdiv(kv_len, ch)
+
+    def page_copies(c, slot):
+        for p in range(ppc):
+            pg = c * ppc + p
+            phys = bt_ref[r * mb + jnp.minimum(pg, mb - 1)]
+            dst = pl.ds(p * page, page)
+            yield pg, pltpu.make_async_copy(
+                k_hbm.at[phys], kbuf.at[slot, dst], sem.at[0, slot])
+            yield pg, pltpu.make_async_copy(
+                v_hbm.at[phys], vbuf.at[slot, dst], sem.at[1, slot])
+
+    def live(pg):
+        return (pg >= first_page) & (pg < n_pages)
+
+    def start(c, slot):
+        for pg, cp in page_copies(c, slot):
+            pl.when(live(pg))(cp.start)
+
+    def wait(c, slot):
+        for pg, cp in page_copies(c, slot):
+            pl.when(live(pg))(cp.wait)
+
+    m_ref[...] = jnp.full(m_ref.shape, NEG, jnp.float32)
+    l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+    acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+    start(c0, 0)
+
+    # row n of a key-value head's block is (position j0 + n // group,
+    # head n % group); the division as in the kernel above
+    n = jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
+    j = j0 + jnp.floor((n.astype(jnp.float32) + 0.5)
+                       * (1.0 / group)).astype(jnp.int32)
+    qpos = pos0 + jnp.minimum(j, t - 1)                       # [rows, 1]
+
+    def body(c, carry):
+        slot = jax.lax.rem(c - c0, 2)
+
+        @pl.when(c + 1 < n_chunks)
+        def _():
+            start(c + 1, 1 - slot)
+
+        wait(c, slot)
+        col = c * ch + jax.lax.broadcasted_iota(jnp.int32, (rows, ch), 1)
+        seen = col <= qpos
+        if window:
+            seen = seen & (col > qpos - window)
+        # pages not copied and positions past this block's last are stale
+        # buffer or a recycled page's old contents: 0 * NaN is NaN, so
+        # select, don't rely on p
+        vrow = c * ch + jax.lax.broadcasted_iota(jnp.int32, (ch, 1), 0)
+        vok = (vrow >= first_page * page) & (vrow < kv_len)
+        for g in range(kvh):
+            lanes = pl.ds(g * hd, hd)
+            s = jax.lax.dot_general(
+                q_ref[0, g], kbuf[slot, :, lanes],
+                (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            s = jnp.where(seen, s * scale, NEG)
+            m_prev = m_ref[g]
+            m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.where(seen, jnp.exp(s - m_new), 0.0)
+            l_ref[g] = alpha * l_ref[g] + p.sum(axis=1, keepdims=True)
+            v = jnp.where(vok, vbuf[slot, :, lanes], 0)
+            acc_ref[g] = alpha * acc_ref[g] + jnp.dot(
+                p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+            m_ref[g] = m_new
+        return carry
+
+    jax.lax.fori_loop(c0, n_chunks, body, None)
+    for g in range(kvh):
+        o_ref[0, g] = (acc_ref[g] / l_ref[g]).astype(o_ref.dtype)
+
+
+def paged_attention_gqa(q, k_pool, v_pool, block_table, cache_pos,
+                        window: int = 0):
+    """Attention of ``q`` [b, kv_heads, t, group, head_dim] over the
+    pages ``block_table`` [b, S // page] names in the pools [P, page,
+    kv_heads * head_dim]: the ``group`` query heads of key-value head
+    ``g`` read lanes ``g * head_dim ..`` of a pool row. Row ``r``'s query
+    ``j`` sits at position ``cache_pos[r] + j`` and sees the positions up
+    to its own, the last ``window`` of them if one is given. The new
+    positions are in the pool already. Returns q's shape and dtype.
+    ``paged_attend_path`` says whether the shapes qualify."""
+    return _paged_attention_gqa(q, k_pool, v_pool, block_table, cache_pos,
+                                int(window), INTERPRET)
+
+
+@functools.partial(jax.jit, static_argnums=(5, 6))
+def _paged_attention_gqa(q, k_pool, v_pool, block_table, cache_pos, window,
+                         interpret):
+    b, kvh, t, group, hd = q.shape
+    page, mb = k_pool.shape[1], block_table.shape[1]
+    ppc = max(1, _GQA_CHUNK // page)
+    tq = min(_GQA_TQ, t)
+    t_pad = -(-t // tq) * tq
+    rows = tq * group
+    qx = jnp.pad(q, ((0, 0), (0, 0), (0, t_pad - t), (0, 0), (0, 0)))
+    qx = qx.reshape(b, kvh, t_pad * group, hd)
+    kernel = functools.partial(
+        _gqa_kernel, kvh=kvh, group=group, hd=hd, t=t, tq=tq, page=page,
+        ppc=ppc, mb=mb, window=window, scale=1.0 / math.sqrt(hd))
+    block = pl.BlockSpec((1, kvh, rows, hd), lambda r, qb, *_: (r, 0, qb, 0))
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(b, t_pad // tq),
+            in_specs=[block,
+                      pl.BlockSpec(memory_space=pl.ANY),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=block,
+            scratch_shapes=[
+                pltpu.VMEM((2, ppc * page, kvh * hd), k_pool.dtype),
+                pltpu.VMEM((2, ppc * page, kvh * hd), v_pool.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.VMEM((kvh, rows, 1), jnp.float32),
+                pltpu.VMEM((kvh, rows, 1), jnp.float32),
+                pltpu.VMEM((kvh, rows, hd), jnp.float32),
+            ]),
+        out_shape=jax.ShapeDtypeStruct(qx.shape, q.dtype),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_GQA_VMEM),
+        interpret=interpret,
+        name=("paged_gqa_" + ("decode" if t == 1 else "prefill")
+              + ("_window" if window else "_full")),
+    )(block_table.reshape(-1).astype(jnp.int32),
+      cache_pos.astype(jnp.int32), qx, k_pool, v_pool)
+    return out.reshape(b, kvh, t_pad, group, hd)[:, :, :t]

@@ -4,7 +4,8 @@ This is the single source of truth for every program the inference
 engine dispatches — the bucketed prefill, the admit scatter, the fused
 ``decode_chunk`` scan, and the paged-KV family (prefix-aware paged
 prefill, copy-on-write page copy, paged decode, fused draft+verify
-speculative decode).  ``serve/engine.py`` acquires them through the
+speculative decode), for whichever model the program key names
+(``models/serving.py``).  ``serve/engine.py`` acquires them through the
 registry (replacing its six retired module-global ``lru_cache`` stores)
 and ``analysis/jaxpr_audit.py`` enumerates them through the same
 functions — so the auditor's key set and the registry's key set are the
@@ -23,15 +24,13 @@ per-builder docstrings below.
 
 from __future__ import annotations
 
-import dataclasses
 import functools
-from typing import Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..models.nanogpt import GPT, GPTConfig, sample_logits
+from ..models.serving import config_from_key, sample_logits
 from .registry import ProgramDef
 
 # -- aval templates --------------------------------------------------------
@@ -49,19 +48,18 @@ _KEY_T = jax.ShapeDtypeStruct((2,), np.uint32)
 
 
 def _qtag(cfg_tuple: tuple) -> str:
-    """Name suffix for quantized-serving configs (ISSUE 11): the f32
-    default keeps its historical names (grep-stable), while a quantized
-    program's NAME carries its dtypes — the auditor's recompile guard
-    treats same-name-different-key as a collision, so two dtype variants
-    of one program must not share a name."""
-    cfg = GPTConfig(*cfg_tuple)
-    parts = []
-    if cfg.weights_dtype != "f32":
-        parts.append(f"w={cfg.weights_dtype}"
-                     + ("+emb" if cfg.quant_embed else ""))
-    if cfg.kv_dtype != "f32":
-        parts.append(f"kv={cfg.kv_dtype}")
-    return ("," + ",".join(parts)) if parts else ""
+    """What a program's NAME says of its model and dtypes (the config's
+    own ``program_tag``): two variants of one program must not share a
+    name, the auditor's recompile guard treats same-name-different-key as
+    a collision."""
+    return config_from_key(cfg_tuple).program_tag()
+
+
+def _model(cfg_tuple: tuple):
+    """``(config, module)`` of a program key: the programs name no model
+    (``models/serving.py`` says what they ask of one)."""
+    cfg = config_from_key(cfg_tuple)
+    return cfg, cfg.build()
 
 
 @functools.lru_cache(maxsize=64)
@@ -71,8 +69,7 @@ def _templates(cfg_tuple: tuple, batch: int, paged: bool):
     nothing compiles.  Bounded lru: entries are tiny aval trees, keyed
     by full config, and 64 far exceeds the distinct (config × batch)
     pairs any process serves."""
-    cfg = GPTConfig(*cfg_tuple)
-    model = GPT(cfg)
+    cfg, model = _model(cfg_tuple)
     dummy = jnp.zeros((batch, 1), jnp.int32)
     if paged:
         mb = cfg.block_size // cfg.page_size
@@ -92,8 +89,7 @@ def _templates(cfg_tuple: tuple, batch: int, paged: bool):
 
 
 def build_prefill(cfg_tuple: tuple, bucket: int):
-    cfg = GPTConfig(*cfg_tuple)
-    model = GPT(cfg)
+    cfg, model = _model(cfg_tuple)
 
     @jax.jit
     def prefill(params, tokens, true_len, key, temp, top_k, top_p):
@@ -136,8 +132,7 @@ def build_slot_admit(cfg_tuple: tuple, num_slots: int):
 
 
 def build_slot_decode(cfg_tuple: tuple, num_slots: int, chunk: int):
-    cfg = GPTConfig(*cfg_tuple)
-    model = GPT(cfg)
+    cfg, model = _model(cfg_tuple)
 
     @functools.partial(jax.jit, donate_argnums=(1,))
     def decode(params, cache, tok, active, base_keys, gen_idx,
@@ -191,8 +186,7 @@ def build_slot_decode(cfg_tuple: tuple, num_slots: int, chunk: int):
 
 
 def build_paged_prefill(cfg_tuple: tuple, bucket: int):
-    cfg = GPTConfig(*cfg_tuple)
-    model = GPT(cfg)
+    cfg, model = _model(cfg_tuple)
 
     @functools.partial(jax.jit, donate_argnums=(1,))
     def prefill(params, cache, bt_row, start, tokens, true_suffix, key,
@@ -205,11 +199,10 @@ def build_paged_prefill(cfg_tuple: tuple, bucket: int):
         length. Samples the request's first token (key-schedule index 0)
         at the true last prompt position and returns it with the updated
         pool — the pool is DONATED: suffix K/V scatter in place."""
-        logits, varsc = model.apply(
+        last, varsc = model.apply(
             {"params": params, "cache": cache}, tokens, train=False,
-            mutable=["cache"], block_table=bt_row, cache_pos=start)
-        last = jax.lax.dynamic_index_in_dim(logits, true_suffix - 1,
-                                            axis=1, keepdims=False)  # [1,V]
+            mutable=["cache"], block_table=bt_row, cache_pos=start,
+            last_pos=true_suffix - 1)                                # [1,V]
         tok = sample_logits(last, jax.random.fold_in(key, 0),
                             temp, top_k, top_p)
         return tok, varsc["cache"]
@@ -237,8 +230,7 @@ def build_paged_decode(cfg_tuple: tuple, num_slots: int, chunk: int):
     (``pos``) instead of a cache variable. Inactive rows have their
     tables redirected to the NULL page so their garbage writes can never
     touch a page that was freed and reallocated to a live slot."""
-    cfg = GPTConfig(*cfg_tuple)
-    model = GPT(cfg)
+    cfg, model = _model(cfg_tuple)
 
     @functools.partial(jax.jit, donate_argnums=(1,))
     def decode(params, cache, bt, tok, active, pos, base_keys, gen_idx,
@@ -248,8 +240,8 @@ def build_paged_decode(cfg_tuple: tuple, num_slots: int, chunk: int):
             bt_eff = jnp.where(act[:, None], bt, 0)
             logits, varsc = model.apply(
                 {"params": params, "cache": cache}, tok[:, None],
-                train=False, mutable=["cache"], block_table=bt_eff,
-                cache_pos=pos)
+                train=False, mutable=["cache", "counters"],
+                block_table=bt_eff, cache_pos=pos)
             lg = logits[:, 0]                           # [S, V]
             # quarantine is latched PER ITERATION while the row is
             # active: the null-page redirect means a finished row's
@@ -265,16 +257,20 @@ def build_paged_decode(cfg_tuple: tuple, num_slots: int, chunk: int):
             gidx = jnp.where(act, gidx + 1, gidx)
             rem = jnp.where(act, rem - 1, rem)
             done = act & ((rem <= 0) | ((eos >= 0) & (nxt == eos)))
+            # what the model counted this step (``counters``: small
+            # integer arrays, or nothing), summed over the chunk below
             return ((varsc["cache"], nxt, act & ~done, pos, gidx, rem,
-                     nanc, lg), (nxt, emitted))
+                     nanc, lg), (nxt, emitted, varsc.get("counters", {})))
 
         lg0 = jnp.zeros((num_slots, cfg.vocab_size), jnp.float32)
         nan0 = jnp.zeros((num_slots,), bool)
         (cache, tok, active, pos, gen_idx, remaining, nan_seen, lg), \
-            (toks, emitted) = jax.lax.scan(
+            (toks, emitted, counted) = jax.lax.scan(
                 body, (cache, tok, active, pos, gen_idx, remaining,
                        nan0, lg0), None, length=chunk)
-        return toks, emitted, lg, tok, active, pos, nan_seen, cache
+        counted = jax.tree.map(lambda c: c.sum(axis=0), counted)
+        return (toks, emitted, lg, tok, active, pos, nan_seen, cache,
+                counted)
 
     return decode
 
@@ -324,8 +320,7 @@ def build_spec_decode(cfg_tuple: tuple, num_slots: int, chunk: int,
     rewind — their K/V sit beyond the new cursor in slot-owned blocks,
     causally masked until overwritten (exactly how padded prefill K/V
     are retired)."""
-    cfg = GPTConfig(*cfg_tuple)
-    model = GPT(cfg)
+    cfg, model = _model(cfg_tuple)
     g1 = int(gamma) + 1
 
     @functools.partial(jax.jit, donate_argnums=(1,))
@@ -387,7 +382,8 @@ def build_spec_decode(cfg_tuple: tuple, num_slots: int, chunk: int,
          lg), (toks, emit) = jax.lax.scan(
                 body, (cache, tok, active, pos, gen_idx, remaining,
                        hist, nan0, lg0), None, length=chunk)
-        return toks, emit, lg, tok, active, pos, nan_seen, cache
+        # the last output: what a model counts (nothing is counted here)
+        return toks, emit, lg, tok, active, pos, nan_seen, cache, {}
 
     return spec
 
@@ -439,7 +435,7 @@ def slot_decode_def(cfg_tuple: tuple, num_slots: int,
 
 
 def _paged_cfg(cfg_tuple: tuple):
-    cfg = GPTConfig(*cfg_tuple)
+    cfg = config_from_key(cfg_tuple)
     if not cfg.page_size or not cfg.kv_pages:
         raise ValueError(
             "paged program defs need a config with page_size/kv_pages "

@@ -570,6 +570,11 @@ def create_server(params, cfg, *, host: str = "127.0.0.1", port: int = 0,
                 # with an f32 pool, 0 on the gather path
                 "paged_kernel_dispatches": sum(
                     s.paged_kernel_dispatches for s in stats),
+                # what the model counted in its decode steps (expert
+                # picks, pages read and skipped, ...): engine 0's sums
+                "model_counters": {
+                    k: np.asarray(v).tolist()
+                    for k, v in stats[0].model_counters.items()},
                 "warmup": (warm_thread.stats()
                            if warm_thread is not None else None),
                 # multi-tenant serving (ISSUE 17): live quota fill,

@@ -72,8 +72,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..models.nanogpt import GPT, GPTConfig, decode_config
-from ..ops.paged_attention import KERNEL, paged_attend_path
+from ..models.serving import attend_path_id
+from ..ops.paged_attention import GATHER
 from ..programs import default_registry
 from ..programs.serve_defs import (cow_def, paged_decode_def,
                                    paged_prefill_def, prefill_def,
@@ -83,6 +83,9 @@ from ..utils.resilience import fault_point
 from ..utils.trace import span
 
 PyTree = Any
+
+
+_KV_BYTES = {"int8": 1, "bf16": 2, "f32": 4}
 
 
 class NoFreeSlotError(RuntimeError):
@@ -192,6 +195,10 @@ class EngineStats:
     # metrics/serve.csv/stats report them without reaching into config
     weights_dtype: str = "f32"
     kv_dtype: str = "f32"
+    # what the model counted in its decode steps, summed since the
+    # engine was built: {"<layer>/<module>/<name>": integers}; empty for
+    # a model that counts nothing (models/serving.py: ``counters``)
+    model_counters: Dict[str, Any] = dataclasses.field(default_factory=dict)
 
     def spec_accept_rate(self) -> Optional[float]:
         """Accepted / drafted speculative tokens (None before the first
@@ -357,7 +364,7 @@ class InferenceEngine:
     (the scheduler serializes access).
     """
 
-    def __init__(self, params: PyTree, config: GPTConfig,
+    def __init__(self, params: PyTree, config: Any,
                  num_slots: int = 8, decode_chunk: int = 1,
                  paged: bool = False, page_size: int = 16,
                  kv_pages: Optional[int] = None, spec_tokens: int = 0,
@@ -404,15 +411,17 @@ class InferenceEngine:
         self.weights_tag = weights_tag
         self.weights_dtype = str(getattr(config, "weights_dtype", "f32"))
         self.kv_dtype = str(getattr(config, "kv_dtype", "f32"))
-        if self.weights_dtype not in ("f32", "int8", "int4"):
+        # the dtypes any model may serve in; each model refuses those it
+        # cannot (its module raises when the programs are traced)
+        if self.weights_dtype not in ("f32", "bf16", "int8", "int4"):
             raise ValueError(
-                f"weights_dtype must be 'f32', 'int8' or 'int4', got "
-                f"{self.weights_dtype!r}")
-        if self.kv_dtype not in ("f32", "int8"):
+                f"weights_dtype must be 'f32', 'bf16', 'int8' or 'int4', "
+                f"got {self.weights_dtype!r}")
+        if self.kv_dtype not in _KV_BYTES:
             raise ValueError(
-                f"kv_dtype must be 'f32' or 'int8', got "
+                f"kv_dtype must be 'f32', 'bf16' or 'int8', got "
                 f"{self.kv_dtype!r}")
-        base_cfg = decode_config(config)
+        base_cfg = config.decode_config()
         self.block_size = int(config.block_size)
         self.num_slots = int(num_slots)
         self.decode_chunk = int(decode_chunk)
@@ -437,11 +446,9 @@ class InferenceEngine:
             self.config = dataclasses.replace(
                 base_cfg, page_size=self.page_size, kv_pages=self.kv_pages)
             self._alloc = BlockAllocator(self.kv_pages, self.page_size)
-            # the model's own dispatch point, asked with what it will be
-            # asked: the id on the dispatch spans and what /stats counts
-            self.attend_path = paged_attend_path(
-                base_cfg.n_embd, self.page_size, jnp.float32,
-                jnp.int8 if self.kv_dtype == "int8" else jnp.float32)
+            # the model's own dispatch point, asked with what its layers
+            # will ask: the id on the dispatch spans and what /stats counts
+            self.attend_path = attend_path_id(self.config)
         else:
             self.page_size = 0
             self.attend_path = "dense"
@@ -449,17 +456,12 @@ class InferenceEngine:
             self.kv_pages = 0
             self.config = base_cfg
             self._alloc = None
-        if self.weights_dtype != "f32":
-            # quantize-at-load: accept either an f32 checkpoint tree or
-            # a pre-quantized one (load_for_serving quantizes once; the
-            # fleet's factory rebuilds then detect and skip)
-            from .load import params_are_quantized, quantize_params
-            if not params_are_quantized(params):
-                params = quantize_params(params, self.config)
-        self.params = jax.tree.map(jnp.asarray, params)
+        self._kernel_attend = self.paged and GATHER not in self.attend_path
+        self.params = jax.tree.map(jnp.asarray,
+                                   self.config.prepare_params(params))
         self.weights_bytes = int(sum(x.nbytes
                                      for x in jax.tree.leaves(self.params)))
-        self._cfg_tuple = dataclasses.astuple(self.config)
+        self._cfg_tuple = self.config.program_key()
         # every program comes from the process-wide device-program
         # registry (gym_tpu.programs): engines over the same config —
         # replicas, supervisor rebuilds, hot-swapped generations —
@@ -513,8 +515,9 @@ class InferenceEngine:
 
     @property
     def kv_elem_bytes(self) -> int:
-        """Bytes per stored KV element (1 under int8, 4 under f32)."""
-        return 1 if self.kv_dtype == "int8" else 4
+        """Bytes per stored KV element (1 under int8, 2 under bf16, 4
+        under f32)."""
+        return _KV_BYTES[self.kv_dtype]
 
     @property
     def kv_blocks_capacity_effective(self) -> int:
@@ -608,8 +611,20 @@ class InferenceEngine:
             defs.extend(prefill_def(cfg, b) for b in buckets)
         return defs
 
+    def _count(self, counted: PyTree) -> int:
+        """Add what the model counted in this dispatch to
+        ``stats.model_counters``; returns the bytes read back."""
+        read = 0
+        totals = self.stats.model_counters
+        for path, leaf in jax.tree_util.tree_flatten_with_path(counted)[0]:
+            leaf = np.asarray(leaf)
+            read += leaf.nbytes
+            name = "/".join(str(getattr(k, "key", k)) for k in path)
+            totals[name] = totals.get(name, 0) + leaf.astype(np.int64)
+        return read
+
     def _init_cache(self) -> PyTree:
-        model = GPT(self.config)
+        model = self.config.build()
         dummy = jnp.zeros((self.num_slots, 1), jnp.int32)
         if self.paged:
             # the pool is batch-shape independent ([kv_pages, page,
@@ -907,7 +922,7 @@ class InferenceEngine:
             with span("serve.prefill.dispatch", path=self.attend_path):
                 tok, self._cache = prefill(self.params, self._cache,
                                            *args)
-            self.stats.paged_kernel_dispatches += self.attend_path == KERNEL
+            self.stats.paged_kernel_dispatches += self._kernel_attend
         except BaseException:
             for pg in held:
                 al.decref(pg)
@@ -1102,16 +1117,17 @@ class InferenceEngine:
                 head = (self.params, self._cache)
         with span("serve.decode.dispatch", path=self.attend_path):
             out = prog(*head, *tail)
-        self.stats.paged_kernel_dispatches += self.attend_path == KERNEL
+        self.stats.paged_kernel_dispatches += self._kernel_attend
         with span("serve.decode.readback") as rb:
             # the first read waits for the step; the logits are the bulk
             if self.paged:
                 toks, emitted, lg, final_tok, final_active, final_pos, \
-                    nan_seen, cache = out
+                    nan_seen, cache, counted = out
                 final_pos = np.asarray(final_pos)
                 nan_seen = np.asarray(nan_seen)
                 self._pos = final_pos.astype(np.int32).copy()
                 read = final_pos.nbytes + nan_seen.nbytes
+                read += self._count(counted)
             else:
                 toks, emitted, lg, final_tok, final_active, cache = out
                 nan_seen, read = None, 0

@@ -1,4 +1,4 @@
-"""Params-only checkpoint → serveable (params, GPTConfig).
+"""Params-only checkpoint → serveable (params, config).
 
 A training run dir (``fit(save_dir=..., checkpoint_interval=...)``) holds
 step-numbered Orbax checkpoints of the FULL train state — per-node
@@ -33,7 +33,7 @@ from typing import Any, Callable, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..models.nanogpt import GPTConfig
+from ..models.serving import config_from_dict
 from ..utils.checkpoint import CheckpointNotFoundError, restore_params
 
 PyTree = Any
@@ -128,27 +128,28 @@ def read_run_config(run_dir: str,
         return json.load(f)
 
 
-def gpt_config_from_run(config: Dict[str, Any]) -> GPTConfig:
-    """Rebuild the ``GPTConfig`` from a captured run config
+def gpt_config_from_run(config: Dict[str, Any]):
+    """Rebuild the model's config from a captured run config
     (``trainer._model_config`` flattens the module's nested ``config``
-    dataclass into ``model_config.config``). Unknown keys are ignored so
+    dataclass into ``model_config.config``; the family is the snapshot's
+    ``model_type``, GPT-2 where it names none:
+    ``models/serving.py:config_from_dict``). Unknown keys are ignored so
     an older server binary can read a newer run's snapshot."""
     model_cfg = (config.get("model_config") or {}).get("config")
     if not isinstance(model_cfg, dict):
         raise ValueError(
             "config.json carries no model_config.config — was this run's "
-            "model a GPT? (serving currently supports the GPT family)")
-    fields = {f.name for f in dataclasses.fields(GPTConfig)}
-    return GPTConfig(**{k: v for k, v in model_cfg.items() if k in fields})
+            "model one the serving stack knows (models/serving.py)?")
+    return config_from_dict(model_cfg)
 
 
 def load_for_serving(run_dir: str, step: Optional[int] = None,
-                     config: Optional[GPTConfig] = None,
+                     config: Optional[Any] = None,
                      config_path: Optional[str] = None,
                      weights_dtype: Optional[str] = None,
                      kv_dtype: Optional[str] = None,
                      quant_embed: bool = False
-                     ) -> Tuple[PyTree, GPTConfig, Dict[str, Any]]:
+                     ) -> Tuple[PyTree, Any, Dict[str, Any]]:
     """Restore a ``fit()`` run dir for inference.
 
     Returns ``(params, config, info)``: the node-AVERAGED f32 param tree

@@ -80,7 +80,7 @@ def _build_parser() -> argparse.ArgumentParser:
                      help="pickled numpy params tree written by the "
                           "router (fleet spawn path)")
     src.add_argument("--config-json", default=None, metavar="JSON",
-                     help="GPTConfig fields as JSON (with --params-file)")
+                     help="the model config's fields as JSON (with --params-file)")
     p.add_argument("--replica-id", type=int, default=0)
     p.add_argument("--num_slots", type=int, default=4)
     p.add_argument("--decode_chunk", type=int, default=1)
@@ -468,7 +468,7 @@ def main(argv=None) -> int:
         f"gym_tpu.serve.worker: program registry disk tier at "
         f"{resolved}\n")
 
-    from ..models.nanogpt import GPTConfig
+    from ..models.serving import config_from_dict
     from .engine import InferenceEngine
     from .metrics import ServeMetrics
     from .scheduler import Scheduler
@@ -483,8 +483,7 @@ def main(argv=None) -> int:
             params = pickle.load(f)
         with open(args.config_json) as f:
             raw = json.load(f)
-        fields = {f.name for f in dataclasses.fields(GPTConfig)}
-        cfg = GPTConfig(**{k: v for k, v in raw.items() if k in fields})
+        cfg = config_from_dict(raw)
     elif args.ckpt:
         from .load import load_for_serving
         params, cfg, info = load_for_serving(

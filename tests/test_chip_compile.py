@@ -208,3 +208,93 @@ def test_demo_dct_topk_compiles_at_gpt2_base_leaves(v5e_chip, shape):
         return codec.from_chunks(tiles)
 
     _compile(fn, v5e_chip, (shape, jnp.float32))
+
+
+# the Cohere2-MoE cell as served (perfbench/configs/command-a-plus.json,
+# perfbench/traffic/serve-closed-rag.json): 4 layers, 16 held experts,
+# 32,768 rows of the vocabulary, bfloat16, 32 slots, 12,288 pages of 16
+CHIP_BYTES = int(15.75 * 1024 ** 3)
+
+
+def _command_a_plus_cfg(kv_pages=12288):
+    import dataclasses
+    from gym_tpu.models.cohere2_moe import Cohere2MoeConfig
+    return dataclasses.replace(
+        Cohere2MoeConfig(
+            vocab_size=32768, num_hidden_layers=4, held_experts=(0, 16),
+            block_size=16384).decode_config(),
+        page_size=16, kv_pages=kv_pages)
+
+
+@pytest.mark.parametrize("t,window", [(1, 0), (1, 4096), (4096, 4096),
+                                      (16384, 0), (16384, 4096)],
+                         ids=["decode_full", "decode_window",
+                              "prefill4k_window", "prefill16k_full",
+                              "prefill16k_window"])
+def test_grouped_paged_kernel_compiles_for_v5e(v5e_chip, t, window):
+    """The grouped page walk at the cell's sizes: 16 query heads a
+    key-value head, head dimension 128, a bfloat16 pool row of 1,024
+    lanes, with and without a window."""
+    b = 32 if t == 1 else 1
+    hlo = _compile(
+        functools.partial(paged_attention.paged_attention_gqa,
+                          window=window),
+        v5e_chip, ((b, 8, t, 16, 128), jnp.bfloat16),
+        ((12288, 16, 1024), jnp.bfloat16), ((12288, 16, 1024), jnp.bfloat16),
+        ((b, 1024), jnp.int32), ((b,), jnp.int32))
+    name = ("paged_gqa_" + ("decode" if t == 1 else "prefill")
+            + ("_window" if window else "_full"))
+    assert "tpu_custom_call" in hlo and name in hlo
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill4096",
+                                     "prefill16384"])
+def test_command_a_plus_programs_fit_the_chip(v5e_chip, monkeypatch,
+                                              program):
+    """The decode program and the 4,096 and 16,384 prefill buckets as the
+    cell's engine compiles them: arguments (9.5 GB of weights, 3 GiB of
+    pool), outputs and temporaries fit the 15.75 GiB the chip gives; both
+    grouped kernels are in, the expert products are XLA's grouped-matmul
+    kernel; and besides the in-place scatter of the new positions no
+    instruction's result is the size of a pool array or of a gathered
+    ``[b, S, kv_heads, head_dim]`` window."""
+    import re
+    from gym_tpu.programs import serve_defs
+    monkeypatch.setattr(paged_attention, "_on_tpu", lambda: True)
+    cfg = _command_a_plus_cfg()
+    key = cfg.program_key()
+    assert set(cfg.attend_paths()) == {paged_attention.KERNEL,
+                                       paged_attention.KERNEL_WINDOW}
+    pdef = (serve_defs.paged_decode_def(key, 32, 1) if program == "decode"
+            else serve_defs.paged_prefill_def(key, int(program[7:])))
+    args = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=v5e_chip),
+        pdef.args)
+    compiled = pdef.builder().lower(*args).compile()
+    mem = compiled.memory_analysis()
+    # the donated pool is argument and output alike: counted once
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    assert total < CHIP_BYTES, (total / 2 ** 30, mem)
+    hlo = compiled.as_text()
+    kind = "decode" if program == "decode" else "prefill"
+    assert f"paged_gqa_{kind}_full" in hlo
+    assert f"paged_gqa_{kind}_window" in hlo
+    assert "ragged-dot" in hlo
+    pool_elems = cfg.kv_pages * 16 * 1024
+    big = []
+    for m in re.finditer(
+            r"= (\w+)\[([\d,]+)\]\S* (copy|gather|transpose|"
+            r"dynamic-update-slice|dynamic-slice|convert)\(", hlo):
+        n = 1
+        for d in m.group(2).split(","):
+            n *= int(d)
+        if n >= pool_elems:
+            big.append(m.group(0))
+    assert not big, big[:3]
+    # and no row's pages are gathered out of a pool into a window
+    pool = f"bf16[{cfg.kv_pages},16,1024]"
+    gathered = [line for line in hlo.splitlines()
+                if " gather(" in line and pool in line]
+    assert not gathered, gathered[:2]
+    assert re.search(r"input_output_alias=\{.*may-alias", hlo)
