@@ -46,6 +46,32 @@ def _vec(n, dt):
 
 _KEY_T = jax.ShapeDtypeStruct((2,), np.uint32)
 
+# The decode programs' per-slot state, by name.  A decode program takes
+# it as ONE dict and returns it whole: what it advanced (``tok``,
+# ``active``, ``gen_idx``, ``remaining``; paged also ``pos``, speculative
+# also ``hist``) and, unchanged, what only an admission writes
+# (``base_keys``, ``eos``, ``temp``, ``top_k``, ``top_p``, the block
+# table).  The returned dict is the next dispatch's argument as it is:
+# the state lives on the device, and the engine hands over a NumPy array
+# in an entry's place only where the host wrote that entry since
+# (``serve/engine.py``: the host's mirrors).
+SLOT_STATE = ("tok", "active", "base_keys", "gen_idx", "remaining", "eos",
+              "temp", "top_k", "top_p")
+PAGED_STATE = SLOT_STATE + ("bt", "pos")
+SPEC_STATE = PAGED_STATE + ("hist",)
+
+
+def _state_tpl(names, s: int, mb: int = 0, block_size: int = 0) -> dict:
+    tpl = {"tok": _vec(s, np.int32), "active": _vec(s, np.bool_),
+           "base_keys": jax.ShapeDtypeStruct((s, 2), np.uint32),
+           "gen_idx": _vec(s, np.int32), "remaining": _vec(s, np.int32),
+           "eos": _vec(s, np.int32), "temp": _vec(s, np.float32),
+           "top_k": _vec(s, np.int32), "top_p": _vec(s, np.float32),
+           "bt": jax.ShapeDtypeStruct((s, mb), np.int32),
+           "pos": _vec(s, np.int32),
+           "hist": jax.ShapeDtypeStruct((s, block_size), np.int32)}
+    return {name: tpl[name] for name in names}
+
 
 def _qtag(cfg_tuple: tuple) -> str:
     """What a program's NAME says of its model and dtypes (the config's
@@ -135,8 +161,7 @@ def build_slot_decode(cfg_tuple: tuple, num_slots: int, chunk: int):
     cfg, model = _model(cfg_tuple)
 
     @functools.partial(jax.jit, donate_argnums=(1,))
-    def decode(params, cache, tok, active, base_keys, gen_idx,
-               remaining, eos, temp, top_k, top_p):
+    def decode(params, cache, state):
         """``chunk`` decode steps for the whole slot batch in ONE
         dispatch (a ``lax.scan``, amortizing per-dispatch overhead the
         way ``generate_fast``'s whole-request scan does). Each scanned
@@ -148,16 +173,30 @@ def build_slot_decode(cfg_tuple: tuple, num_slots: int, chunk: int):
         creep, no garbage emission; its masked compute is the price of
         the fixed shape until the next admit).
 
-        Returns ``(toks [chunk, S], emitted [chunk, S], last_logits
-        [S, V], final_tok, final_active, cache)`` — ``emitted`` marks
-        which scanned steps each slot was active for; the host replays
-        it to route tokens to requests."""
+        ``state`` is the ``SLOT_STATE`` dict. Returns ``(read, logits,
+        state, cache)``:
+
+        - ``read``: the few small arrays the host downloads after every
+          step: ``toks`` / ``emitted`` [chunk, S] (``emitted`` marks
+          which scanned steps each slot was active for; the host replays
+          it to route tokens to requests), the final ``tok`` / ``active``
+          [S], ``nan_seen`` [S] (non-finite logits while the row was
+          active, latched per scanned step: no path reads logits to
+          decide anything) and ``counted`` (nothing here);
+        - ``logits`` [S, V]: the last scanned step's, left on the device
+          (teacher forcing and tests fetch them);
+        - ``state``: the argument with what this dispatch advanced, the
+          next dispatch's argument as it is."""
+        base_keys, eos = state["base_keys"], state["eos"]
+        temp, top_k, top_p = state["temp"], state["top_k"], state["top_p"]
+
         def body(carry, _):
-            cache, tok, act, gidx, rem, _lg = carry
+            cache, tok, act, gidx, rem, nanc, _lg = carry
             logits, varsc = model.apply(
                 {"params": params, "cache": cache}, tok[:, None],
                 train=False, mutable=["cache"])
             lg = logits[:, 0]                               # [S, V]
+            nanc = nanc | (act & ~jnp.isfinite(lg).all(axis=-1))
             keys = jax.vmap(jax.random.fold_in)(base_keys, gidx)
             nxt = jax.vmap(sample_logits)(lg, keys, temp, top_k, top_p)
             nxt = jnp.where(act, nxt, tok).astype(jnp.int32)
@@ -172,15 +211,21 @@ def build_slot_decode(cfg_tuple: tuple, num_slots: int, chunk: int):
             # last step's logits ride in the CARRY (teacher-forcing /
             # debug observable) — stacking [chunk, S, V] would move the
             # whole vocab per scanned step at GPT-2 vocab sizes
-            return ((new_cache, nxt, act & ~done, gidx, rem, lg),
+            return ((new_cache, nxt, act & ~done, gidx, rem, nanc, lg),
                     (nxt, emitted))
 
         lg0 = jnp.zeros((num_slots, cfg.vocab_size), jnp.float32)
-        (cache, tok, active, gen_idx, remaining, lg), (toks, emitted) = \
-            jax.lax.scan(body,
-                         (cache, tok, active, gen_idx, remaining, lg0),
-                         None, length=chunk)
-        return toks, emitted, lg, tok, active, cache
+        nan0 = jnp.zeros((num_slots,), bool)
+        (cache, tok, active, gen_idx, remaining, nan_seen, lg), \
+            (toks, emitted) = jax.lax.scan(
+                body, (cache, state["tok"], state["active"],
+                       state["gen_idx"], state["remaining"], nan0, lg0),
+                None, length=chunk)
+        read = {"toks": toks, "emitted": emitted, "tok": tok,
+                "active": active, "nan_seen": nan_seen, "counted": {}}
+        state = {**state, "tok": tok, "active": active,
+                 "gen_idx": gen_idx, "remaining": remaining}
+        return read, lg, state, cache
 
     return decode
 
@@ -229,12 +274,21 @@ def build_paged_decode(cfg_tuple: tuple, num_slots: int, chunk: int):
     slot's block table and the per-row cursor is explicit carry state
     (``pos``) instead of a cache variable. Inactive rows have their
     tables redirected to the NULL page so their garbage writes can never
-    touch a page that was freed and reallocated to a live slot."""
+    touch a page that was freed and reallocated to a live slot.
+
+    ``decode(params, cache, state)`` with the ``PAGED_STATE`` dict;
+    returns ``(read, logits, state, cache)`` as the slot decode does,
+    ``read`` with the final ``pos`` besides and with ``counted``: what
+    the model counted (its ``counters`` collection), summed over the
+    chunk. ``state`` comes back with ``pos`` advanced and the block
+    table as it was given."""
     cfg, model = _model(cfg_tuple)
 
     @functools.partial(jax.jit, donate_argnums=(1,))
-    def decode(params, cache, bt, tok, active, pos, base_keys, gen_idx,
-               remaining, eos, temp, top_k, top_p):
+    def decode(params, cache, state):
+        bt, base_keys, eos = state["bt"], state["base_keys"], state["eos"]
+        temp, top_k, top_p = state["temp"], state["top_k"], state["top_p"]
+
         def body(carry, _):
             cache, tok, act, pos, gidx, rem, nanc, _lg = carry
             bt_eff = jnp.where(act[:, None], bt, 0)
@@ -245,9 +299,8 @@ def build_paged_decode(cfg_tuple: tuple, num_slots: int, chunk: int):
             lg = logits[:, 0]                           # [S, V]
             # quarantine is latched PER ITERATION while the row is
             # active: the null-page redirect means a finished row's
-            # later iterations read clean garbage, so (unlike the
-            # unpaged program) the LAST step's logits cannot witness a
-            # poison that struck mid-chunk
+            # later iterations read clean garbage, so the LAST step's
+            # logits cannot witness a poison that struck mid-chunk
             nanc = nanc | (act & ~jnp.isfinite(lg).all(axis=-1))
             keys = jax.vmap(jax.random.fold_in)(base_keys, gidx)
             nxt = jax.vmap(sample_logits)(lg, keys, temp, top_k, top_p)
@@ -266,11 +319,16 @@ def build_paged_decode(cfg_tuple: tuple, num_slots: int, chunk: int):
         nan0 = jnp.zeros((num_slots,), bool)
         (cache, tok, active, pos, gen_idx, remaining, nan_seen, lg), \
             (toks, emitted, counted) = jax.lax.scan(
-                body, (cache, tok, active, pos, gen_idx, remaining,
-                       nan0, lg0), None, length=chunk)
+                body, (cache, state["tok"], state["active"], state["pos"],
+                       state["gen_idx"], state["remaining"], nan0, lg0),
+                None, length=chunk)
         counted = jax.tree.map(lambda c: c.sum(axis=0), counted)
-        return (toks, emitted, lg, tok, active, pos, nan_seen, cache,
-                counted)
+        read = {"toks": toks, "emitted": emitted, "tok": tok,
+                "active": active, "pos": pos, "nan_seen": nan_seen,
+                "counted": counted}
+        state = {**state, "tok": tok, "active": active, "pos": pos,
+                 "gen_idx": gen_idx, "remaining": remaining}
+        return read, lg, state, cache
 
     return decode
 
@@ -319,13 +377,20 @@ def build_spec_decode(cfg_tuple: tuple, num_slots: int, chunk: int,
     greedy. Rejected drafts need no page copy: the rollback is a cursor
     rewind — their K/V sit beyond the new cursor in slot-owned blocks,
     causally masked until overwritten (exactly how padded prefill K/V
-    are retired)."""
+    are retired).
+
+    ``spec(params, cache, state)`` with the ``SPEC_STATE`` dict; returns
+    ``(read, logits, state, cache)`` as the paged decode does, ``toks``
+    and ``emitted`` [chunk, S, γ+1]. The token history ``hist`` is part
+    of the state and stays on the device, grown by what each iteration
+    emitted; the host replays the same tokens into its own copy."""
     cfg, model = _model(cfg_tuple)
     g1 = int(gamma) + 1
 
     @functools.partial(jax.jit, donate_argnums=(1,))
-    def spec(params, cache, bt, hist, tok, active, pos, base_keys,
-             gen_idx, remaining, eos, temp, top_k, top_p):
+    def spec(params, cache, state):
+        bt, base_keys, eos = state["bt"], state["base_keys"], state["eos"]
+        temp, top_k, top_p = state["temp"], state["top_k"], state["top_p"]
         sample_row = jax.vmap(sample_logits,
                               in_axes=(0, 0, None, None, None))
 
@@ -380,10 +445,16 @@ def build_spec_decode(cfg_tuple: tuple, num_slots: int, chunk: int,
         nan0 = jnp.zeros((num_slots,), bool)
         (cache, tok, active, pos, gen_idx, remaining, hist, nan_seen,
          lg), (toks, emit) = jax.lax.scan(
-                body, (cache, tok, active, pos, gen_idx, remaining,
-                       hist, nan0, lg0), None, length=chunk)
-        # the last output: what a model counts (nothing is counted here)
-        return toks, emit, lg, tok, active, pos, nan_seen, cache, {}
+                body, (cache, state["tok"], state["active"], state["pos"],
+                       state["gen_idx"], state["remaining"], state["hist"],
+                       nan0, lg0), None, length=chunk)
+        # ``counted``: what a model counts (nothing is counted here)
+        read = {"toks": toks, "emitted": emit, "tok": tok,
+                "active": active, "pos": pos, "nan_seen": nan_seen,
+                "counted": {}}
+        state = {**state, "tok": tok, "active": active, "pos": pos,
+                 "gen_idx": gen_idx, "remaining": remaining, "hist": hist}
+        return read, lg, state, cache
 
     return spec
 
@@ -425,11 +496,7 @@ def slot_decode_def(cfg_tuple: tuple, num_slots: int,
         family="serve.decode",
         config={"config": cfg_tuple, "num_slots": s,
                 "decode_chunk": chunk},
-        args=(params_tpl, slot_cache_tpl, _vec(s, np.int32),
-              _vec(s, np.bool_), jax.ShapeDtypeStruct((s, 2), np.uint32),
-              _vec(s, np.int32), _vec(s, np.int32), _vec(s, np.int32),
-              _vec(s, np.float32), _vec(s, np.int32),
-              _vec(s, np.float32)),
+        args=(params_tpl, slot_cache_tpl, _state_tpl(SLOT_STATE, s)),
         donate_args=(1,),
         builder=lambda: build_slot_decode(cfg_tuple, s, chunk))
 
@@ -483,13 +550,7 @@ def paged_decode_def(cfg_tuple: tuple, num_slots: int,
         name=f"serve.paged_decode[slots={s},chunk={chunk}{_qtag(cfg_tuple)}]",
         family="serve.paged_decode",
         config={**pcfg, "num_slots": s, "decode_chunk": chunk},
-        args=(params_tpl, pool_tpl,
-              jax.ShapeDtypeStruct((s, mb), np.int32),
-              _vec(s, np.int32), _vec(s, np.bool_), _vec(s, np.int32),
-              jax.ShapeDtypeStruct((s, 2), np.uint32),
-              _vec(s, np.int32), _vec(s, np.int32), _vec(s, np.int32),
-              _vec(s, np.float32), _vec(s, np.int32),
-              _vec(s, np.float32)),
+        args=(params_tpl, pool_tpl, _state_tpl(PAGED_STATE, s, mb)),
         donate_args=(1,),
         builder=lambda: build_paged_decode(cfg_tuple, s, chunk))
 
@@ -505,12 +566,6 @@ def spec_decode_def(cfg_tuple: tuple, num_slots: int, chunk: int,
         config={**pcfg, "num_slots": s, "decode_chunk": chunk,
                 "gamma": gamma},
         args=(params_tpl, pool_tpl,
-              jax.ShapeDtypeStruct((s, mb), np.int32),
-              jax.ShapeDtypeStruct((s, cfg.block_size), np.int32),
-              _vec(s, np.int32), _vec(s, np.bool_), _vec(s, np.int32),
-              jax.ShapeDtypeStruct((s, 2), np.uint32),
-              _vec(s, np.int32), _vec(s, np.int32), _vec(s, np.int32),
-              _vec(s, np.float32), _vec(s, np.int32),
-              _vec(s, np.float32)),
+              _state_tpl(SPEC_STATE, s, mb, cfg.block_size)),
         donate_args=(1,),
         builder=lambda: build_spec_decode(cfg_tuple, s, chunk, gamma))

@@ -61,6 +61,23 @@ blocks, masked until overwritten). Every position is sampled from the
 true conditional with the request's own key schedule, so the emitted
 stream equals the non-speculative engine's EXACTLY for every sampling
 configuration — drafts only decide how many samples one dispatch keeps.
+
+**What crosses between host and device** (ISSUE 28). The decode
+programs' per-slot state (input token, active flag, cursor, key index,
+remaining budget, key, EOS id, temperature, top-k, top-p, block table;
+speculative: the token history) lives ON THE DEVICE: a decode dispatch
+returns it whole and the next one takes it as it is
+(``programs/serve_defs.py``: ``SLOT_STATE`` / ``PAGED_STATE`` /
+``SPEC_STATE``). The engine's NumPy arrays are the host's MIRROR of it,
+kept equal by replaying each step's small download. A write to a mirror
+outside ``step`` (admit, release, park, resume, quarantine, a forced
+token) names it stale, and the next dispatch is handed the stale mirrors
+themselves, as NumPy arguments: the executable transfers them with its
+other arguments, in one batch. The round's path calls no eager device
+operation (no ``jnp.asarray``, no ``PRNGKey``: the base key is made on
+the host), a step after which nothing was admitted or released uploads
+nothing, and a step reads back tokens and flags, a few KiB: the logits
+stay on the device until something asks for ``last_logits``.
 """
 
 from __future__ import annotations
@@ -75,7 +92,8 @@ import numpy as np
 from ..models.serving import attend_path_id
 from ..ops.paged_attention import GATHER
 from ..programs import default_registry
-from ..programs.serve_defs import (cow_def, paged_decode_def,
+from ..programs.serve_defs import (PAGED_STATE, SLOT_STATE, SPEC_STATE,
+                                   cow_def, paged_decode_def,
                                    paged_prefill_def, prefill_def,
                                    slot_admit_def, slot_decode_def,
                                    spec_decode_def)
@@ -86,6 +104,28 @@ PyTree = Any
 
 
 _KV_BYTES = {"int8": 1, "bf16": 2, "f32": 4}
+
+# the engine's attribute that mirrors each entry of a decode program's
+# state (``programs/serve_defs.py``); ``remaining`` and ``eos`` are made
+# from theirs (``InferenceEngine._mirror``)
+_MIRROR = {"tok": "_next_tok", "active": "_active", "pos": "_pos",
+           "gen_idx": "_gen_idx", "base_keys": "_base_keys",
+           "temp": "_temp", "top_k": "_top_k", "top_p": "_top_p",
+           "bt": "_bt", "hist": "_hist"}
+
+
+def derive_base_key(seed: int) -> np.ndarray:
+    """The two words of ``jax.random.PRNGKey(seed)``, made on the host:
+    under threefry the seed's high and low 32 bits (the high word is 0
+    without 64-bit mode, where JAX keeps the seed's low 32 bits only).
+    ``PRNGKey`` itself is a dispatch and a read-back; any other key
+    implementation, and a seed offset, keep it."""
+    if (jax.config.jax_default_prng_impl != "threefry2x32"
+            or jax.config.jax_random_seed_offset):
+        return np.asarray(jax.random.PRNGKey(seed), np.uint32)
+    bits = int(np.int64(seed)) & (2 ** 64 - 1)   # OverflowError as JAX's
+    high = (bits >> 32) if jax.config.jax_enable_x64 else 0
+    return np.array([high, bits & 0xFFFFFFFF], np.uint32)
 
 
 class NoFreeSlotError(RuntimeError):
@@ -128,7 +168,8 @@ class ParkedSlot:
     ``resume`` continues the generation byte-identical to an
     uncontended run: everything a decode dispatch reads about a slot
     (block table, cursors, token history, sampling vectors, the
-    ``fold_in(base, gen_idx)`` key schedule) is per-step host input.
+    ``fold_in(base, gen_idx)`` key schedule) has a mirror on the host,
+    which ``resume`` writes and the next dispatch uploads.
     ``released`` marks a consumed snapshot (resumed or dropped)."""
 
     block_table: np.ndarray
@@ -172,7 +213,13 @@ class EngineStats:
     active_slots: int = 0
     num_slots: int = 0
     readback_bytes: int = 0              # cumulative bytes decode steps read
-    #                                      back from the device
+    #                                      back from the device (the logits
+    #                                      only when something asked for
+    #                                      them: ``last_logits``)
+    upload_arrays: int = 0               # host arrays handed to decode
+    #                                      dispatches (stale mirrors)
+    resident_steps: int = 0              # decode dispatches that uploaded
+    #                                      none: all state was on the device
     paged_kernel_dispatches: int = 0     # decode + prefill dispatches whose
     #                                      attend ran the Pallas page walk
     #                                      (ops/paged_attention.py); 0 on
@@ -506,10 +553,31 @@ class InferenceEngine:
         self._top_k = np.full(s, self.config.vocab_size, np.int32)
         self._top_p = np.ones(s, np.float32)
         self._base_keys = np.zeros((s, 2), np.uint32)
+        # The arrays above are the host's MIRROR of the decode programs'
+        # per-slot state (events, park, release and the scheduler read
+        # them). The state itself lives on the device: ``_dev`` is the
+        # dict the last decode dispatch returned and the next one's
+        # argument as it is. Whatever writes a mirror outside ``step``
+        # names it in ``_stale``, and the next dispatch is handed that
+        # mirror, a NumPy array, in the device copy's place.
+        self._dev: Dict[str, Any] = {}
+        self._stale: set = set()
+        self._logits: Any = None         # [S, V] post-step, on the device
+        self._logits_host: Optional[np.ndarray] = None
         self.stats = EngineStats(num_slots=s,
                                  weights_dtype=self.weights_dtype,
                                  kv_dtype=self.kv_dtype)
-        self.last_logits: Optional[np.ndarray] = None  # [S, V] post-step
+
+    @property
+    def last_logits(self) -> Optional[np.ndarray]:
+        """The last decode step's logits ``[S, V]`` (None before the
+        first). They stay on the device: nothing on the serving path
+        reads them. The first access after a step transfers them and
+        adds their bytes to ``stats.readback_bytes``."""
+        if self._logits_host is None and self._logits is not None:
+            self._logits_host = np.asarray(self._logits)
+            self.stats.readback_bytes += self._logits_host.nbytes
+        return self._logits_host
 
     # -- quantized-serving observables ------------------------------------
 
@@ -611,17 +679,22 @@ class InferenceEngine:
             defs.extend(prefill_def(cfg, b) for b in buckets)
         return defs
 
-    def _count(self, counted: PyTree) -> int:
-        """Add what the model counted in this dispatch to
-        ``stats.model_counters``; returns the bytes read back."""
-        read = 0
+    def _count(self, counted: PyTree) -> None:
+        """Add what the model counted in this dispatch (already on the
+        host) to ``stats.model_counters``."""
         totals = self.stats.model_counters
         for path, leaf in jax.tree_util.tree_flatten_with_path(counted)[0]:
-            leaf = np.asarray(leaf)
-            read += leaf.nbytes
             name = "/".join(str(getattr(k, "key", k)) for k in path)
             totals[name] = totals.get(name, 0) + leaf.astype(np.int64)
-        return read
+
+    def _mirror(self, name: str) -> np.ndarray:
+        """The host's copy of one entry of the decode state, in the
+        dtype the programs take."""
+        if name == "remaining":
+            return (self._max_new - self._generated).astype(np.int32)
+        if name == "eos":
+            return self._eos.astype(np.int32)
+        return getattr(self, _MIRROR[name])
 
     def _init_cache(self) -> PyTree:
         model = self.config.build()
@@ -805,13 +878,12 @@ class InferenceEngine:
             slot = free[0]
             fault_point("serve.prefill")
             n = len(prompt)
-            base_key = np.asarray(jax.random.PRNGKey(sp.seed), np.uint32)
+            key = derive_base_key(sp.seed)
             top_k = (self.config.vocab_size if sp.top_k is None
                      else int(sp.top_k))
             top_p = 1.0 if sp.top_p is None else float(sp.top_p)
         if self.paged:
-            first = self._prefill_paged(slot, prompt, sp, base_key,
-                                        top_k, top_p)
+            first = self._prefill_paged(slot, prompt, sp, key, top_k, top_p)
         else:
             with span("serve.prefill.args"):
                 bucket = prompt_bucket(n, self.block_size)
@@ -819,9 +891,9 @@ class InferenceEngine:
                 prefill = self._prefill_prog(bucket)
                 padded = np.zeros((1, bucket), np.int32)
                 padded[0, :n] = prompt
-                args = (self.params, jnp.asarray(padded), np.int32(n),
-                        jnp.asarray(base_key), np.float32(sp.temperature),
-                        np.int32(top_k), np.float32(top_p))
+                args = (self.params, padded, np.int32(n), key,
+                        np.float32(sp.temperature), np.int32(top_k),
+                        np.float32(top_p))
             with span("serve.prefill.dispatch", path=self.attend_path):
                 tok, row_cache = prefill(*args)
                 self._cache = self._admit_prog(self._cache, row_cache,
@@ -842,11 +914,12 @@ class InferenceEngine:
         self._temp[slot] = sp.temperature
         self._top_k[slot] = top_k
         self._top_p[slot] = top_p
-        self._base_keys[slot] = base_key
+        self._base_keys[slot] = key
         if self.paged:
             # token history feeds the n-gram draft; the first token is
             # emitted (index n), giving hist_len == cursor + 1
             self._hist[slot, n] = first
+        self._stale.update(SPEC_STATE)   # every mirror has a new row
         finished = (sp.max_new_tokens <= 1
                     or (sp.eos_token is not None and first == sp.eos_token))
         if finished:
@@ -914,11 +987,12 @@ class InferenceEngine:
                 prefill = self._prefill_prog(bucket)
                 padded = np.zeros((1, bucket), np.int32)
                 padded[0, :suffix] = prompt[start:]
-                args = (jnp.asarray(self._bt[slot][None]),
-                        jnp.asarray(np.asarray([start], np.int32)),
-                        jnp.asarray(padded), np.int32(suffix),
-                        jnp.asarray(base_key), np.float32(sp.temperature),
-                        np.int32(top_k), np.float32(top_p))
+                # NumPy as it is: the executable's own argument path
+                # transfers the batch
+                args = (self._bt[slot][None], np.asarray([start], np.int32),
+                        padded, np.int32(suffix), base_key,
+                        np.float32(sp.temperature), np.int32(top_k),
+                        np.float32(top_p))
             with span("serve.prefill.dispatch", path=self.attend_path):
                 tok, self._cache = prefill(self.params, self._cache,
                                            *args)
@@ -965,6 +1039,7 @@ class InferenceEngine:
             if pg:
                 self._alloc.decref(int(pg))
         self._bt[slot] = 0
+        self._stale.add("bt")
         self.stats.kv_blocks_in_use = self._alloc.in_use()
         self.stats.kv_blocks_cached = self._alloc.cached()
 
@@ -975,6 +1050,7 @@ class InferenceEngine:
         references are dropped (shared prefix blocks stay resident for
         future hits)."""
         self._active[slot] = False
+        self._stale.add("active")
         self._release_pages(slot)
         self.stats.active_slots = int(self._active.sum())
 
@@ -1012,16 +1088,18 @@ class InferenceEngine:
         # references moved to the snapshot: zero the row WITHOUT decref
         # so release()/step()'s page sweep cannot double-free them
         self._bt[slot] = 0
+        self._stale.update(("active", "bt"))
         self.stats.preemptions += 1
         self.stats.active_slots = int(self._active.sum())
         return parked
 
     def resume(self, parked: ParkedSlot) -> int:
         """Restore a parked snapshot into a free slot. No device work —
-        the KV pool is shared across slots and the block table is a
-        per-dispatch host input, so the resumed generation continues
-        from exactly the token it was preempted at, byte-identical by
-        the per-token key schedule. Raises ``NoFreeSlotError`` when
+        the KV pool is shared across slots and the slot's state is
+        written to the host's mirrors, which go up with the next decode
+        dispatch, so the resumed generation continues from exactly the
+        token it was preempted at, byte-identical by the per-token key
+        schedule. Raises ``NoFreeSlotError`` when
         every slot is busy (the scheduler checks first)."""
         if parked.released:
             raise ValueError("parked snapshot already consumed")
@@ -1044,6 +1122,7 @@ class InferenceEngine:
         self._top_k[slot] = parked.top_k
         self._top_p[slot] = parked.top_p
         self._base_keys[slot] = parked.base_key
+        self._stale.update(SPEC_STATE)
         parked.released = True
         self.stats.resumes += 1
         self.stats.active_slots = int(self._active.sum())
@@ -1071,6 +1150,20 @@ class InferenceEngine:
         between dispatches, admission too: continuous batching at chunk
         granularity.
 
+        What crosses to the device and back: the decode state (tokens,
+        cursors, key indices, budgets, sampling vectors, block table)
+        lives on the device, returned by one dispatch and handed to the
+        next as it is; only a mirror the host wrote since (``_stale``:
+        an admission, a release, a park or resume, a quarantine, a
+        forced token) goes up, as a NumPy argument of the dispatch
+        itself, so a step after which nothing was admitted or released
+        uploads nothing (``stats.resident_steps``). One small download
+        comes back (``jax.device_get`` of the program's ``read``: the
+        tokens, ``emitted``, the final token / active / cursor vectors,
+        the NaN latch, the model's counters) and the host's mirrors are
+        brought up to the device's state from it; the logits stay on the
+        device (``last_logits``).
+
         ``override_tokens`` (teacher forcing, tests/eval only) replaces a
         slot's INPUT token for ONE single step — the call runs a chunk-1
         program regardless of ``decode_chunk`` and the returned logits
@@ -1082,6 +1175,7 @@ class InferenceEngine:
         if override_tokens:
             for slot, tok in override_tokens.items():
                 self._next_tok[slot] = int(tok)
+            self._stale.add("tok")
             spec_run = False
             if self.decode_chunk != 1 or self._spec_prog is not None:
                 if self._step1_prog is None:
@@ -1099,76 +1193,61 @@ class InferenceEngine:
         # hit-counted AFTER the idle early-out so hit N is the Nth REAL
         # decode dispatch — "hang at dispatch 2" reproduces exactly
         fault_point("serve.decode")
-        with span("serve.decode.args"):
+        with span("serve.decode.args") as sp:
             was_active = self._active.copy()
-            remaining = (self._max_new - self._generated).astype(np.int32)
-            tail = (jnp.asarray(self._next_tok), jnp.asarray(self._active),
-                    jnp.asarray(self._base_keys),
-                    jnp.asarray(self._gen_idx), jnp.asarray(remaining),
-                    jnp.asarray(self._eos.astype(np.int32)),
-                    jnp.asarray(self._temp), jnp.asarray(self._top_k),
-                    jnp.asarray(self._top_p))
-            if self.paged:
-                head = (self.params, self._cache, jnp.asarray(self._bt))
-                if spec_run:
-                    head += (jnp.asarray(self._hist),)
-                tail = tail[:2] + (jnp.asarray(self._pos),) + tail[2:]
-            else:
-                head = (self.params, self._cache)
+            names = (SPEC_STATE if spec_run
+                     else PAGED_STATE if self.paged else SLOT_STATE)
+            # an entry the last dispatch did not return goes up too: all
+            # of them at the first step, ``hist`` after a step of the plain
+            # program (the host replays tokens into its own copy whatever
+            # program ran)
+            up = [n for n in names
+                  if n in self._stale or n not in self._dev]
+            state = {n: self._dev[n] for n in names if n not in up}
+            state.update((n, self._mirror(n)) for n in up)
+            sp.ids["uploads"] = len(up)
+            self.stats.upload_arrays += len(up)
+            self.stats.resident_steps += int(not up)
         with span("serve.decode.dispatch", path=self.attend_path):
-            out = prog(*head, *tail)
+            read, self._logits, self._dev, self._cache = prog(
+                self.params, self._cache, state)
+        self._logits_host = None
+        self._stale.clear()
         self.stats.paged_kernel_dispatches += self._kernel_attend
         with span("serve.decode.readback") as rb:
-            # the first read waits for the step; the logits are the bulk
-            if self.paged:
-                toks, emitted, lg, final_tok, final_active, final_pos, \
-                    nan_seen, cache, counted = out
-                final_pos = np.asarray(final_pos)
-                nan_seen = np.asarray(nan_seen)
-                self._pos = final_pos.astype(np.int32).copy()
-                read = final_pos.nbytes + nan_seen.nbytes
-                read += self._count(counted)
-            else:
-                toks, emitted, lg, final_tok, final_active, cache = out
-                nan_seen, read = None, 0
-            self._cache = cache
-            toks = np.asarray(toks)
-            emitted = np.asarray(emitted)
-            self.last_logits = np.asarray(lg)
-            final_tok = np.asarray(final_tok)
-            final_active = np.asarray(final_active)
-            read += sum(a.nbytes for a in (toks, emitted, self.last_logits,
-                                           final_tok, final_active))
-            rb.ids["bytes"] = read
-            self.stats.readback_bytes += read
+            # one wait for the step, then a few KiB
+            read = jax.device_get(read)
+            nbytes = sum(a.nbytes for a in jax.tree.leaves(read))
+            rb.ids["bytes"] = nbytes
+            self.stats.readback_bytes += nbytes
+            toks, emitted = read["toks"], read["emitted"]
+            nan_seen = read["nan_seen"]
         with span("serve.decode.events"):
+            self._count(read["counted"])
             if toks.ndim == 2:
                 # non-speculative programs emit one token per scanned step;
                 # widen to the speculative [chunk, S, γ+1] layout so ONE host
                 # replay path routes both
                 toks = toks[..., None]
                 emitted = emitted[..., None]
-            self._next_tok = final_tok.astype(np.int32).copy()
-            self._active = final_active.copy()
+            # the mirrors take the device's final state (``_gen_idx``,
+            # ``_generated`` and ``_hist`` follow below, token by token)
+            self._next_tok = read["tok"].astype(np.int32)
+            self._active = read["active"].copy()
+            if self.paged:
+                self._pos = read["pos"].astype(np.int32)
             # numerical quarantine: non-finite logits fail ONLY their own
             # slot — the model's per-row cache math keeps rows isolated (and
             # the decode attends NaN-poison an overflowing row/position on
-            # purpose, so this is the designated catch point). Unpaged, the
-            # check reads the LAST scanned step's logits for every slot
-            # that emitted anywhere in this chunk: a poisoned slot that
-            # finishes mid-chunk goes inactive but keeps attending its own
-            # NaN cache rows, so the poison stays visible in the final
-            # logits. Paged, that witness FAILS — a finished row's table is
-            # redirected to the null page, so its later iterations read
-            # clean garbage — and the programs instead LATCH non-finite
-            # logits per iteration while the row is active (`nan_seen`).
-            if nan_seen is not None:
-                bad = nan_seen
-            else:
-                bad = emitted.any(axis=(0, 2)) & ~np.isfinite(
-                    self.last_logits).all(axis=1)
-            for slot in np.nonzero(bad)[0]:
+            # purpose, so this is the designated catch point). Every decode
+            # program LATCHES non-finite logits per iteration while the row
+            # is active (``nan_seen``): the last step's logits could not
+            # witness a poison that struck a paged row mid-chunk (a finished
+            # row's table is redirected to the null page, so its later
+            # iterations read clean garbage), and no path reads logits.
+            for slot in np.nonzero(nan_seen)[0]:
                 self._active[slot] = False           # quarantine = evict
+                self._stale.add("active")
                 self.stats.quarantined += 1
             events: List[TokenEvent] = []
             n_steps = toks.shape[0]
@@ -1196,8 +1275,9 @@ class InferenceEngine:
                         last_emit = (not emitted[k, slot, j + 1:].any()
                                      and not emitted[k + 1:, slot].any())
                         finished = bool(last_emit and not self._active[slot])
-                        events.append(TokenEvent(int(slot), tok, finished,
-                                                 poisoned=bool(bad[slot])))
+                        events.append(TokenEvent(
+                            int(slot), tok, finished,
+                            poisoned=bool(nan_seen[slot])))
             if self.paged:
                 # blocks of slots that finished (or were quarantined) this
                 # chunk go back to the allocator; shared prefix blocks stay

@@ -337,8 +337,11 @@ def _serve_readback_carries_its_bytes(reqs, recs, eng):
     reads = [r for r in recs if r.name == "serve.decode.readback"]
     assert len(reads) == eng.stats.decode_steps
     assert sum(r.ids["bytes"] for r in reads) == eng.stats.readback_bytes
-    # the logits alone: [slots, vocab] f32 a step
-    assert all(r.ids["bytes"] > 2 * 48 * 4 for r in reads)
+    # tokens and flags, not the [slots, vocab] f32 logits (ISSUE 28)
+    assert all(0 < r.ids["bytes"] < 2 * 48 * 4 for r in reads)
+    # and how many host arrays each decode dispatch was handed
+    args = [r for r in recs if r.name == "serve.decode.args"]
+    assert sum(r.ids["uploads"] for r in args) == eng.stats.upload_arrays
 
 
 @pytest.mark.parametrize("check", [_serve_leaves_tile_the_round,
