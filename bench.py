@@ -612,14 +612,13 @@ def measure_serving() -> dict:
     seq_warm = timed(run_sequential)
     eng_warm = timed(run_engine)
 
-    # ---- shared-prefix workload (ISSUE 7): paged prefix-shared KV vs
-    # the PR-4 per-slot engine on the realistic chatbot/agent shape —
-    # N requests dominated by one long common system prompt. The paged
-    # engine prefills the shared blocks ONCE and admits the rest
-    # through the prefix cache; the PR-4 engine re-prefills the full
-    # prompt every time. Aggregate tok/s and p99 TTFT are the headline;
-    # the structural assert is that prefill WORK (padded tokens
-    # dispatched) drops.
+    # ---- shared-prefix workload (ISSUE 7): prefix-shared KV on the
+    # realistic chatbot/agent shape — N requests dominated by one long
+    # common system prompt. The engine prefills the shared blocks ONCE
+    # and admits the rest through the prefix cache. The structural
+    # assert is that prefill WORK (padded tokens dispatched) is less
+    # than every prompt prefilled whole (what the unpaged PR-4 engine,
+    # gone since ISSUE 29, dispatched).
     n_shared = int(os.environ.get("GYM_TPU_BENCH_SERVE_SHARED_REQS", 12))
     sys_len, tail_len, shared_mnew = 224, 8, 8
     shared_sys = rng.integers(0, cfg.vocab_size, sys_len)
@@ -631,7 +630,7 @@ def measure_serving() -> dict:
         for i in range(n_shared)]
     shared_new = sum(sp.max_new_tokens for _, sp in shared_workload)
 
-    def shared_arm(paged: bool, spec: int = 0, arm_cfg=None,
+    def shared_arm(spec: int = 0, arm_cfg=None,
                    arm_params=None) -> dict:
         arm_cfg = cfg if arm_cfg is None else arm_cfg
         arm_params = params if arm_params is None else arm_params
@@ -639,7 +638,7 @@ def measure_serving() -> dict:
         def mk():
             return InferenceEngine(arm_params, arm_cfg,
                                    num_slots=num_slots,
-                                   decode_chunk=chunk, paged=paged,
+                                   decode_chunk=chunk,
                                    page_size=16, spec_tokens=spec)
 
         def serve(sched, wl):
@@ -673,13 +672,15 @@ def measure_serving() -> dict:
             out["spec_accept_rate"] = eng.stats.spec_accept_rate()
         return out
 
-    pr4_arm = shared_arm(paged=False)
-    paged_arm = shared_arm(paged=True)
-    spec_arm = shared_arm(paged=True, spec=4)
+    from gym_tpu.serve.engine import prompt_bucket
+    whole_prompts = sum(prompt_bucket(len(p), cfg.block_size)
+                        for p, _ in shared_workload)
+    paged_arm = shared_arm()
+    spec_arm = shared_arm(spec=4)
     # structural acceptance (ISSUE 7): the shared blocks are measurably
     # ELIDED from prefill dispatch work, not just faster by luck
-    assert paged_arm["prefill_tokens"] < pr4_arm["prefill_tokens"], (
-        paged_arm, pr4_arm)
+    assert paged_arm["prefill_tokens"] < whole_prompts, (
+        paged_arm, whole_prompts)
     assert paged_arm["prefix_hit_blocks"] > 0, paged_arm
 
     # ---- quantized serving (ISSUE 11): int8 weights + int8 paged KV.
@@ -767,7 +768,7 @@ def measure_serving() -> dict:
 
     # tok/s: the shared-prefix workload on the quantized engine (weights
     # dequant fused into the matmuls + int8 KV), vs the f32 paged arm
-    quant_arm = shared_arm(paged=True, arm_cfg=qcfg, arm_params=qparams)
+    quant_arm = shared_arm(arm_cfg=qcfg, arm_params=qparams)
 
     capacity_ratio = round(cap_int8.stats.kv_blocks_cached
                            / max(cap_f32.stats.kv_blocks_cached, 1), 2)
@@ -849,14 +850,9 @@ def measure_serving() -> dict:
                          f"tail, max_new {shared_mnew}, page 16, "
                          f"{num_slots} slots, chunk {chunk}; programs "
                          f"warm, prefix cache cold"),
-            "pr4_engine": pr4_arm,
             "paged_engine": paged_arm,
             "paged_spec_engine": spec_arm,
-            "tok_s_speedup": round(paged_arm["tok_s"] / pr4_arm["tok_s"],
-                                   2),
-            "p99_ttft_speedup": round(
-                pr4_arm["p99_ttft_s"] / paged_arm["p99_ttft_s"], 2),
-            "prefill_tokens_elided": (pr4_arm["prefill_tokens"]
+            "prefill_tokens_elided": (whole_prompts
                                       - paged_arm["prefill_tokens"]),
         },
         "quantized": quantized,
@@ -1358,7 +1354,7 @@ def measure_fleet() -> dict:
     tm = ServeMetrics(tempfile.mkdtemp(prefix="gym_tpu_abt_"),
                       engine_log_every=10)
     thread_router = build_fleet(
-        params_a, cfg, paged=True, metrics=tm,
+        params_a, cfg, metrics=tm,
         log=lambda *a, **k: None, **ab_kw).start()
     run_streamed(thread_router, ab_wl)     # warm + seed the disk tier
     run_streamed(thread_router, ab_wl)

@@ -2,11 +2,10 @@
 
 Every compiled program the repo ships — the trainer step for each
 strategy (the function ``NodeRuntime.compile`` jits under ``shard_map``),
-the serving engine's bucketed prefill / admit / fused ``decode_chunk``
-programs, and the paged-KV family (prefix-aware paged prefill,
-copy-on-write page copy, paged decode, fused draft+verify speculative
-decode) — is abstractly traced (never compiled or executed) and
-checked:
+the serving engine's paged-KV family (prefix-aware bucketed prefill,
+copy-on-write page copy, fused ``decode_chunk`` decode, fused
+draft+verify speculative decode) — is abstractly traced (never compiled
+or executed) and checked:
 
 - **Donation** — an argument donated via ``donate_argnums`` whose buffer
   XLA cannot alias to an output (no output with the same shape/dtype
@@ -277,19 +276,10 @@ def engine_program_defs(num_slots: int = 2, decode_chunk: int = 4,
     NOT private engine builders: the defs the auditor traces are the
     defs the engine acquires, so the audit key set and the registry key
     set cannot drift independently."""
-    import dataclasses as _dc
-
-    from ..models.nanogpt import decode_config
-    from ..programs import serve_defs as sd
-
-    cfg_tuple = _dc.astuple(decode_config(_tiny_gpt_config()))
-    defs = [sd.prefill_def(cfg_tuple, int(b)) for b in buckets]
-    defs.append(sd.slot_admit_def(cfg_tuple, num_slots))
-    defs.append(sd.slot_decode_def(cfg_tuple, num_slots, decode_chunk))
-    defs.extend(paged_program_defs(num_slots=num_slots,
-                                   decode_chunk=decode_chunk,
-                                   buckets=buckets, page_size=page_size,
-                                   gamma=gamma))
+    defs = paged_program_defs(num_slots=num_slots,
+                              decode_chunk=decode_chunk,
+                              buckets=buckets, page_size=page_size,
+                              gamma=gamma)
     defs.extend(quantized_program_defs(num_slots=num_slots,
                                        decode_chunk=decode_chunk,
                                        buckets=buckets,
@@ -361,8 +351,7 @@ def engine_program_specs(num_slots: int = 2, decode_chunk: int = 4,
                          ) -> List[ProgramSpec]:
     """The serving engine's program families, traced exactly as the
     engine acquires them from the device-program registry, with their
-    real donation masks: prefill (none), admit (cache, arg 0), decode
-    (cache, arg 1), paged family (pool, arg 1 / CoW arg 0)."""
+    real donation masks: the pool, arg 1 (arg 0 of the page copy)."""
     return [_spec_from_def(d)
             for d in engine_program_defs(num_slots=num_slots,
                                          decode_chunk=decode_chunk,

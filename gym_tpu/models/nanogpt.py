@@ -450,8 +450,8 @@ class CausalSelfAttention(nn.Module):
         that do not tile) gathers each row's pages into its logical [S]
         window and reduces over the same static S axis with the same
         masks as ``_decode_attend``: paged token streams are then
-        bit-identical to the unpaged engine and ``generate_fast`` (the
-        tests' contract). The KERNEL path (a TPU, float32 pool) walks
+        bit-identical to ``generate_fast`` (the tests' contract). The
+        KERNEL path (a TPU, float32 pool) walks
         only the row's live pages in the pool, 128 positions at a time
         under a running maximum: the same bf16-rounded products and every
         live position attended, but another order of the float32 sums, so
@@ -538,8 +538,8 @@ class CausalSelfAttention(nn.Module):
                 v_all = kv_dequantize(v_all, window(vs_pool, H), q.dtype)
             # attend exactly like the unpaged path: the reductions run
             # over the same static S axis with the same masks, which is
-            # what keeps paged token streams bit-identical to the
-            # unpaged engine and generate_fast
+            # what keeps paged token streams bit-identical to
+            # generate_fast
             att = jnp.einsum("bqhd,bkhd->bhqk", q.reshape(b, t, H, hd),
                              k_all) / math.sqrt(hd)
             col_pos = jnp.arange(S)                         # [S]

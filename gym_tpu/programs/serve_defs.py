@@ -1,16 +1,16 @@
 """The serving engine's device programs, as registry ``ProgramDef``s.
 
 This is the single source of truth for every program the inference
-engine dispatches — the bucketed prefill, the admit scatter, the fused
-``decode_chunk`` scan, and the paged-KV family (prefix-aware paged
-prefill, copy-on-write page copy, paged decode, fused draft+verify
-speculative decode), for whichever model the program key names
-(``models/serving.py``).  ``serve/engine.py`` acquires them through the
-registry (replacing its six retired module-global ``lru_cache`` stores)
-and ``analysis/jaxpr_audit.py`` enumerates them through the same
-functions — so the auditor's key set and the registry's key set are the
-same set by construction, and a program signature drifting between the
-two is impossible rather than merely tested.
+engine dispatches — the prefix-aware paged prefill (one per bucket), the
+copy-on-write page copy, the fused ``decode_chunk`` scan over the page
+pool and the fused draft+verify speculative decode, for whichever model
+the program key names (``models/serving.py``).  ``serve/engine.py``
+acquires them through the registry (replacing its six retired
+module-global ``lru_cache`` stores) and ``analysis/jaxpr_audit.py``
+enumerates them through the same functions — so the auditor's key set
+and the registry's key set are the same set by construction, and a
+program signature drifting between the two is impossible rather than
+merely tested.
 
 Each ``ProgramDef`` carries the EXACT argument avals its engine call
 site dispatches with: the registry AOT-compiles against these templates
@@ -48,16 +48,15 @@ _KEY_T = jax.ShapeDtypeStruct((2,), np.uint32)
 
 # The decode programs' per-slot state, by name.  A decode program takes
 # it as ONE dict and returns it whole: what it advanced (``tok``,
-# ``active``, ``gen_idx``, ``remaining``; paged also ``pos``, speculative
-# also ``hist``) and, unchanged, what only an admission writes
+# ``active``, ``gen_idx``, ``remaining``, ``pos``; speculative also
+# ``hist``) and, unchanged, what only an admission writes
 # (``base_keys``, ``eos``, ``temp``, ``top_k``, ``top_p``, the block
 # table).  The returned dict is the next dispatch's argument as it is:
 # the state lives on the device, and the engine hands over a NumPy array
 # in an entry's place only where the host wrote that entry since
 # (``serve/engine.py``: the host's mirrors).
-SLOT_STATE = ("tok", "active", "base_keys", "gen_idx", "remaining", "eos",
-              "temp", "top_k", "top_p")
-PAGED_STATE = SLOT_STATE + ("bt", "pos")
+PAGED_STATE = ("tok", "active", "base_keys", "gen_idx", "remaining", "eos",
+               "temp", "top_k", "top_p", "bt", "pos")
 SPEC_STATE = PAGED_STATE + ("hist",)
 
 
@@ -89,145 +88,24 @@ def _model(cfg_tuple: tuple):
 
 
 @functools.lru_cache(maxsize=64)
-def _templates(cfg_tuple: tuple, batch: int, paged: bool):
-    """``(params_tpl, cache_tpl)`` aval pytrees for a ``batch``-row
-    engine cache under this config — host-side ``eval_shape`` only,
-    nothing compiles.  Bounded lru: entries are tiny aval trees, keyed
-    by full config, and 64 far exceeds the distinct (config × batch)
-    pairs any process serves."""
+def _templates(cfg_tuple: tuple, batch: int):
+    """``(params_tpl, pool_tpl)`` aval pytrees for a ``batch``-row
+    engine under this config — host-side ``eval_shape`` only, nothing
+    compiles.  Bounded lru: entries are tiny aval trees, keyed by full
+    config, and 64 far exceeds the distinct (config × batch) pairs any
+    process serves."""
     cfg, model = _model(cfg_tuple)
     dummy = jnp.zeros((batch, 1), jnp.int32)
-    if paged:
-        mb = cfg.block_size // cfg.page_size
-        shapes = jax.eval_shape(
-            lambda: model.init(
-                {"params": jax.random.PRNGKey(0)}, dummy, train=False,
-                block_table=jnp.zeros((batch, mb), jnp.int32),
-                cache_pos=jnp.zeros((batch,), jnp.int32)))
-    else:
-        shapes = jax.eval_shape(
-            lambda: model.init({"params": jax.random.PRNGKey(0)}, dummy,
-                               train=False))
+    mb = cfg.block_size // cfg.page_size
+    shapes = jax.eval_shape(
+        lambda: model.init(
+            {"params": jax.random.PRNGKey(0)}, dummy, train=False,
+            block_table=jnp.zeros((batch, mb), jnp.int32),
+            cache_pos=jnp.zeros((batch,), jnp.int32)))
     return shapes["params"], shapes["cache"]
 
 
 # -- builders (the jitted closures the registry compiles) ------------------
-
-
-def build_prefill(cfg_tuple: tuple, bucket: int):
-    cfg, model = _model(cfg_tuple)
-
-    @jax.jit
-    def prefill(params, tokens, true_len, key, temp, top_k, top_p):
-        """tokens [1, bucket] right-padded; returns the sampled first
-        token [1] and the filled single-row cache. The first token is
-        sampled INSIDE the program (key schedule index 0) at the true
-        last prompt position, so no per-``true_len`` slicing program
-        exists outside this bucket's compile."""
-        logits, varsc = model.apply({"params": params}, tokens,
-                                    train=False, mutable=["cache"])
-        last = jax.lax.dynamic_index_in_dim(logits, true_len - 1, axis=1,
-                                            keepdims=False)   # [1, V]
-        tok = sample_logits(last, jax.random.fold_in(key, 0),
-                            temp, top_k, top_p)
-        return tok, varsc["cache"]
-
-    return prefill
-
-
-def build_slot_admit(cfg_tuple: tuple, num_slots: int):
-    # the engine cache is DONATED: it is multi-MB (num_slots ×
-    # block_size × n_embd × 2 × n_layer) and threaded linearly through
-    # the step loop — without donation every dispatch memcpys the whole
-    # thing, which on CPU dominates the step
-    @functools.partial(jax.jit, donate_argnums=(0,))
-    def admit(cache, row_cache, slot, true_len):
-        """Scatter a freshly prefilled single-row cache into slot ``slot``
-        and rewind that slot's integer cursors to ``true_len`` (the
-        prefill ran over the PADDED bucket, so its own cursor reads the
-        bucket length; pad K/V beyond ``true_len`` stays in the row but is
-        causally masked until each position is overwritten by decode)."""
-        def leaf(c, n):
-            if c.dtype == jnp.int32:     # per-row cursor ('i'/'pos') leaves
-                return c.at[slot].set(true_len)
-            return c.at[slot].set(n[0])
-
-        return jax.tree.map(leaf, cache, row_cache)
-
-    return admit
-
-
-def build_slot_decode(cfg_tuple: tuple, num_slots: int, chunk: int):
-    cfg, model = _model(cfg_tuple)
-
-    @functools.partial(jax.jit, donate_argnums=(1,))
-    def decode(params, cache, state):
-        """``chunk`` decode steps for the whole slot batch in ONE
-        dispatch (a ``lax.scan``, amortizing per-dispatch overhead the
-        way ``generate_fast``'s whole-request scan does). Each scanned
-        step feeds every slot its current token and samples its next
-        with its own key/params. Slot lifecycle bookkeeping runs ON
-        DEVICE so no host round trip is needed mid-chunk: a slot that
-        hits EOS or exhausts ``remaining`` flips inactive and freezes —
-        its token and integer cursors stop advancing (no cache-overflow
-        creep, no garbage emission; its masked compute is the price of
-        the fixed shape until the next admit).
-
-        ``state`` is the ``SLOT_STATE`` dict. Returns ``(read, logits,
-        state, cache)``:
-
-        - ``read``: the few small arrays the host downloads after every
-          step: ``toks`` / ``emitted`` [chunk, S] (``emitted`` marks
-          which scanned steps each slot was active for; the host replays
-          it to route tokens to requests), the final ``tok`` / ``active``
-          [S], ``nan_seen`` [S] (non-finite logits while the row was
-          active, latched per scanned step: no path reads logits to
-          decide anything) and ``counted`` (nothing here);
-        - ``logits`` [S, V]: the last scanned step's, left on the device
-          (teacher forcing and tests fetch them);
-        - ``state``: the argument with what this dispatch advanced, the
-          next dispatch's argument as it is."""
-        base_keys, eos = state["base_keys"], state["eos"]
-        temp, top_k, top_p = state["temp"], state["top_k"], state["top_p"]
-
-        def body(carry, _):
-            cache, tok, act, gidx, rem, nanc, _lg = carry
-            logits, varsc = model.apply(
-                {"params": params, "cache": cache}, tok[:, None],
-                train=False, mutable=["cache"])
-            lg = logits[:, 0]                               # [S, V]
-            nanc = nanc | (act & ~jnp.isfinite(lg).all(axis=-1))
-            keys = jax.vmap(jax.random.fold_in)(base_keys, gidx)
-            nxt = jax.vmap(sample_logits)(lg, keys, temp, top_k, top_p)
-            nxt = jnp.where(act, nxt, tok).astype(jnp.int32)
-            new_cache = jax.tree.map(
-                lambda n, o: jnp.where(act, n, o)
-                if n.dtype == jnp.int32 else n,
-                varsc["cache"], cache)
-            emitted = act
-            gidx = jnp.where(act, gidx + 1, gidx)
-            rem = jnp.where(act, rem - 1, rem)
-            done = act & ((rem <= 0) | ((eos >= 0) & (nxt == eos)))
-            # last step's logits ride in the CARRY (teacher-forcing /
-            # debug observable) — stacking [chunk, S, V] would move the
-            # whole vocab per scanned step at GPT-2 vocab sizes
-            return ((new_cache, nxt, act & ~done, gidx, rem, nanc, lg),
-                    (nxt, emitted))
-
-        lg0 = jnp.zeros((num_slots, cfg.vocab_size), jnp.float32)
-        nan0 = jnp.zeros((num_slots,), bool)
-        (cache, tok, active, gen_idx, remaining, nan_seen, lg), \
-            (toks, emitted) = jax.lax.scan(
-                body, (cache, state["tok"], state["active"],
-                       state["gen_idx"], state["remaining"], nan0, lg0),
-                None, length=chunk)
-        read = {"toks": toks, "emitted": emitted, "tok": tok,
-                "active": active, "nan_seen": nan_seen, "counted": {}}
-        state = {**state, "tok": tok, "active": active,
-                 "gen_idx": gen_idx, "remaining": remaining}
-        return read, lg, state, cache
-
-    return decode
 
 
 def build_paged_prefill(cfg_tuple: tuple, bucket: int):
@@ -269,19 +147,36 @@ def build_cow(cfg_tuple: tuple):
 
 
 def build_paged_decode(cfg_tuple: tuple, num_slots: int, chunk: int):
-    """Paged twin of the slot decode: same fused ``decode_chunk`` scan
-    and on-device lifecycle, but K/V flow through the page pool via each
-    slot's block table and the per-row cursor is explicit carry state
-    (``pos``) instead of a cache variable. Inactive rows have their
+    """``chunk`` decode steps for the whole slot batch in ONE dispatch
+    (a ``lax.scan``, amortizing per-dispatch overhead the way
+    ``generate_fast``'s whole-request scan does). Each scanned step
+    feeds every slot its current token and samples its next with its own
+    key/params; K/V flow through the page pool via each slot's block
+    table and the per-row cursor is explicit carry state (``pos``).
+    Slot lifecycle bookkeeping runs ON DEVICE so no host round trip is
+    needed mid-chunk: a slot that hits EOS or exhausts ``remaining``
+    flips inactive and freezes — its token and cursor stop advancing (no
+    overflow creep, no garbage emission; its masked compute is the price
+    of the fixed shape until the next admit). Inactive rows have their
     tables redirected to the NULL page so their garbage writes can never
     touch a page that was freed and reallocated to a live slot.
 
     ``decode(params, cache, state)`` with the ``PAGED_STATE`` dict;
-    returns ``(read, logits, state, cache)`` as the slot decode does,
-    ``read`` with the final ``pos`` besides and with ``counted``: what
-    the model counted (its ``counters`` collection), summed over the
-    chunk. ``state`` comes back with ``pos`` advanced and the block
-    table as it was given."""
+    returns ``(read, logits, state, cache)``:
+
+    - ``read``: the few small arrays the host downloads after every
+      step: ``toks`` / ``emitted`` [chunk, S] (``emitted`` marks which
+      scanned steps each slot was active for; the host replays it to
+      route tokens to requests), the final ``tok`` / ``active`` /
+      ``pos`` [S], ``nan_seen`` [S] (non-finite logits while the row
+      was active, latched per scanned step: no path reads logits to
+      decide anything) and ``counted``: what the model counted (its
+      ``counters`` collection), summed over the chunk;
+    - ``logits`` [S, V]: the last scanned step's, left on the device
+      (teacher forcing and tests fetch them);
+    - ``state``: the argument with what this dispatch advanced (the
+      block table as it was given), the next dispatch's argument as it
+      is."""
     cfg, model = _model(cfg_tuple)
 
     @functools.partial(jax.jit, donate_argnums=(1,))
@@ -462,45 +357,6 @@ def build_spec_decode(cfg_tuple: tuple, num_slots: int, chunk: int,
 # -- ProgramDefs -----------------------------------------------------------
 
 
-def prefill_def(cfg_tuple: tuple, bucket: int) -> ProgramDef:
-    params_tpl, _ = _templates(cfg_tuple, 1, False)
-    return ProgramDef(
-        name=f"serve.prefill[bucket={bucket}{_qtag(cfg_tuple)}]", family="serve.prefill",
-        config={"config": cfg_tuple, "bucket": bucket},
-        args=(params_tpl,
-              jax.ShapeDtypeStruct((1, int(bucket)), np.int32),
-              _scalar(np.int32), _KEY_T, _scalar(np.float32),
-              _scalar(np.int32), _scalar(np.float32)),
-        donate_args=(),
-        builder=lambda: build_prefill(cfg_tuple, int(bucket)))
-
-
-def slot_admit_def(cfg_tuple: tuple, num_slots: int) -> ProgramDef:
-    _, row_cache_tpl = _templates(cfg_tuple, 1, False)
-    _, slot_cache_tpl = _templates(cfg_tuple, num_slots, False)
-    return ProgramDef(
-        name=f"serve.admit[slots={num_slots}{_qtag(cfg_tuple)}]", family="serve.admit",
-        config={"config": cfg_tuple, "num_slots": num_slots},
-        args=(slot_cache_tpl, row_cache_tpl, _scalar(np.int32),
-              _scalar(np.int32)),
-        donate_args=(0,),
-        builder=lambda: build_slot_admit(cfg_tuple, num_slots))
-
-
-def slot_decode_def(cfg_tuple: tuple, num_slots: int,
-                    chunk: int) -> ProgramDef:
-    params_tpl, slot_cache_tpl = _templates(cfg_tuple, num_slots, False)
-    s = num_slots
-    return ProgramDef(
-        name=f"serve.decode[slots={s},chunk={chunk}{_qtag(cfg_tuple)}]",
-        family="serve.decode",
-        config={"config": cfg_tuple, "num_slots": s,
-                "decode_chunk": chunk},
-        args=(params_tpl, slot_cache_tpl, _state_tpl(SLOT_STATE, s)),
-        donate_args=(1,),
-        builder=lambda: build_slot_decode(cfg_tuple, s, chunk))
-
-
 def _paged_cfg(cfg_tuple: tuple):
     cfg = config_from_key(cfg_tuple)
     if not cfg.page_size or not cfg.kv_pages:
@@ -515,7 +371,7 @@ def _paged_cfg(cfg_tuple: tuple):
 
 def paged_prefill_def(cfg_tuple: tuple, bucket: int) -> ProgramDef:
     _cfg, mb, pcfg = _paged_cfg(cfg_tuple)
-    params_tpl, pool_tpl = _templates(cfg_tuple, 1, True)
+    params_tpl, pool_tpl = _templates(cfg_tuple, 1)
     return ProgramDef(
         name=f"serve.paged_prefill[bucket={bucket}{_qtag(cfg_tuple)}]",
         family="serve.paged_prefill",
@@ -532,7 +388,7 @@ def paged_prefill_def(cfg_tuple: tuple, bucket: int) -> ProgramDef:
 
 def cow_def(cfg_tuple: tuple) -> ProgramDef:
     cfg, _mb, pcfg = _paged_cfg(cfg_tuple)
-    _, pool_tpl = _templates(cfg_tuple, 1, True)
+    _, pool_tpl = _templates(cfg_tuple, 1)
     return ProgramDef(
         name=f"serve.cow[page={cfg.page_size}{_qtag(cfg_tuple)}]", family="serve.cow",
         config=pcfg,
@@ -544,7 +400,7 @@ def cow_def(cfg_tuple: tuple) -> ProgramDef:
 def paged_decode_def(cfg_tuple: tuple, num_slots: int,
                      chunk: int) -> ProgramDef:
     _cfg, mb, pcfg = _paged_cfg(cfg_tuple)
-    params_tpl, pool_tpl = _templates(cfg_tuple, num_slots, True)
+    params_tpl, pool_tpl = _templates(cfg_tuple, num_slots)
     s = num_slots
     return ProgramDef(
         name=f"serve.paged_decode[slots={s},chunk={chunk}{_qtag(cfg_tuple)}]",
@@ -558,7 +414,7 @@ def paged_decode_def(cfg_tuple: tuple, num_slots: int,
 def spec_decode_def(cfg_tuple: tuple, num_slots: int, chunk: int,
                     gamma: int) -> ProgramDef:
     cfg, mb, pcfg = _paged_cfg(cfg_tuple)
-    params_tpl, pool_tpl = _templates(cfg_tuple, num_slots, True)
+    params_tpl, pool_tpl = _templates(cfg_tuple, num_slots)
     s = num_slots
     return ProgramDef(
         name=f"serve.spec_decode[slots={s},chunk={chunk},gamma={gamma}{_qtag(cfg_tuple)}]",

@@ -1,8 +1,8 @@
 """Background AOT warmup: pay every compile OFF the request path.
 
 The serving engine's compile set is bounded — the full power-of-two
-prefill-bucket family (≤ ⌈log2(block_size)⌉ + 1 programs) plus one
-decode/admit (unpaged) or paged-decode/CoW/spec (paged) program — but a
+prefill-bucket family (≤ ⌈log2(block_size)⌉ + 1 programs) plus the
+paged-decode, copy-on-write and speculative programs — but a
 cold server still pays each of those compiles on the first request that
 needs it, which is exactly where p99 TTFT lives.  ``WarmupThread`` walks
 the engine's complete ``ProgramDef`` family through the registry in a

@@ -8,14 +8,14 @@ max_new_tokens)`` signature) with no request layer. This package is the
 path from a trained ``fit()`` run dir to tokens-per-second under
 concurrent load:
 
-- ``engine``: fixed-capacity slot batch with per-slot ring-position KV
-  caches, ONE jitted decode step shared by every request (per-slot
-  cursors/masks and vectorized per-slot sampling params), and prefill
+- ``engine``: fixed-capacity slot batch over one paged KV cache, ONE
+  jitted decode step shared by every request (per-slot cursors/masks
+  and vectorized per-slot sampling params), and prefill
   bucketed to powers of two so total compilations are bounded by
   ``O(log block_size)`` instead of one per prompt length. Requests enter
   free slots and leave on EOS/max-tokens BETWEEN decode steps —
-  continuous batching, no drain-the-batch barrier. With ``paged=True``
-  the KV cache becomes a shared PAGE POOL with per-slot block tables, a
+  continuous batching, no drain-the-batch barrier. The KV cache is a
+  shared PAGE POOL with per-slot block tables, a
   ref-counted allocator and a prefix hash table: block-aligned shared
   prompt prefixes are prefilled once and reused copy-free across
   requests, and ``spec_tokens=γ`` adds self-drafting speculative

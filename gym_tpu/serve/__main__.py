@@ -134,18 +134,19 @@ def _build_parser() -> argparse.ArgumentParser:
                    help="decode steps fused per dispatch (chunk boundary "
                         "= deadline-cancellation granularity)")
     p.add_argument("--page_size", type=int, default=16,
-                   help="paged KV cache page size in tokens (must divide "
-                        "block_size; 0 reverts to the unpaged per-slot "
-                        "cache). Paging enables copy-free prefix sharing "
-                        "across requests")
+                   help="KV cache page size in tokens, >= 1 (the page "
+                        "pool is the only KV cache). A size that does "
+                        "not divide the checkpoint's block_size falls "
+                        "to its largest divisor not above it, and a "
+                        "given --kv_pages is scaled to as many tokens")
     p.add_argument("--kv_pages", type=int, default=None,
                    help="physical pages in the paged KV pool (default: "
                         "null page + num_slots full windows; smaller "
                         "pools admit lazily as blocks free)")
     p.add_argument("--spec_tokens", type=int, default=0,
-                   help="speculative decoding draft length γ (0 = off; "
-                        "paged only). Token streams stay exactly equal "
-                        "to non-speculative decoding")
+                   help="speculative decoding draft length γ (0 = "
+                        "off). Token streams stay exactly equal to "
+                        "non-speculative decoding")
     p.add_argument("--quant", choices=("int8", "int4"), default=None,
                    help="quantize the restored params at load: per-tile "
                         "int8/int4 + f32 scales (QuantizeCodec tiling), "
@@ -301,7 +302,7 @@ def create_server(params, cfg, *, host: str = "127.0.0.1", port: int = 0,
 
     ``warmup=True`` starts a background thread precompiling the fleet's
     COMPLETE program family (all power-of-two prefill buckets + the
-    decode/admit or paged/spec programs) through the device-program
+    decode, copy-on-write and speculative programs) through the device-program
     registry before traffic needs them — cold-start p99 TTFT pays no
     compiles.  The registry's persistent executable tier is always on
     (``program_cache_dir`` places it; ``programs.resolve_cache_dir``
@@ -314,7 +315,7 @@ def create_server(params, cfg, *, host: str = "127.0.0.1", port: int = 0,
     from ..utils import trace
     from ..utils.resilience import fault_point
     from .autoscale import AutoscalePolicy, Autoscaler
-    from .engine import SamplingParams
+    from .engine import SamplingParams, fit_pool
     from .metrics import ServeMetrics
     from .router import (FleetReloadError, NoHealthyReplicaError,
                          build_fleet, build_process_fleet)
@@ -331,20 +332,15 @@ def create_server(params, cfg, *, host: str = "127.0.0.1", port: int = 0,
         import tempfile
         metrics_dir = tempfile.mkdtemp(prefix="gym_tpu_serve_")
 
-    if page_size and cfg.block_size % page_size:
-        # a page size that doesn't divide this checkpoint's window can't
-        # page — serve unpaged rather than refuse the checkpoint
+    asked = page_size
+    page_size, kv_pages = fit_pool(page_size, cfg.block_size, kv_pages)
+    if page_size != asked:
         sys.stderr.write(
-            f"gym_tpu.serve: page_size {page_size} does not divide "
-            f"block_size {cfg.block_size} — serving unpaged"
-            + (", speculative decoding disabled (it requires the paged "
-               "cache)" if spec_tokens else "") + "\n")
-        page_size = 0
-    paged = page_size > 0
-    if spec_tokens and not paged:
-        sys.stderr.write(
-            "gym_tpu.serve: --spec_tokens requires the paged cache "
-            "(--page_size > 0) — speculative decoding disabled\n")
+            f"gym_tpu.serve: page_size {asked} does not divide "
+            f"block_size {cfg.block_size} — serving with page_size "
+            f"{page_size}"
+            + (f" and kv_pages {kv_pages} (as many tokens)"
+               if kv_pages is not None else "") + "\n")
 
     from .. import programs as programs_mod
     resolved = programs_mod.enable_disk_tier(program_cache_dir)
@@ -367,10 +363,8 @@ def create_server(params, cfg, *, host: str = "127.0.0.1", port: int = 0,
         base = fleet_dir or tempfile.mkdtemp(prefix="gym_tpu_fleet_")
         router = build_process_fleet(
             params, cfg, base, replicas=replicas, num_slots=num_slots,
-            decode_chunk=decode_chunk,
-            page_size=(page_size or 16) if paged else 0,
-            kv_pages=kv_pages,
-            spec_tokens=spec_tokens if paged else 0,
+            decode_chunk=decode_chunk, page_size=page_size,
+            kv_pages=kv_pages, spec_tokens=spec_tokens,
             max_queue=max_queue, metrics=metrics,
             dispatch_timeout_s=dispatch_timeout,
             max_restarts=max_restarts, max_failovers=failover_retries,
@@ -416,9 +410,9 @@ def create_server(params, cfg, *, host: str = "127.0.0.1", port: int = 0,
         # same config, no recompiles
         router = build_fleet(
             params, cfg, replicas=replicas, num_slots=num_slots,
-            decode_chunk=decode_chunk, paged=paged,
-            page_size=page_size or 16, kv_pages=kv_pages,
-            spec_tokens=spec_tokens if paged else 0, max_queue=max_queue,
+            decode_chunk=decode_chunk, page_size=page_size,
+            kv_pages=kv_pages, spec_tokens=spec_tokens,
+            max_queue=max_queue,
             metrics=metrics, dispatch_timeout_s=dispatch_timeout,
             max_restarts=max_restarts, max_failovers=failover_retries,
             weights_tag=weights_tag, quotas=quotas, preempt=preempt)
@@ -534,10 +528,10 @@ def create_server(params, cfg, *, host: str = "127.0.0.1", port: int = 0,
                 "prefills": sum(s.prefills for s in stats),
                 "prefill_buckets": buckets,
                 "prefill_tokens": sum(s.prefill_tokens for s in stats),
-                "paged": bool(getattr(eng0, "paged", False)),
-                "page_size": int(getattr(eng0, "page_size", 0)),
-                "kv_pages": int(getattr(eng0, "kv_pages", 0)),
-                "spec_tokens": int(getattr(eng0, "spec_tokens", 0)),
+                "paged": True,          # the only cache there is
+                "page_size": int(eng0.page_size),
+                "kv_pages": int(eng0.kv_pages),
+                "spec_tokens": int(eng0.spec_tokens),
                 # quantized serving (ISSUE 11): config echo + the
                 # f32-normalized pool capacity and actual byte
                 # footprints (honest accounting — scale sidecars
@@ -946,6 +940,9 @@ def create_server(params, cfg, *, host: str = "127.0.0.1", port: int = 0,
 def main(argv=None) -> int:
     parser = _build_parser()
     args = parser.parse_args(argv)
+    if args.page_size < 1:
+        parser.error(f"--page_size must be >= 1, got {args.page_size}: "
+                     f"the page pool is the only KV cache")
     if getattr(args, "quant_embed") and not args.quant:
         # refuse, don't silently no-op: quant_embed only has meaning on
         # a quantized weight tree
@@ -1095,8 +1092,7 @@ def main(argv=None) -> int:
     if handle.scheduler is not None:
         eng = handle.scheduler.engine
         kv = (f"paged kv: page {eng.page_size} x {eng.kv_pages} pages"
-              + (f", spec {eng.spec_tokens}" if eng.spec_tokens else "")
-              if eng.paged else "unpaged kv")
+              + (f", spec {eng.spec_tokens}" if eng.spec_tokens else ""))
         if eng.weights_dtype != "f32" or eng.kv_dtype != "f32":
             kv += f", quant w={eng.weights_dtype} kv={eng.kv_dtype}"
         fleet_note = f"{args.replicas} replica(s)"

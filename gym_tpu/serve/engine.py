@@ -5,52 +5,51 @@ per request signature (prompt length × new tokens × sampling config —
 every new shape recompiles), the engine compiles a FIXED-SHAPE program
 set once and runs every request through it:
 
+- **One KV cache, a pool of pages** (PagedAttention, arXiv
+  2309.06180): fixed-size pages addressed through a per-slot block table
+  (``models/nanogpt.py:_decode_attend_paged`` — off the TPU static
+  ``[block_size]`` reductions and masks, the sums ``generate_fast``'s
+  own attend makes, which keeps the served token streams bit-identical
+  to it; on a TPU with a float32 pool a Pallas kernel that reads only
+  each row's live pages in place (``ops/paged_attention.py``), the same
+  products summed in another order, held to a logits tolerance instead:
+  ``EngineStats.paged_kernel_dispatches`` and the ``path`` id of the
+  dispatch spans say which ran). The pool is batch-shape independent: a
+  1-row prefill and an S-row decode run against the SAME buffers.
 - **Decode step** (compiled once per ``(config, num_slots)``): the whole
   slot batch advances one token. Each slot is an independent sequence at
-  its own cache position — the model's per-row cursors/masks
-  (``models/nanogpt.py:_decode_attend``) keep rows isolated — and the
-  per-slot sampling params (temperature / top-k / top-p / PRNG key) ride
-  in as vectors, applied by a vmapped ``sample_logits``. Inactive slots
-  compute garbage that is never read and their integer cursors are
-  frozen, so a free slot can idle forever without overflowing.
+  its own cursor, reading and writing only the pages of its own block
+  table, and the per-slot sampling params (temperature / top-k / top-p /
+  PRNG key) ride in as vectors, applied by a vmapped ``sample_logits``.
+  Inactive slots compute garbage that is never read, written to the null
+  page, and their cursors are frozen, so a free slot can idle forever.
 - **Prefill** (compiled once per power-of-two bucket): a single request's
-  prompt, right-padded to the bucket length, fills a fresh single-row
-  cache and samples the first token at the TRUE last prompt position
-  (padded positions are causally masked away from real queries and
-  overwritten before any later query can attend to them). Total prefill
-  compilations are bounded by ``⌈log2(block_size)⌉ + 1`` — the bucket
-  count — instead of one per distinct prompt length.
-- **Admit/evict** (compiled once): the prefilled row is scattered into
-  the engine cache at the slot index and the slot's cursors rewound to
-  the true prompt length. Admission and eviction happen BETWEEN decode
-  steps (continuous batching): a finished slot frees mid-flight while
-  its neighbors keep decoding — no drain-the-batch barrier.
+  prompt suffix, right-padded to the bucket length, is written into the
+  slot's pages and the first token sampled at the TRUE last prompt
+  position (padded positions are causally masked away from real queries
+  and overwritten before any later query can attend to them). Total
+  prefill compilations are bounded by ``⌈log2(block_size)⌉ + 1`` — the
+  bucket count — instead of one per distinct prompt length.
+- **Prefix sharing**: a ref-counted ``BlockAllocator`` plus an
+  exact-content prefix hash table admit a prompt whose longest
+  block-aligned prefix is already resident WITHOUT re-prefilling or
+  copying those blocks: prefill processes only the suffix (one
+  bucket-padded dispatch), and a fully-matched final block is
+  copy-on-written so its last token can be re-forwarded for the
+  first-token logits without perturbing other readers. Blocks a request
+  may ever write (suffix pads + the whole decode budget) are reserved at
+  admit, so shared pages are full, immutable prompt blocks by
+  construction and the jitted programs never need to allocate.
+- **Admit/evict** are host bookkeeping BETWEEN decode steps (continuous
+  batching): a finished slot's pages go back to the allocator mid-flight
+  while its neighbors keep decoding — no drain-the-batch barrier.
 
 Parity oracle (tests/test_serve.py): for a single request the engine's
 token stream is IDENTICAL to ``generate_fast`` with the same sampling
-config and seed — both use the shared ``sample_logits`` kernel and the
-``fold_in(PRNGKey(seed), token_index)`` key schedule, and the per-row
-cache math is the same program modulo batch width.
-
-**Paged KV + prefix sharing** (``paged=True``; PagedAttention, arXiv
-2309.06180): the cache becomes a POOL of fixed-size pages addressed
-through a per-slot block table (``models/nanogpt.py:_decode_attend_paged``
-— off the TPU the same static-[block_size] reductions and masks as the
-unpaged attend, which keeps paged token streams bit-identical; on a TPU
-with a float32 pool a Pallas kernel that reads only each row's live pages
-in place (``ops/paged_attention.py``), the same products summed in
-another order, held to a logits tolerance instead:
-``EngineStats.paged_kernel_dispatches`` and the ``path`` id of the
-dispatch spans say which ran). A ref-counted
-``BlockAllocator`` plus an exact-content prefix hash table admit a
-prompt whose longest block-aligned prefix is already resident WITHOUT
-re-prefilling or copying those blocks: prefill processes only the
-suffix (one bucket-padded dispatch), and a fully-matched final block is
-copy-on-written so its last token can be re-forwarded for the
-first-token logits without perturbing other readers. Blocks a request
-may ever write (suffix pads + the whole decode budget) are reserved at
-admit, so shared pages are full, immutable prompt blocks by
-construction and the jitted programs never need to allocate.
+config and seed off the TPU — both use the shared ``sample_logits``
+kernel and the ``fold_in(PRNGKey(seed), token_index)`` key schedule, and
+the gather path of the paged attend reduces exactly like
+``generate_fast``'s.
 
 **Speculative decoding** (``spec_tokens=γ``; arXiv 2302.01318), fused
 into the ``decode_chunk`` scan: draft γ tokens per slot by on-device
@@ -67,8 +66,8 @@ programs' per-slot state (input token, active flag, cursor, key index,
 remaining budget, key, EOS id, temperature, top-k, top-p, block table;
 speculative: the token history) lives ON THE DEVICE: a decode dispatch
 returns it whole and the next one takes it as it is
-(``programs/serve_defs.py``: ``SLOT_STATE`` / ``PAGED_STATE`` /
-``SPEC_STATE``). The engine's NumPy arrays are the host's MIRROR of it,
+(``programs/serve_defs.py``: ``PAGED_STATE`` / ``SPEC_STATE``). The
+engine's NumPy arrays are the host's MIRROR of it,
 kept equal by replaying each step's small download. A write to a mirror
 outside ``step`` (admit, release, park, resume, quarantine, a forced
 token) names it stale, and the next dispatch is handed the stale mirrors
@@ -92,10 +91,8 @@ import numpy as np
 from ..models.serving import attend_path_id
 from ..ops.paged_attention import GATHER
 from ..programs import default_registry
-from ..programs.serve_defs import (PAGED_STATE, SLOT_STATE, SPEC_STATE,
-                                   cow_def, paged_decode_def,
-                                   paged_prefill_def, prefill_def,
-                                   slot_admit_def, slot_decode_def,
+from ..programs.serve_defs import (PAGED_STATE, SPEC_STATE, cow_def,
+                                   paged_decode_def, paged_prefill_def,
                                    spec_decode_def)
 from ..utils.resilience import fault_point
 from ..utils.trace import span
@@ -161,7 +158,7 @@ class SamplingParams:
 
 @dataclasses.dataclass
 class ParkedSlot:
-    """Host-side snapshot of one preempted slot (paged engines only).
+    """Host-side snapshot of one preempted slot.
     The block-table REFERENCES move into the snapshot — pages stay
     pinned in the pool at their current refcounts, exactly like the
     slot-owned write blocks the spec-decode rewind masks — so a later
@@ -223,9 +220,9 @@ class EngineStats:
     paged_kernel_dispatches: int = 0     # decode + prefill dispatches whose
     #                                      attend ran the Pallas page walk
     #                                      (ops/paged_attention.py); 0 on
-    #                                      the gather path and unpaged
+    #                                      the gather path
     quarantined: int = 0                 # slots shut down on NaN/Inf logits
-    # paged-KV observables (0 on an unpaged engine)
+    # page-pool observables
     kv_blocks_in_use: int = 0            # pages referenced by live slots
     kv_blocks_cached: int = 0            # resident reusable prefix blocks
     prefix_hit_blocks: int = 0           # cumulative blocks served from the
@@ -263,6 +260,33 @@ def prompt_bucket(n: int, block_size: int) -> int:
         raise ValueError("empty prompt")
     b = 1 << (n - 1).bit_length()
     return min(b, block_size)
+
+
+def fit_page_size(asked: int, block_size: int) -> int:
+    """The page size a server gives a checkpoint: ``asked`` where it
+    divides the checkpoint's ``block_size``, else the largest divisor of
+    ``block_size`` not above it (a page may not straddle the window's
+    end, and a checkpoint is not refused over it). Below 1 there is
+    nothing to fit: the page pool is the only KV cache."""
+    if asked < 1:
+        raise ValueError(
+            f"page_size must be >= 1, got {asked}: the page pool is the "
+            f"engine's only KV cache (page_size 0 used to select the "
+            f"unpaged slot ring, which is gone)")
+    return max(d for d in range(1, min(asked, block_size) + 1)
+               if block_size % d == 0)
+
+
+def fit_pool(page_size: int, block_size: int,
+             kv_pages: Optional[int] = None) -> Tuple[int, Optional[int]]:
+    """``(page_size, kv_pages)`` a server builds its engine with. A pool
+    size that was given counted pages of the size asked for: where the
+    page is fitted down it is scaled up, so that the pool holds the
+    tokens that were asked for (``None`` stays: the engine sizes it)."""
+    fitted = fit_page_size(page_size, block_size)
+    if kv_pages is not None:
+        kv_pages = -(-kv_pages * page_size // fitted)
+    return fitted, kv_pages
 
 
 def max_prefill_buckets(block_size: int) -> int:
@@ -363,6 +387,19 @@ class BlockAllocator:
             self._ref.pop(page)
             self._free.append(page)
 
+    def condemn(self, page: int) -> bool:
+        """``page`` belongs to a quarantined row, so its content is no
+        longer trusted. False while another user still reads it (the
+        last of them condemns it); else it leaves the prefix cache, so
+        the decref that follows frees it, and True tells the engine to
+        write it over."""
+        if self._ref.get(page, 0) != 1:
+            return False
+        key = self._key_of.pop(page, None)
+        if key is not None:
+            del self._cache[key]
+        return True
+
     # -- prefix cache -----------------------------------------------------
 
     def lookup(self, parent_cid: int, block: bytes):
@@ -413,7 +450,7 @@ class InferenceEngine:
 
     def __init__(self, params: PyTree, config: Any,
                  num_slots: int = 8, decode_chunk: int = 1,
-                 paged: bool = False, page_size: int = 16,
+                 paged: bool = True, page_size: int = 16,
                  kv_pages: Optional[int] = None, spec_tokens: int = 0,
                  weights_tag: Optional[str] = None):
         """``decode_chunk``: decode steps fused into one dispatch (a
@@ -424,17 +461,12 @@ class InferenceEngine:
         throughput) at the cost of slot-turnaround latency: a slot
         finishing mid-chunk frees only at the chunk boundary.
 
-        ``paged=True`` switches the KV cache to a page POOL
-        (``kv_pages`` pages of ``page_size`` tokens; default pool =
-        1 null page + ``num_slots`` full windows) with a per-slot block
-        table, a ref-counted allocator and a prefix hash table: a prompt
-        whose longest block-aligned prefix is already resident is
-        admitted WITHOUT re-prefilling or copying those blocks.
-        ``spec_tokens=γ > 0`` (paged only) adds self-drafting
-        speculative decoding: each decode iteration drafts γ tokens by
-        n-gram lookup and verifies them in one batched model call —
-        token streams stay EXACTLY equal to the non-speculative engine
-        (see ``programs.serve_defs.build_spec_decode``).
+        The KV cache is ``kv_pages`` pages of ``page_size`` tokens
+        (default: 1 null page + ``num_slots`` full windows + 1
+        copy-on-write page). It is the only cache: ``paged`` is accepted
+        for callers that still name it, and ``False`` is refused.
+        ``spec_tokens=γ > 0`` drafts γ tokens a decode iteration and
+        verifies them in one model call (the module docstring has both).
 
         ``weights_tag`` names the parameter set this engine serves (e.g.
         ``"step-120"``) — pure observability for the fleet router's
@@ -448,12 +480,10 @@ class InferenceEngine:
         if spec_tokens < 0:
             raise ValueError(
                 f"spec_tokens must be >= 0, got {spec_tokens}")
-        if spec_tokens and not paged:
+        if not paged:
             raise ValueError(
-                "speculative decoding rides on the paged KV path — pass "
-                "paged=True (the rollback contract needs slot-owned "
-                "write blocks)")
-        self.paged = bool(paged)
+                "paged=False: the unpaged slot ring is gone, the page "
+                "pool is the engine's only KV cache (drop the argument)")
         self.spec_tokens = int(spec_tokens)
         self.weights_tag = weights_tag
         self.weights_dtype = str(getattr(config, "weights_dtype", "f32"))
@@ -472,38 +502,30 @@ class InferenceEngine:
         self.block_size = int(config.block_size)
         self.num_slots = int(num_slots)
         self.decode_chunk = int(decode_chunk)
-        if self.paged:
-            if page_size < 1 or self.block_size % page_size:
-                raise ValueError(
-                    f"page_size must be >= 1 and divide block_size "
-                    f"{self.block_size}, got {page_size}")
-            self.page_size = int(page_size)
-            self.max_blocks = self.block_size // self.page_size
-            if kv_pages is None:
-                # null page + one full window per slot + one page of
-                # copy-on-write headroom (also satisfies the 1-slot
-                # minimum below)
-                kv_pages = 2 + self.num_slots * self.max_blocks
-            if kv_pages < 2 + self.max_blocks:
-                raise ValueError(
-                    f"kv_pages={kv_pages} too small: need the null page "
-                    f"+ one full window ({self.max_blocks} blocks) + one "
-                    f"copy-on-write page")
-            self.kv_pages = int(kv_pages)
-            self.config = dataclasses.replace(
-                base_cfg, page_size=self.page_size, kv_pages=self.kv_pages)
-            self._alloc = BlockAllocator(self.kv_pages, self.page_size)
-            # the model's own dispatch point, asked with what its layers
-            # will ask: the id on the dispatch spans and what /stats counts
-            self.attend_path = attend_path_id(self.config)
-        else:
-            self.page_size = 0
-            self.attend_path = "dense"
-            self.max_blocks = 0
-            self.kv_pages = 0
-            self.config = base_cfg
-            self._alloc = None
-        self._kernel_attend = self.paged and GATHER not in self.attend_path
+        if page_size < 1 or self.block_size % page_size:
+            raise ValueError(
+                f"page_size must be >= 1 and divide block_size "
+                f"{self.block_size}, got {page_size}")
+        self.page_size = int(page_size)
+        self.max_blocks = self.block_size // self.page_size
+        if kv_pages is None:
+            # null page + one full window per slot + one page of
+            # copy-on-write headroom (also satisfies the 1-slot
+            # minimum below)
+            kv_pages = 2 + self.num_slots * self.max_blocks
+        if kv_pages < 2 + self.max_blocks:
+            raise ValueError(
+                f"kv_pages={kv_pages} too small: need the null page "
+                f"+ one full window ({self.max_blocks} blocks) + one "
+                f"copy-on-write page")
+        self.kv_pages = int(kv_pages)
+        self.config = dataclasses.replace(
+            base_cfg, page_size=self.page_size, kv_pages=self.kv_pages)
+        self._alloc = BlockAllocator(self.kv_pages, self.page_size)
+        # the model's own dispatch point, asked with what its layers
+        # will ask: the id on the dispatch spans and what /stats counts
+        self.attend_path = attend_path_id(self.config)
+        self._kernel_attend = GATHER not in self.attend_path
         self.params = jax.tree.map(jnp.asarray,
                                    self.config.prepare_params(params))
         self.weights_bytes = int(sum(x.nbytes
@@ -516,33 +538,29 @@ class InferenceEngine:
         # engine holds are pinned against capacity eviction for its
         # lifetime (released via weakref when the engine is collected)
         self._registry = default_registry()
-        if self.paged:
-            self._admit_prog = None
-            self._decode_prog = self._acquire(paged_decode_def(
-                self._cfg_tuple, self.num_slots, self.decode_chunk))
-            self._cow_prog = self._acquire(cow_def(self._cfg_tuple))
-            self._spec_prog = (
-                self._acquire(spec_decode_def(
-                    self._cfg_tuple, self.num_slots, self.decode_chunk,
-                    self.spec_tokens))
-                if self.spec_tokens else None)
-        else:
-            self._admit_prog = self._acquire(slot_admit_def(
-                self._cfg_tuple, self.num_slots))
-            self._decode_prog = self._acquire(slot_decode_def(
-                self._cfg_tuple, self.num_slots, self.decode_chunk))
-            self._cow_prog = None
-            self._spec_prog = None
+        decode_def = paged_decode_def(self._cfg_tuple, self.num_slots,
+                                      self.decode_chunk)
+        self._decode_prog = self._acquire(decode_def)
+        self._cow_prog = self._acquire(cow_def(self._cfg_tuple))
+        self._spec_prog = (
+            self._acquire(spec_decode_def(
+                self._cfg_tuple, self.num_slots, self.decode_chunk,
+                self.spec_tokens))
+            if self.spec_tokens else None)
         self._step1_prog = None          # lazy chunk-1 twin (teacher forcing)
         self._prefill_progs: Dict[int, Any] = {}   # bucket → handle
         self._seen_buckets: set = set()
-        self._cache = self._init_cache()
+        # the pool as the programs take it. It is batch-shape independent
+        # ([kv_pages, page, n_embd] per layer): a 1-row prefill and an
+        # S-row decode run against the SAME buffers — that is what makes
+        # the prefix blocks shareable without an admit-scatter program
+        self._cache = jax.tree.map(
+            lambda sh: jnp.zeros(sh.shape, sh.dtype), decode_def.args[1])
         s = self.num_slots
-        if self.paged:
-            self._bt = np.zeros((s, self.max_blocks), np.int32)
-            self._pos = np.zeros(s, np.int32)          # per-slot KV cursor
-            self._hist = np.zeros((s, self.block_size), np.int32)
-            self._prompt_len = np.zeros(s, np.int32)
+        self._bt = np.zeros((s, self.max_blocks), np.int32)
+        self._pos = np.zeros(s, np.int32)          # per-slot KV cursor
+        self._hist = np.zeros((s, self.block_size), np.int32)
+        self._prompt_len = np.zeros(s, np.int32)
         self._active = np.zeros(s, bool)
         self._next_tok = np.zeros(s, np.int32)     # input token per slot
         self._gen_idx = np.zeros(s, np.int32)      # key-schedule index
@@ -596,9 +614,7 @@ class InferenceEngine:
         slot, head) scale sidecar (4/hd of the int8 payload — 6.25% at
         head dim 64) is NOT hidden inside this number: it is reported
         separately by ``kv_pool_bytes``. Equals the plain usable-block
-        count on an f32 engine; 0 unpaged."""
-        if not self.paged:
-            return 0
+        count on an f32 engine."""
         return (self.kv_pages - 1) * (4 // self.kv_elem_bytes)
 
     def kv_pool_bytes(self) -> Dict[str, int]:
@@ -634,10 +650,7 @@ class InferenceEngine:
         already built is a hit, not a compile)."""
         h = self._prefill_progs.get(bucket)
         if h is None:
-            pdef = (paged_prefill_def(self._cfg_tuple, bucket)
-                    if self.paged
-                    else prefill_def(self._cfg_tuple, bucket))
-            h = self._acquire(pdef)
+            h = self._acquire(paged_prefill_def(self._cfg_tuple, bucket))
             self._prefill_progs[bucket] = h
         # exact per-key attribution: ensure_reporting is True only if
         # THIS call ran the build — a global-counter diff would charge
@@ -649,8 +662,8 @@ class InferenceEngine:
     def warmup_defs(self) -> List[Any]:
         """This engine's COMPLETE program family — what the background
         warmup precompiles so no request ever pays a compile: the full
-        power-of-two prefill-bucket family plus the decode/admit (or
-        paged decode/CoW/spec) programs, traffic-critical first."""
+        power-of-two prefill-bucket family plus the decode, CoW and
+        speculative programs, traffic-critical first."""
         buckets: List[int] = []
         b = 1
         while b < self.block_size:
@@ -658,25 +671,17 @@ class InferenceEngine:
             b <<= 1
         buckets.append(self.block_size)
         cfg, s, chunk = self._cfg_tuple, self.num_slots, self.decode_chunk
-        if self.paged:
-            defs = [paged_decode_def(cfg, s, chunk)]
-            if self.spec_tokens:
-                defs.append(spec_decode_def(cfg, s, chunk,
-                                            self.spec_tokens))
-            defs.append(cow_def(cfg))
-            if chunk != 1 or self.spec_tokens:
-                # the lazy chunk-1 twin (teacher forcing / eval
-                # harnesses) is part of the family too — without it a
-                # warmed or disk-restored process pays its compile on
-                # the first override_tokens step
-                defs.append(paged_decode_def(cfg, s, 1))
-            defs.extend(paged_prefill_def(cfg, b) for b in buckets)
-        else:
-            defs = [slot_decode_def(cfg, s, chunk),
-                    slot_admit_def(cfg, s)]
-            if chunk != 1:
-                defs.append(slot_decode_def(cfg, s, 1))
-            defs.extend(prefill_def(cfg, b) for b in buckets)
+        defs = [paged_decode_def(cfg, s, chunk)]
+        if self.spec_tokens:
+            defs.append(spec_decode_def(cfg, s, chunk, self.spec_tokens))
+        defs.append(cow_def(cfg))
+        if chunk != 1 or self.spec_tokens:
+            # the lazy chunk-1 twin (teacher forcing / eval harnesses)
+            # is part of the family too — without it a warmed or
+            # disk-restored process pays its compile on the first
+            # override_tokens step
+            defs.append(paged_decode_def(cfg, s, 1))
+        defs.extend(paged_prefill_def(cfg, b) for b in buckets)
         return defs
 
     def _count(self, counted: PyTree) -> None:
@@ -695,27 +700,6 @@ class InferenceEngine:
         if name == "eos":
             return self._eos.astype(np.int32)
         return getattr(self, _MIRROR[name])
-
-    def _init_cache(self) -> PyTree:
-        model = self.config.build()
-        dummy = jnp.zeros((self.num_slots, 1), jnp.int32)
-        if self.paged:
-            # the pool is batch-shape independent ([kv_pages, page,
-            # n_embd] per layer): a 1-row prefill and an S-row decode run
-            # against the SAME buffers — that is what makes the prefix
-            # blocks shareable without an admit-scatter program
-            shapes = jax.eval_shape(
-                lambda: model.init(
-                    {"params": jax.random.PRNGKey(0)}, dummy, train=False,
-                    block_table=jnp.zeros(
-                        (self.num_slots, self.max_blocks), jnp.int32),
-                    cache_pos=jnp.zeros((self.num_slots,), jnp.int32)))
-        else:
-            shapes = jax.eval_shape(
-                lambda: model.init({"params": jax.random.PRNGKey(0)},
-                                   dummy, train=False))
-        return jax.tree.map(lambda sh: jnp.zeros(sh.shape, sh.dtype),
-                            shapes["cache"])
 
     # -- slot lifecycle ---------------------------------------------------
 
@@ -752,16 +736,15 @@ class InferenceEngine:
                 f"KV cache (block_size {self.block_size}); crop the prompt "
                 f"to block_size - max_new_tokens, or use `generate`, whose "
                 f"full-context resampling slides the context window")
-        if self.paged:
-            # worst case (zero prefix hits, +1 copy-on-write headroom)
-            # must fit the pool EVER, so a queued request always
-            # eventually admits once running slots release their blocks
-            worst = -(-(n + sp.max_new_tokens) // self.page_size) + 1
-            if worst > self.kv_pages - 1:
-                raise ValueError(
-                    f"request needs up to {worst} KV blocks but the "
-                    f"paged pool holds {self.kv_pages - 1}; raise "
-                    f"kv_pages or shrink prompt/max_new_tokens")
+        # worst case (zero prefix hits, +1 copy-on-write headroom) must
+        # fit the pool EVER, so a queued request always eventually
+        # admits once running slots release their blocks
+        worst = -(-(n + sp.max_new_tokens) // self.page_size) + 1
+        if worst > self.kv_pages - 1:
+            raise ValueError(
+                f"request needs up to {worst} KV blocks but the "
+                f"paged pool holds {self.kv_pages - 1}; raise "
+                f"kv_pages or shrink prompt/max_new_tokens")
 
     # -- paged planning ---------------------------------------------------
 
@@ -837,29 +820,13 @@ class InferenceEngine:
         ``(would admit() succeed right now, resident-prefix score)``.
         The capacity answer is exact, not conservative — it runs the
         same plan ``admit`` would and excludes the would-be-pinned
-        prefix blocks from the evictable supply. Unpaged:
-        ``(True, 0)`` — ordering degrades to FCFS."""
-        if not self.paged:
-            return True, 0
+        prefix blocks from the evictable supply."""
         p = np.asarray(prompt, np.int32).reshape(-1)
         hit_pages, _chain, cow_src, _cid, _start, _suffix, _bucket, \
             _n_new, need = self._plan_paged(p, sp.max_new_tokens)
         pinned = hit_pages + ([cow_src] if cow_src is not None else [])
         score = len(hit_pages) + (1 if cow_src is not None else 0)
         return self._alloc.available(exclude=pinned) >= need, score
-
-    def resident_prefix_blocks(self, prompt) -> int:
-        """How many leading full blocks of ``prompt`` the prefix cache
-        could serve right now. 0 on an unpaged engine."""
-        if not self.paged:
-            return 0
-        p = np.asarray(prompt, np.int32).reshape(-1)
-        return len(self._walk_prefix(p)[0])
-
-    def has_capacity(self, prompt, sp: SamplingParams) -> bool:
-        """Whether an ``admit`` of this request would succeed RIGHT NOW
-        (block supply; the caller checks ``free_slots`` itself)."""
-        return self.admit_probe(prompt, sp)[0]
 
     def admit(self, prompt: np.ndarray,
               sp: SamplingParams) -> Tuple[int, TokenEvent]:
@@ -882,25 +849,7 @@ class InferenceEngine:
             top_k = (self.config.vocab_size if sp.top_k is None
                      else int(sp.top_k))
             top_p = 1.0 if sp.top_p is None else float(sp.top_p)
-        if self.paged:
-            first = self._prefill_paged(slot, prompt, sp, key, top_k, top_p)
-        else:
-            with span("serve.prefill.args"):
-                bucket = prompt_bucket(n, self.block_size)
-                self._seen_buckets.add(bucket)
-                prefill = self._prefill_prog(bucket)
-                padded = np.zeros((1, bucket), np.int32)
-                padded[0, :n] = prompt
-                args = (self.params, padded, np.int32(n), key,
-                        np.float32(sp.temperature), np.int32(top_k),
-                        np.float32(top_p))
-            with span("serve.prefill.dispatch", path=self.attend_path):
-                tok, row_cache = prefill(*args)
-                self._cache = self._admit_prog(self._cache, row_cache,
-                                               np.int32(slot), np.int32(n))
-            with span("serve.prefill.readback"):
-                first = int(np.asarray(tok)[0])
-            self.stats.prefill_tokens += bucket
+        first = self._prefill_paged(slot, prompt, sp, key, top_k, top_p)
         self.stats.prefills += 1
         self.stats.tokens_generated += 1
         # slot bookkeeping: the first token came from the prefill (key
@@ -915,17 +864,15 @@ class InferenceEngine:
         self._top_k[slot] = top_k
         self._top_p[slot] = top_p
         self._base_keys[slot] = key
-        if self.paged:
-            # token history feeds the n-gram draft; the first token is
-            # emitted (index n), giving hist_len == cursor + 1
-            self._hist[slot, n] = first
+        # token history feeds the n-gram draft; the first token is
+        # emitted (index n), giving hist_len == cursor + 1
+        self._hist[slot, n] = first
         self._stale.update(SPEC_STATE)   # every mirror has a new row
         finished = (sp.max_new_tokens <= 1
                     or (sp.eos_token is not None and first == sp.eos_token))
         if finished:
             self._active[slot] = False
-            if self.paged:
-                self._release_pages(slot)
+            self._release_pages(slot)
         self.stats.active_slots = int(self._active.sum())
         self.stats.prefill_buckets = tuple(sorted(self._seen_buckets))
         return slot, TokenEvent(slot, first, finished)
@@ -1033,8 +980,6 @@ class InferenceEngine:
         already-cleared row is a no-op). Cached prefix blocks stay
         resident at refcount 0; plain owned blocks return to the free
         list."""
-        if not self.paged:
-            return
         for pg in self._bt[slot]:
             if pg:
                 self._alloc.decref(int(pg))
@@ -1043,12 +988,23 @@ class InferenceEngine:
         self.stats.kv_blocks_in_use = self._alloc.in_use()
         self.stats.kv_blocks_cached = self._alloc.cached()
 
+    def _scrub_pages(self, slot: int) -> None:
+        """Write over the pages only this quarantined row holds, before
+        they are freed: a masked position still multiplies (0 x NaN), so
+        a page that kept its NaNs would poison its next owner, and that
+        one's, which is no recovery. The source is the null page, finite
+        or every row would be poisoned through its unallocated table
+        entries; the copy is the admit path's own program. Rare path:
+        one small dispatch a page."""
+        for pg in self._bt[slot]:
+            if pg and self._alloc.condemn(int(pg)):
+                self._cache = self._cow_prog(
+                    self._cache, np.int32(0), np.int32(pg))
+
     def release(self, slot: int) -> None:
         """Free a slot between decode steps (EOS/max-tokens eviction or a
-        cancelled request). Unpaged, the cache rows stay as-is — the next
-        admit overwrites them wholesale; paged, the slot's block-table
-        references are dropped (shared prefix blocks stay resident for
-        future hits)."""
+        cancelled request): the slot's block-table references are
+        dropped (shared prefix blocks stay resident for future hits)."""
         self._active[slot] = False
         self._stale.add("active")
         self._release_pages(slot)
@@ -1061,13 +1017,7 @@ class InferenceEngine:
         dispatches): snapshot its entire host-side cursor state and
         block table WITHOUT decreffing the pages — the snapshot owns the
         references — deactivate the row, and return the snapshot. Pure
-        host bookkeeping: no device work, no copies of KV state. Paged
-        engines only (an unpaged slot's cache rows are overwritten
-        wholesale by the next admit, so nothing parkable survives)."""
-        if not self.paged:
-            raise ValueError(
-                "park() requires a paged engine — unpaged cache rows do "
-                "not survive the next admit")
+        host bookkeeping: no device work, no copies of KV state."""
         if not self._active[slot]:
             raise ValueError(f"slot {slot} is not active — nothing to park")
         parked = ParkedSlot(
@@ -1150,19 +1100,12 @@ class InferenceEngine:
         between dispatches, admission too: continuous batching at chunk
         granularity.
 
-        What crosses to the device and back: the decode state (tokens,
-        cursors, key indices, budgets, sampling vectors, block table)
-        lives on the device, returned by one dispatch and handed to the
-        next as it is; only a mirror the host wrote since (``_stale``:
-        an admission, a release, a park or resume, a quarantine, a
-        forced token) goes up, as a NumPy argument of the dispatch
-        itself, so a step after which nothing was admitted or released
-        uploads nothing (``stats.resident_steps``). One small download
-        comes back (``jax.device_get`` of the program's ``read``: the
-        tokens, ``emitted``, the final token / active / cursor vectors,
-        the NaN latch, the model's counters) and the host's mirrors are
-        brought up to the device's state from it; the logits stay on the
-        device (``last_logits``).
+        What crosses to the device and back is in the module docstring:
+        only a mirror the host wrote since (``_stale``) goes up, so a
+        step after which nothing was admitted or released uploads
+        nothing (``stats.resident_steps``); one small download comes
+        back (``jax.device_get`` of the program's ``read``) and the
+        mirrors are brought up to the device's state from it.
 
         ``override_tokens`` (teacher forcing, tests/eval only) replaces a
         slot's INPUT token for ONE single step — the call runs a chunk-1
@@ -1179,12 +1122,8 @@ class InferenceEngine:
             spec_run = False
             if self.decode_chunk != 1 or self._spec_prog is not None:
                 if self._step1_prog is None:
-                    self._step1_prog = self._acquire(
-                        paged_decode_def(self._cfg_tuple,
-                                         self.num_slots, 1)
-                        if self.paged
-                        else slot_decode_def(self._cfg_tuple,
-                                             self.num_slots, 1))
+                    self._step1_prog = self._acquire(paged_decode_def(
+                        self._cfg_tuple, self.num_slots, 1))
                 prog = self._step1_prog
         elif spec_run:
             prog = self._spec_prog
@@ -1195,8 +1134,7 @@ class InferenceEngine:
         fault_point("serve.decode")
         with span("serve.decode.args") as sp:
             was_active = self._active.copy()
-            names = (SPEC_STATE if spec_run
-                     else PAGED_STATE if self.paged else SLOT_STATE)
+            names = SPEC_STATE if spec_run else PAGED_STATE
             # an entry the last dispatch did not return goes up too: all
             # of them at the first step, ``hist`` after a step of the plain
             # program (the host replays tokens into its own copy whatever
@@ -1234,8 +1172,7 @@ class InferenceEngine:
             # ``_generated`` and ``_hist`` follow below, token by token)
             self._next_tok = read["tok"].astype(np.int32)
             self._active = read["active"].copy()
-            if self.paged:
-                self._pos = read["pos"].astype(np.int32)
+            self._pos = read["pos"].astype(np.int32)
             # numerical quarantine: non-finite logits fail ONLY their own
             # slot — the model's per-row cache math keeps rows isolated (and
             # the decode attends NaN-poison an overflowing row/position on
@@ -1262,11 +1199,10 @@ class InferenceEngine:
                             emitted[k, slot].sum()) - 1
                     for j in np.nonzero(emitted[k, slot])[0]:
                         tok = int(toks[k, slot, j])
-                        if self.paged:
-                            hl = (int(self._prompt_len[slot])
-                                  + int(self._generated[slot]))
-                            if hl < self.block_size:
-                                self._hist[slot, hl] = tok
+                        hl = (int(self._prompt_len[slot])
+                              + int(self._generated[slot]))
+                        if hl < self.block_size:
+                            self._hist[slot, hl] = tok
                         self._gen_idx[slot] += 1
                         self._generated[slot] += 1
                         # finished iff the device stopped emitting for this
@@ -1278,12 +1214,13 @@ class InferenceEngine:
                         events.append(TokenEvent(
                             int(slot), tok, finished,
                             poisoned=bool(nan_seen[slot])))
-            if self.paged:
-                # blocks of slots that finished (or were quarantined) this
-                # chunk go back to the allocator; shared prefix blocks stay
-                # resident for future hits
-                for slot in np.nonzero(was_active & ~self._active)[0]:
-                    self._release_pages(slot)
+            # blocks of slots that finished (or were quarantined) this
+            # chunk go back to the allocator; shared prefix blocks stay
+            # resident for future hits
+            for slot in np.nonzero(was_active & ~self._active)[0]:
+                if nan_seen[slot]:
+                    self._scrub_pages(slot)
+                self._release_pages(slot)
             self.stats.tokens_generated += len(events)
             self.stats.decode_steps += int(was_active.any()) * n_steps
             self.stats.active_slots = int(self._active.sum())

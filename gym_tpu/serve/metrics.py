@@ -14,7 +14,7 @@ kinds share the header:
   ticks of the driver loop): cumulative tokens, rolling tokens/s, queue
   depth, active-slot occupancy, plus the paged-KV/speculative
   observables ``kv_blocks_in_use`` / ``prefix_hit_blocks`` /
-  ``spec_accept_rate`` (blank-or-zero on unpaged engines and absent in
+  ``spec_accept_rate`` (blank with speculation off; absent in
   pre-paging CSVs). ``status=restart`` marks a supervisor engine
   rebuild; ``status=reload`` a rolling weight hot-swap.
 
@@ -389,8 +389,8 @@ class ServeMetrics:
         self._rate = _RateState()       # legacy single-engine EWMA slot
         self._replicas: Dict[int, _ReplicaAgg] = {}
         self._ewma_idle_reset_s = float(ewma_idle_reset_s)
-        # last engine sample of the paged/speculative observables (an
-        # unpaged engine reports 0 blocks and a None accept rate)
+        # last engine sample of the paged/speculative observables (a
+        # None accept rate with speculation off)
         self._kv_blocks_in_use = 0
         self._prefix_hit_blocks = 0
         self._spec_accept_rate: Optional[float] = None

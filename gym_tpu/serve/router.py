@@ -377,9 +377,8 @@ class Router:
         bonus = 0.0
         try:
             eng = sched.engine
-            if getattr(eng, "paged", False):
-                bonus = (eng.admit_probe(prompt, sp)[1] * eng.page_size
-                         * self.prefix_bonus_weight)
+            bonus = (eng.admit_probe(prompt, sp)[1] * eng.page_size
+                     * self.prefix_bonus_weight)
         except Exception:  # noqa: BLE001 — cross-thread probe race:
             bonus = 0.0    # stickiness lost for one pick, nothing else
         return load - bonus
@@ -679,7 +678,7 @@ class Router:
 
 def build_fleet(params: PyTree, config, *, replicas: int = 1,
                 num_slots: int = 4, decode_chunk: int = 1,
-                paged: bool = False, page_size: int = 16,
+                page_size: int = 16,
                 kv_pages: Optional[int] = None, spec_tokens: int = 0,
                 max_queue: int = 64, metrics=None,
                 dispatch_timeout_s: float = 120.0, max_restarts: int = 5,
@@ -705,7 +704,7 @@ def build_fleet(params: PyTree, config, *, replicas: int = 1,
         def factory(rid=rid):
             return InferenceEngine(
                 box["params"], config, num_slots=num_slots,
-                decode_chunk=decode_chunk, paged=paged,
+                decode_chunk=decode_chunk,
                 page_size=page_size, kv_pages=kv_pages,
                 spec_tokens=spec_tokens, weights_tag=box.get("tag"))
 

@@ -344,9 +344,9 @@ class Scheduler:
                  quota_clock=time.monotonic):
         """``prefix_window``: how many queued requests the admit step may
         look ahead to prefer one whose prompt prefix is RESIDENT in the
-        paged engine's prefix cache (most resident blocks win, FCFS
-        breaks ties — so an unpaged engine, where every score is 0,
-        keeps exact FCFS order). 1 = strict FCFS.
+        engine's prefix cache (most resident blocks win, FCFS breaks
+        ties — so traffic that shares no prefix, where every score is
+        0, keeps exact FCFS order). 1 = strict FCFS.
 
         ``starvation_rounds``: anti-starvation bound for the paged
         block pool — once the HEAD request has been passed over this
@@ -362,10 +362,10 @@ class Scheduler:
         (the default) disables quota enforcement entirely.
 
         ``preempt``: allow a STRICTLY more urgent queued request to
-        park the least urgent running slot at a chunk boundary (paged
-        engines only — parking is a host-side snapshot over pinned
-        pages). The parked request keeps its ``Request`` object and
-        stream; it resumes byte-identical once pressure clears, bounded
+        park the least urgent running slot at a chunk boundary (parking
+        is a host-side snapshot over pinned pages). The parked request
+        keeps its ``Request`` object and stream; it resumes
+        byte-identical once pressure clears, bounded
         by ``max_preemptions`` parks per request and the same
         ``starvation_rounds`` anti-starvation contract as the queue
         head. ``quota_clock`` injects the bucket clock for
@@ -874,7 +874,7 @@ class Scheduler:
         dispatches. The victim keeps its ``Request`` (stream pauses),
         is bounded by ``max_preemptions`` parks, and its pages stay
         pinned for the byte-identical resume."""
-        if not engine.paged or engine.free_slots():
+        if engine.free_slots():
             return
         victim = None
         with self._lock:

@@ -443,7 +443,11 @@ def _splice_mismatch(i: int, want: int, got: int) -> BaseException:
 
 
 def main(argv=None) -> int:
-    args = _build_parser().parse_args(argv)
+    parser = _build_parser()
+    args = parser.parse_args(argv)
+    if args.page_size < 1:
+        parser.error(f"--page_size must be >= 1, got {args.page_size}: "
+                     f"the page pool is the only KV cache")
     if args.device == "cpu":
         os.environ.setdefault("JAX_PLATFORMS", "cpu")
         import jax
@@ -469,7 +473,7 @@ def main(argv=None) -> int:
         f"{resolved}\n")
 
     from ..models.serving import config_from_dict
-    from .engine import InferenceEngine
+    from .engine import InferenceEngine, fit_pool
     from .metrics import ServeMetrics
     from .scheduler import Scheduler
     from .supervisor import Supervisor
@@ -495,10 +499,8 @@ def main(argv=None) -> int:
               "--params-file/--config-json", file=sys.stderr)
         return 1
 
-    page_size = args.page_size
-    if page_size and cfg.block_size % page_size:
-        page_size = 0
-    paged = page_size > 0
+    page_size, kv_pages = fit_pool(args.page_size, cfg.block_size,
+                                   args.kv_pages)
 
     metrics_dir = args.metrics_dir
     if metrics_dir is None:
@@ -512,9 +514,8 @@ def main(argv=None) -> int:
     def factory():
         return InferenceEngine(
             box["params"], cfg, num_slots=args.num_slots,
-            decode_chunk=args.decode_chunk, paged=paged,
-            page_size=page_size or 16, kv_pages=args.kv_pages,
-            spec_tokens=args.spec_tokens if paged else 0,
+            decode_chunk=args.decode_chunk, page_size=page_size,
+            kv_pages=kv_pages, spec_tokens=args.spec_tokens,
             weights_tag=box.get("tag"))
 
     quotas = None
@@ -561,8 +562,7 @@ def main(argv=None) -> int:
     sys.stderr.write(
         f"gym_tpu.serve.worker: ready — replica {args.replica_id} "
         f"pid {os.getpid()} on {sock_path} "
-        f"({args.num_slots} slots, "
-        f"{'paged' if paged else 'unpaged'} kv)\n")
+        f"({args.num_slots} slots, page {page_size} kv)\n")
     sys.stderr.flush()
 
     conns: list = []

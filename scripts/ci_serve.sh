@@ -2,11 +2,12 @@
 # Serving gate (ISSUE 4 + ISSUE 7) — the serve/decode/paged unit suites
 # plus one CLI smoke run through the real HTTP entry point, run NEXT TO
 # scripts/ci_tier1.sh, ci_faults.sh and ci_sim.sh. The unit suites pin
-# the engine-vs-generate_fast parity oracle (unpaged AND paged/prefix-
-# shared/speculative), teacher-forcing logits, bounded prefill
+# the engine-vs-generate_fast parity oracle (the page pool: an engine
+# built with defaults, prefix-shared, speculative), teacher-forcing
+# logits, bounded prefill
 # compilation and the params-only restore; the smoke run proves
 # `python -m gym_tpu.serve` end to end: train a tiny checkpoint, serve
-# it (paged by default), answer 4 CONCURRENT requests, prove PREFIX
+# it (from the page pool, the only cache), answer 4 CONCURRENT requests, prove PREFIX
 # SHARING live (two requests sharing a prompt prefix ->
 # prefix_hit_blocks > 0 in /stats), then the SIGTERM drill — the server
 # must exit rc=0 with a clean-shutdown line and a tokens_per_s

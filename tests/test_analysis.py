@@ -466,9 +466,11 @@ def test_shipped_programs_audit_clean():
     assert rep["violations"] == 0, rep
     names = {p["name"] for p in rep["programs"]}
     assert len(names) == len(rep["programs"]) >= 26
-    assert any(n.startswith("serve.decode") for n in names)
-    assert any(n.startswith("serve.prefill") for n in names)
-    # ISSUE 7: the paged/speculative serving programs are audited too
+    # ISSUE 29: the engine has one KV cache; no program of the unpaged
+    # slot ring is left to audit
+    assert not any(n.startswith(("serve.decode", "serve.prefill[",
+                                 "serve.admit")) for n in names)
+    # ISSUE 7: the paged/speculative serving programs are audited
     assert any(n.startswith("serve.paged_prefill") for n in names)
     assert any(n.startswith("serve.paged_decode") for n in names)
     assert any(n.startswith("serve.spec_decode") for n in names)

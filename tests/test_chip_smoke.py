@@ -110,11 +110,14 @@ def test_smoke_rehearsal_runs_every_phase_and_still_fails(tmp_path):
         assert phases[name]["ok"], phases[name]
     assert phases["serve"]["checks"]["all_equal_generate_fast"]
     assert any(r["streamed"] for r in phases["serve"]["requests"])
-    # off the TPU the paged attend is the gather path: streams and logits
-    # bit-identical to the unpaged engine's, no kernel dispatch counted
+    # off the TPU the paged attend is the gather path: the engine forced
+    # along generate_fast's tokens samples them, its logits are the plain
+    # forward's to rounding, and no kernel dispatch is counted
     engines = phases["serve"]["engines"]
     assert engines["paged_attend_path"] == "gather"
-    assert engines["paged_logit_gap_max"] == 0.0 and engines["steps"] > 1
+    assert engines["paged_stream_equal"] and engines["steps"] > 1
+    assert engines["paged_logit_gap_max"] < 1e-4
+    assert phases["serve"]["checks"]["paged_equals_generate_fast"]
     assert phases["serve"]["stats"]["paged_kernel_dispatches"] == 0
     assert phases["serve"]["streams_equal_generate_fast"] == len(
         phases["serve"]["requests"])

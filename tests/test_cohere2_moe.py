@@ -368,7 +368,7 @@ def test_config_reads_layer_types_and_refuses_what_it_cannot_run():
     with pytest.raises(ValueError, match="whole groups"):
         Cohere2MoeConfig(num_attention_heads=12, num_key_value_heads=8)
     small = model_config(_sizes())
-    with pytest.raises(ValueError, match="paged cache only"):
+    with pytest.raises(ValueError, match="only KV cache"):
         InferenceEngine(weights_moe.make_params(_sizes(), 0), small,
                         num_slots=1, paged=False)
 

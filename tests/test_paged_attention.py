@@ -173,7 +173,7 @@ def _tiny(**kw):
 
 def _pool(dcfg, seed=1):
     """The engine's pool for ``dcfg``, filled with noise."""
-    _, shapes = _templates(dataclasses.astuple(dcfg), 1, True)
+    _, shapes = _templates(dataclasses.astuple(dcfg), 1)
     leaves, tree = jax.tree.flatten(shapes)
     keys = jax.random.split(jax.random.PRNGKey(seed), len(leaves))
     return jax.tree.unflatten(tree, [
@@ -287,15 +287,6 @@ def test_engine_on_the_kernel_serves_the_gather_path_tokens(
     assert eng_k.stats.prefix_hit_blocks >= 2      # read through the table
 
 
-def test_unpaged_engine_reports_the_dense_path():
-    cfg, _, params = _tiny()
-    eng = InferenceEngine(params, cfg, num_slots=2)
-    assert eng.attend_path == "dense"
-    eng.admit(np.arange(5), SamplingParams(max_new_tokens=3))
-    eng.step()
-    assert eng.stats.paged_kernel_dispatches == 0
-
-
 def test_off_the_tpu_the_dispatch_picks_the_gather_path_and_logs_it(caplog):
     _, dcfg, params = _tiny()
     assert pa.paged_attend_path(768, 16, jnp.float32,
@@ -337,7 +328,7 @@ def test_the_path_follows_backend_shape_and_dtype(monkeypatch, on_tpu,
 def test_int8_pool_stays_on_the_gather_path_under_the_interpreter(
         interpret):
     _, dcfg, params = _tiny(kv_dtype="int8")
-    _, shapes = _templates(dataclasses.astuple(dcfg), 1, True)
+    _, shapes = _templates(dataclasses.astuple(dcfg), 1)
     cache = jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype), shapes)
     bt = jnp.asarray(np.arange(1, 1 + MB)[None], jnp.int32)
     text = jax.jit(lambda c: _apply(

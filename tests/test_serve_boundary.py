@@ -27,11 +27,12 @@ from gym_tpu.serve.engine import (InferenceEngine, SamplingParams,
                                   derive_base_key)
 from gym_tpu.serve.scheduler import RequestStatus, Scheduler
 
-KINDS = {"unpaged": dict(),
-         "paged": dict(paged=True, page_size=8),
-         "paged_chunk3": dict(paged=True, page_size=8, decode_chunk=3),
-         "spec": dict(paged=True, page_size=8, decode_chunk=2,
-                      spec_tokens=3)}
+# "default_pool": what every caller that names no cache gets (page 16, a
+# window a slot); the others name a page size
+KINDS = {"default_pool": dict(),
+         "paged": dict(page_size=8),
+         "paged_chunk3": dict(page_size=8, decode_chunk=3),
+         "spec": dict(page_size=8, decode_chunk=2, spec_tokens=3)}
 SP = dict(temperature=0.9, top_k=7)
 
 
@@ -180,22 +181,12 @@ def _quarantined_row(setup, kind, s):
     s.admit("b", *_request(1))
     s.step()
     eng = s.eng
-    if eng.paged:
-        page = int(eng._bt[slot, 0])
-        eng._cache = jax.tree.map(lambda x: x.at[page].set(jnp.nan),
-                                  eng._cache)
-    else:
-        eng._cache = jax.tree.map(
-            lambda x: x.at[slot].set(jnp.nan)
-            if jnp.issubdtype(x.dtype, jnp.floating) else x, eng._cache)
+    page = int(eng._bt[slot, 0])
+    eng._cache = jax.tree.map(lambda x: x.at[page].set(jnp.nan),
+                              eng._cache)
     assert any(e.poisoned for e in s.step())
     assert eng.stats.quarantined == 1 and slot in eng.free_slots()
     del s.toks["poisoned"]
-    # a freed page keeps its NaNs, and a masked NaN still poisons the
-    # page's next owner (0 x NaN): not this test's subject
-    eng._cache = jax.tree.map(
-        lambda x: jnp.nan_to_num(x)
-        if jnp.issubdtype(x.dtype, jnp.floating) else x, eng._cache)
     s.step()                                     # the evicted row stays quiet
     assert s.admit("c", *_request(2)) == slot
 
@@ -206,9 +197,7 @@ WRITERS = [_admit_later, _release_then_admit_into_the_slot,
 
 @pytest.mark.parametrize("writer,kind", [
     pytest.param(w, k, id=f"{w.__name__.strip('_')}-{k}")
-    for w in WRITERS for k in KINDS
-    # park() needs a paged engine
-    if not (w is _park_then_resume_into_another_slot and k == "unpaged")])
+    for w in WRITERS for k in KINDS])
 def test_writer_of_a_mirror_is_seen_by_the_next_step(setup, kind, writer):
     s = Streams(_engine(setup, kind))
     writer(setup, kind, s)

@@ -295,11 +295,11 @@ def test_nan_quarantine_isolates_slot(setup, tmp_path):
     sched.step()                       # both admitted + one decode step
     assert ha.status is RequestStatus.RUNNING
     slot_a = next(s for s, r in sched._by_slot.items() if r is ha)
-    # poison slot A's float cache rows (K/V) — the engine-visible shape
-    # of a numerical fault confined to one row
-    eng._cache = jax.tree.map(
-        lambda x: x.at[slot_a].set(jnp.nan)
-        if jnp.issubdtype(x.dtype, jnp.floating) else x, eng._cache)
+    # poison slot A's first page (K/V) — the engine-visible shape of a
+    # numerical fault confined to one row
+    page_a = int(eng._bt[slot_a, 0])
+    eng._cache = jax.tree.map(lambda x: x.at[page_a].set(jnp.nan),
+                              eng._cache)
     _drain(sched, [ha, hb])
     with pytest.raises(SlotQuarantinedError, match="quarantined"):
         ha.result(timeout=1)
@@ -308,13 +308,21 @@ def test_nan_quarantine_isolates_slot(setup, tmp_path):
     head = metrics.headline()
     assert head["requests_quarantined"] == 1
     assert head["requests_done"] == 1
-    # the quarantined slot is free and a fresh admit fully overwrites
-    # the poisoned rows — the slot serves cleanly again
-    hc = sched.submit(pb, SamplingParams(max_new_tokens=12,
-                                         temperature=0.9, top_k=7,
-                                         seed=11))
-    _drain(sched, [hc])
-    assert hc.result(timeout=1) == ref_b
+    # the quarantined row's pages were written over before they were
+    # freed (a masked NaN still multiplies): no page keeps one
+    assert all(bool(jnp.isfinite(x).all())
+               for x in jax.tree.leaves(eng._cache))
+    # the quarantined slot is free and serves cleanly again, on the
+    # poisoned page itself: two requests of two pages each take the four
+    # pages that were freed, A's among them
+    again = [sched.submit(pb, SamplingParams(max_new_tokens=12,
+                                             temperature=0.9, top_k=7,
+                                             seed=11)) for _ in range(2)]
+    sched.step()
+    assert page_a in eng._bt
+    _drain(sched, again)
+    for hc in again:
+        assert hc.result(timeout=1) == ref_b
 
 
 def test_nan_quarantine_catches_slot_finishing_mid_chunk(setup):
@@ -322,21 +330,58 @@ def test_nan_quarantine_catches_slot_finishing_mid_chunk(setup):
     max-tokens MID-chunk goes inactive before the chunk tail — the
     quarantine check must still catch it (its final-step logits flow
     from the NaN cache rows), not deliver the garbage as a completed
-    request."""
+    request. The neighbour row, on pages of its own, is not touched."""
     cfg, model, params = setup
     eng = InferenceEngine(params, cfg, num_slots=2, decode_chunk=4)
     # max_new=3: one token from prefill, two from the next chunk — the
     # slot deactivates at scanned step 2 of 4, well before the tail
     slot, ev = eng.admit(_prompt(6, 30), SamplingParams(max_new_tokens=3,
                                                         seed=12))
+    other, _ = eng.admit(_prompt(7, 31), SamplingParams(max_new_tokens=9,
+                                                        seed=13))
     assert not ev.finished
-    eng._cache = jax.tree.map(
-        lambda x: x.at[slot].set(jnp.nan)
-        if jnp.issubdtype(x.dtype, jnp.floating) else x, eng._cache)
-    events = [e for e in eng.step() if e.slot == slot]
-    assert events and all(e.poisoned for e in events)
+    page = int(eng._bt[slot, 0])
+    eng._cache = jax.tree.map(lambda x: x.at[page].set(jnp.nan),
+                              eng._cache)
+    events = eng.step()
+    mine = [e for e in events if e.slot == slot]
+    assert mine and all(e.poisoned for e in mine)
+    theirs = [e for e in events if e.slot == other]
+    assert len(theirs) == 4 and not any(e.poisoned for e in theirs)
     assert eng.stats.quarantined == 1
-    assert slot in eng.free_slots()
+    assert slot in eng.free_slots() and other not in eng.free_slots()
+
+
+@pytest.mark.parametrize("readers", [1, 2])
+def test_nan_quarantine_forgets_a_poisoned_prefix_page(setup, readers):
+    """A poisoned page that holds a full prompt block is in the prefix
+    table: when the last row that reads it is quarantined it leaves the
+    table and is written over, so the same prompt sent again is
+    prefilled anew and served, not handed the NaNs."""
+    cfg, model, params = setup
+    eng = InferenceEngine(params, cfg, num_slots=2)
+    prompt = _prompt(20, 40)                   # block 0 is full: hashed
+    sp = SamplingParams(max_new_tokens=6, temperature=0.8, top_k=5, seed=4)
+    slots = [eng.admit(prompt, sp)[0] for _ in range(readers)]
+    shared = int(eng._bt[slots[0], 0])
+    assert all(int(eng._bt[s, 0]) == shared for s in slots)
+    assert eng.stats.prefix_hit_blocks == readers - 1
+    eng._cache = jax.tree.map(lambda x: x.at[shared].set(jnp.nan),
+                              eng._cache)
+    assert all(e.poisoned for e in eng.step())
+    assert eng.stats.quarantined == readers
+    assert eng._alloc.cached() == 0 and eng._alloc.in_use() == 0
+    assert all(bool(jnp.isfinite(x).all())
+               for x in jax.tree.leaves(eng._cache))
+    hits = eng.stats.prefix_hit_blocks
+    slot, ev = eng.admit(prompt, sp)
+    assert eng.stats.prefix_hit_blocks == hits      # prefilled anew
+    got = [ev.token]
+    while slot not in eng.free_slots():
+        got += [e.token for e in eng.step()]
+    ref = generate_fast(params, cfg, prompt[None], 6, temperature=0.8,
+                        top_k=5, seed=4)
+    assert got == ref[0, 20:].tolist()
 
 
 # -- supervisor -----------------------------------------------------------

@@ -125,7 +125,7 @@ def test_dispatch_prefix_affinity_sticks_to_warm_replica(setup):
     replica routes BACK to it — the admit_probe bonus beats the idle
     tie-break that would otherwise send it to replica 0."""
     cfg, params, _ = setup
-    router, _m = _fleet(params, cfg, paged=True, page_size=16,
+    router, _m = _fleet(params, cfg, page_size=16,
                         kv_pages=64)
     try:
         shared = _prompt(34, 3)           # 2 full pages of shared prefix

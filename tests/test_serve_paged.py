@@ -6,7 +6,7 @@ Oracles:
   padded-bucket prompts, prompts served THROUGH shared prefix blocks,
   the copy-on-write full-hit path, and a real restored checkpoint. The
   paged attend runs the same static-[block_size] reductions and masks
-  as the unpaged one, so the streams match bitwise. That is the contract
+  as ``generate_fast``'s, so the streams match bitwise. That is the contract
   OFF the TPU, where these tests run and the attend takes its gather
   path. On a TPU with a float32 pool the attend is the Pallas page walk
   (``gym_tpu/ops/paged_attention.py``): the same bf16-rounded products
@@ -33,6 +33,7 @@ Oracles:
 """
 
 import os
+import threading
 
 import numpy as np
 import pytest
@@ -40,8 +41,10 @@ import pytest
 import jax
 
 from gym_tpu.models.nanogpt import GPT, GPTConfig, generate_fast
+from gym_tpu.ops import paged_attention
 from gym_tpu.serve.engine import (BlockAllocator, InferenceEngine,
                                   NoFreeBlocksError, SamplingParams,
+                                  fit_page_size, fit_pool,
                                   max_prefill_buckets)
 from gym_tpu.serve.metrics import ServeMetrics, read_headline
 from gym_tpu.serve.scheduler import RequestStatus, Scheduler
@@ -287,10 +290,141 @@ def test_speculative_eos_mid_chunk(setup):
     assert got == ref[:5]
 
 
-def test_speculative_requires_paged(setup):
+# -- one cache: the pool (ISSUE 29) ------------------------------------------
+
+
+def test_engine_with_defaults_builds_the_pool(setup):
+    """An engine that names no cache gets the page pool: page 16, the
+    null page, a window a slot and the copy-on-write page; off the TPU
+    its attend is the gather path and its stream is ``generate_fast``'s."""
     cfg, model, params = setup
-    with pytest.raises(ValueError, match="paged"):
-        InferenceEngine(params, cfg, num_slots=2, spec_tokens=2)
+    eng = InferenceEngine(params, cfg, num_slots=3)
+    assert (eng.page_size, eng.max_blocks) == (16, cfg.block_size // 16)
+    assert eng.kv_pages == 2 + 3 * eng.max_blocks
+    assert eng.config.page_size == 16
+    assert eng.config.kv_pages == eng.kv_pages
+    assert eng.kv_blocks_capacity_effective == eng.kv_pages - 1
+    assert eng.attend_path == paged_attention.GATHER
+    prompt = _prompt(11, 7)
+    kw = dict(temperature=0.8, top_k=5, seed=3)
+    got = _run_one(eng, prompt, SamplingParams(max_new_tokens=9, **kw))
+    ref = generate_fast(params, cfg, prompt[None], 9, **kw)
+    assert got == ref[0, 11:].tolist()
+    assert eng.stats.paged_kernel_dispatches == 0
+    assert eng.stats.prefill_tokens == 16      # bucket(11)
+    assert eng.stats.kv_blocks_in_use == 0     # the finished row's pages
+    assert eng.stats.spec_accept_rate() is None
+
+
+def _engine_refuses(setup, **kw):
+    cfg, model, params = setup
+    InferenceEngine(params, cfg, num_slots=2, **kw)
+
+
+def _server_refuses(setup):
+    from gym_tpu.serve.__main__ import create_server
+    cfg, model, params = setup
+    create_server(params, cfg, port=0, page_size=0, warmup=False)
+
+
+def _cli_refuses(module, argv, capsys):
+    """The parser's own refusal: exit 2 before a checkpoint is read."""
+    import importlib
+    with pytest.raises(SystemExit) as exc:
+        importlib.import_module(module).main(argv)
+    assert exc.value.code == 2
+    raise ValueError(capsys.readouterr().err)
+
+
+@pytest.mark.parametrize("refuses", [
+    pytest.param(lambda s, c: _engine_refuses(s, paged=False),
+                 id="engine-paged-false"),
+    pytest.param(lambda s, c: _engine_refuses(s, paged=False,
+                                              spec_tokens=2),
+                 id="engine-paged-false-spec"),
+    pytest.param(lambda s, c: _engine_refuses(s, page_size=0),
+                 id="engine-page-size-0"),
+    pytest.param(lambda s, c: _server_refuses(s), id="create-server"),
+    pytest.param(lambda s, c: _cli_refuses(
+        "gym_tpu.serve.__main__",
+        ["--ckpt", "/nonexistent", "--page_size", "0"], c), id="cli"),
+    pytest.param(lambda s, c: _cli_refuses(
+        "gym_tpu.serve.worker",
+        ["--socket", "/nonexistent", "--page_size", "0"], c), id="worker"),
+])
+def test_paged_false_and_page_size_zero_are_refused(setup, capsys, refuses):
+    """The ring is gone: whatever used to select it is refused, with a
+    message that names the pool (or the page size's range)."""
+    with pytest.raises(ValueError, match="only KV cache|page_size must be"):
+        refuses(setup, capsys)
+
+
+@pytest.mark.parametrize("asked,block,fitted", [
+    (16, 64, 16), (16, 40, 10), (16, 1024, 16), (48, 64, 32),
+    (7, 64, 4), (16, 17, 1), (128, 64, 64)])
+def test_page_size_falls_to_a_divisor_of_block_size(asked, block, fitted):
+    assert fit_page_size(asked, block) == fitted
+    assert block % fitted == 0 and fitted <= asked
+    # a pool size that was given keeps its tokens; none given stays none
+    page, pages = fit_pool(asked, block, 9)
+    assert page == fitted and (pages - 1) * page < 9 * asked <= pages * page
+    assert fit_pool(asked, block) == (fitted, None)
+
+
+def test_server_serves_a_checkpoint_whose_window_the_page_does_not_divide(
+        capsys, tmp_path):
+    """``create_server`` used to fall back to the ring here; it serves
+    from the pool at the largest page that divides the window."""
+    from gym_tpu.serve.__main__ import create_server
+    cfg = GPTConfig(block_size=40, vocab_size=48, n_layer=1, n_head=2,
+                    n_embd=16, dropout=0.0, bias=True)
+    params = GPT(cfg).init({"params": jax.random.PRNGKey(0)},
+                           np.zeros((1, 8), np.int64), train=False)["params"]
+    # 5 pages of 16 are 80 tokens, so 8 pages of 10 (5 of those would be
+    # refused: a window is four, beside the null and the copy page)
+    handle = create_server(params, cfg, port=0, num_slots=2, page_size=16,
+                           kv_pages=5, spec_tokens=2, warmup=False,
+                           metrics_dir=str(tmp_path))
+    # close() waits for serve_forever to have run
+    threading.Thread(target=handle.httpd.serve_forever, daemon=True).start()
+    try:
+        eng = handle.scheduler.engine
+        assert (eng.page_size, eng.max_blocks, eng.spec_tokens) == (10, 4, 2)
+        assert eng.kv_pages == 8
+        assert ("serving with page_size 10 and kv_pages 8"
+                in capsys.readouterr().err)
+        prompt = _prompt(6, 1)
+        got = handle.scheduler.submit(prompt, SamplingParams(
+            max_new_tokens=5, top_k=1))
+        ref = generate_fast(params, cfg, prompt[None], 5, top_k=1)
+        assert got.result(timeout=120) == ref[0, 6:].tolist()
+    finally:
+        handle.close(drain_deadline_s=5.0)
+
+
+def test_no_slot_program_is_defined_or_warmed(setup):
+    """Neither the definitions, the auditor's enumeration, an engine's
+    warm-up family nor the registry holds a program of the ring."""
+    from gym_tpu import programs
+    from gym_tpu.analysis.jaxpr_audit import engine_program_defs
+    from gym_tpu.programs import serve_defs
+    cfg, model, params = setup
+    for gone in ("prefill_def", "slot_admit_def", "slot_decode_def",
+                 "build_prefill", "build_slot_admit", "build_slot_decode",
+                 "SLOT_STATE"):
+        assert not hasattr(serve_defs, gone), gone
+    eng = InferenceEngine(params, cfg, num_slots=2, decode_chunk=2)
+    _run_one(eng, _prompt(6, 1), SamplingParams(max_new_tokens=3))
+    paged = ("serve.paged_prefill", "serve.paged_decode", "serve.cow",
+             "serve.spec_decode")
+    names = ([d.name for d in eng.warmup_defs()]
+             + [d.name for d in engine_program_defs()]
+             + [n for n in programs.default_registry().keys().values()
+                if n.startswith("serve.")])
+    assert names and all(n.startswith(paged) for n in names), names
+    families = {d.family for d in eng.warmup_defs()}
+    assert families == {"serve.paged_prefill", "serve.paged_decode",
+                        "serve.cow"}
 
 
 # -- bounded compilation ---------------------------------------------------
@@ -549,8 +683,8 @@ def test_starvation_guard_covers_prefix_priority(setup):
 def test_scheduler_prefix_aware_admit_ordering(setup):
     """With one free slot and a cold-prefix request ahead of a
     hot-prefix request in the queue, the hot one is admitted first
-    (within the lookahead window); on an unpaged engine the same queue
-    stays strict FCFS."""
+    (within the lookahead window); where nothing is resident the same
+    queue stays strict FCFS."""
     cfg, model, params = setup
     shared = _prompt(16, 90)
     eng = InferenceEngine(params, cfg, num_slots=1, paged=True,
@@ -568,7 +702,7 @@ def test_scheduler_prefix_aware_admit_ordering(setup):
     assert hot.status in (RequestStatus.RUNNING, RequestStatus.DONE)
     assert cold.status is RequestStatus.QUEUED
     _drain(sched, [cold, hot])
-    # unpaged: all scores 0 -> FCFS preserved
+    # a cold prefix cache: all scores 0 -> FCFS preserved
     engu = InferenceEngine(params, cfg, num_slots=1)
     schedu = Scheduler(engu, max_queue=8, prefix_window=4)
     first = schedu.submit(_prompt(6, 94), SamplingParams(
@@ -614,16 +748,6 @@ def test_metrics_carry_paged_and_spec_observables(setup, tmp_path):
     assert post["prefix_hit_blocks"] == head["prefix_hit_blocks"]
     assert post["spec_accept_rate"] is not None
     metrics.close()
-
-
-def test_unpaged_engine_reports_zero_paged_stats(setup):
-    cfg, model, params = setup
-    eng = InferenceEngine(params, cfg, num_slots=2)
-    _run_one(eng, _prompt(6, 1), SamplingParams(max_new_tokens=3))
-    assert eng.stats.kv_blocks_in_use == 0
-    assert eng.stats.prefix_hit_blocks == 0
-    assert eng.stats.spec_accept_rate() is None
-    assert eng.stats.prefill_tokens == 8       # bucket(6) — comparable
 
 
 # -- quantized serving (ISSUE 11) ------------------------------------------

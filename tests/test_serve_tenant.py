@@ -155,7 +155,7 @@ def test_unknown_slo_class_rejected_typed(setup):
 
 
 def test_single_tenant_keeps_fcfs_order(setup):
-    """The default deployment (one tenant, unpaged engine) must keep
+    """The default deployment (one tenant, no shared prefix) must keep
     the exact pre-tenant admission order: FCFS."""
     cfg, model, params = setup
     eng = InferenceEngine(params, cfg, num_slots=1)

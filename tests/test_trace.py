@@ -286,7 +286,9 @@ def served(tiny_gpt):
     eng = InferenceEngine(params, cfg, num_slots=2, page_size=8)
     sched = Scheduler(eng)
     mark = _mark()
-    reqs = [sched.submit(np.arange(3 + 5 * i) % 48,
+    # no two prompts share a first page: every prefill is of the whole
+    # prompt (the engine was the unpaged ring here until PR 29)
+    reqs = [sched.submit((np.arange(3 + 5 * i) + 7 * i) % 48,
                          SamplingParams(max_new_tokens=4 + i, seed=i))
             for i in range(3)]
     for _ in range(200):
