@@ -311,7 +311,9 @@ def quantized_program_defs(num_slots: int = 2, decode_chunk: int = 4,
     cfg_tuple = _dc.astuple(
         _dc.replace(base, page_size=page_size, kv_pages=kv_pages,
                     weights_dtype="int8", kv_dtype="int8"))
-    defs = [sd.paged_prefill_def(cfg_tuple, int(b)) for b in buckets]
+    # an engine with gamma > 0 admits into the speculative state
+    defs = [sd.paged_prefill_def(cfg_tuple, int(b), num_slots, hist=True)
+            for b in buckets]
     defs.append(sd.cow_def(cfg_tuple))
     defs.append(sd.paged_decode_def(cfg_tuple, num_slots, decode_chunk))
     defs.append(sd.spec_decode_def(cfg_tuple, num_slots, decode_chunk,
@@ -338,7 +340,9 @@ def paged_program_defs(num_slots: int = 2, decode_chunk: int = 4,
     kv_pages = 2 + num_slots * mb
     cfg_tuple = _dc.astuple(
         _dc.replace(base, page_size=page_size, kv_pages=kv_pages))
-    defs = [sd.paged_prefill_def(cfg_tuple, int(b)) for b in buckets]
+    # an engine with gamma > 0 admits into the speculative state
+    defs = [sd.paged_prefill_def(cfg_tuple, int(b), num_slots, hist=True)
+            for b in buckets]
     defs.append(sd.cow_def(cfg_tuple))
     defs.append(sd.paged_decode_def(cfg_tuple, num_slots, decode_chunk))
     defs.append(sd.spec_decode_def(cfg_tuple, num_slots, decode_chunk,

@@ -49,12 +49,14 @@ _KEY_T = jax.ShapeDtypeStruct((2,), np.uint32)
 # The decode programs' per-slot state, by name.  A decode program takes
 # it as ONE dict and returns it whole: what it advanced (``tok``,
 # ``active``, ``gen_idx``, ``remaining``, ``pos``; speculative also
-# ``hist``) and, unchanged, what only an admission writes
-# (``base_keys``, ``eos``, ``temp``, ``top_k``, ``top_p``, the block
-# table).  The returned dict is the next dispatch's argument as it is:
-# the state lives on the device, and the engine hands over a NumPy array
-# in an entry's place only where the host wrote that entry since
-# (``serve/engine.py``: the host's mirrors).
+# ``hist``; the block table with the rows that stopped cleared) and,
+# unchanged, what only an admission writes (``base_keys``, ``eos``,
+# ``temp``, ``top_k``, ``top_p``).  A prefill program takes it too and
+# returns it with the admitted slot's row set, so the state threads
+# prefill -> decode -> prefill ON THE DEVICE: the returned dict is the
+# next dispatch's argument as it is, and the engine hands over a NumPy
+# array in an entry's place only where the host itself wrote a live row
+# since (``serve/engine.py``: the host's mirrors, and when they go up).
 PAGED_STATE = ("tok", "active", "base_keys", "gen_idx", "remaining", "eos",
                "temp", "top_k", "top_p", "bt", "pos")
 SPEC_STATE = PAGED_STATE + ("hist",)
@@ -112,8 +114,9 @@ def build_paged_prefill(cfg_tuple: tuple, bucket: int):
     cfg, model = _model(cfg_tuple)
 
     @functools.partial(jax.jit, donate_argnums=(1,))
-    def prefill(params, cache, bt_row, start, tokens, true_suffix, key,
-                temp, top_k, top_p):
+    def prefill(params, cache, state, slot, bt_row, start, tokens,
+                true_suffix, key, temp, top_k, top_p, max_new, eos,
+                prompt=None):
         """Prefix-aware paged prefill: process only the SUFFIX tokens the
         prefix cache could not supply. ``tokens`` [1, bucket] is the
         right-padded suffix, ``start`` [1] the first suffix position
@@ -121,14 +124,37 @@ def build_paged_prefill(cfg_tuple: tuple, bucket: int):
         prefix K/V through ``bt_row``), ``true_suffix`` its unpadded
         length. Samples the request's first token (key-schedule index 0)
         at the true last prompt position and returns it with the updated
-        pool — the pool is DONATED: suffix K/V scatter in place."""
+        pool — the pool is DONATED: suffix K/V scatter in place.
+
+        ADMISSION HAPPENS HERE: ``state`` is the decode programs' state
+        and comes back with row ``slot`` set to the admitted request
+        (input token = the sampled first token, cursor = the prompt's
+        length, key index 1, ``max_new - 1`` tokens left, its sampling
+        vectors, base key and block-table row; ``active`` unless that
+        first token already ended it, by the decode programs' own rule).
+        No host array of the state is written for an admission, so the
+        decode step that follows takes all of it from the device. A
+        speculative engine's state holds the token history: ``prompt``
+        [block_size] (the whole prompt, zero-padded) becomes its row."""
         last, varsc = model.apply(
             {"params": params, "cache": cache}, tokens, train=False,
             mutable=["cache"], block_table=bt_row, cache_pos=start,
             last_pos=true_suffix - 1)                                # [1,V]
         tok = sample_logits(last, jax.random.fold_in(key, 0),
                             temp, top_k, top_p)
-        return tok, varsc["cache"]
+        first = tok[0].astype(jnp.int32)
+        n = start[0] + true_suffix
+        left = max_new - 1
+        live = ~((left <= 0) | ((eos >= 0) & (first == eos)))
+        row = {"tok": first, "active": live, "base_keys": key,
+               "gen_idx": 1, "remaining": left, "eos": eos, "temp": temp,
+               "top_k": top_k, "top_p": top_p, "pos": n,
+               "bt": jnp.where(live, bt_row[0], 0)}
+        if prompt is not None:
+            row["hist"] = prompt.at[n].set(first)
+        state = {name: arr.at[slot].set(row[name])
+                 for name, arr in state.items()}
+        return tok, state, varsc["cache"]
 
     return prefill
 
@@ -174,9 +200,11 @@ def build_paged_decode(cfg_tuple: tuple, num_slots: int, chunk: int):
       ``counters`` collection), summed over the chunk;
     - ``logits`` [S, V]: the last scanned step's, left on the device
       (teacher forcing and tests fetch them);
-    - ``state``: the argument with what this dispatch advanced (the
-      block table as it was given), the next dispatch's argument as it
-      is."""
+    - ``state``: the argument with what this dispatch advanced, the
+      next dispatch's argument as it is. A row that stopped (EOS, its
+      budget, non-finite logits: ``nan_seen`` rows clear their own
+      ``active``) has its block-table row cleared, as the host clears
+      its mirror's when it frees the pages."""
     cfg, model = _model(cfg_tuple)
 
     @functools.partial(jax.jit, donate_argnums=(1,))
@@ -196,7 +224,8 @@ def build_paged_decode(cfg_tuple: tuple, num_slots: int, chunk: int):
             # active: the null-page redirect means a finished row's
             # later iterations read clean garbage, so the LAST step's
             # logits cannot witness a poison that struck mid-chunk
-            nanc = nanc | (act & ~jnp.isfinite(lg).all(axis=-1))
+            bad = act & ~jnp.isfinite(lg).all(axis=-1)
+            nanc = nanc | bad
             keys = jax.vmap(jax.random.fold_in)(base_keys, gidx)
             nxt = jax.vmap(sample_logits)(lg, keys, temp, top_k, top_p)
             nxt = jnp.where(act, nxt, tok).astype(jnp.int32)
@@ -204,7 +233,8 @@ def build_paged_decode(cfg_tuple: tuple, num_slots: int, chunk: int):
             pos = jnp.where(act, pos + 1, pos)
             gidx = jnp.where(act, gidx + 1, gidx)
             rem = jnp.where(act, rem - 1, rem)
-            done = act & ((rem <= 0) | ((eos >= 0) & (nxt == eos)))
+            # a poisoned row stops itself: the quarantine is no host write
+            done = act & ((rem <= 0) | ((eos >= 0) & (nxt == eos)) | bad)
             # what the model counted this step (``counters``: small
             # integer arrays, or nothing), summed over the chunk below
             return ((varsc["cache"], nxt, act & ~done, pos, gidx, rem,
@@ -222,7 +252,8 @@ def build_paged_decode(cfg_tuple: tuple, num_slots: int, chunk: int):
                 "active": active, "pos": pos, "nan_seen": nan_seen,
                 "counted": counted}
         state = {**state, "tok": tok, "active": active, "pos": pos,
-                 "gen_idx": gen_idx, "remaining": remaining}
+                 "gen_idx": gen_idx, "remaining": remaining,
+                 "bt": jnp.where(active[:, None], bt, 0)}
         return read, lg, state, cache
 
     return decode
@@ -303,7 +334,8 @@ def build_spec_decode(cfg_tuple: tuple, num_slots: int, chunk: int,
             # LEGALLY NaN from the per-position window-overflow poison
             # on rejected drafts, while position 0 is always in-window
             # for an active row
-            nanc = nanc | (act & ~jnp.isfinite(logits[:, 0]).all(axis=-1))
+            bad = act & ~jnp.isfinite(logits[:, 0]).all(axis=-1)
+            nanc = nanc | bad
             idxs = gidx[:, None] + jnp.arange(g1)[None, :]
             keys = jax.vmap(jax.vmap(jax.random.fold_in,
                                      in_axes=(None, 0)))(base_keys, idxs)
@@ -324,7 +356,7 @@ def build_spec_decode(cfg_tuple: tuple, num_slots: int, chunk: int,
                 sampled, jnp.maximum(m - 1, 0)[:, None], axis=1)[:, 0]
             new_tok = jnp.where(act, new_tok, tok).astype(jnp.int32)
             rem = rem - m
-            done = act & ((rem <= 0) | any_eos)
+            done = act & ((rem <= 0) | any_eos | bad)
             # history grows by the emitted tokens so the NEXT iteration's
             # draft can match against them
             rows = jnp.arange(num_slots)[:, None]
@@ -348,7 +380,8 @@ def build_spec_decode(cfg_tuple: tuple, num_slots: int, chunk: int,
                 "active": active, "pos": pos, "nan_seen": nan_seen,
                 "counted": {}}
         state = {**state, "tok": tok, "active": active, "pos": pos,
-                 "gen_idx": gen_idx, "remaining": remaining, "hist": hist}
+                 "gen_idx": gen_idx, "remaining": remaining, "hist": hist,
+                 "bt": jnp.where(active[:, None], bt, 0)}
         return read, lg, state, cache
 
     return spec
@@ -369,19 +402,30 @@ def _paged_cfg(cfg_tuple: tuple):
     return cfg, mb, pcfg
 
 
-def paged_prefill_def(cfg_tuple: tuple, bucket: int) -> ProgramDef:
-    _cfg, mb, pcfg = _paged_cfg(cfg_tuple)
+def paged_prefill_def(cfg_tuple: tuple, bucket: int, num_slots: int = 1,
+                      hist: bool = False) -> ProgramDef:
+    """The prefill of one ``bucket`` for an engine of ``num_slots`` rows
+    (the decode state it writes the admitted row into has that many);
+    ``hist``: the state is the speculative programs', with the token
+    history."""
+    cfg, mb, pcfg = _paged_cfg(cfg_tuple)
     params_tpl, pool_tpl = _templates(cfg_tuple, 1)
+    s = int(num_slots)
+    names = SPEC_STATE if hist else PAGED_STATE
+    tag = f",slots={s}" + (",hist" if hist else "")
     return ProgramDef(
-        name=f"serve.paged_prefill[bucket={bucket}{_qtag(cfg_tuple)}]",
+        name=f"serve.paged_prefill[bucket={bucket}{tag}{_qtag(cfg_tuple)}]",
         family="serve.paged_prefill",
-        config={**pcfg, "bucket": bucket},
-        args=(params_tpl, pool_tpl,
+        config={**pcfg, "bucket": bucket, "num_slots": s, "hist": hist},
+        args=(params_tpl, pool_tpl, _state_tpl(names, s, mb, cfg.block_size),
+              _scalar(np.int32),
               jax.ShapeDtypeStruct((1, mb), np.int32),
               jax.ShapeDtypeStruct((1,), np.int32),
               jax.ShapeDtypeStruct((1, int(bucket)), np.int32),
               _scalar(np.int32), _KEY_T, _scalar(np.float32),
-              _scalar(np.int32), _scalar(np.float32)),
+              _scalar(np.int32), _scalar(np.float32),
+              _scalar(np.int32), _scalar(np.int32))
+        + ((_vec(cfg.block_size, np.int32),) if hist else ()),
         donate_args=(1,),
         builder=lambda: build_paged_prefill(cfg_tuple, int(bucket)))
 

@@ -14,7 +14,9 @@ concurrent load:
   bucketed to powers of two so total compilations are bounded by
   ``O(log block_size)`` instead of one per prompt length. Requests enter
   free slots and leave on EOS/max-tokens BETWEEN decode steps —
-  continuous batching, no drain-the-batch barrier. The KV cache is a
+  continuous batching, no drain-the-batch barrier — and one decode step
+  is always in flight: the scheduler's round queues step K before it
+  reads step K-1, and admissions ride the device's queue. The KV cache is a
   shared PAGE POOL with per-slot block tables, a
   ref-counted allocator and a prefix hash table: block-aligned shared
   prompt prefixes are prefilled once and reused copy-free across

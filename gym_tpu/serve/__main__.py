@@ -559,10 +559,16 @@ def create_server(params, cfg, *, host: str = "127.0.0.1", port: int = 0,
                 # max_s]} (utils/trace.py; names in PERF.md §3)
                 "spans": trace.totals(),
                 "readback_bytes": sum(s.readback_bytes for s in stats),
-                # host arrays handed to decode dispatches, and decode
-                # dispatches that were handed none (all state resident)
+                # host arrays of the decode state handed to dispatches,
+                # and decode dispatches that were handed none (all
+                # state resident)
                 "upload_arrays": sum(s.upload_arrays for s in stats),
                 "resident_steps": sum(s.resident_steps for s in stats),
+                # decode steps dispatched while the step before was
+                # unread, and times a host write or an idle round waited
+                # out what was in flight
+                "steps_ahead": sum(s.steps_ahead for s in stats),
+                "drains": sum(s.drains for s in stats),
                 # decode + prefill dispatches whose attend ran the Pallas
                 # page walk: equals decode dispatches + prefills on a TPU
                 # with an f32 pool, 0 on the gather path

@@ -40,9 +40,12 @@ set once and runs every request through it:
   may ever write (suffix pads + the whole decode budget) are reserved at
   admit, so shared pages are full, immutable prompt blocks by
   construction and the jitted programs never need to allocate.
-- **Admit/evict** are host bookkeeping BETWEEN decode steps (continuous
-  batching): a finished slot's pages go back to the allocator mid-flight
-  while its neighbors keep decoding — no drain-the-batch barrier.
+- **Admit/evict** ride the device's queue (continuous batching): the
+  prefill program itself writes the admitted row into the decode state,
+  the decode programs decide EOS, budget and quarantine themselves, and
+  the host learns of both one read later — a finished slot's pages go
+  back to the allocator while its neighbors keep decoding, no
+  drain-the-batch barrier, and no wait before the next step is queued.
 
 Parity oracle (tests/test_serve.py): for a single request the engine's
 token stream is IDENTICAL to ``generate_fast`` with the same sampling
@@ -61,22 +64,43 @@ true conditional with the request's own key schedule, so the emitted
 stream equals the non-speculative engine's EXACTLY for every sampling
 configuration — drafts only decide how many samples one dispatch keeps.
 
-**What crosses between host and device** (ISSUE 28). The decode
-programs' per-slot state (input token, active flag, cursor, key index,
-remaining budget, key, EOS id, temperature, top-k, top-p, block table;
-speculative: the token history) lives ON THE DEVICE: a decode dispatch
-returns it whole and the next one takes it as it is
-(``programs/serve_defs.py``: ``PAGED_STATE`` / ``SPEC_STATE``). The
-engine's NumPy arrays are the host's MIRROR of it,
-kept equal by replaying each step's small download. A write to a mirror
-outside ``step`` (admit, release, park, resume, quarantine, a forced
-token) names it stale, and the next dispatch is handed the stale mirrors
-themselves, as NumPy arguments: the executable transfers them with its
-other arguments, in one batch. The round's path calls no eager device
+**What crosses between host and device** (ISSUE 28, ISSUE 30). The
+decode programs' per-slot state (input token, active flag, cursor, key
+index, remaining budget, key, EOS id, temperature, top-k, top-p, block
+table; speculative: the token history) lives ON THE DEVICE and threads
+through every dispatch: a prefill returns it with the admitted row set,
+a decode step with what it advanced, and the next dispatch takes it as
+it is (``programs/serve_defs.py``: ``PAGED_STATE`` / ``SPEC_STATE``).
+The engine's NumPy arrays are the host's MIRROR of it, brought up to a
+step by replaying that step's small download.
+
+**A decode step always in flight.** ``step(ahead=True)`` (the
+scheduler's call) dispatches step K from the device's own state BEFORE it reads step
+K-1's download, so the mirrors, the events, the free slots and the
+allocator are those of step K-1 while the device runs step K. A row
+that stopped at K-1 is inactive in the device's state already, so K
+computes nothing for it that is read; its slot is refilled one step
+later. A prefill's first token stays a device array and comes down in
+the same ``jax.device_get`` as the next read: nothing on the round's
+path waits for the device before the device has its next step.
+``admit`` and a plain ``step()`` are the same calls with a wait on top
+(``admit_nowait`` / ``step(ahead=True)``, then ``_settle``), for
+callers that want a step's tokens from the call that made them.
+
+A write by the HOST to a row the device may be computing (``release``
+of an active slot, ``park``, ``resume``, a forced token, ``last_logits``)
+first waits out what is in flight (``_settle``: its events are kept for
+the next ``step`` / ``drain``, and their slots are not
+handed out before), which brings the mirrors level with the device;
+then it names the mirror stale, and the next dispatch, prefill or
+decode, is handed the stale mirrors themselves, as NumPy arguments.
+``stats.drains`` counts those waits, ``stats.steps_ahead`` the decode
+steps dispatched ahead of the read before them, ``stats.upload_arrays``
+the mirrors that went up. The round's path calls no eager device
 operation (no ``jnp.asarray``, no ``PRNGKey``: the base key is made on
-the host), a step after which nothing was admitted or released uploads
-nothing, and a step reads back tokens and flags, a few KiB: the logits
-stay on the device until something asks for ``last_logits``.
+the host), a step in steady state has no host argument at all, and a
+read is tokens and flags, a few KiB: the logits stay on the device
+until something asks for ``last_logits``.
 """
 
 from __future__ import annotations
@@ -129,6 +153,13 @@ class NoFreeSlotError(RuntimeError):
     """``admit()`` was called with every slot occupied — a scheduler bug
     (the driver must check ``free_slots()`` first). Subclasses
     ``RuntimeError`` so pre-existing callers keep working."""
+
+
+class UnsettledWriteError(RuntimeError):
+    """A mirror of the decode state was about to go up while a dispatch
+    was still unread — an engine bug: the mirrors are level with the
+    device only once everything in flight was read, and every host write
+    to a live row settles first."""
 
 
 class NoFreeBlocksError(RuntimeError):
@@ -198,6 +229,15 @@ class TokenEvent:
 
 
 @dataclasses.dataclass
+class _Flight:
+    """A decode dispatch whose ``read`` the host has not taken yet."""
+
+    read: Any                  # the program's small download, on the device
+    spec_run: bool             # the speculative program's layout
+    ahead: bool                # dispatched before the step before was read
+
+
+@dataclasses.dataclass
 class EngineStats:
     tokens_generated: int = 0
     decode_steps: int = 0
@@ -213,10 +253,18 @@ class EngineStats:
     #                                      back from the device (the logits
     #                                      only when something asked for
     #                                      them: ``last_logits``)
-    upload_arrays: int = 0               # host arrays handed to decode
-    #                                      dispatches (stale mirrors)
+    upload_arrays: int = 0               # host arrays of the decode state
+    #                                      handed to dispatches (stale
+    #                                      mirrors: only a host write to a
+    #                                      live row makes one)
     resident_steps: int = 0              # decode dispatches that uploaded
     #                                      none: all state was on the device
+    steps_ahead: int = 0                 # of ``decode_steps``, those
+    #                                      dispatched while the step before
+    #                                      had not yet been read
+    drains: int = 0                      # times a host write or an idle
+    #                                      round waited out what was in
+    #                                      flight
     paged_kernel_dispatches: int = 0     # decode + prefill dispatches whose
     #                                      attend ran the Pallas page walk
     #                                      (ops/paged_attention.py); 0 on
@@ -444,8 +492,10 @@ class InferenceEngine:
 
     Request-level concerns (queueing, backpressure, completion futures)
     live in ``scheduler.Scheduler``; the engine only knows slots. Not
-    thread-safe — one driver thread calls ``admit``/``step``/``release``
-    (the scheduler serializes access).
+    thread-safe — one driver thread calls ``admit_nowait`` /
+    ``step(ahead=True)`` / ``release`` (the scheduler serializes
+    access); ``admit`` and ``step()`` are those with a wait, for direct
+    callers.
     """
 
     def __init__(self, params: PyTree, config: Any,
@@ -573,13 +623,22 @@ class InferenceEngine:
         self._base_keys = np.zeros((s, 2), np.uint32)
         # The arrays above are the host's MIRROR of the decode programs'
         # per-slot state (events, park, release and the scheduler read
-        # them). The state itself lives on the device: ``_dev`` is the
-        # dict the last decode dispatch returned and the next one's
-        # argument as it is. Whatever writes a mirror outside ``step``
-        # names it in ``_stale``, and the next dispatch is handed that
-        # mirror, a NumPy array, in the device copy's place.
-        self._dev: Dict[str, Any] = {}
+        # them), as of the last read. The state itself lives on the
+        # device: ``_dev`` is the dict the last dispatch (prefill or
+        # decode) returned and the next one's argument as it is. A host
+        # write to a live row settles first, then names its mirror in
+        # ``_stale``, and the next dispatch is handed that mirror, a
+        # NumPy array, in the device copy's place.
+        self._state_names = SPEC_STATE if self.spec_tokens else PAGED_STATE
+        self._dev: Dict[str, Any] = {
+            name: jnp.asarray(self._mirror(name))
+            for name in self._state_names}
         self._stale: set = set()
+        self._flight: Optional[_Flight] = None   # the unread decode step
+        # prefills whose first token is still on the device, in dispatch
+        # order: (slot, token array); all of them younger than ``_flight``
+        self._firsts: List[Tuple[int, Any]] = []
+        self._held: List[TokenEvent] = []    # read, not yet handed out
         self._logits: Any = None         # [S, V] post-step, on the device
         self._logits_host: Optional[np.ndarray] = None
         self.stats = EngineStats(num_slots=s,
@@ -592,6 +651,7 @@ class InferenceEngine:
         first). They stay on the device: nothing on the serving path
         reads them. The first access after a step transfers them and
         adds their bytes to ``stats.readback_bytes``."""
+        self._settle()
         if self._logits_host is None and self._logits is not None:
             self._logits_host = np.asarray(self._logits)
             self.stats.readback_bytes += self._logits_host.nbytes
@@ -650,7 +710,9 @@ class InferenceEngine:
         already built is a hit, not a compile)."""
         h = self._prefill_progs.get(bucket)
         if h is None:
-            h = self._acquire(paged_prefill_def(self._cfg_tuple, bucket))
+            h = self._acquire(paged_prefill_def(
+                self._cfg_tuple, bucket, self.num_slots,
+                bool(self.spec_tokens)))
             self._prefill_progs[bucket] = h
         # exact per-key attribution: ensure_reporting is True only if
         # THIS call ran the build — a global-counter diff would charge
@@ -681,7 +743,8 @@ class InferenceEngine:
             # disk-restored process pays its compile on the first
             # override_tokens step
             defs.append(paged_decode_def(cfg, s, 1))
-        defs.extend(paged_prefill_def(cfg, b) for b in buckets)
+        defs.extend(paged_prefill_def(cfg, b, s, bool(self.spec_tokens))
+                    for b in buckets)
         return defs
 
     def _count(self, counted: PyTree) -> None:
@@ -704,7 +767,54 @@ class InferenceEngine:
     # -- slot lifecycle ---------------------------------------------------
 
     def free_slots(self) -> List[int]:
-        return [i for i in range(self.num_slots) if not self._active[i]]
+        """Slots an admission may take: inactive as of the last read,
+        and with no event still waiting to be handed out (whoever maps
+        slots to requests must see a slot's last token before its next
+        occupant)."""
+        held = {ev.slot for ev in self._held}
+        return [i for i in range(self.num_slots)
+                if not self._active[i] and i not in held]
+
+    # -- what is in flight ------------------------------------------------
+
+    def _state_args(self, names) -> Tuple[Dict[str, Any], int]:
+        """The decode state as the next dispatch takes it: the device's
+        own arrays, and in their place the mirrors the host wrote since
+        (or an entry the last dispatch did not return: ``hist`` after a
+        step of the plain program, which the host replays tokens into
+        whatever ran). Returns it with the number of mirrors in it."""
+        up = [n for n in names if n in self._stale or n not in self._dev]
+        if up and (self._flight is not None or self._firsts):
+            # a mirror is level with the device only once all was read:
+            # every writer of one settles first
+            raise UnsettledWriteError(
+                f"decode-state mirrors {up} would go up while a dispatch "
+                f"is unread (a host write did not settle)")
+        state = {n: self._dev[n] for n in names if n not in up}
+        state.update((n, self._mirror(n)) for n in up)
+        self.stats.upload_arrays += len(up)
+        return state, len(up)
+
+    def _take(self) -> List[TokenEvent]:
+        events, self._held = self._held, []
+        return events
+
+    def _settle(self) -> None:
+        """Wait out what is in flight (a decode step, first tokens) and
+        bring the mirrors level with the device. The events stay held
+        for the next ``step`` / ``drain``."""
+        if self._flight is None and not self._firsts:
+            return
+        self.stats.drains += 1
+        flight, firsts = self._flight, self._firsts
+        self._flight, self._firsts = None, []
+        self._collect(flight, firsts)
+
+    def drain(self) -> List[TokenEvent]:
+        """``_settle``, and hand out every event not yet handed out:
+        after it nothing is in flight and nothing is held."""
+        self._settle()
+        return self._take()
 
     def validate(self, prompt: np.ndarray, sp: SamplingParams) -> None:
         """Typed rejection of requests the decode path cannot serve
@@ -830,10 +940,22 @@ class InferenceEngine:
 
     def admit(self, prompt: np.ndarray,
               sp: SamplingParams) -> Tuple[int, TokenEvent]:
-        """Prefill ``prompt`` into a free slot and sample its first token.
-        Returns ``(slot, event)``; when the first token already finishes
-        the request (``max_new_tokens == 1`` or instant EOS) the slot is
-        released before returning."""
+        """``admit_nowait`` and a wait for its first token: returns
+        ``(slot, event)``; when the first token already finishes the
+        request (``max_new_tokens == 1`` or instant EOS) the slot is
+        free again before returning. For direct callers; the scheduler
+        takes the first token as an event of its next ``step(ahead=True)``."""
+        slot = self.admit_nowait(prompt, sp)
+        self._settle()
+        at = next(i for i, ev in enumerate(self._held) if ev.slot == slot)
+        return slot, self._held.pop(at)
+
+    def admit_nowait(self, prompt: np.ndarray, sp: SamplingParams) -> int:
+        """Dispatch ``prompt``'s prefill into a free slot and return the
+        slot. The program samples the first token and writes the slot's
+        row of the decode state itself; the token stays on the device
+        and arrives as this slot's first ``TokenEvent`` with the next
+        read (``step`` / ``drain``)."""
         with span("serve.prefill.args"):
             prompt = np.asarray(prompt, np.int32).reshape(-1)
             self.validate(prompt, sp)
@@ -849,44 +971,56 @@ class InferenceEngine:
             top_k = (self.config.vocab_size if sp.top_k is None
                      else int(sp.top_k))
             top_p = 1.0 if sp.top_p is None else float(sp.top_p)
-        first = self._prefill_paged(slot, prompt, sp, key, top_k, top_p)
+            eos = -1 if sp.eos_token is None else int(sp.eos_token)
+        tok = self._prefill_paged(slot, prompt, sp, key, top_k, top_p, eos)
         self.stats.prefills += 1
-        self.stats.tokens_generated += 1
-        # slot bookkeeping: the first token came from the prefill (key
-        # index 0); decode steps continue the schedule at index 1
+        # the host's mirror of the row the program wrote: the first token
+        # comes from the prefill (key index 0), decode steps continue the
+        # schedule at index 1. ``active`` until the token is read and
+        # says otherwise; the token itself (``_next_tok``, the history's
+        # entry n) is written when it is read
         self._active[slot] = True
-        self._next_tok[slot] = first
         self._gen_idx[slot] = 1
         self._generated[slot] = 1
         self._max_new[slot] = sp.max_new_tokens
-        self._eos[slot] = -1 if sp.eos_token is None else int(sp.eos_token)
+        self._eos[slot] = eos
         self._temp[slot] = sp.temperature
         self._top_k[slot] = top_k
         self._top_p[slot] = top_p
         self._base_keys[slot] = key
+        with span("serve.prefill.readback"):
+            # no wait: the token is read with the next step's download
+            self._firsts.append((slot, tok))
+        self.stats.active_slots = int(self._active.sum())
+        self.stats.prefill_buckets = tuple(sorted(self._seen_buckets))
+        return slot
+
+    def _first_token(self, slot: int, tok: int) -> None:
+        """A prefill's token, read: finish the mirror's row as the
+        program finished the device's, and hold the event."""
+        self._next_tok[slot] = tok
         # token history feeds the n-gram draft; the first token is
         # emitted (index n), giving hist_len == cursor + 1
-        self._hist[slot, n] = first
-        self._stale.update(SPEC_STATE)   # every mirror has a new row
-        finished = (sp.max_new_tokens <= 1
-                    or (sp.eos_token is not None and first == sp.eos_token))
+        self._hist[slot, self._prompt_len[slot]] = tok
+        finished = bool(self._max_new[slot] <= 1 or tok == self._eos[slot])
         if finished:
             self._active[slot] = False
             self._release_pages(slot)
-        self.stats.active_slots = int(self._active.sum())
-        self.stats.prefill_buckets = tuple(sorted(self._seen_buckets))
-        return slot, TokenEvent(slot, first, finished)
+        self.stats.tokens_generated += 1
+        self._held.append(TokenEvent(slot, tok, finished))
 
     def _prefill_paged(self, slot: int, prompt: np.ndarray,
                        sp: SamplingParams, base_key, top_k: int,
-                       top_p: float) -> int:
+                       top_p: float, eos: int):
         """Prefix-aware paged prefill: pin the resident shared-prefix
         blocks, copy-on-write a fully-matched final block, allocate the
         owned blocks (prefill pads + the whole decode budget — blocks
         are reserved at admit, so mid-decode writes can never need an
         allocation the jitted program couldn't perform), dispatch the
-        SUFFIX-only prefill, then content-register this prompt's own
-        full blocks for future requests to hit."""
+        SUFFIX-only prefill (which also writes the slot's row of the
+        decode state), then content-register this prompt's own full
+        blocks for future requests to hit. Returns the first token, a
+        device array nobody has waited for."""
         n = len(prompt)
         page, al = self.page_size, self._alloc
         full = n // page
@@ -896,7 +1030,7 @@ class InferenceEngine:
         # an admission that fails its request must not shrink the pool
         held: List[int] = []
         try:
-            with span("serve.prefill.args"):
+            with span("serve.prefill.args") as sp_args:
                 hit_pages, chain, cow_src, cid, start, suffix, bucket, \
                     n_new, need = self._plan_paged(prompt,
                                                    sp.max_new_tokens)
@@ -934,15 +1068,25 @@ class InferenceEngine:
                 prefill = self._prefill_prog(bucket)
                 padded = np.zeros((1, bucket), np.int32)
                 padded[0, :suffix] = prompt[start:]
+                state, ups = self._state_args(self._state_names)
+                if ups:
+                    sp_args.ids["uploads"] = ups
                 # NumPy as it is: the executable's own argument path
                 # transfers the batch
-                args = (self._bt[slot][None], np.asarray([start], np.int32),
-                        padded, np.int32(suffix), base_key,
+                args = (state, np.int32(slot), self._bt[slot][None],
+                        np.asarray([start], np.int32), padded,
+                        np.int32(suffix), base_key,
                         np.float32(sp.temperature), np.int32(top_k),
-                        np.float32(top_p))
+                        np.float32(top_p), np.int32(sp.max_new_tokens),
+                        np.int32(eos))
+                if self.spec_tokens:
+                    whole = np.zeros(self.block_size, np.int32)
+                    whole[:n] = prompt
+                    args += (whole,)
             with span("serve.prefill.dispatch", path=self.attend_path):
-                tok, self._cache = prefill(self.params, self._cache,
-                                           *args)
+                tok, self._dev, self._cache = prefill(
+                    self.params, self._cache, *args)
+            self._stale.clear()
             self.stats.paged_kernel_dispatches += self._kernel_attend
         except BaseException:
             for pg in held:
@@ -972,8 +1116,7 @@ class InferenceEngine:
         self.stats.prefill_tokens += bucket
         self.stats.kv_blocks_in_use = al.in_use()
         self.stats.kv_blocks_cached = al.cached()
-        with span("serve.prefill.readback"):
-            return int(np.asarray(tok)[0])
+        return tok
 
     def _release_pages(self, slot: int) -> None:
         """Drop this slot's block-table references (idempotent: an
@@ -983,8 +1126,9 @@ class InferenceEngine:
         for pg in self._bt[slot]:
             if pg:
                 self._alloc.decref(int(pg))
+        # the mirror's row only: the programs clear the device's when the
+        # row stops, and read no inactive row's table before that
         self._bt[slot] = 0
-        self._stale.add("bt")
         self.stats.kv_blocks_in_use = self._alloc.in_use()
         self.stats.kv_blocks_cached = self._alloc.cached()
 
@@ -1002,22 +1146,34 @@ class InferenceEngine:
                     self._cache, np.int32(0), np.int32(pg))
 
     def release(self, slot: int) -> None:
-        """Free a slot between decode steps (EOS/max-tokens eviction or a
-        cancelled request): the slot's block-table references are
-        dropped (shared prefix blocks stay resident for future hits)."""
-        self._active[slot] = False
-        self._stale.add("active")
+        """Free a slot (a cancelled request, a deadline): the slot's
+        block-table references are dropped (shared prefix blocks stay
+        resident for future hits). A slot the device may still be
+        advancing is a host write to a live row: what is in flight is
+        waited out first, so that no step that saw the old occupant is
+        unread when the slot is handed out again, and the events it
+        still had for this slot are dropped with it. A slot that already
+        stopped costs no wait and no upload."""
+        if self._active[slot]:
+            self._settle()
+        if self._active[slot]:               # the wait may have ended it
+            self._active[slot] = False
+            self._stale.add("active")
+        self._held = [ev for ev in self._held if ev.slot != slot]
         self._release_pages(slot)
         self.stats.active_slots = int(self._active.sum())
 
     # -- preemptible decode (park / resume) --------------------------------
 
     def park(self, slot: int) -> ParkedSlot:
-        """Preempt an ACTIVE slot at a chunk boundary (between ``step``
-        dispatches): snapshot its entire host-side cursor state and
-        block table WITHOUT decreffing the pages — the snapshot owns the
-        references — deactivate the row, and return the snapshot. Pure
-        host bookkeeping: no device work, no copies of KV state."""
+        """Preempt an ACTIVE slot at a chunk boundary: wait out what is
+        in flight (the snapshot is of the mirrors, which must be level
+        with the device; the caller takes the held events with
+        ``drain`` BEFORE parking if it routes them by slot), snapshot its
+        entire host-side cursor state and block table WITHOUT decreffing
+        the pages — the snapshot owns the references — deactivate the
+        row, and return the snapshot. No copies of KV state."""
+        self._settle()
         if not self._active[slot]:
             raise ValueError(f"slot {slot} is not active — nothing to park")
         parked = ParkedSlot(
@@ -1038,7 +1194,7 @@ class InferenceEngine:
         # references moved to the snapshot: zero the row WITHOUT decref
         # so release()/step()'s page sweep cannot double-free them
         self._bt[slot] = 0
-        self._stale.update(("active", "bt"))
+        self._stale.add("active")
         self.stats.preemptions += 1
         self.stats.active_slots = int(self._active.sum())
         return parked
@@ -1046,13 +1202,15 @@ class InferenceEngine:
     def resume(self, parked: ParkedSlot) -> int:
         """Restore a parked snapshot into a free slot. No device work —
         the KV pool is shared across slots and the slot's state is
-        written to the host's mirrors, which go up with the next decode
+        written to the host's mirrors (level with the device once what
+        is in flight was waited out), which go up with the next
         dispatch, so the resumed generation continues from exactly the
         token it was preempted at, byte-identical by the per-token key
         schedule. Raises ``NoFreeSlotError`` when
         every slot is busy (the scheduler checks first)."""
         if parked.released:
             raise ValueError("parked snapshot already consumed")
+        self._settle()
         free = self.free_slots()
         if not free:
             raise NoFreeSlotError(
@@ -1091,21 +1249,30 @@ class InferenceEngine:
         self.stats.kv_blocks_in_use = self._alloc.in_use()
         self.stats.kv_blocks_cached = self._alloc.cached()
 
-    def step(self, override_tokens: Optional[Dict[int, int]] = None
-             ) -> List[TokenEvent]:
-        """Advance every active slot by up to ``decode_chunk`` tokens (one
-        dispatch); returns the new tokens in generation order. Slots that
-        finish (EOS / max-tokens, decided ON DEVICE mid-chunk) come back
-        inactive and are free for the next admit — eviction happens
-        between dispatches, admission too: continuous batching at chunk
-        granularity.
+    def step(self, override_tokens: Optional[Dict[int, int]] = None,
+             ahead: bool = False) -> List[TokenEvent]:
+        """Dispatch one decode step (every active slot advances by up to
+        ``decode_chunk`` tokens) and return the tokens read, in
+        generation order.
 
-        What crosses to the device and back is in the module docstring:
-        only a mirror the host wrote since (``_stale``) goes up, so a
-        step after which nothing was admitted or released uploads
-        nothing (``stats.resident_steps``); one small download comes
-        back (``jax.device_get`` of the program's ``read``) and the
-        mirrors are brought up to the device's state from it.
+        ``ahead=True`` is the scheduler's call, one decode step AHEAD:
+        step K is dispatched from the device's own state, then what the
+        device finished BEFORE it is read — step K-1's small download
+        and the first tokens of the prefills dispatched since — and
+        those events are the return (each prefill's first token after
+        the step's tokens). The driver thread waits for step K-1 while
+        step K is already queued, and delivers, picks and admits while
+        it runs. Slots that finish (EOS / max-tokens / non-finite
+        logits, decided ON DEVICE) come back inactive one read later and
+        are free for the next admit: continuous batching at chunk
+        granularity, one step behind. With no row active any more
+        nothing is dispatched and the step in flight is waited out (its
+        events are the return), also the one dispatched just before the
+        read that said so; with nothing in flight either, ``[]``.
+
+        The default is that and a wait for the step just dispatched, for
+        direct callers: the return ends with this step's tokens and
+        nothing is in flight afterwards.
 
         ``override_tokens`` (teacher forcing, tests/eval only) replaces a
         slot's INPUT token for ONE single step — the call runs a chunk-1
@@ -1113,115 +1280,163 @@ class InferenceEngine:
         (``self.last_logits``) are the model's prediction conditioned on
         the forced history, while sampling proceeds normally.
         """
-        prog = self._decode_prog
-        spec_run = self._spec_prog is not None
         if override_tokens:
+            self._settle()                   # a host write to live rows
             for slot, tok in override_tokens.items():
                 self._next_tok[slot] = int(tok)
             self._stale.add("tok")
-            spec_run = False
-            if self.decode_chunk != 1 or self._spec_prog is not None:
-                if self._step1_prog is None:
-                    self._step1_prog = self._acquire(paged_decode_def(
-                        self._cfg_tuple, self.num_slots, 1))
-                prog = self._step1_prog
-        elif spec_run:
-            prog = self._spec_prog
+        self._advance(forced=bool(override_tokens))
+        if not ahead:
+            self._settle()
+        return self._take()
+
+    def _advance(self, forced: bool = False) -> None:
+        """Step K out, then step K-1 (and the prefills between them)
+        in: the events are held."""
+        if self._active.any():
+            flight, firsts = self._flight, self._firsts
+            launched = self._launch(forced, ahead=flight is not None)
+            self._flight, self._firsts = launched, []
+            if flight is not None or firsts:
+                self._collect(flight, firsts)
         if not self._active.any():
-            return []
+            # no row is left, or the read just said the last ones
+            # stopped: a step dispatched ahead of that read computes
+            # nothing, and is waited out here, so that an engine with no
+            # request has nothing in flight
+            self._settle()
+
+    def _launch(self, forced: bool, ahead: bool) -> _Flight:
+        """Dispatch one decode step and wait for nothing. The caller saw
+        a row active in the mirrors (rows only ever stop on the device
+        first, so they never say too little). What crosses to the device
+        is in the module docstring: only a mirror the host wrote since
+        (``_stale``) goes up, so a step after which no live row was
+        written by the host uploads nothing (``stats.resident_steps``)."""
+        prog = self._decode_prog
+        spec_run = self._spec_prog is not None and not forced
+        if spec_run:
+            prog = self._spec_prog
+        elif forced and (self.decode_chunk != 1
+                         or self._spec_prog is not None):
+            if self._step1_prog is None:
+                self._step1_prog = self._acquire(paged_decode_def(
+                    self._cfg_tuple, self.num_slots, 1))
+            prog = self._step1_prog
         # hit-counted AFTER the idle early-out so hit N is the Nth REAL
         # decode dispatch — "hang at dispatch 2" reproduces exactly
         fault_point("serve.decode")
         with span("serve.decode.args") as sp:
-            was_active = self._active.copy()
-            names = SPEC_STATE if spec_run else PAGED_STATE
-            # an entry the last dispatch did not return goes up too: all
-            # of them at the first step, ``hist`` after a step of the plain
-            # program (the host replays tokens into its own copy whatever
-            # program ran)
-            up = [n for n in names
-                  if n in self._stale or n not in self._dev]
-            state = {n: self._dev[n] for n in names if n not in up}
-            state.update((n, self._mirror(n)) for n in up)
-            sp.ids["uploads"] = len(up)
-            self.stats.upload_arrays += len(up)
-            self.stats.resident_steps += int(not up)
+            state, ups = self._state_args(
+                SPEC_STATE if spec_run else PAGED_STATE)
+            sp.ids["uploads"] = ups
+            self.stats.resident_steps += int(not ups)
         with span("serve.decode.dispatch", path=self.attend_path):
             read, self._logits, self._dev, self._cache = prog(
                 self.params, self._cache, state)
         self._logits_host = None
         self._stale.clear()
         self.stats.paged_kernel_dispatches += self._kernel_attend
-        with span("serve.decode.readback") as rb:
-            # one wait for the step, then a few KiB
-            read = jax.device_get(read)
-            nbytes = sum(a.nbytes for a in jax.tree.leaves(read))
-            rb.ids["bytes"] = nbytes
-            self.stats.readback_bytes += nbytes
-            toks, emitted = read["toks"], read["emitted"]
-            nan_seen = read["nan_seen"]
+        return _Flight(read, spec_run, ahead)
+
+    def _collect(self, flight: Optional[_Flight],
+                 firsts: List[Tuple[int, Any]]) -> None:
+        """Read a decode step's download and the first tokens of the
+        prefills dispatched after it, in ONE transfer, bring the mirrors
+        up to them and hold the events."""
+        first_toks = [tok for _slot, tok in firsts]
+        if flight is not None:
+            with span("serve.decode.readback") as rb:
+                # one wait for the step (and the prefills behind it),
+                # then a few KiB
+                read, first_toks = jax.device_get((flight.read, first_toks))
+                nbytes = sum(a.nbytes for a in jax.tree.leaves(read))
+                rb.ids["bytes"] = nbytes
+                self.stats.readback_bytes += nbytes
         with span("serve.decode.events"):
-            self._count(read["counted"])
-            if toks.ndim == 2:
-                # non-speculative programs emit one token per scanned step;
-                # widen to the speculative [chunk, S, γ+1] layout so ONE host
-                # replay path routes both
-                toks = toks[..., None]
-                emitted = emitted[..., None]
-            # the mirrors take the device's final state (``_gen_idx``,
-            # ``_generated`` and ``_hist`` follow below, token by token)
-            self._next_tok = read["tok"].astype(np.int32)
-            self._active = read["active"].copy()
-            self._pos = read["pos"].astype(np.int32)
-            # numerical quarantine: non-finite logits fail ONLY their own
-            # slot — the model's per-row cache math keeps rows isolated (and
-            # the decode attends NaN-poison an overflowing row/position on
-            # purpose, so this is the designated catch point). Every decode
-            # program LATCHES non-finite logits per iteration while the row
-            # is active (``nan_seen``): the last step's logits could not
-            # witness a poison that struck a paged row mid-chunk (a finished
-            # row's table is redirected to the null page, so its later
-            # iterations read clean garbage), and no path reads logits.
-            for slot in np.nonzero(nan_seen)[0]:
-                self._active[slot] = False           # quarantine = evict
-                self._stale.add("active")
-                self.stats.quarantined += 1
-            events: List[TokenEvent] = []
-            n_steps = toks.shape[0]
-            for k in range(n_steps):
-                for slot in np.nonzero(emitted[k].any(axis=1))[0]:
-                    if spec_run:
-                        # acceptance accounting: γ drafted per active slot
-                        # per iteration; all emitted beyond the one
-                        # guaranteed token were accepted drafts
-                        self.stats.spec_drafted += self.spec_tokens
-                        self.stats.spec_accepted += int(
-                            emitted[k, slot].sum()) - 1
-                    for j in np.nonzero(emitted[k, slot])[0]:
-                        tok = int(toks[k, slot, j])
-                        hl = (int(self._prompt_len[slot])
-                              + int(self._generated[slot]))
-                        if hl < self.block_size:
-                            self._hist[slot, hl] = tok
-                        self._gen_idx[slot] += 1
-                        self._generated[slot] += 1
-                        # finished iff the device stopped emitting for this
-                        # slot (its last emitted token) and it came back
-                        # inactive
-                        last_emit = (not emitted[k, slot, j + 1:].any()
-                                     and not emitted[k + 1:, slot].any())
-                        finished = bool(last_emit and not self._active[slot])
-                        events.append(TokenEvent(
-                            int(slot), tok, finished,
-                            poisoned=bool(nan_seen[slot])))
-            # blocks of slots that finished (or were quarantined) this
-            # chunk go back to the allocator; shared prefix blocks stay
-            # resident for future hits
-            for slot in np.nonzero(was_active & ~self._active)[0]:
-                if nan_seen[slot]:
-                    self._scrub_pages(slot)
-                self._release_pages(slot)
-            self.stats.tokens_generated += len(events)
-            self.stats.decode_steps += int(was_active.any()) * n_steps
+            if flight is None:
+                # no step to read them with: the device was idle before
+                # these prefills (the first round, or after a drain)
+                with span("serve.prefill.readback"):
+                    first_toks = jax.device_get(first_toks)
+            else:
+                self._replay(flight, read, [slot for slot, _tok in firsts])
+            for (slot, _dev), tok in zip(firsts, first_toks):
+                self._first_token(slot, int(tok[0]))
             self.stats.active_slots = int(self._active.sum())
-        return events
+
+    def _replay(self, flight: _Flight, read: Dict[str, Any],
+                admitted: List[int]) -> None:
+        """One decode step's download into mirrors and events.
+        ``admitted``: slots a prefill wrote AFTER this step (the step saw
+        them inactive, and the mirrors' rows are the newer)."""
+        toks, emitted = read["toks"], read["emitted"]
+        nan_seen = read["nan_seen"]
+        self._count(read["counted"])
+        if toks.ndim == 2:
+            # non-speculative programs emit one token per scanned step;
+            # widen to the speculative [chunk, S, γ+1] layout so ONE host
+            # replay path routes both
+            toks = toks[..., None]
+            emitted = emitted[..., None]
+        was_active = self._active.copy()
+        # the mirrors take the device's final state (``_gen_idx``,
+        # ``_generated`` and ``_hist`` follow below, token by token)
+        newer = np.zeros(self.num_slots, bool)
+        newer[admitted] = True
+        self._next_tok = np.where(newer, self._next_tok,
+                                  read["tok"]).astype(np.int32)
+        self._active = np.where(newer, self._active, read["active"])
+        self._pos = np.where(newer, self._pos, read["pos"]).astype(np.int32)
+        # numerical quarantine: non-finite logits fail ONLY their own
+        # slot — the model's per-row cache math keeps rows isolated (and
+        # the decode attends NaN-poison an overflowing row/position on
+        # purpose, so this is the designated catch point). Every decode
+        # program LATCHES non-finite logits per iteration while the row
+        # is active (``nan_seen``) and stops the row itself: the last
+        # step's logits could not witness a poison that struck a paged
+        # row mid-chunk (a finished row's table is redirected to the
+        # null page, so its later iterations read clean garbage), and no
+        # path reads logits. Quarantine = evict, with no host write.
+        self.stats.quarantined += int(nan_seen.sum())
+        events: List[TokenEvent] = []
+        n_steps = toks.shape[0]
+        for k in range(n_steps):
+            for slot in np.nonzero(emitted[k].any(axis=1))[0]:
+                if flight.spec_run:
+                    # acceptance accounting: γ drafted per active slot
+                    # per iteration; all emitted beyond the one
+                    # guaranteed token were accepted drafts
+                    self.stats.spec_drafted += self.spec_tokens
+                    self.stats.spec_accepted += int(
+                        emitted[k, slot].sum()) - 1
+                for j in np.nonzero(emitted[k, slot])[0]:
+                    tok = int(toks[k, slot, j])
+                    hl = (int(self._prompt_len[slot])
+                          + int(self._generated[slot]))
+                    if hl < self.block_size:
+                        self._hist[slot, hl] = tok
+                    self._gen_idx[slot] += 1
+                    self._generated[slot] += 1
+                    # finished iff the device stopped emitting for this
+                    # slot (its last emitted token) and it came back
+                    # inactive
+                    last_emit = (not emitted[k, slot, j + 1:].any()
+                                 and not emitted[k + 1:, slot].any())
+                    finished = bool(last_emit and not self._active[slot])
+                    events.append(TokenEvent(
+                        int(slot), tok, finished,
+                        poisoned=bool(nan_seen[slot])))
+        # blocks of slots that finished (or were quarantined) this
+        # chunk go back to the allocator; shared prefix blocks stay
+        # resident for future hits. The scrub and whatever takes the
+        # pages next are queued behind the step in flight, which has
+        # these rows inactive already
+        for slot in np.nonzero(was_active & ~self._active)[0]:
+            if nan_seen[slot]:
+                self._scrub_pages(slot)
+            self._release_pages(slot)
+        self.stats.tokens_generated += len(events)
+        self.stats.decode_steps += n_steps
+        self.stats.steps_ahead += n_steps * flight.ahead
+        self._held.extend(events)

@@ -1807,6 +1807,9 @@ class ProcessRouter:
                 "active_slots": h.get("active_slots", 0),
                 "num_slots": h.get("num_slots", 0),
                 "tokens_generated": h.get("tokens_generated", 0),
+                "decode_steps": h.get("decode_steps", 0),
+                "steps_ahead": h.get("steps_ahead", 0),
+                "drains": h.get("drains", 0),
                 "tokens_per_s_ewma": h.get("tokens_per_s_ewma"),
                 "programs_compiled": h.get("programs_compiled"),
                 "engine_generation": h.get("engine_generation", 0),

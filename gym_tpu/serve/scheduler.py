@@ -17,9 +17,11 @@ The engine (``engine.py``) knows slots; this layer knows REQUESTS:
   bounded lookahead window — a request whose prompt prefix is resident
   in the paged engine's prefix cache is admitted ahead of its FCFS turn
   so shared-prefix bursts hit the cache before eviction churn loses
-  them), advance every active slot one token (the shared decode step),
-  and complete/evict finished requests BETWEEN steps — continuous
-  batching. Running requests past
+  them), give the device its NEXT decode step and only then read the
+  one before it (``engine.step(ahead=True)``: the round never waits for the
+  device before the device has its next step queued), and deliver that
+  step's tokens, the admitted requests' first tokens among them —
+  continuous batching, one step behind the device. Running requests past
   their deadline are cancelled at the chunk boundary and their slot
   freed; a slot the engine quarantined (NaN/Inf logits) fails only its
   own request.
@@ -796,7 +798,9 @@ class Scheduler:
                     continue
                 try:
                     padded = engine.stats.prefill_tokens
-                    slot, ev = engine.admit(req.prompt, req.sampling)
+                    # the prefill is queued behind the step in flight; its
+                    # first token is an event of this round's read
+                    slot = engine.admit_nowait(req.prompt, req.sampling)
                     # the padded tokens this prefill dispatched
                     admit_span.ids["bucket"] = (
                         engine.stats.prefill_tokens - padded)
@@ -841,13 +845,8 @@ class Scheduler:
                                               RequestStatus.FAILED)
                     if not stale and not resolved:
                         req.status = RequestStatus.RUNNING
-                        req.first_token_t = time.perf_counter()
-                        req.tokens.append(ev.token)
+                        self._by_slot[slot] = req
                         admitted += 1
-                        if not ev.finished:
-                            self._by_slot[slot] = req
-                if not stale and not resolved:
-                    req._notify_progress()     # first token: wake streamers
                 if resolved and not stale:
                     engine.release(slot)   # same engine; free the row
                     continue
@@ -858,8 +857,6 @@ class Scheduler:
                         "engine replaced during admission (supervisor "
                         "failover) — retry"))
                     break
-                if ev.finished:
-                    self._complete(req)
         return admitted
 
     # -- preemptible decode (driver side) ---------------------------------
@@ -876,14 +873,28 @@ class Scheduler:
         pinned for the byte-identical resume."""
         if engine.free_slots():
             return
+
+        def candidates():
+            if self._epoch != epoch or not self._queue:
+                return []
+            urgent = min(r.priority for r in self._queue)
+            return [(slot, req) for slot, req in self._by_slot.items()
+                    if req.priority > urgent
+                    and req.preemptions < self.max_preemptions]
+
+        with self._lock:
+            if not candidates():
+                return
+        # a park is a host write to a live row: the step in flight is
+        # waited out, and its tokens delivered HERE, while the slots
+        # still map to the requests they were made for (the victim's
+        # stream must hold every token its snapshot counts)
+        self._deliver(engine.drain(), epoch, engine)
+        if engine.free_slots():
+            return                   # that step freed a slot: no park
         victim = None
         with self._lock:
-            if self._epoch != epoch or not self._queue:
-                return
-            urgent = min(r.priority for r in self._queue)
-            cands = [(slot, req) for slot, req in self._by_slot.items()
-                     if req.priority > urgent
-                     and req.preemptions < self.max_preemptions]
+            cands = candidates()
             if not cands:
                 return
             # least urgent class first; most remaining work second (the
@@ -909,6 +920,11 @@ class Scheduler:
         passes, the same anti-starvation contract as the queue head:
         a batch request always eventually progresses."""
         resumed: List[Request] = []
+        if engine.free_slots():
+            # a resume writes a row through the host's mirrors, which are
+            # level with the device only once the step in flight was read
+            # (outside the lock; its tokens are delivered at once)
+            self._deliver(engine.drain(), epoch, engine)
         while True:
             with self._lock:
                 if (self._epoch != epoch or not self._parked
@@ -943,11 +959,19 @@ class Scheduler:
                     active_slots=engine.stats.active_slots)
 
     def step(self) -> int:
-        """One scheduling round; returns the number of tokens produced
-        (0 = idle). Admission happens BEFORE the decode step so a freed
-        slot turns around within one round. Epoch-guarded: a stale driver
-        (one that wedged, was failed over past, and finally woke) discards
-        its events instead of touching the rebuilt engine's requests."""
+        """One scheduling round; returns the number of tokens delivered
+        (0 = idle, or nothing read yet). The engine is one decode step
+        ahead of the round: the prefills of this round's admissions are
+        queued behind the step in flight, then ``engine.step(ahead=True)``
+        queues the NEXT step and reads the one before it, and what is
+        delivered (tokens, finished slots, the admitted requests' first
+        tokens) is that earlier step's, while the device runs. A slot the
+        device freed is therefore refilled one step later, and nothing
+        on the round's path waits for the device before it has work.
+        Epoch-guarded: a stale driver (one that wedged, was failed over
+        past, and finally woke) discards its events, the dead engine's
+        step in flight among them, instead of touching the rebuilt
+        engine's requests."""
         self._round += 1
         # the round's record, and its leaves', are kept only if it
         # produced or admitted anything: the driver polls when idle
@@ -976,14 +1000,16 @@ class Scheduler:
             if self.preempt:
                 with span("serve.preempt"):
                     self._preempt_for_queued(epoch, engine)
-        produced = 0 if paused else self._admit_from_queue(epoch, engine)
-        events = engine.step()
+        if not paused:
+            self._admit_from_queue(epoch, engine)
+        events = engine.step(ahead=True)
         with span("serve.deliver"):
-            return produced + self._deliver(events, epoch, engine)
+            return self._deliver(events, epoch, engine)
 
     def _deliver(self, events, epoch: int, engine: InferenceEngine) -> int:
-        """Apply a decode step's events to their requests, sweep deadlines
-        and cancellations at the chunk boundary, resolve and wake."""
+        """Apply a read's events (a decode step's tokens, admitted
+        requests' first tokens) to their requests, sweep deadlines and
+        cancellations at the chunk boundary, resolve and wake."""
         produced = 0
         now = time.perf_counter()
         completed: List[Request] = []
@@ -992,6 +1018,19 @@ class Scheduler:
         with self._lock:
             if self._epoch != epoch:
                 return produced        # stale driver: discard the chunk
+            sweep = any(req.id in self._cancelled
+                        or (req.deadline_s is not None
+                            and now > req.deadline_t)
+                        for req in self._by_slot.values())
+        if sweep:
+            # freeing a running slot is a host write to a live row: the
+            # step in flight is waited out HERE, outside the lock, and
+            # its tokens are delivered with this round's, before the
+            # slot can be handed to anyone else
+            events = events + engine.drain()
+        with self._lock:
+            if self._epoch != epoch:
+                return produced
             for ev in events:
                 req = self._by_slot.get(ev.slot)
                 if req is None:      # slot freed by a cancel between steps
@@ -1005,6 +1044,8 @@ class Scheduler:
                         f"non-finite logits in slot {ev.slot} — request "
                         f"quarantined after {len(req.tokens)} tokens")))
                     continue
+                if req.first_token_t is None:
+                    req.first_token_t = now      # the prefill's token
                 req.tokens.append(ev.token)
                 produced += 1
                 if req not in progressed:

@@ -174,6 +174,8 @@ class WorkerServer:
             "num_slots": int(stats.num_slots),
             "tokens_generated": int(stats.tokens_generated),
             "decode_steps": int(stats.decode_steps),
+            "steps_ahead": int(stats.steps_ahead),
+            "drains": int(stats.drains),
             "prefills": int(stats.prefills),
             "tokens_per_s_ewma": self.metrics.tokens_per_s_ewma(),
             "programs_compiled": programs_mod.xla_compile_counter(),

@@ -12,7 +12,8 @@ the logits stay on the device. Oracles:
   equal a fresh engine's that serves each request alone;
 - the base key made on the host is ``jax.random.PRNGKey``'s;
 - ``last_logits`` still gives the step's logits, and is counted once;
-- the round's path calls no eager device operation.
+- the round's path calls no eager device operation, and (ISSUE 30) reads
+  nothing back between a decode dispatch and the next.
 """
 
 import numpy as np
@@ -125,15 +126,18 @@ def test_step_after_no_admission_uploads_nothing(setup, kind):
     eng = _engine(setup, kind)
     for i in range(2):
         eng.admit(*_request(i, max_new=40))
-    eng.step()                                   # the mirrors go up, once
+    # ISSUE 30: the prefill writes the admitted row on the device, so not
+    # even the first step after admissions is handed a mirror (ISSUE 28's
+    # engine sent them all up here, once: ``first > 0``, no resident step)
+    eng.step()
     first = eng.stats.upload_arrays
-    assert first > 0 and eng.stats.resident_steps == 0
+    assert first == 0 and eng.stats.resident_steps == 1
     logits_bytes = eng.num_slots * eng.config.vocab_size * 4
     for n in range(1, 4):
         read = eng.stats.readback_bytes
         assert eng.step()
         assert eng.stats.upload_arrays == first
-        assert eng.stats.resident_steps == n
+        assert eng.stats.resident_steps == n + 1
         assert 0 < eng.stats.readback_bytes - read < min(logits_bytes,
                                                          64 * 1024)
     assert not eng._stale
@@ -353,3 +357,60 @@ def test_served_round_issues_no_eager_device_operation(setup, kind,
         if again.status is RequestStatus.DONE:
             break
     assert again.result(timeout=1) == alone
+
+
+@pytest.mark.parametrize("kind", list(KINDS))
+def test_served_round_reads_nothing_between_a_dispatch_and_the_next(
+        setup, kind, monkeypatch):
+    """ISSUE 30: the driver thread never waits for the device before the
+    device has its next decode step. Every read-back on the round's path
+    is one ``jax.device_get``, made right after a decode dispatch and of
+    an OLDER step than that one (or of first tokens only); an admission
+    reads nothing; and the engine has no other way to wait (``np.asarray``
+    of a device array would be one: the events are built from what
+    ``device_get`` returned)."""
+    alone = [_alone(setup, kind, *_request(i, max_new=12 + 5 * i))
+             for i in range(4)]
+    eng = _engine(setup, kind, num_slots=2)
+    sched = Scheduler(eng)
+    log = []
+
+    def device_get(tree):
+        flight = eng._flight
+        # what is being read is not the step that was just dispatched
+        younger = flight is not None and not any(
+            leaf is mine for leaf in jax.tree.leaves(tree)
+            for mine in jax.tree.leaves(flight.read))
+        log.append(("read", younger))
+        return jax.device_get(tree)
+
+    monkeypatch.setattr(engine_mod, "jax", _Refusing(
+        jax, device_get=device_get))
+    for name in ("_decode_prog", "_spec_prog"):
+        prog = getattr(eng, name)
+        if prog is not None:
+            monkeypatch.setattr(
+                eng, name, lambda *a, _prog=prog: (
+                    log.append(("dispatch", True)), _prog(*a))[1])
+    admit = eng.admit_nowait
+    monkeypatch.setattr(eng, "admit_nowait", lambda *a: (
+        log.append(("admit", True)), admit(*a))[1])
+    reqs = [sched.submit(*_request(i, max_new=12 + 5 * i)) for i in range(4)]
+    rounds = 0
+    while not all(r.status is RequestStatus.DONE for r in reqs):
+        sched.step()
+        rounds += 1
+        assert rounds < 200
+    assert [r.result(timeout=1) for r in reqs] == alone
+    kinds = [k for k, _ in log]
+    assert kinds.count("admit") == 4 and kinds.count("dispatch") >= 10
+    reads = [i for i, k in enumerate(kinds) if k == "read"]
+    # each read follows a decode dispatch at once, and is of an older step
+    # (the one read at the very end waits out the step dispatched before
+    # the read that said no row was left: there the device has no work)
+    assert all(kinds[i - 1] == "dispatch" and log[i][1] for i in reads[:-1])
+    assert kinds[reads[-1] - 1] == "read" and not log[reads[-1]][1]
+    assert len(reads) == kinds.count("dispatch") + 1
+    # all but the first dispatch, counted in scanned steps like the total
+    assert (eng.stats.steps_ahead
+            == eng.stats.decode_steps - eng.decode_chunk)
