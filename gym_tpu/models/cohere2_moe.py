@@ -152,6 +152,35 @@ def rotate_interleaved(x, pos, theta: float):
     return x * cos + partner * sin
 
 
+def pool_slots(block_table, cache_pos, t: int, page: int):
+    """Where a call's ``t`` new positions of every row go in a page pool:
+    ``(wpos, phys, off)`` [b, t], the position in its row, the physical
+    page and the place in it. A position past the row's table lands on
+    the null page (the caller poisons its output)."""
+    mb = block_table.shape[1]
+    wpos = cache_pos[:, None] + jnp.arange(t)[None, :]       # [b, t]
+    lblk = jnp.clip(wpos // page, 0, mb - 1)
+    phys = jnp.take_along_axis(block_table, lblk, axis=1)
+    phys = jnp.where(wpos < mb * page, phys, 0)
+    return wpos, phys, wpos % page
+
+
+def by_query_block(attend, hb, cache_pos, qc: int):
+    """``attend(hb_c [b, tc, C], pos_c [b]) -> [b, tc, C]`` over the
+    queries of ``hb`` [b, t, C], ``qc`` at a time once ``t`` is whole
+    blocks of it: 16k positions of 128 heads are a GiB in float32 before
+    the rotation has made its copies."""
+    b, t, C = hb.shape
+    if t <= qc or t % qc:
+        return attend(hb, cache_pos)
+    out = jax.lax.map(
+        lambda c: attend(
+            jax.lax.dynamic_slice_in_dim(hb, c * qc, qc, axis=1),
+            cache_pos + c * qc),
+        jnp.arange(t // qc))                            # [t/qc, b, qc, C]
+    return jnp.moveaxis(out, 0, 1).reshape(b, t, C)
+
+
 class LayerNormNoBias(nn.Module):
     eps: float
     param_dtype: jnp.dtype
@@ -196,7 +225,7 @@ class GroupedPagedAttention(nn.Module):
         wv = self.param("v_proj", init, (C, KV * hd), dt)
         wo = self.param("o_proj", init, (H * hd, C), dt)
         hb = h.astype(dt)
-        wpos = cache_pos[:, None] + jnp.arange(t)[None, :]       # [b, t]
+        wpos, phys, off = pool_slots(block_table, cache_pos, t, page)
         k = jnp.einsum("btc,ckd->btkd", hb, wk.reshape(C, KV, hd),
                        preferred_element_type=jnp.float32)
         v = jnp.dot(hb, wv, preferred_element_type=jnp.float32)
@@ -206,11 +235,6 @@ class GroupedPagedAttention(nn.Module):
                            lambda: jnp.zeros((P, page, KV * hd), kv_dt))
         cv = self.variable("cache", "v",
                            lambda: jnp.zeros((P, page, KV * hd), kv_dt))
-        lblk = jnp.clip(wpos // page, 0, mb - 1)
-        phys = jnp.take_along_axis(block_table, lblk, axis=1)
-        # out-of-window writes land on the null page and are poisoned below
-        phys = jnp.where(wpos < S, phys, 0)
-        off = wpos % page
         k_pool = ck.value.at[phys, off].set(
             k.reshape(b, t, KV * hd).astype(kv_dt))
         v_pool = cv.value.at[phys, off].set(v.astype(kv_dt))
@@ -273,19 +297,7 @@ class GroupedPagedAttention(nn.Module):
                               wo.reshape(KV, G, hd, C),
                               preferred_element_type=jnp.float32)
 
-        # a long prefill takes its queries a block at a time: 16k
-        # positions of 128 heads are a GiB in float32 before the rotation
-        # has made its copies
-        qc = cfg.attn_query_block
-        if t <= qc or t % qc:
-            out = attend(hb, cache_pos)
-        else:
-            out = jax.lax.map(
-                lambda c: attend(
-                    jax.lax.dynamic_slice_in_dim(hb, c * qc, qc, axis=1),
-                    cache_pos + c * qc),
-                jnp.arange(t // qc))                    # [t/qc, b, qc, C]
-            out = jnp.moveaxis(out, 0, 1).reshape(b, t, C)
+        out = by_query_block(attend, hb, cache_pos, cfg.attn_query_block)
         return jnp.where((wpos < S)[:, :, None], out, jnp.nan)
 
 

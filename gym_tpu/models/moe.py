@@ -371,7 +371,7 @@ def moe_param_specs(params: PyTree, base_specs: PyTree = None,
     return jax.tree_util.tree_unflatten(treedef, out)
 
 
-# -- a chip's share of a sigmoid-routed expert layer (serving) -------------
+# -- a chip's share of a routed expert layer (serving) -------------
 
 
 def _gated(x, w_gate, w_up, w_down, dot):
@@ -382,11 +382,16 @@ def _gated(x, w_gate, w_up, w_down, dot):
     return dot((jax.nn.silu(g) * u).astype(x.dtype), w_down)
 
 
+_SCORE_FNS = {"sigmoid": jax.nn.sigmoid,
+              "softmax": lambda x: jax.nn.softmax(x, axis=-1)}
+
+
 class HeldExperts(nn.Module):
     """The expert layer of a deployment that shares each layer's experts
     over several chips, as ONE of those chips runs it (inference only).
 
-    Routing runs over all ``n_experts``: ``sigmoid`` scores in float32,
+    Routing runs over all ``n_experts``: scores in float32 (``score_fn``:
+    ``sigmoid`` of each router output, or ``softmax`` over all of them),
     the ``topk`` largest, their weights renormalised to sum to one
     (``norm_topk``). The chip holds the routed experts ``held = (lo,
     hi)`` and computes their part of the sum alone: token-picks are
@@ -418,6 +423,7 @@ class HeldExperts(nn.Module):
     norm_topk: bool = True
     chunk_rows: int = 8192
     param_dtype: Any = jnp.bfloat16
+    score_fn: str = "sigmoid"
 
     @nn.compact
     def __call__(self, h, live=None):
@@ -425,6 +431,9 @@ class HeldExperts(nn.Module):
         C, F, E, K = self.hidden, self.width, self.n_experts, self.topk
         lo, hi = self.held
         nh = hi - lo
+        if self.score_fn not in _SCORE_FNS:
+            raise ValueError(f"score_fn must be one of "
+                             f"{sorted(_SCORE_FNS)}, got {self.score_fn!r}")
         if not 0 <= lo < hi <= E:
             raise ValueError(f"held experts {self.held} not within "
                              f"[0, {E})")
@@ -438,7 +447,7 @@ class HeldExperts(nn.Module):
         hb = h.astype(dt)
 
         with jax.named_scope("moe.router"):
-            scores = jax.nn.sigmoid(jnp.dot(
+            scores = _SCORE_FNS[self.score_fn](jnp.dot(
                 h, router.astype(jnp.float32),
                 precision=jax.lax.Precision.HIGHEST))
             top_s, top_i = jax.lax.top_k(scores, K)              # [S, K]

@@ -30,9 +30,10 @@ import dataclasses
 from typing import Any, Dict
 
 from .cohere2_moe import FAMILY as COHERE2_MOE, Cohere2MoeConfig
+from .keye_vl2 import FAMILY as KEYE_VL2, KeyeVL2Config
 from .nanogpt import GPTConfig, sample_logits  # noqa: F401 — re-exported
 
-FAMILIES = {COHERE2_MOE: Cohere2MoeConfig}
+FAMILIES = {COHERE2_MOE: Cohere2MoeConfig, KEYE_VL2: KeyeVL2Config}
 
 
 def config_from_key(key: tuple):

@@ -89,10 +89,14 @@ _GQA_VMEM = 64 << 20
 KERNEL = "pallas_paged"
 KERNEL_WINDOW = "pallas_paged_window"   # the grouped kernel with a window
 GATHER = "gather"
+# learned sparse attention (``ops/sparse_attention.py``): index scores,
+# the exact ``topk`` selection, an attend over the kept keys only
+SPARSE = "sparse_topk"
 
 
 def paged_attend_path(n_embd: int, page_size: int, dtype, kv_dtype,
-                      head_dim: int = 0, window: int = 0) -> str:
+                      head_dim: int = 0, window: int = 0,
+                      sparse_topk: int = 0) -> str:
     """Which implementation the paged attend takes, from what the code
     can observe. ``n_embd`` is the pool row's width (all key-value heads
     of a position). THE dispatch point — the model and the engine's
@@ -111,7 +115,14 @@ def paged_attend_path(n_embd: int, page_size: int, dtype, kv_dtype,
     when the head dimension is whole lane tiles (a multiple of 128),
     queries and pool share one of float32 and bfloat16 and a page is
     whole sublane tiles of it (8 rows of float32, 16 of bfloat16) that
-    divide the chunk; else ``GATHER``."""
+    divide the chunk; else ``GATHER``.
+
+    ``sparse_topk`` given (a layer that keeps that many keys a query by
+    a learned index, ``ops/sparse_attention.py``): ``SPARSE`` on every
+    backend and dtype; it reads the kept positions where they lie and
+    never builds a row's window."""
+    if sparse_topk:
+        return SPARSE
     dtype, kv_dtype = jnp.dtype(dtype), jnp.dtype(kv_dtype)
     if head_dim:
         ok = dtype == kv_dtype and dtype in (jnp.float32, jnp.bfloat16)

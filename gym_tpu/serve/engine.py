@@ -689,10 +689,10 @@ class InferenceEngine:
                 for name, sub in node.items():
                     if hasattr(sub, "items"):
                         walk(sub)
-                    elif name in ("k", "v"):
-                        payload += int(sub.nbytes)
                     elif name.endswith("_scale"):
                         scales += int(sub.nbytes)
+                    else:       # keys, values, whatever else a layer keeps
+                        payload += int(sub.nbytes)
 
         walk(self._cache)
         return {"payload": payload, "scales": scales}

@@ -298,3 +298,67 @@ def test_command_a_plus_programs_fit_the_chip(v5e_chip, monkeypatch,
                 if " gather(" in line and pool in line]
     assert not gathered, gathered[:2]
     assert re.search(r"input_output_alias=\{.*may-alias", hlo)
+
+
+# the KeyeVL2 cell as served (perfbench/configs/keye-vl2-30b-a3b.json,
+# perfbench/traffic/serve-closed-longdoc.json): 8 layers, 16 held experts,
+# 18,992 rows of the vocabulary, bfloat16, 16 slots, rows of 36,864
+# positions, 32,768 pages of 16
+def _keye_cfg(kv_pages=32768):
+    import dataclasses
+    from gym_tpu.models.keye_vl2 import KeyeVL2Config
+    return dataclasses.replace(
+        KeyeVL2Config(vocab_size=18992, num_hidden_layers=8,
+                      held_experts=(0, 16), block_size=36864
+                      ).decode_config(),
+        page_size=16, kv_pages=kv_pages)
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill32768"])
+def test_keye_programs_fit_the_chip_and_copy_no_pool(v5e_chip, monkeypatch,
+                                                     program):
+    """The decode program and the longest prefill bucket as the cell's
+    engine compiles them: arguments (1.7 GB of weights, 9.1 GB of pools),
+    outputs and temporaries fit the 15.75 GiB the chip gives; the expert
+    products are XLA's grouped-matmul kernel; and besides the in-place
+    writes of the new positions no instruction's result is the size of a
+    key or value pool array, and the index keys' pool (a page's keys side
+    by side on one row, whole lane tiles) is neither copied, transposed
+    nor converted. The prefill's attend over its masks is the Pallas
+    kernel (``ops/sparse_attention.py:masked_attend``)."""
+    import re
+    from gym_tpu.programs import serve_defs
+    monkeypatch.setattr(paged_attention, "_on_tpu", lambda: True)
+    cfg = _keye_cfg()
+    key = cfg.program_key()
+    assert set(cfg.attend_paths()) == {paged_attention.SPARSE}
+    pdef = (serve_defs.paged_decode_def(key, 16, 1) if program == "decode"
+            else serve_defs.paged_prefill_def(key, int(program[7:]), 16))
+    args = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=v5e_chip),
+        pdef.args)
+    compiled = pdef.builder().lower(*args).compile()
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    assert total < CHIP_BYTES, (total / 2 ** 30, mem)
+    hlo = compiled.as_text()
+    assert "ragged-dot" in hlo
+    assert ("sparse_masked_prefill" in hlo) == (program != "decode")
+    pool_elems = cfg.kv_pages * 16 * 512
+    big = []
+    for m in re.finditer(
+            r"= (\w+)\[([\d,]+)\]\S* (copy|gather|transpose|"
+            r"dynamic-slice|convert)\(", hlo):
+        n = 1
+        for d in m.group(2).split(","):
+            n *= int(d)
+        if n >= pool_elems:
+            big.append(m.group(0))
+    assert not big, big[:3]
+    index_pool = f"bf16[{cfg.kv_pages},1024]"
+    moved = [line for line in hlo.splitlines()
+             if re.search(re.escape(index_pool)
+                          + r"\S* (copy|transpose|convert)\(", line)]
+    assert not moved, moved[:2]
+    assert re.search(r"input_output_alias=\{.*may-alias", hlo)

@@ -475,3 +475,77 @@ def test_grouped_dot_primitive_direct():
     go = jax.grad(lambda g: (grouped_outer(x, g, gs) ** 2).sum())(
         jnp.asarray(rng.standard_normal((R, H)), jnp.float32))
     assert go.shape == (R, H) and np.all(np.isfinite(go))
+
+
+# -- a chip's share of a softmax-routed expert layer (HeldExperts) -----------
+
+
+def _held_params(key, c, f, e):
+    ks = jax.random.split(key, 4)
+    n = lambda k, s: 0.3 * jax.random.normal(k, s, jnp.float32)  # noqa: E731
+    return {"router": n(ks[0], (c, e)), "gate_proj": n(ks[1], (e, c, f)),
+            "up_proj": n(ks[2], (e, c, f)), "down_proj": n(ks[3], (e, f, c))}
+
+
+def _softmax_layer_by_hand(h, p, topk, renormalise=True):
+    r = jax.nn.softmax(h @ p["router"], axis=-1)
+    top_r, top_i = jax.lax.top_k(r, topk)
+    w = top_r / top_r.sum(-1, keepdims=True) if renormalise else top_r
+    out = jnp.zeros_like(h)
+    for e in range(p["router"].shape[1]):
+        w_e = jnp.where(top_i == e, w, 0.0).sum(-1)
+        y = (jax.nn.silu(h @ p["gate_proj"][e]) * (h @ p["up_proj"][e])
+             ) @ p["down_proj"][e]
+        out = out + w_e[:, None] * y
+    return out
+
+
+@pytest.mark.parametrize("norm_topk", [True, False],
+                         ids=["renormalised", "as_scored"])
+def test_softmax_routed_shares_add_up_to_the_uncut_layer(norm_topk):
+    """Sixteen softmax-routed experts over eight chips, two a chip: the
+    routed parts the eight shares compute (each routes over all sixteen
+    and runs its own two) sum to the uncut layer, computed by hand; one
+    share alone is not the layer; there is no shared expert."""
+    from gym_tpu.models.moe import HeldExperts
+    c, f, e, k = 32, 16, 16, 4
+    p = _held_params(jax.random.PRNGKey(3), c, f, e)
+    h = jax.random.normal(jax.random.PRNGKey(4), (24, c), jnp.float32)
+    want = _softmax_layer_by_hand(h, p, k, norm_topk)
+    total = jnp.zeros_like(h)
+    for lo in range(0, e, 2):
+        layer = HeldExperts(hidden=c, width=f, n_experts=e, topk=k,
+                            held=(lo, lo + 2), n_shared=0,
+                            norm_topk=norm_topk, param_dtype=jnp.float32,
+                            score_fn="softmax")
+        share = {n: (v[lo:lo + 2] if n != "router" else v)
+                 for n, v in p.items()}
+        routed, shared = layer.apply({"params": share}, h)
+        assert not np.asarray(shared).any()
+        total = total + routed
+    np.testing.assert_allclose(total, want, atol=2e-5)
+    assert np.abs(np.asarray(routed - want)).max() > 0.05
+
+
+def test_held_experts_score_functions_differ_and_unknown_is_refused():
+    """``sigmoid`` (the default, as every earlier caller has it) and
+    ``softmax`` weigh the same chosen experts differently when the
+    weights are not renormalised; another name is refused."""
+    from gym_tpu.models.moe import HeldExperts
+    c, f, e, k = 32, 16, 8, 2
+    p = _held_params(jax.random.PRNGKey(5), c, f, e)
+    h = jax.random.normal(jax.random.PRNGKey(6), (12, c), jnp.float32)
+    outs = {}
+    for fn in ("sigmoid", "softmax"):
+        layer = HeldExperts(hidden=c, width=f, n_experts=e, topk=k,
+                            held=(0, e), n_shared=0, norm_topk=False,
+                            param_dtype=jnp.float32, score_fn=fn)
+        outs[fn], _ = layer.apply({"params": p}, h)
+    np.testing.assert_allclose(
+        outs["softmax"], _softmax_layer_by_hand(h, p, k, False), atol=2e-5)
+    assert np.abs(np.asarray(outs["sigmoid"] - outs["softmax"])).max() > 0.05
+    assert HeldExperts(hidden=c, width=f, n_experts=e, topk=k, held=(0, e),
+                       n_shared=0).score_fn == "sigmoid"
+    with pytest.raises(ValueError, match="score_fn"):
+        HeldExperts(hidden=c, width=f, n_experts=e, topk=k, held=(0, e),
+                    n_shared=0, score_fn="tanh").apply({"params": p}, h)

@@ -110,3 +110,198 @@ def test_dispatch_of_the_grouped_attend(monkeypatch, dtype, kv, page, window,
                                 window=window) == pa.GATHER
 
 
+
+
+# -- learned sparse attention (ops/sparse_attention.py) ----------------------
+
+import gym_tpu.ops.sparse_attention as sa  # noqa: E402
+
+J, DI = 3, 8        # index heads and their dimension
+
+
+def _topk_mask(scores, seen, k):
+    """The kept set as ``lax.top_k`` names it (ties to the lower index)."""
+    s = jnp.where(seen, jnp.where(scores == 0, 0.0, scores), -jnp.inf)
+    _v, idx = jax.lax.top_k(s, min(k, s.shape[-1]))
+    mask = jnp.zeros(s.shape, bool)
+    mask = jnp.put_along_axis(mask, idx, True, axis=-1, inplace=False)
+    return mask & seen
+
+
+def _tied_scores(rng, shape):
+    """Scores with plateaus of equal values across the threshold: a few
+    distinct levels, zeros of both signs, and some distinct values."""
+    levels = np.asarray([-2.5, -0.0, 0.0, 0.75, 0.75, 1.5, 3.0], np.float32)
+    s = levels[rng.integers(0, len(levels), shape)]
+    distinct = rng.random(shape) < 0.3
+    return jnp.asarray(np.where(distinct, rng.standard_normal(shape), s),
+                       jnp.float32)
+
+
+@pytest.mark.parametrize("k", [1, 5, 16, 64], ids=lambda k: f"k{k}")
+def test_kept_set_equals_top_k_with_planted_ties(k):
+    """The threshold search keeps exactly ``lax.top_k``'s set on seeded
+    scores with planted ties at the threshold, rows with fewer than ``k``
+    keys and ``-0.0`` beside ``0.0``."""
+    rng = np.random.default_rng(k)
+    scores = _tied_scores(rng, (6, 64))
+    n_seen = jnp.asarray([1, 3, 17, 40, 63, 64])
+    seen = jnp.arange(64)[None, :] < n_seen[:, None]
+    keys = jnp.where(seen, sa.sortable(scores), 0)
+    got = sa.kept_mask(keys, k)
+    want = _topk_mask(scores, seen, k)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert (np.asarray(got).sum(-1)
+            == np.minimum(np.asarray(n_seen), k)).all()
+
+
+def test_sortable_orders_as_the_numbers_do():
+    x = jnp.asarray([-jnp.inf, -3.0, -1e-30, -0.0, 0.0, 1e-30, 2.0,
+                     jnp.inf], jnp.float32)
+    u = np.asarray(sa.sortable(x)).astype(np.int64)
+    assert u[3] == u[4] and (np.diff(np.delete(u, 3)) > 0).all()
+    assert u.min() > 0
+
+
+def _sparse_case(rng, b, t, dtype):
+    q = jnp.asarray(rng.standard_normal((b, KVH, t, G, HD)), dtype)
+    qi = jnp.asarray(rng.standard_normal((b, t, J, DI)), dtype)
+    wi = jnp.asarray(rng.standard_normal((b, t, J)), jnp.float32)
+    kp = jnp.asarray(rng.standard_normal((POOL, KPAGE, KVH * HD)), dtype)
+    vp = jnp.asarray(rng.standard_normal((POOL, KPAGE, KVH * HD)), dtype)
+    kip = jnp.asarray(rng.standard_normal((POOL, KPAGE * DI)), dtype)
+    bt = jnp.asarray(1 + rng.permutation(POOL - 1)[:b * MB].reshape(b, MB),
+                     jnp.int32)
+    return q, qi, wi, kp, vp, kip, bt
+
+
+def _masked(q, qi, wi, kp, vp, kip, bt, pos, topk):
+    """Plain grouped attention over the positions ``lax.top_k`` keeps of
+    the index scores, from gathered windows."""
+    b, kvh, t, _g, hd = q.shape
+    s = bt.shape[1] * kp.shape[1]
+    k = kp[bt].reshape(b, s, kvh, hd).astype(jnp.float32)
+    v = vp[bt].reshape(b, s, kvh, hd).astype(jnp.float32)
+    ki = kip[bt].reshape(b, s, -1)
+    scores = sa.index_scores(qi, wi, ki)
+    qpos = pos[:, None] + jnp.arange(t)[None]
+    seen = jnp.arange(s)[None, None] <= qpos[..., None]
+    keep = _topk_mask(scores, seen, topk)
+    att = jnp.einsum("bktgd,bskd->bktgs", q.astype(jnp.float32),
+                     k) / np.sqrt(hd)
+    att = jax.nn.softmax(jnp.where(keep[:, None, :, None], att, -jnp.inf),
+                         -1)
+    return jnp.einsum("bktgs,bskd->bktgd", att, v)
+
+
+@pytest.mark.parametrize("topk", [6, 200], ids=["topk6", "keeps_all"])
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 1e-5),
+                                       (jnp.bfloat16, 3e-2)],
+                         ids=["f32", "bf16"])
+def test_sparse_decode_attends_over_the_kept_keys(dtype, tol, topk):
+    """A decode step's rows at different depths: the gathered kept keys
+    give what masked attention over ``lax.top_k``'s set gives; a row of
+    at most ``topk`` positions (every row at ``topk`` 200) is plain
+    grouped attention."""
+    rng = np.random.default_rng(topk)
+    q, qi, wi, kp, vp, kip, bt = _sparse_case(rng, 4, 1, dtype)
+    pos = jnp.asarray([0, 5, 40, 95], jnp.int32)
+    out = sa.attend_rows(q, qi, wi, kp, vp, kip, bt, pos, topk)
+    assert out.shape == q.shape and out.dtype == q.dtype
+    want = _masked(q, qi, wi, kp, vp, kip, bt, pos, topk)
+    assert float(jnp.abs(out.astype(jnp.float32) - want).max()) < tol
+    if topk >= MB * KPAGE:
+        plain = _gathered(q, kp, vp, bt, pos, 0)
+        assert float(jnp.abs(out.astype(jnp.float32) - plain).max()) < tol
+
+
+@pytest.mark.parametrize("topk", [6, 200], ids=["topk6", "keeps_all"])
+@pytest.mark.parametrize("ties", [False, True], ids=["distinct", "ties"])
+def test_sparse_prefill_block_attends_over_the_kept_keys(topk, ties):
+    """A block of 24 queries that starts mid-row, key blocks of 16 of
+    which the later ones are never visited: every query keeps its own
+    set, the exact one also where index scores tie at the threshold
+    (index keys repeated along the row)."""
+    rng = np.random.default_rng(topk + ties)
+    q, qi, wi, kp, vp, kip, bt = _sparse_case(rng, 2, 24, jnp.float32)
+    if ties:
+        # each row's index keys take 5 distinct values only
+        few = jnp.asarray(rng.standard_normal((5, DI)), jnp.float32)
+        pick = jnp.asarray(rng.integers(0, 5, (POOL, KPAGE)))
+        kip = few[pick].reshape(POOL, KPAGE * DI)
+    pos = jnp.asarray([3, 37], jnp.int32)
+    s = MB * KPAGE
+    out = sa.attend_block(
+        q, qi, wi, kp[bt].reshape(2, s, KVH, HD),
+        vp[bt].reshape(2, s, KVH, HD), kip[bt].reshape(2, s, DI), pos,
+        topk, key_block=16)
+    want = _masked(q, qi, wi, kp, vp, kip, bt, pos, topk)
+    assert float(jnp.abs(out - want).max()) < 1e-5
+    if topk >= s:
+        plain = _gathered(q, kp, vp, bt, pos, 0)
+        assert float(jnp.abs(out - plain).max()) < 1e-5
+
+
+def test_dispatch_of_the_sparse_attend():
+    """A layer with ``sparse_topk`` takes the sparse path on every
+    backend and dtype; its id holds no ``gather``."""
+    for dtype in (jnp.float32, jnp.bfloat16):
+        assert pa.paged_attend_path(512, 16, dtype, dtype, head_dim=128,
+                                    sparse_topk=2048) == pa.SPARSE
+    assert pa.GATHER not in pa.SPARSE
+
+
+@pytest.mark.parametrize("s,k", [(64, 5), (96, 40), (384, 100)],
+                         ids=["one_block", "three_blocks", "blocks_of_128"])
+def test_compact_lists_the_marked_positions_in_rising_order(s, k):
+    """No sort, scatter or gather: every row's marked positions come out
+    as ``nonzero`` gives them, rows with none and with exactly ``k``
+    marked among them."""
+    rng = np.random.default_rng(s)
+    counts = [0, 1, k // 2, k, k - 1]
+    mask = np.zeros((len(counts), s), bool)
+    for r, n in enumerate(counts):
+        mask[r, rng.permutation(s)[:n]] = True
+    idx, there = sa.compact(jnp.asarray(mask), k)
+    assert idx.shape == there.shape == (len(counts), k)
+    for r, n in enumerate(counts):
+        assert np.asarray(there[r]).sum() == n
+        np.testing.assert_array_equal(np.asarray(idx[r])[:n],
+                                      np.nonzero(mask[r])[0])
+        assert not np.asarray(idx[r])[n:].any()
+
+
+@pytest.mark.parametrize("ties", [False, True], ids=["distinct", "ties"])
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-5),
+                                       (jnp.bfloat16, 3e-2)],
+                         ids=["f32", "bf16"])
+def test_masked_prefill_kernel_equals_the_plain_walk(monkeypatch, dtype, tol,
+                                                     ties):
+    """The Pallas kernel of a prefill block's attend (whole tiles: 256
+    queries, key steps of 512, head dimension 128) under the interpreter
+    against the same block in ``jax.numpy``: two rows that start at
+    different depths, the second past the first key step, keys past the
+    block's last position never read (NaNs planted there)."""
+    kvh, g, hd, di, tc, s, topk = 2, 2, 128, 8, 256, 1536, 100
+    rng = np.random.default_rng(int(ties))
+    q = jnp.asarray(rng.standard_normal((2, kvh, tc, g, hd)), dtype)
+    qi = jnp.asarray(rng.standard_normal((2, tc, J, di)), dtype)
+    wi = jnp.asarray(rng.standard_normal((2, tc, J)), jnp.float32)
+    k = jnp.asarray(rng.standard_normal((2, s, kvh, hd)), dtype)
+    v = jnp.asarray(rng.standard_normal((2, s, kvh, hd)), dtype)
+    ki = jnp.asarray(rng.standard_normal((2, s, di)), dtype)
+    if ties:
+        few = jnp.asarray(rng.standard_normal((7, di)), dtype)
+        ki = few[jnp.asarray(rng.integers(0, 7, (2, s)))]
+    pos = jnp.asarray([40, 700], jnp.int32)
+    v = v.at[:, 700 + tc:].set(jnp.nan)
+    args = (q, qi, wi, k, v, ki, pos, topk)
+    assert not sa.masked_attend_shapes_ok(tc, s, 256, hd, dtype)
+    want = sa.attend_block(*args, key_block=512)
+    monkeypatch.setattr(pa, "INTERPRET", True)
+    assert sa.masked_attend_shapes_ok(tc, s, 512, hd, dtype)
+    got = sa.attend_block(*args, key_block=512)
+    assert got.shape == q.shape and got.dtype == q.dtype
+    assert bool(jnp.isfinite(got.astype(jnp.float32)).all())
+    assert float(jnp.abs(got.astype(jnp.float32)
+                         - want.astype(jnp.float32)).max()) < tol
