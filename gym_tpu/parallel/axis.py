@@ -125,6 +125,21 @@ class AxisCtx:
             idx = idx * size + lax.axis_index(name)
         return idx
 
+    def fold_counter(self, step: jnp.ndarray) -> jnp.ndarray:
+        """The step counter as ONE value for the nodes folded on this
+        device. Invariant: all nodes of a device block hold the same
+        ``step`` (``init_state`` zeroes it, every step program adds 1 to
+        all of them, a checkpoint stores what such a program wrote, and
+        ``elastic.reshard_state`` refuses rows that differ), so the
+        reduction over the vmapped axis loses nothing — and its result is
+        unbatched under the fold's ``vmap``. A ``lax.cond`` gated on it
+        (every strategy's H-gate) therefore stays an XLA ``conditional``;
+        on the batched counter it is a ``select`` that computes both
+        branches every step and rewrites every leaf they return."""
+        if VNODE_AXIS not in self.axes:
+            return step
+        return lax.pmax(step, VNODE_AXIS)
+
     def broadcast_from(self, tree: PyTree, src: int = 0) -> PyTree:
         """Every node receives node `src`'s value (reference ``broadcast``).
 

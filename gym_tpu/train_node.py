@@ -111,7 +111,10 @@ def make_train_step(loss_model: LossModel, strategy: Strategy, ctx: AxisCtx,
             state = state.replace(
                 params=constrain_params(state.params, param_specs)
             )
-        step_rng = jax.random.fold_in(state.rng, state.step)
+        # one counter a fold, unbatched under its vmap: the strategies'
+        # gates on it stay conditionals (AxisCtx.fold_counter)
+        step = ctx.fold_counter(state.step)
+        step_rng = jax.random.fold_in(state.rng, step)
         if ctx.seq_axes:
             # decorrelate dropout across a node's sequence chunks
             step_rng = jax.random.fold_in(step_rng, ctx.seq_index())
@@ -154,14 +157,14 @@ def make_train_step(loss_model: LossModel, strategy: Strategy, ctx: AxisCtx,
 
         with jax.named_scope("strategy"):
             params, sstate, metrics = strategy.step(
-                grads, state.params, state.strategy_state, state.step, ctx
+                grads, state.params, state.strategy_state, step, ctx
             )
         params = constrain_params(params, param_specs)
         new_state = state.replace(
             params=params,
             model_state=model_state,
             strategy_state=sstate,
-            step=state.step + 1,
+            step=step + 1,
         )
         metrics = dict(metrics)
         metrics["loss"] = loss
@@ -189,7 +192,17 @@ def make_multi_train_step(loss_model: LossModel, strategy: Strategy,
     node_step = make_train_step(loss_model, strategy, ctx, param_specs,
                                 skip_nonfinite)
 
+    return scan_steps(node_step, ctx)
+
+
+def scan_steps(node_step, ctx: AxisCtx):
+    """``node_step`` chained over a leading [S] axis of batches. The step
+    counter enters the scan's carry as the fold's one value
+    (``AxisCtx.fold_counter``), so it rides the carry unbatched and every
+    iteration's gate is a conditional, as in a single dispatch."""
+
     def node_multi(state: TrainState, batches):
+        state = state.replace(step=ctx.fold_counter(state.step))
         return jax.lax.scan(node_step, state, batches)
 
     return node_multi
@@ -263,7 +276,8 @@ def make_pipeline_train_step(pipe_model, strategy: Strategy, ctx: AxisCtx,
         if param_specs is not None:
             state = state.replace(
                 params=constrain_params(state.params, param_specs))
-        step_rng = jax.random.fold_in(state.rng, state.step)
+        step = ctx.fold_counter(state.step)  # as in make_train_step
+        step_rng = jax.random.fold_in(state.rng, step)
         if ctx.seq_axes:
             # decorrelate dropout across a node's sequence chunks (same
             # contract as make_train_step — without it, pp×cp×dropout
@@ -301,14 +315,14 @@ def make_pipeline_train_step(pipe_model, strategy: Strategy, ctx: AxisCtx,
             )
 
         params, sstate, metrics = strategy.step(
-            grads, state.params, state.strategy_state, state.step, ctx
+            grads, state.params, state.strategy_state, step, ctx
         )
         params = constrain_params(params, param_specs)
         new_state = state.replace(
             params=params,
             model_state=model_state,
             strategy_state=sstate,
-            step=state.step + 1,
+            step=step + 1,
         )
         metrics = dict(metrics)
         metrics["loss"] = loss

@@ -731,7 +731,7 @@ class Trainer:
             from jax.sharding import PartitionSpec as P
             from .parallel.axis import NODE_AXIS
             from .train_node import (make_pipeline_eval_step,
-                                     make_pipeline_train_step)
+                                     make_pipeline_train_step, scan_steps)
             pstep = make_pipeline_train_step(pipe_model, strategy,
                                              runtime.ctx, skip_nonfinite,
                                              param_specs)
@@ -742,7 +742,7 @@ class Trainer:
             multi_step = None
             if steps_per_call > 1:
                 multi_step = runtime.compile(
-                    lambda st, bs: jax.lax.scan(pstep, st, bs), **io_specs)
+                    scan_steps(pstep, runtime.ctx), **io_specs)
             eval_pipe = pipe_model
             if pipe_model.compute_dtype is not None:
                 from .parallel.pipeline_model import PipelinedGPTLossModel

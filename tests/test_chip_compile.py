@@ -362,3 +362,141 @@ def test_keye_programs_fit_the_chip_and_copy_no_pool(v5e_chip, monkeypatch,
                           + r"\S* (copy|transpose|convert)\(", line)]
     assert not moved, moved[:2]
     assert re.search(r"input_output_alias=\{.*may-alias", hlo)
+
+
+# -- the fold's H-gate (ISSUE 32) -------------------------------------------
+# The fold cell as trained (perfbench/configs/gpt2-base.json,
+# perfbench/traffic/train-fold4-diloco.json): GPT-2 base, 4 nodes folded by
+# vmap on one chip, batch 2 x 1,024 a node with remat, flash attention, bf16
+# autocast, AdamW 6e-4 under DiLoCo H=100.
+FOLD_NODES, FOLD_BATCH = 4, 2
+# the smallest matrix leaf of GPT-2 base over the fold's four nodes
+# (attn.c_proj's [4, 768, 768]): a select this large under the `strategy`
+# scope is the gate choosing between old and new state
+FOLD_LEAF_ELEMS = FOLD_NODES * 768 * 768
+
+
+def _strategy_selects(hlo):
+    """Element counts of the selects under the ``strategy`` scope."""
+    import re
+    sizes = []
+    for m in re.finditer(
+            r"= \w+\[([\d,]*)\]\S* select\([^\n]*op_name=\"([^\"]*)\"", hlo):
+        if re.search(r"(^|[/(])strategy([/)]|$)", m.group(2)):
+            n = 1
+            for d in filter(None, m.group(1).split(",")):
+                n *= int(d)
+            sizes.append(n)
+    return sizes
+
+
+def _conditionals(hlo):
+    return hlo.count(" conditional(")
+
+
+@pytest.mark.parametrize("program", ["train_step", "multi_step"])
+def test_fold_cell_keeps_the_h_gate_a_conditional(v5e_chip, monkeypatch,
+                                                  program):
+    """The fold cell's step program, and ``multi_step`` over two steps,
+    compiled for the described chip: the step counter reaches the gate
+    unbatched (``AxisCtx.fold_counter``), so the program holds an XLA
+    ``conditional`` and, under the ``strategy`` scope, no ``select`` of a
+    parameter leaf's size. With the counter batched over the fold the
+    gate was 150 such selects and no conditional: both branches every
+    step, every leaf of parameters, master and momentum rewritten."""
+    from gym_tpu.models.base import LossModel
+    from gym_tpu.models.nanogpt import GPT, GPTConfig
+    from gym_tpu.parallel import NodeRuntime
+    from gym_tpu.strategy import DiLoCoStrategy, OptimSpec
+    from gym_tpu.train_node import (make_init_fn, make_multi_train_step,
+                                    make_train_step)
+    monkeypatch.setattr(flash_attention, "_on_tpu", lambda: True)
+    k, b, seq = FOLD_NODES, FOLD_BATCH, 1024
+    (device,) = v5e_chip.device_set
+    runtime = NodeRuntime.create(k, [device])
+    assert runtime.n_virt == k
+    model = LossModel(
+        GPT(GPTConfig(block_size=seq, vocab_size=50304, n_layer=12,
+                      n_head=12, n_embd=768, dropout=0.0,
+                      attn_impl="flash", remat=True)), jnp.bfloat16)
+    strategy = DiLoCoStrategy(
+        OptimSpec("adamw", lr=6e-4), H=100, lr_scheduler="lambda_cosine",
+        lr_scheduler_kwargs={"warmup_steps": 100})
+    strategy.finalize(1000)
+    micro = (jnp.zeros((b, seq), jnp.int32),) * 2
+    init_fn = make_init_fn(model, strategy, micro, 0, None, ctx=runtime.ctx)
+    init = runtime.compile(lambda _: init_fn(runtime.ctx.node_index()),
+                           donate_state=False)
+    node = runtime.node_sharding
+    state = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=node),
+        jax.eval_shape(init, jax.ShapeDtypeStruct((k,), jnp.int32,
+                                                  sharding=node)))
+    assert state.step.shape == (k,)
+    make, lead = ((make_train_step, ()) if program == "train_step"
+                  else (make_multi_train_step, (2,)))
+    batch = (jax.ShapeDtypeStruct((k,) + lead + (1, b, seq), jnp.int32,
+                                  sharding=node),) * 2
+    step = runtime.compile(make(model, strategy, runtime.ctx, None, False),
+                           donate_batch=True)
+    compiled = step.lower(state, batch).compile()
+    mem = compiled.memory_analysis()
+    assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes + mem.temp_size_in_bytes) < HBM_BYTES
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" in hlo
+    assert _conditionals(hlo) >= 1
+    sizes = _strategy_selects(hlo)
+    assert max(sizes, default=0) < FOLD_LEAF_ELEMS, sorted(sizes)[-3:]
+
+
+def _gated(name):
+    from gym_tpu.strategy import (DecoupledMomentumStrategy, FedAvgStrategy,
+                                  NoLoCoStrategy, OptimSpec, SPARTAStrategy)
+    sgd = OptimSpec("sgd", lr=0.1)
+    return {
+        "fedavg": lambda: FedAvgStrategy(inner_optim=sgd, H=2),
+        # SPARTA exchanges every step at its default interval of 1 and
+        # has no gate then; the gate exists from interval 2
+        "sparta": lambda: SPARTAStrategy(inner_optim=sgd, p_sparta=0.5,
+                                         interval=2),
+        "demo": lambda: DecoupledMomentumStrategy(optim_spec=sgd, frac=0.2,
+                                                  H=2),
+        "noloco": lambda: NoLoCoStrategy(optim_spec=sgd, H=2),
+    }[name]()
+
+
+@pytest.mark.parametrize("name", ["fedavg", "sparta", "demo", "noloco"])
+def test_gated_strategies_keep_a_conditional_on_a_fold(name):
+    """Every strategy that gates its communication on the step counter
+    takes it from ``node_step``: on a 4-node fold (this sandbox's CPU, a
+    tiny model) the compiled step holds the ``conditional`` and the
+    communication runs on its steps only. None of these gates is per
+    node: aliveness and participation masks inside the branches are, and
+    stay ``where``s."""
+    import numpy as np
+    from gym_tpu.models.base import LossModel
+    from gym_tpu.parallel import NodeRuntime
+    from gym_tpu.train_node import make_init_fn, make_train_step
+    from test_trainer_e2e import TinyLossModel
+    k = 4
+    rng = np.random.default_rng(0)
+    x = rng.normal(size=(k, 1, 8, 8, 8)).astype(np.float32)
+    y = rng.integers(0, 10, size=(k, 1, 8)).astype(np.int32)
+    rt = NodeRuntime.create(k, jax.devices("cpu")[:1])
+    assert rt.n_virt == k
+    lm, strat = LossModel(TinyLossModel()), _gated(name)
+    strat.finalize(4)
+    state = rt.init_state(make_init_fn(lm, strat, (x[0, 0], y[0, 0]),
+                                       seed=0, ctx=rt.ctx))
+    step = rt.compile(make_train_step(lm, strat, rt.ctx))
+    batch = rt.shard_batch((x, y))
+    assert _conditionals(step.lower(state, batch).compile().as_text()) >= 1
+    comm = []
+    for _ in range(3):
+        state, m = step(state, batch)
+        comm.append(float(np.asarray(m["comm_bytes"])[0]))
+    # steps 0, 1, 2: FedAvg, DeMo and NoLoCo communicate at 2 (H=2, never
+    # at 0); SPARTA's interval has no `step > 0` and exchanges at 0 and 2
+    assert [c > 0 for c in comm] == [name == "sparta", False, True]
+    assert state.step.shape == (k,)

@@ -154,6 +154,25 @@ def test_reshard_rejects_per_node_state():
         reshard_state(per_node, elastic_meta(4, STACKED_LAYOUT, N), target)
 
 
+@pytest.mark.parametrize("k_to", [6, 2, 4], ids=["join", "leave", "same"])
+def test_reshard_hands_the_fold_one_counter(k_to):
+    """The step programs read ONE step counter a fold
+    (``AxisCtx.fold_counter``). A membership change keeps that true: the
+    restored counter is the checkpoint's on every one of the K' rows, and
+    a checkpoint whose rows disagree is refused instead of being handed
+    to a fold (at the same K the rows are what the lockstep program
+    wrote, and are passed through)."""
+    saved = _mk_state(4, step=7)
+    out = reshard_state(saved, elastic_meta(4, STACKED_LAYOUT, N),
+                        _mk_state(k_to, seed=9, step=0))
+    np.testing.assert_array_equal(np.asarray(out.step), np.full(k_to, 7))
+    if k_to != 4:
+        torn = saved.replace(step=jnp.asarray([7, 7, 6, 7], jnp.int32))
+        with pytest.raises(NodeCountMismatchError, match="rows differ"):
+            reshard_state(torn, elastic_meta(4, STACKED_LAYOUT, N),
+                          _mk_state(k_to, seed=9, step=0))
+
+
 def test_zero_step_rejects_mismatched_shard():
     """Satellite: feeding a K'-sized optimizer shard to a K-node step
     raises the typed error naming both sizes (instead of a shape error

@@ -784,3 +784,162 @@ def test_compressed_link_rejects_incoherent_compositions():
         DiLoCoStrategy(H=2, codec="int8", shard_outer=True)
     with pytest.raises(ValueError, match="participation"):
         DiLoCoStrategy(H=2, codec="int8", participation=0.5)
+
+
+# -- one step counter a fold (ISSUE 32) -------------------------------------
+# The step programs hand the strategies the fold's ONE counter
+# (AxisCtx.fold_counter), so DiLoCo's H-gate is a real conditional under
+# the vmap. These hold the arithmetic to what the spread program (one node
+# a device, where the gate always was a conditional) computes.
+
+_FOLD_K, _FOLD_H, _FOLD_STEPS = 4, 3, 7
+
+
+def _fold_batches():
+    rng = np.random.default_rng(32)
+    x = rng.normal(size=(_FOLD_STEPS, _FOLD_K, 1, 8, 8, 8)).astype(np.float32)
+    y = rng.integers(0, 10, size=(_FOLD_STEPS, _FOLD_K, 1, 8)).astype(np.int32)
+    return x, y
+
+
+def _fold_build(n_devices):
+    from gym_tpu.models.base import LossModel
+    from gym_tpu.train_node import make_init_fn
+    from test_trainer_e2e import TinyLossModel
+    x, y = _fold_batches()
+    rt = NodeRuntime.create(_FOLD_K, jax.devices()[:n_devices])
+    assert rt.n_virt == _FOLD_K // n_devices
+    lm = LossModel(TinyLossModel())
+    strat = DiLoCoStrategy(optim_spec=OptimSpec("adamw", lr=1e-2), H=_FOLD_H)
+    strat.finalize(_FOLD_STEPS)
+    init = make_init_fn(lm, strat, (x[0, 0, 0], y[0, 0, 0]), seed=0,
+                        ctx=rt.ctx)
+    return rt, lm, strat, rt.init_state(init)
+
+
+def _outer_state(state):
+    """(master, outer momentum) of a DiLoCo TrainState, as host trees."""
+    mod = jax.device_get(state.strategy_state)["modules"][0]
+    return mod["master"], mod["outer_opt"]
+
+
+def _fold_trajectory(n_devices):
+    """7 single-step dispatches: per step (params, master, momentum,
+    comm_bytes, step) on the host."""
+    from gym_tpu.train_node import make_train_step
+    x, y = _fold_batches()
+    rt, lm, strat, state = _fold_build(n_devices)
+    assert state.step.shape == (_FOLD_K,)
+    step = rt.compile(make_train_step(lm, strat, rt.ctx))
+    out = []
+    for t in range(_FOLD_STEPS):
+        state, m = step(state, rt.shard_batch((x[t], y[t])))
+        master, mom = _outer_state(state)
+        out.append({"params": jax.device_get(state.params),
+                    "master": master, "momentum": mom,
+                    "comm": np.asarray(m["comm_bytes"]),
+                    "step": np.asarray(state.step)})
+    return out
+
+
+@pytest.fixture(scope="module")
+def fold_run():
+    return _fold_trajectory(1)
+
+
+def test_fold_diloco_matches_spread_step_by_step(fold_run):
+    """4 nodes folded on one device against the same 4 nodes on 4 devices,
+    over two outer rounds: parameters, master, outer momentum and
+    comm_bytes agree at every step (a mean over the vmap axis and one
+    over devices may sum in another order: the tolerance of the other
+    fold-against-spread tests here)."""
+    spread = _fold_trajectory(_FOLD_K)
+    for t, (f, s) in enumerate(zip(fold_run, spread)):
+        for key in ("params", "master", "momentum"):
+            for a, b in zip(jax.tree.leaves(f[key]),
+                            jax.tree.leaves(s[key])):
+                np.testing.assert_allclose(a, b, atol=1e-6, rtol=1e-5,
+                                           err_msg=f"step {t} {key}")
+        np.testing.assert_array_equal(f["comm"], s["comm"])
+        # the global state keeps its [K] counter on both placements
+        np.testing.assert_array_equal(f["step"], np.full(_FOLD_K, t + 1))
+        np.testing.assert_array_equal(s["step"], f["step"])
+
+
+def test_fold_outer_step_runs_on_its_steps_only(fold_run):
+    """The branch is taken at steps 3 and 6 (H=3) and nowhere else: there
+    comm_bytes is positive and the master moves; on every other step
+    master and outer momentum are bit-identical to the step before."""
+    outer_steps = (3, 6)        # t % H == 0 and t > 0, of 7 steps
+    for t in range(1, _FOLD_STEPS):
+        before, now = fold_run[t - 1], fold_run[t]
+        same = all(
+            np.array_equal(a, b) for key in ("master", "momentum")
+            for a, b in zip(jax.tree.leaves(before[key]),
+                            jax.tree.leaves(now[key])))
+        if t in outer_steps:
+            assert np.all(now["comm"] > 0) and not same, t
+        else:
+            assert np.all(now["comm"] == 0) and same, t
+
+
+def test_fold_multi_step_bit_identical_to_single_steps(fold_run):
+    """multi_step over the same 7 batches: the counter rides the scan's
+    carry, the gate fires on the same steps, and the final state equals
+    the 7 single dispatches' bit for bit."""
+    from gym_tpu.train_node import make_multi_train_step
+    x, y = _fold_batches()
+    rt, lm, strat, state = _fold_build(1)
+    multi = rt.compile(make_multi_train_step(lm, strat, rt.ctx))
+    state, m = multi(state, rt.shard_batch((np.moveaxis(x, 0, 1),
+                                            np.moveaxis(y, 0, 1))))
+    assert state.step.shape == (_FOLD_K,)
+    np.testing.assert_array_equal(np.asarray(state.step),
+                                  np.full(_FOLD_K, _FOLD_STEPS))
+    last = fold_run[-1]
+    master, mom = _outer_state(state)
+    for want, got in ((last["params"], jax.device_get(state.params)),
+                      (last["master"], master), (last["momentum"], mom)):
+        for a, b in zip(jax.tree.leaves(want), jax.tree.leaves(got)):
+            np.testing.assert_array_equal(a, b)
+    np.testing.assert_array_equal(
+        np.asarray(m["comm_bytes"]),
+        np.stack([f["comm"] for f in fold_run], axis=1))
+
+
+def test_fold_checkpoint_mid_round_keeps_the_outer_schedule(tmp_path):
+    """A checkpoint written mid-round (step 4 of rounds ending at 3 and 6)
+    restores into a fold whose next outer step lands on step 6, as in the
+    run that never stopped; the checkpointed counter is [K]."""
+    import orbax.checkpoint as ocp
+    from gym_tpu import Trainer
+    from test_trainer_e2e import TinyLossModel, blobs
+
+    def fit(max_steps, save_dir):
+        return Trainer(TinyLossModel(), blobs(256, seed=5), None).fit(
+            strategy=DiLoCoStrategy(optim_spec=OptimSpec("adamw", lr=1e-3),
+                                    H=_FOLD_H),
+            num_nodes=_FOLD_K, devices=[0], max_steps=max_steps,
+            batch_size=16, minibatch_size=8, val_interval=0,
+            show_progress=False, seed=11, checkpoint_interval=4,
+            save_dir=save_dir, run_name="fold_ckpt",
+            log_dir=str(tmp_path / "logs"))
+
+    straight = fit(7, str(tmp_path / "straight"))
+    fit(4, str(tmp_path / "resume"))
+    mgr = ocp.CheckpointManager(
+        str(tmp_path / "resume" / "fold_ckpt"),
+        options=ocp.CheckpointManagerOptions(create=False, read_only=True))
+    saved = mgr.restore(4, args=ocp.args.Composite(
+        state=ocp.args.StandardRestore()))["state"]
+    mgr.close()
+    np.testing.assert_array_equal(np.asarray(saved["step"]),
+                                  np.full(_FOLD_K, 4))
+    resumed = fit(7, str(tmp_path / "resume"))
+    assert [s for s, _ in resumed.history["train_loss"]] == [4, 5, 6]
+    taken = [s for s, c in resumed.history["comm_bytes"] if c > 0]
+    assert taken == [6]
+    assert [s for s, c in straight.history["comm_bytes"] if c > 0] == [3, 6]
+    for a, b in zip(jax.tree.leaves(straight.params),
+                    jax.tree.leaves(resumed.params)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
