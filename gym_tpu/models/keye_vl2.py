@@ -21,9 +21,11 @@ Per layer, input ``x`` [T, hidden] (float32 residual stream)::
 After the last layer ``rms``, then ``logits = y W_head`` (untied) over
 the rows of the vocabulary this chip holds.
 
-Serving only, paged only (as ``cohere2_moe.py``, whose pool arithmetic and
-query blocks it shares): a layer keeps ``k``, ``v`` and ``kI`` (after
-norm and rotary) a position in the engine's pools, ``[kv_pages,
+The norm, the grouped projections with their q/k norms and rotation, the
+embedding and the head are ``decoder_parts.py``'s, shared with
+``brumby.py``. Serving only, paged only (as ``cohere2_moe.py``, whose
+pool arithmetic and query blocks it shares): a layer keeps ``k``, ``v`` and
+``kI`` (after norm and rotary) a position in the engine's pools, ``[kv_pages,
 page_size, kv_heads * head_dim]`` twice and ``[kv_pages, page_size *
 indexer_head_dim]`` (a page's index keys side by side on one row). Weights and pools in ``weights_dtype`` /
 ``kv_dtype``; residual, norms, softmaxes, router and index scores in
@@ -40,6 +42,9 @@ import jax
 import jax.numpy as jnp
 
 from .cohere2_moe import _DTYPES, by_query_block, pool_slots
+from .decoder_parts import (RMSNorm, embed_tokens, key_heads, project_out,
+                            qkvo_params, query_heads, rotate_half,
+                            untied_head, value_heads)
 from .moe import HeldExperts
 
 FAMILY = "KeyeVL2"
@@ -119,25 +124,6 @@ class KeyeVL2Config:
         return jax.tree.map(lambda x: jnp.asarray(x, dt), params)
 
 
-def rotate_half(x, pos, theta: float):
-    """Rotary embedding over the whole last axis, lane ``i`` paired with
-    lane ``i + d/2``: ``x`` [..., t, *, d] float32 with ``pos``
-    broadcastable to ``x``'s leading axes up to ``t``."""
-    d = x.shape[-1]
-    inv = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
-    ang = pos[..., None].astype(jnp.float32) * inv            # [..., d/2]
-    cos = jnp.concatenate([jnp.cos(ang)] * 2, axis=-1)
-    sin = jnp.concatenate([jnp.sin(ang)] * 2, axis=-1)
-    x1, x2 = x[..., :d // 2], x[..., d // 2:]
-    return x * cos + jnp.concatenate([-x2, x1], axis=-1) * sin
-
-
-def rms(x, w, eps: float):
-    x = x.astype(jnp.float32)
-    return x * jax.lax.rsqrt(jnp.square(x).mean(-1, keepdims=True) + eps) \
-        * w.astype(jnp.float32)
-
-
 def write_index_keys(pool, ki, block_table, cache_pos, page: int):
     """The index keys ``ki`` [b, t, d] of every row's ``t`` new positions
     (the first at ``cache_pos``) into ``pool`` [pages, page * d], whole
@@ -157,17 +143,6 @@ def write_index_keys(pool, ki, block_table, cache_pos, page: int):
         lambda r, new, at: jax.lax.dynamic_update_slice(r, new, (at, 0)))(
             pool[phys].reshape(b, n * page, d), ki, cache_pos % page)
     return pool.at[phys].set(rows.reshape(b, n, page * d))
-
-
-class RMSNorm(nn.Module):
-    eps: float
-    param_dtype: jnp.dtype
-
-    @nn.compact
-    def __call__(self, x):
-        w = self.param("weight", nn.initializers.ones, (x.shape[-1],),
-                       self.param_dtype)
-        return rms(x, w, self.eps)
 
 
 class SparsePagedAttention(nn.Module):
@@ -194,12 +169,7 @@ class SparsePagedAttention(nn.Module):
         eps, theta = cfg.rms_norm_eps, cfg.rope_theta
         init = nn.initializers.normal(0.02)
         ones, zeros = nn.initializers.ones, nn.initializers.zeros
-        wq = self.param("q_proj", init, (C, H * hd), dt)
-        wk = self.param("k_proj", init, (C, KV * hd), dt)
-        wv = self.param("v_proj", init, (C, KV * hd), dt)
-        wo = self.param("o_proj", init, (H * hd, C), dt)
-        gq = self.param("q_norm", ones, (hd,), dt)
-        gk = self.param("k_norm", ones, (hd,), dt)
+        wq, wk, wv, wo, gq, gk = qkvo_params(self, C, H, KV, hd, dt)
         wqi = self.param("index_q_proj", init, (C, J * di), dt)
         wki = self.param("index_k_proj", init, (C, di), dt)
         wwi = self.param("index_weights_proj", init, (C, J), dt)
@@ -208,10 +178,8 @@ class SparsePagedAttention(nn.Module):
         hb = h.astype(dt)
         wpos, phys, off = pool_slots(block_table, cache_pos, t, page)
 
-        k = jnp.einsum("btc,ckd->btkd", hb, wk.reshape(C, KV, hd),
-                       preferred_element_type=jnp.float32)
-        k = rotate_half(rms(k, gk, eps), wpos[:, :, None], theta)
-        v = jnp.dot(hb, wv, preferred_element_type=jnp.float32)
+        k = key_heads(hb, wk, gk, wpos, KV, hd, eps, theta)
+        v = value_heads(hb, wv)
         ki = jnp.dot(hb, wki, preferred_element_type=jnp.float32)
         mean = ki.mean(-1, keepdims=True)
         var = jnp.square(ki - mean).mean(-1, keepdims=True)
@@ -271,10 +239,7 @@ class SparsePagedAttention(nn.Module):
             of this call is in them already); their output projected."""
             tc = hb_c.shape[1]
             qpos = pos_c[:, None] + jnp.arange(tc)[None, :]
-            q = jnp.einsum("btc,ckgd->bktgd", hb_c,
-                           wq.reshape(C, KV, G, hd),
-                           preferred_element_type=jnp.float32)
-            q = rotate_half(rms(q, gq, eps), qpos[:, None, :, None],
+            q = query_heads(hb_c, wq, gq, qpos, KV, G, hd, eps,
                             theta).astype(dt)
             qi = jnp.einsum("btc,cjd->btjd", hb_c, wqi.reshape(C, J, di),
                             preferred_element_type=jnp.float32)
@@ -286,9 +251,7 @@ class SparsePagedAttention(nn.Module):
             else:
                 y = sa.attend_block(q, qi, wi, k_row, v_row, ki_row, pos_c,
                                     topk, cfg.attn_key_block)
-            return jnp.einsum("bktgd,kgdc->btc", y,
-                              wo.reshape(KV, G, hd, C),
-                              preferred_element_type=jnp.float32)
+            return project_out(y, wo, KV, G, hd)
 
         out = by_query_block(attend, hb, cache_pos, cfg.attn_query_block)
         return jnp.where((wpos < S)[:, :, None], out, jnp.nan)
@@ -344,18 +307,8 @@ class KeyeVL2(nn.Module):
                                  f"{sorted(_DTYPES)}, got "
                                  f"{getattr(cfg, name)!r}")
         dt = _DTYPES[cfg.weights_dtype]
-        init = nn.initializers.normal(0.02)
-        embed = self.param("embed_tokens", init,
-                           (cfg.vocab_size, cfg.hidden_size), dt)
-        x = embed[tokens].astype(jnp.float32)
+        x = embed_tokens(self, tokens, cfg.vocab_size, cfg.hidden_size, dt)
         for i in range(cfg.num_hidden_layers):
             x = Block(cfg, name=f"layers_{i}")(x, block_table, cache_pos)
-        if last_pos is not None:
-            x = jax.lax.dynamic_index_in_dim(x, last_pos, axis=1,
-                                             keepdims=False)
-        y = RMSNorm(cfg.rms_norm_eps, dt, name="norm")(x)
-        head = self.param("lm_head", init,
-                          (cfg.hidden_size, cfg.vocab_size), dt)
-        with jax.named_scope("head"):
-            return jnp.dot(y.astype(dt), head,
-                           preferred_element_type=jnp.float32)
+        return untied_head(self, x, last_pos, cfg.vocab_size,
+                           cfg.rms_norm_eps, dt)

@@ -18,7 +18,24 @@ answers:
   (``ops/paged_attention.py:paged_attend_path``);
 * ``prepare_params()``   a parameter tree as this config serves it;
 * the fields ``block_size`` (positions a row may reach), ``vocab_size``,
-  ``page_size``, ``kv_pages``, ``weights_dtype``, ``kv_dtype``.
+  ``page_size``, ``kv_pages``, ``weights_dtype``, ``kv_dtype``;
+* ``fixed_row_cache`` (optional, default false): true where a row's
+  cache is ONE block of fixed size whatever the row's length (a
+  recurrent state: ``models/brumby.py``) and not a run of pages that
+  grows by a position a token. The engine's one pool manager then makes
+  a page the whole row (``page_size = block_size``, one block-table
+  entry a row, ``kv_pages`` counts blocks: ``engine.fit_pool``), needs
+  no copy-on-write page, serves no prefix of a prompt from a block and
+  registers none (``_walk_prefix``), and refuses ``spec_tokens > 0`` (a
+  state cannot be rewound). Allocation, the refcount, park / resume /
+  ``release_parked``, the scrub of a quarantined row's block with the
+  null block's zeros and ``kv_pool_bytes`` are the paged models' own.
+  What the MODEL owes in return: its ``cache`` leaves are indexed by
+  block on their first axis; block 0 stays zeros; a row at cursor 0 has
+  no past, so a block another row left reads as zeros to it (the engine
+  zeroes nothing at admission); a prefill bucket's padding (the
+  positions past ``last_pos``) leaves the block as it is; a row whose
+  table entry is 0 is not live and touches nothing.
 
 A family's key starts with its ``model_type``; GPT-2's is its plain field
 tuple, as it always was (its programs' keys and names did not move).
@@ -29,11 +46,13 @@ from __future__ import annotations
 import dataclasses
 from typing import Any, Dict
 
+from .brumby import FAMILY as BRUMBY, BrumbyConfig
 from .cohere2_moe import FAMILY as COHERE2_MOE, Cohere2MoeConfig
 from .keye_vl2 import FAMILY as KEYE_VL2, KeyeVL2Config
 from .nanogpt import GPTConfig, sample_logits  # noqa: F401 — re-exported
 
-FAMILIES = {COHERE2_MOE: Cohere2MoeConfig, KEYE_VL2: KeyeVL2Config}
+FAMILIES = {COHERE2_MOE: Cohere2MoeConfig, KEYE_VL2: KeyeVL2Config,
+            BRUMBY: BrumbyConfig}
 
 
 def config_from_key(key: tuple):

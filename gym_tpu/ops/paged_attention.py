@@ -72,6 +72,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .flash_attention import _on_tpu  # noqa: F401 — steered here by tests
+from .power_retention import RETENTION
 
 _log = logging.getLogger(__name__)
 
@@ -92,11 +93,13 @@ GATHER = "gather"
 # learned sparse attention (``ops/sparse_attention.py``): index scores,
 # the exact ``topk`` selection, an attend over the kept keys only
 SPARSE = "sparse_topk"
+# ``RETENTION`` (imported above): power retention, no keys and values
+# are kept, a row's one block of state is decayed, updated and read
 
 
 def paged_attend_path(n_embd: int, page_size: int, dtype, kv_dtype,
                       head_dim: int = 0, window: int = 0,
-                      sparse_topk: int = 0) -> str:
+                      sparse_topk: int = 0, retention: bool = False) -> str:
     """Which implementation the paged attend takes, from what the code
     can observe. ``n_embd`` is the pool row's width (all key-value heads
     of a position). THE dispatch point — the model and the engine's
@@ -120,7 +123,13 @@ def paged_attend_path(n_embd: int, page_size: int, dtype, kv_dtype,
     ``sparse_topk`` given (a layer that keeps that many keys a query by
     a learned index, ``ops/sparse_attention.py``): ``SPARSE`` on every
     backend and dtype; it reads the kept positions where they lie and
-    never builds a row's window."""
+    never builds a row's window.
+
+    ``retention`` (a layer whose cache is a recurrent state of fixed
+    size, ``ops/power_retention.py``): ``RETENTION`` on every backend
+    and dtype; there are no pages of keys to walk or gather."""
+    if retention:
+        return RETENTION
     if sparse_topk:
         return SPARSE
     dtype, kv_dtype = jnp.dtype(dtype), jnp.dtype(kv_dtype)

@@ -315,7 +315,7 @@ def create_server(params, cfg, *, host: str = "127.0.0.1", port: int = 0,
     from ..utils import trace
     from ..utils.resilience import fault_point
     from .autoscale import AutoscalePolicy, Autoscaler
-    from .engine import SamplingParams, fit_pool
+    from .engine import SamplingParams, fit_pool, row_cache
     from .metrics import ServeMetrics
     from .router import (FleetReloadError, NoHealthyReplicaError,
                          build_fleet, build_process_fleet)
@@ -333,8 +333,9 @@ def create_server(params, cfg, *, host: str = "127.0.0.1", port: int = 0,
         metrics_dir = tempfile.mkdtemp(prefix="gym_tpu_serve_")
 
     asked = page_size
-    page_size, kv_pages = fit_pool(page_size, cfg.block_size, kv_pages)
-    if page_size != asked:
+    page_size, kv_pages = fit_pool(page_size, cfg.block_size, kv_pages,
+                                   config=cfg)
+    if page_size != asked and not row_cache(cfg):
         sys.stderr.write(
             f"gym_tpu.serve: page_size {asked} does not divide "
             f"block_size {cfg.block_size} — serving with page_size "

@@ -310,6 +310,13 @@ def prompt_bucket(n: int, block_size: int) -> int:
     return min(b, block_size)
 
 
+def row_cache(config: Any) -> bool:
+    """Whether this model's per-row cache is ONE block of fixed size (a
+    recurrent state) and not a run of pages that grows with the row
+    (``models/serving.py``: ``fixed_row_cache``)."""
+    return bool(getattr(config, "fixed_row_cache", False))
+
+
 def fit_page_size(asked: int, block_size: int) -> int:
     """The page size a server gives a checkpoint: ``asked`` where it
     divides the checkpoint's ``block_size``, else the largest divisor of
@@ -326,11 +333,17 @@ def fit_page_size(asked: int, block_size: int) -> int:
 
 
 def fit_pool(page_size: int, block_size: int,
-             kv_pages: Optional[int] = None) -> Tuple[int, Optional[int]]:
+             kv_pages: Optional[int] = None,
+             config: Any = None) -> Tuple[int, Optional[int]]:
     """``(page_size, kv_pages)`` a server builds its engine with. A pool
     size that was given counted pages of the size asked for: where the
     page is fitted down it is scaled up, so that the pool holds the
-    tokens that were asked for (``None`` stays: the engine sizes it)."""
+    tokens that were asked for (``None`` stays: the engine sizes it).
+    For a ``config`` whose cache is one block a row (``row_cache``) the
+    page is the whole row and ``kv_pages`` counts blocks, not positions:
+    it stays as given."""
+    if row_cache(config):
+        return int(block_size), kv_pages
     fitted = fit_page_size(page_size, block_size)
     if kv_pages is not None:
         kv_pages = -(-kv_pages * page_size // fitted)
@@ -552,6 +565,20 @@ class InferenceEngine:
         self.block_size = int(config.block_size)
         self.num_slots = int(num_slots)
         self.decode_chunk = int(decode_chunk)
+        # a model whose cache is one block of state a row: a page is the
+        # whole row (one table entry a row, ``kv_pages`` counts blocks),
+        # nothing of it can be shared, copied on write or rewound
+        self.row_cache = row_cache(config)
+        if self.row_cache:
+            page_size = self.block_size
+            if self.spec_tokens:
+                raise ValueError(
+                    "spec_tokens > 0 with a model whose cache is a "
+                    "recurrent state: rejected drafts cannot be rewound "
+                    "out of a state without a copy of it")
+        # pages an admission may need beyond its own: the copy-on-write
+        # of a shared last block
+        self._cow_room = 0 if self.row_cache else 1
         if page_size < 1 or self.block_size % page_size:
             raise ValueError(
                 f"page_size must be >= 1 and divide block_size "
@@ -561,13 +588,14 @@ class InferenceEngine:
         if kv_pages is None:
             # null page + one full window per slot + one page of
             # copy-on-write headroom (also satisfies the 1-slot
-            # minimum below)
+            # minimum below; with one block a row, the spare is a parked
+            # row's)
             kv_pages = 2 + self.num_slots * self.max_blocks
-        if kv_pages < 2 + self.max_blocks:
+        if kv_pages < 1 + self.max_blocks + self._cow_room:
             raise ValueError(
                 f"kv_pages={kv_pages} too small: need the null page "
-                f"+ one full window ({self.max_blocks} blocks) + one "
-                f"copy-on-write page")
+                f"+ one full window ({self.max_blocks} blocks)"
+                + (" + one copy-on-write page" if self._cow_room else ""))
         self.kv_pages = int(kv_pages)
         self.config = dataclasses.replace(
             base_cfg, page_size=self.page_size, kv_pages=self.kv_pages)
@@ -849,7 +877,8 @@ class InferenceEngine:
         # worst case (zero prefix hits, +1 copy-on-write headroom) must
         # fit the pool EVER, so a queued request always eventually
         # admits once running slots release their blocks
-        worst = -(-(n + sp.max_new_tokens) // self.page_size) + 1
+        worst = (-(-(n + sp.max_new_tokens) // self.page_size)
+                 + self._cow_room)
         if worst > self.kv_pages - 1:
             raise ValueError(
                 f"request needs up to {worst} KV blocks but the "
@@ -867,6 +896,10 @@ class InferenceEngine:
         hit_pages: List[int] = []
         chain: List[int] = []
         cid = 0
+        if self.row_cache:
+            # a state is not addressed by position: no prefix of a
+            # prompt is served from one, and none is registered
+            return hit_pages, chain
         for b in range(len(prompt) // page):
             ent = al.probe(cid, prompt[b * page:(b + 1) * page].tobytes())
             if ent is None:
@@ -1097,7 +1130,7 @@ class InferenceEngine:
         # probes must not keep a never-admitted prefix artificially hot
         for pg in hit_pages:
             al.touch(pg)
-        if cow_src is None:
+        if cow_src is None and not self.row_cache:
             # register the freshly-prefilled full PROMPT blocks (their
             # content is immutable — decode writes start past them);
             # the CoW path has nothing new: every block was cached

@@ -502,7 +502,7 @@ def main(argv=None) -> int:
         return 1
 
     page_size, kv_pages = fit_pool(args.page_size, cfg.block_size,
-                                   args.kv_pages)
+                                   args.kv_pages, config=cfg)
 
     metrics_dir = args.metrics_dir
     if metrics_dir is None:

@@ -1,0 +1,105 @@
+"""The served programs of the power-retention decoder (``models/brumby.py``)
+compiled for a described v5e at the benchmark cell's sizes, without the
+chip: that they fit, that the decode step's pass over the state is the
+Pallas kernel in place on the donated pool, and that nothing of a state
+array's size is moved beside it. ``tests/test_chip_compile.py``'s rule
+for the page pools, for the pool of state blocks; a file of its own so
+that the two compiles (a minute each) run beside that file's five
+minutes and not after them."""
+
+import os
+
+import jax
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from gym_tpu.ops import paged_attention
+
+
+@pytest.fixture(scope="module")
+def v5e_chip():
+    """Sharding on one chip of a described ``v5e:2x2`` host. The compile
+    cache is off around the module: a compile for a described device is
+    written to it but cannot be read back without a chip."""
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler installed
+        pytest.skip(f"cannot describe a v5e:2x2 topology here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+# the Brumby cell as served (perfbench/configs/brumby-14b-base.json,
+# perfbench/traffic/serve-closed-longgen.json): 8 layers, the whole
+# vocabulary, bfloat16 weights, a float32 state of 8 x 128 x 8,320 (+ 8 x
+# 8,320) a layer and row, 16 slots, rows of 20,480 positions, 18 blocks
+def _brumby_cfg(kv_pages=18):
+    import dataclasses
+    from gym_tpu.models.brumby import BrumbyConfig
+    return dataclasses.replace(
+        BrumbyConfig(num_hidden_layers=8, block_size=20480).decode_config(),
+        page_size=20480, kv_pages=kv_pages)
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill16384"])
+def test_brumby_programs_fit_the_chip_and_move_no_state(v5e_chip,
+                                                        monkeypatch,
+                                                        program):
+    """The decode program and the longest prefill bucket as the cell's
+    engine compiles them: arguments (8.4 GB of weights, 4.95 GB of
+    state), outputs and temporaries stay under 15.0 GiB of the chip's
+    15.75 (ISSUE 33's line; a prefill a layer at a time over the whole
+    bucket asked 17.5 GB: it runs 2,048 positions at a time through all
+    layers). The decode step's pass over the state is the Pallas kernel,
+    in place on the donated pool, and besides it no float32 instruction's
+    result is as large as ONE row's block of a layer (8.5 M elements):
+    no copy, convert, transpose, slice, update or scatter of a state
+    array. A prefill takes the row's block out and puts it back (one
+    block, by design) and moves nothing of a pool's size."""
+    import re
+    from gym_tpu.ops import power_retention
+    from gym_tpu.programs import serve_defs
+    monkeypatch.setattr(power_retention, "_on_tpu", lambda: True)
+    cfg = _brumby_cfg()
+    key = cfg.program_key()
+    assert set(cfg.attend_paths()) == {paged_attention.RETENTION}
+    pdef = (serve_defs.paged_decode_def(key, 16, 1) if program == "decode"
+            else serve_defs.paged_prefill_def(key, int(program[7:]), 16))
+    args = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=v5e_chip),
+        pdef.args)
+    compiled = pdef.builder().lower(*args).compile()
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    assert total < 15.0 * 1024 ** 3, (total / 2 ** 30, mem)
+    hlo = compiled.as_text()
+    assert ("retention_state_decode" in hlo) == (program == "decode")
+    D = power_retention.feature_dim(128)
+    block = 8 * 128 * D
+    # decode: any float32 result of a block's size; a prefill: a result
+    # in the state's own shape as large as a pool, but for the update in
+    # place that puts the row's block back
+    moved = ("copy|gather|transpose|scatter|dynamic-slice|convert"
+             + ("|dynamic-update-slice" if program == "decode" else ""))
+    shape = r"[\d,]+" if program == "decode" else rf"[\d,]*8,128,{D}"
+    least = block if program == "decode" else cfg.kv_pages * block
+    big = []
+    for m in re.finditer(rf"= f32\[({shape})\]\S* ({moved})\(", hlo):
+        n = 1
+        for d in m.group(1).split(","):
+            n *= int(d)
+        if n >= least:
+            big.append(m.group(0))
+    assert not big, big[:3]
+    pool = f"f32[{cfg.kv_pages},8,128,{D}]"
+    assert hlo.count(pool) >= 8
+    assert re.search(r"input_output_alias=\{.*may-alias", hlo)
