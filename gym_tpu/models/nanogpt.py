@@ -961,9 +961,10 @@ def sample_logits(logits, key, temperature=1.0, top_k=None, top_p=None):
     (``gym_tpu/serve/engine.py``).
 
     ``logits`` is [..., V]; one ``key`` covers the whole call (batch rows
-    share its random bits — the engine vmaps this function to give each
-    request slot its own key). ``temperature``/``top_k``/``top_p`` may be
-    static python scalars (``None`` disables a filter) or traced arrays
+    share its random bits — ``sample_rows`` vmaps this function to give
+    each request slot its own key). ``temperature``/``top_k``/``top_p``
+    may be static python scalars (``None`` disables a filter, and its
+    full-vocabulary sort is not traced at all) or traced arrays
     broadcastable against ``logits[..., :1]``; the array encodings for
     "disabled" are ``top_k >= V`` and ``top_p >= 1``, which reduce to
     no-op ``where``s and reproduce the static-``None`` paths bit-exactly
@@ -971,12 +972,13 @@ def sample_logits(logits, key, temperature=1.0, top_k=None, top_p=None):
     this."""
     v = logits.shape[-1]
     logits = logits.astype(jnp.float32) / temperature
-    k = v if top_k is None else jnp.clip(top_k, 1, v)
-    srt = jnp.sort(logits, axis=-1)[..., ::-1]        # descending
-    kidx = jnp.broadcast_to(jnp.asarray(k - 1, jnp.int32),
-                            (*logits.shape[:-1], 1))
-    kth = jnp.take_along_axis(srt, kidx, axis=-1)
-    logits = jnp.where(logits < kth, -jnp.inf, logits)
+    if top_k is not None:
+        srt = jnp.sort(logits, axis=-1)[..., ::-1]    # descending
+        kidx = jnp.broadcast_to(
+            jnp.asarray(jnp.clip(top_k, 1, v) - 1, jnp.int32),
+            (*logits.shape[:-1], 1))
+        kth = jnp.take_along_axis(srt, kidx, axis=-1)
+        logits = jnp.where(logits < kth, -jnp.inf, logits)
     if top_p is not None:
         # nucleus over the (already top-k-filtered) distribution: keep the
         # smallest prefix of descending-prob tokens whose EXCLUSIVE
@@ -993,6 +995,53 @@ def sample_logits(logits, key, temperature=1.0, top_k=None, top_p=None):
         thr = jnp.take_along_axis(srt, n_keep - 1, axis=-1)
         logits = jnp.where(logits < thr, -jnp.inf, logits)
     return jax.random.categorical(key, logits, axis=-1)
+
+
+def sample_rows(logits, keys, temperature, top_k, top_p, live,
+                sample=sample_logits):
+    """``sample_logits`` for a batch of request slots, each with its own
+    key and parameters: the serving programs' sampler. ``temperature``,
+    ``top_k``, ``top_p`` and ``live`` are [S] in the array encodings
+    above; ``logits`` is [S, V] with ``keys`` [S, 2], or [S, G, V] with
+    ``keys`` [S, G, 2] (a slot's G positions share its parameters).
+    Returns ``(tokens, sorted)``.
+
+    The full-vocabulary sorts are taken ONCE A CALL FOR THE WHOLE BATCH,
+    and only when a live row asks for a filter that needs an order
+    (``1 < top_k < V`` or ``top_p < 1``): ``sorted`` says so. The
+    predicate is a scalar outside every ``vmap``, so ``lax.cond`` stays a
+    conditional on the device and the branch not taken costs nothing
+    (under a ``vmap`` it would turn into a ``select`` and both would
+    run). A row that is not ``live`` never switches the sorts on: what it
+    samples is discarded. The other branch sorts nothing: a greedy row
+    (``top_k <= 1``) keeps what equals its maximum, which is what the
+    k-th value of a sort is for ``k = 1``; every other row keeps
+    everything, as the sorted filters do for ``top_k >= V`` and
+    ``top_p >= 1``. Both branches hand ``sample`` bit-equal filtered
+    logits on the same keys, so the tokens are those of
+    ``vmap(sample_logits)`` whichever branch ran (ties at a greedy row's
+    maximum break as ``categorical`` breaks them).
+
+    ``sample`` is the row sampler both branches end in; the programs hand
+    in their own module's name for it (``programs/serve_defs.py``)."""
+    v = logits.shape[-1]
+    # ``~(top_p >= 1)`` and not ``top_p < 1``: a NaN takes today's path
+    filters = ((top_k > 1) & (top_k < v)) | ~(top_p >= 1.0)
+    need = jnp.any(live & filters)
+
+    def plain_row(lg, key, temp, k, _p):
+        lg = lg.astype(jnp.float32) / temp
+        lg = jnp.where((k <= 1) & (lg < lg.max()), -jnp.inf, lg)
+        return sample(lg, key)
+
+    def over_rows(row):
+        for _ in range(logits.ndim - 2):
+            row = jax.vmap(row, in_axes=(0, 0, None, None, None))
+        return lambda: jax.vmap(row)(logits, keys, temperature, top_k,
+                                     top_p)
+
+    tokens = jax.lax.cond(need, over_rows(sample), over_rows(plain_row))
+    return tokens, need
 
 
 def generate(params: Any, config: GPTConfig, idx: np.ndarray,

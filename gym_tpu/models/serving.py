@@ -49,7 +49,8 @@ from typing import Any, Dict
 from .brumby import FAMILY as BRUMBY, BrumbyConfig
 from .cohere2_moe import FAMILY as COHERE2_MOE, Cohere2MoeConfig
 from .keye_vl2 import FAMILY as KEYE_VL2, KeyeVL2Config
-from .nanogpt import GPTConfig, sample_logits  # noqa: F401 — re-exported
+from .nanogpt import (GPTConfig, sample_logits,  # noqa: F401 — re-exported
+                      sample_rows)
 
 FAMILIES = {COHERE2_MOE: Cohere2MoeConfig, KEYE_VL2: KeyeVL2Config,
             BRUMBY: BrumbyConfig}

@@ -30,7 +30,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..models.serving import config_from_key, sample_logits
+# ``sample_logits`` is the row sampler the programs hand ``sample_rows``,
+# looked up in THIS module when a program is traced: what replaces it here
+# (the benchmark's altered-token control) alters every token of every
+# program, whichever branch the batch's sampler takes
+from ..models.serving import config_from_key, sample_logits, sample_rows
 from .registry import ProgramDef
 
 # -- aval templates --------------------------------------------------------
@@ -140,8 +144,9 @@ def build_paged_prefill(cfg_tuple: tuple, bucket: int):
             {"params": params, "cache": cache}, tokens, train=False,
             mutable=["cache"], block_table=bt_row, cache_pos=start,
             last_pos=true_suffix - 1)                                # [1,V]
-        tok = sample_logits(last, jax.random.fold_in(key, 0),
-                            temp, top_k, top_p)
+        tok, _sorted = sample_rows(
+            last, jax.random.fold_in(key, 0)[None], temp[None], top_k[None],
+            top_p[None], jnp.ones((1,), bool), sample_logits)
         first = tok[0].astype(jnp.int32)
         n = start[0] + true_suffix
         left = max_new - 1
@@ -196,8 +201,11 @@ def build_paged_decode(cfg_tuple: tuple, num_slots: int, chunk: int):
       route tokens to requests), the final ``tok`` / ``active`` /
       ``pos`` [S], ``nan_seen`` [S] (non-finite logits while the row
       was active, latched per scanned step: no path reads logits to
-      decide anything) and ``counted``: what the model counted (its
-      ``counters`` collection), summed over the chunk;
+      decide anything), ``sorted`` [chunk] (whether the scanned step's
+      sampler took its sorts: ``sample_rows`` decides that once a step
+      for the whole batch, from the live rows' ``top_k`` and ``top_p``)
+      and ``counted``: what the model counted (its ``counters``
+      collection), summed over the chunk;
     - ``logits`` [S, V]: the last scanned step's, left on the device
       (teacher forcing and tests fetch them);
     - ``state``: the argument with what this dispatch advanced, the
@@ -227,7 +235,8 @@ def build_paged_decode(cfg_tuple: tuple, num_slots: int, chunk: int):
             bad = act & ~jnp.isfinite(lg).all(axis=-1)
             nanc = nanc | bad
             keys = jax.vmap(jax.random.fold_in)(base_keys, gidx)
-            nxt = jax.vmap(sample_logits)(lg, keys, temp, top_k, top_p)
+            nxt, srt = sample_rows(lg, keys, temp, top_k, top_p, act,
+                                   sample_logits)
             nxt = jnp.where(act, nxt, tok).astype(jnp.int32)
             emitted = act
             pos = jnp.where(act, pos + 1, pos)
@@ -238,19 +247,20 @@ def build_paged_decode(cfg_tuple: tuple, num_slots: int, chunk: int):
             # what the model counted this step (``counters``: small
             # integer arrays, or nothing), summed over the chunk below
             return ((varsc["cache"], nxt, act & ~done, pos, gidx, rem,
-                     nanc, lg), (nxt, emitted, varsc.get("counters", {})))
+                     nanc, lg),
+                    (nxt, emitted, srt, varsc.get("counters", {})))
 
         lg0 = jnp.zeros((num_slots, cfg.vocab_size), jnp.float32)
         nan0 = jnp.zeros((num_slots,), bool)
         (cache, tok, active, pos, gen_idx, remaining, nan_seen, lg), \
-            (toks, emitted, counted) = jax.lax.scan(
+            (toks, emitted, srt, counted) = jax.lax.scan(
                 body, (cache, state["tok"], state["active"], state["pos"],
                        state["gen_idx"], state["remaining"], nan0, lg0),
                 None, length=chunk)
         counted = jax.tree.map(lambda c: c.sum(axis=0), counted)
         read = {"toks": toks, "emitted": emitted, "tok": tok,
                 "active": active, "pos": pos, "nan_seen": nan_seen,
-                "counted": counted}
+                "sorted": srt, "counted": counted}
         state = {**state, "tok": tok, "active": active, "pos": pos,
                  "gen_idx": gen_idx, "remaining": remaining,
                  "bt": jnp.where(active[:, None], bt, 0)}
@@ -317,8 +327,6 @@ def build_spec_decode(cfg_tuple: tuple, num_slots: int, chunk: int,
     def spec(params, cache, state):
         bt, base_keys, eos = state["bt"], state["base_keys"], state["eos"]
         temp, top_k, top_p = state["temp"], state["top_k"], state["top_p"]
-        sample_row = jax.vmap(sample_logits,
-                              in_axes=(0, 0, None, None, None))
 
         def body(carry, _):
             cache, tok, act, pos, gidx, rem, hist, nanc, _lg = carry
@@ -339,8 +347,8 @@ def build_spec_decode(cfg_tuple: tuple, num_slots: int, chunk: int,
             idxs = gidx[:, None] + jnp.arange(g1)[None, :]
             keys = jax.vmap(jax.vmap(jax.random.fold_in,
                                      in_axes=(None, 0)))(base_keys, idxs)
-            sampled = jax.vmap(sample_row)(logits, keys, temp, top_k,
-                                           top_p)              # [S, γ+1]
+            sampled, srt = sample_rows(logits, keys, temp, top_k, top_p,
+                                       act, sample_logits)     # [S, γ+1]
             match = (sampled[:, :gamma] == drafts).astype(jnp.int32)
             acc = jnp.cumprod(match, axis=1).sum(axis=1)        # [S]
             m = acc + 1                       # leading matches + bonus
@@ -366,19 +374,19 @@ def build_spec_decode(cfg_tuple: tuple, num_slots: int, chunk: int,
                 jnp.where(emit, sampled, hist[rows, hpos]))
             lg = logits[:, 0]                 # teacher-forcing observable
             return ((varsc["cache"], new_tok, act & ~done, pos + m,
-                     gidx + m, rem, hist, nanc, lg), (sampled, emit))
+                     gidx + m, rem, hist, nanc, lg), (sampled, emit, srt))
 
         lg0 = jnp.zeros((num_slots, cfg.vocab_size), jnp.float32)
         nan0 = jnp.zeros((num_slots,), bool)
         (cache, tok, active, pos, gen_idx, remaining, hist, nan_seen,
-         lg), (toks, emit) = jax.lax.scan(
+         lg), (toks, emit, srt) = jax.lax.scan(
                 body, (cache, state["tok"], state["active"], state["pos"],
                        state["gen_idx"], state["remaining"], state["hist"],
                        nan0, lg0), None, length=chunk)
         # ``counted``: what a model counts (nothing is counted here)
         read = {"toks": toks, "emitted": emit, "tok": tok,
                 "active": active, "pos": pos, "nan_seen": nan_seen,
-                "counted": {}}
+                "sorted": srt, "counted": {}}
         state = {**state, "tok": tok, "active": active, "pos": pos,
                  "gen_idx": gen_idx, "remaining": remaining, "hist": hist,
                  "bt": jnp.where(active[:, None], bt, 0)}

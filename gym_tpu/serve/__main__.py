@@ -570,6 +570,10 @@ def create_server(params, cfg, *, host: str = "127.0.0.1", port: int = 0,
                 # out what was in flight
                 "steps_ahead": sum(s.steps_ahead for s in stats),
                 "drains": sum(s.drains for s in stats),
+                # decode steps whose sampler sorted the vocabulary (a
+                # live row filtered by top_k or top_p)
+                "sampler_sorted_steps": sum(s.sampler_sorted_steps
+                                            for s in stats),
                 # decode + prefill dispatches whose attend ran the Pallas
                 # page walk: equals decode dispatches + prefills on a TPU
                 # with an f32 pool, 0 on the gather path

@@ -20,7 +20,11 @@ set once and runs every request through it:
   slot batch advances one token. Each slot is an independent sequence at
   its own cursor, reading and writing only the pages of its own block
   table, and the per-slot sampling params (temperature / top-k / top-p /
-  PRNG key) ride in as vectors, applied by a vmapped ``sample_logits``.
+  PRNG key) ride in as vectors, applied by ``sample_rows``: the shared
+  ``sample_logits`` a row, whose full-vocabulary sorts are taken only in
+  a step in which a LIVE row filters (``1 < top_k < V`` or ``top_p < 1``;
+  one conditional a step for the whole batch, the same tokens either
+  way; ``stats.sampler_sorted_steps`` counts those steps).
   Inactive slots compute garbage that is never read, written to the null
   page, and their cursors are frozen, so a free slot can idle forever.
 - **Prefill** (compiled once per power-of-two bucket): a single request's
@@ -262,6 +266,10 @@ class EngineStats:
     steps_ahead: int = 0                 # of ``decode_steps``, those
     #                                      dispatched while the step before
     #                                      had not yet been read
+    sampler_sorted_steps: int = 0        # of ``decode_steps``, those whose
+    #                                      sampler sorted the vocabulary: a
+    #                                      live row had ``1 < top_k < V``
+    #                                      or ``top_p < 1``
     drains: int = 0                      # times a host write or an idle
     #                                      round waited out what was in
     #                                      flight
@@ -1472,4 +1480,5 @@ class InferenceEngine:
         self.stats.tokens_generated += len(events)
         self.stats.decode_steps += n_steps
         self.stats.steps_ahead += n_steps * flight.ahead
+        self.stats.sampler_sorted_steps += int(read["sorted"].sum())
         self._held.extend(events)

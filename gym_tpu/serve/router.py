@@ -1809,6 +1809,7 @@ class ProcessRouter:
                 "tokens_generated": h.get("tokens_generated", 0),
                 "decode_steps": h.get("decode_steps", 0),
                 "steps_ahead": h.get("steps_ahead", 0),
+                "sampler_sorted_steps": h.get("sampler_sorted_steps", 0),
                 "drains": h.get("drains", 0),
                 "tokens_per_s_ewma": h.get("tokens_per_s_ewma"),
                 "programs_compiled": h.get("programs_compiled"),

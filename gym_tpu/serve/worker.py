@@ -175,6 +175,7 @@ class WorkerServer:
             "tokens_generated": int(stats.tokens_generated),
             "decode_steps": int(stats.decode_steps),
             "steps_ahead": int(stats.steps_ahead),
+            "sampler_sorted_steps": int(stats.sampler_sorted_steps),
             "drains": int(stats.drains),
             "prefills": int(stats.prefills),
             "tokens_per_s_ewma": self.metrics.tokens_per_s_ewma(),

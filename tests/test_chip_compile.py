@@ -26,6 +26,8 @@ from gym_tpu.ops.dct import codec_for, sparse_decode_chunks
 from gym_tpu.ops.grouped_matmul import quant_tile_for, quantized_dot
 from gym_tpu.ops.topk_compress import topk_compress
 
+from _hlo import compile_def, ops_by_gate
+
 HBM_BYTES = 16 * 1024 ** 3
 
 
@@ -140,19 +142,15 @@ def test_paged_attention_kernel_compiles_for_v5e(v5e_chip, b, t, c, heads,
     assert not moved, moved[:3]
 
 
-@pytest.mark.parametrize("program", ["decode", "prefill256"])
-def test_served_programs_move_nothing_pool_sized(v5e_chip, monkeypatch,
-                                                 program):
-    """``serve.paged_decode[slots=128,chunk=1]`` and a paged prefill as
-    the served cell's engine compiles them: besides the in-place scatter
-    of the new positions, no instruction's result is the size of a pool
-    array (403 MB) or of a ``[b, S, H, hd]`` window, and the temporaries
-    stay far under the 5 GiB the gathered windows took."""
+@pytest.fixture(scope="module")
+def gpt2_served(v5e_chip):
+    """``compiled(program) -> (config, Compiled)``: a serving program as
+    the GPT-2 cell's engine compiles it (128 slots, pages of 16, the
+    Pallas page walk), each compiled once for the module: ``decode``,
+    ``prefill<bucket>``, ``spec<gamma>``."""
     import dataclasses
-    import re
     from gym_tpu.models.nanogpt import GPTConfig, decode_config
     from gym_tpu.programs import serve_defs
-    monkeypatch.setattr(paged_attention, "_on_tpu", lambda: True)
     slots, page = 128, 16
     cfg = dataclasses.replace(
         decode_config(GPTConfig(block_size=1024, vocab_size=50304,
@@ -160,13 +158,34 @@ def test_served_programs_move_nothing_pool_sized(v5e_chip, monkeypatch,
                                 dropout=0.0)),
         page_size=page, kv_pages=2 + slots * (1024 // page))
     cfg_tuple = dataclasses.astuple(cfg)
-    pdef = (serve_defs.paged_decode_def(cfg_tuple, slots, 1)
-            if program == "decode"
-            else serve_defs.paged_prefill_def(cfg_tuple, 256))
-    args = jax.tree.map(
-        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=v5e_chip),
-        pdef.args)
-    compiled = pdef.builder().lower(*args).compile()
+
+    @functools.cache
+    def compiled(program):
+        if program == "decode":
+            pdef = serve_defs.paged_decode_def(cfg_tuple, slots, 1)
+        elif program.startswith("spec"):
+            pdef = serve_defs.spec_decode_def(cfg_tuple, slots, 1,
+                                              int(program[4:]))
+        else:
+            pdef = serve_defs.paged_prefill_def(cfg_tuple,
+                                                int(program[7:]), slots)
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setattr(paged_attention, "_on_tpu", lambda: True)
+            return cfg, compile_def(pdef, v5e_chip)
+
+    return compiled
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill256"])
+def test_served_programs_move_nothing_pool_sized(gpt2_served, program):
+    """``serve.paged_decode[slots=128,chunk=1]`` and a paged prefill as
+    the served cell's engine compiles them: besides the in-place scatter
+    of the new positions, no instruction's result is the size of a pool
+    array (403 MB) or of a ``[b, S, H, hd]`` window, and the temporaries
+    stay far under the 5 GiB the gathered windows took."""
+    import re
+    cfg, compiled = gpt2_served(program)
+    page = cfg.page_size
     hlo = compiled.as_text()
     assert ("paged_attn_decode" if program == "decode"
             else "paged_attn_prefill") in hlo
@@ -185,6 +204,43 @@ def test_served_programs_move_nothing_pool_sized(v5e_chip, monkeypatch,
     # the write of the new positions is a scatter into the donated pool
     assert hlo.count(f"f32[{cfg.kv_pages},{page},768]") > 24
     assert re.search(r"input_output_alias=\{.*may-alias", hlo)
+
+
+# -- the sampler's gate (ISSUE 36) ------------------------------------------
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill256", "spec4"])
+def test_served_programs_sort_only_inside_the_samplers_conditional(
+        gpt2_served, program):
+    """``sample_rows`` decides once a step, for the whole batch, whether
+    a live row's ``top_k`` / ``top_p`` needs an order; the predicate
+    reaches ``lax.cond`` unbatched, so the compiled step holds an XLA
+    ``conditional`` and both full-vocabulary sorts (``[128, 50304]``: the
+    largest operation of the GPT-2 cell's decode step while every step
+    ran them) sit in ONE of its branches: none at the step's top level,
+    where a later ``vmap`` over the gate would put them back as the
+    operands of a ``select``."""
+    _cfg, compiled = gpt2_served(program)
+    hlo = compiled.as_text()
+    assert ops_by_gate(hlo, "sort") == (2, 0)
+    assert ops_by_gate(hlo, "conditional") == (0, 1)
+
+
+@pytest.mark.parametrize("batched", [False, True],
+                         ids=["scalar_gate", "gate_under_vmap"])
+def test_ops_by_gate_sees_a_gate_that_vmap_turned_into_a_select(
+        v5e_chip, batched):
+    """What the test above is for, at a toy size: the same ``cond`` with
+    its predicate batched compiles to no conditional, and its sort runs
+    at the top level."""
+    def gate(p, x):
+        return jax.lax.cond(p, lambda: jnp.sort(x), lambda: x)
+
+    fn = jax.vmap(gate) if batched else gate
+    hlo = _compile(fn, v5e_chip, (((8,) if batched else ()), jnp.bool_),
+                   ((8, 4096), jnp.float32))
+    assert ops_by_gate(hlo, "sort") == ((0, 1) if batched else (1, 0))
+    assert _conditionals(hlo) == (0 if batched else 1)
 
 
 def test_quantized_dot_compiles_at_gpt2_base_mlp(v5e_chip):

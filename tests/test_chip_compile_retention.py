@@ -15,6 +15,8 @@ from jax.sharding import SingleDeviceSharding
 
 from gym_tpu.ops import paged_attention
 
+from _hlo import compile_def, ops_by_gate
+
 
 @pytest.fixture(scope="module")
 def v5e_chip():
@@ -49,9 +51,31 @@ def _brumby_cfg(kv_pages=18):
         page_size=20480, kv_pages=kv_pages)
 
 
+@pytest.fixture(scope="module")
+def brumby_served(v5e_chip):
+    """``compiled(program) -> (config, Compiled)``: ``decode`` or
+    ``prefill<bucket>`` as the cell's engine compiles it (16 slots, the
+    Pallas state pass), each compiled once for the module."""
+    import functools
+    from gym_tpu.ops import power_retention
+    from gym_tpu.programs import serve_defs
+    cfg = _brumby_cfg()
+    key = cfg.program_key()
+
+    @functools.cache
+    def compiled(program):
+        pdef = (serve_defs.paged_decode_def(key, 16, 1)
+                if program == "decode"
+                else serve_defs.paged_prefill_def(key, int(program[7:]), 16))
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setattr(power_retention, "_on_tpu", lambda: True)
+            return cfg, compile_def(pdef, v5e_chip)
+
+    return compiled
+
+
 @pytest.mark.parametrize("program", ["decode", "prefill16384"])
-def test_brumby_programs_fit_the_chip_and_move_no_state(v5e_chip,
-                                                        monkeypatch,
+def test_brumby_programs_fit_the_chip_and_move_no_state(brumby_served,
                                                         program):
     """The decode program and the longest prefill bucket as the cell's
     engine compiles them: arguments (8.4 GB of weights, 4.95 GB of
@@ -66,17 +90,8 @@ def test_brumby_programs_fit_the_chip_and_move_no_state(v5e_chip,
     block, by design) and moves nothing of a pool's size."""
     import re
     from gym_tpu.ops import power_retention
-    from gym_tpu.programs import serve_defs
-    monkeypatch.setattr(power_retention, "_on_tpu", lambda: True)
-    cfg = _brumby_cfg()
-    key = cfg.program_key()
+    cfg, compiled = brumby_served(program)
     assert set(cfg.attend_paths()) == {paged_attention.RETENTION}
-    pdef = (serve_defs.paged_decode_def(key, 16, 1) if program == "decode"
-            else serve_defs.paged_prefill_def(key, int(program[7:]), 16))
-    args = jax.tree.map(
-        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=v5e_chip),
-        pdef.args)
-    compiled = pdef.builder().lower(*args).compile()
     mem = compiled.memory_analysis()
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
@@ -103,3 +118,17 @@ def test_brumby_programs_fit_the_chip_and_move_no_state(v5e_chip,
     pool = f"f32[{cfg.kv_pages},8,128,{D}]"
     assert hlo.count(pool) >= 8
     assert re.search(r"input_output_alias=\{.*may-alias", hlo)
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill16384"])
+def test_brumby_programs_sort_only_inside_the_samplers_conditional(
+        brumby_served, program):
+    """The cell whose round is the device's step, at the published
+    vocabulary: both sorts of ``[16, 151936]`` (7.35 of the step's 32.8
+    ms while every step ran them) sit in one branch of the sampler's
+    ``conditional``, none at the step's top level
+    (``tests/test_chip_compile.py`` has the GPT-2 cell's programs and
+    what a batched gate compiles to)."""
+    _cfg, compiled = brumby_served(program)
+    hlo = compiled.as_text()
+    assert ops_by_gate(hlo, "sort") == (2, 0)

@@ -991,3 +991,108 @@ def test_metrics_quant_columns_and_old_header_tolerance(setup, tmp_path):
     assert legacy["requests_done"] == 1
     assert legacy["weights_dtype"] is None
     assert legacy["kv_dtype"] is None
+
+
+# -- the sampler's gate (ISSUE 36) -----------------------------------------
+
+
+@pytest.mark.parametrize("kind", ["chunk1", "chunk4", "spec"])
+def test_sampler_sorts_only_in_steps_where_a_filtering_row_is_live(setup,
+                                                                   kind):
+    """A default and a greedy request decode for 30 tokens; beside them a
+    ``top_p = 0.9`` request comes and goes. ``sampler_sorted_steps``
+    counts exactly the decode steps in which that row was live (its
+    first token is the prefill's), stands still before and after, and
+    every stream is its own ``generate_fast`` run whichever branch the
+    sampler took."""
+    cfg, model, params = setup
+    eng = InferenceEngine(params, cfg, num_slots=3, page_size=8, **{
+        "chunk1": {}, "chunk4": {"decode_chunk": 4},
+        "spec": {"spec_tokens": 3}}[kind])
+    asked, toks = {}, {}
+
+    def admit(plen, seed, mnew, **kw):
+        prompt = _prompt(plen, seed)
+        slot, ev = eng.admit(prompt, SamplingParams(
+            max_new_tokens=mnew, seed=seed, **kw))
+        asked[slot] = (prompt, mnew, dict(seed=seed, **kw))
+        toks[slot] = [ev.token]
+        return slot
+
+    def step():
+        evs = eng.step()
+        for ev in evs:
+            toks[ev.slot].append(ev.token)
+        return {ev.slot for ev in evs}
+
+    admit(7, 1, 30, temperature=0.9)
+    admit(9, 2, 30, top_k=1)
+    step(), step()
+    st = eng.stats
+    assert st.decode_steps >= 2 and st.sampler_sorted_steps == 0
+    slot = admit(5, 3, 6, top_p=0.9)
+    steps_with_it = 0
+    while slot not in eng.free_slots():
+        steps_with_it += slot in step()
+    # scanned steps, in ``decode_steps``' unit: one a token without
+    # speculation, one a verified run with it
+    assert st.sampler_sorted_steps == (steps_with_it if kind != "chunk4"
+                                       else 5)
+    assert 1 <= st.sampler_sorted_steps <= 5
+    sorted_then, steps_then = st.sampler_sorted_steps, st.decode_steps
+    while len(eng.free_slots()) < 3:
+        step()
+    assert st.decode_steps > steps_then
+    assert st.sampler_sorted_steps == sorted_then
+    for s, (prompt, mnew, kw) in asked.items():
+        ref = generate_fast(params, cfg, prompt[None], mnew, **kw)
+        assert toks[s] == ref[0, len(prompt):].tolist(), kw
+
+
+def test_a_finished_rows_filter_does_not_keep_the_sorts_on(setup):
+    """The finished row's ``top_k`` stays in the decode state on the
+    device; ``live`` is the step's ``active``, so the steps after it
+    sort nothing."""
+    cfg, model, params = setup
+    eng = InferenceEngine(params, cfg, num_slots=2, page_size=8)
+    eng.admit(_prompt(6, 4), SamplingParams(max_new_tokens=12, seed=4))
+    slot, _ev = eng.admit(_prompt(6, 5), SamplingParams(
+        max_new_tokens=3, top_k=5, seed=5))
+    while len(eng.free_slots()) < 2:
+        eng.step()
+    assert int(eng._top_k[slot]) == 5            # still there
+    assert eng.stats.decode_steps == 11
+    assert eng.stats.sampler_sorted_steps == 2
+
+
+def test_stats_serve_the_samplers_sorted_steps(setup, tmp_path):
+    """``/stats`` carries the counter: 0 after a default and a greedy
+    request, the filtering request's decode steps after it."""
+    import json
+    import urllib.request
+    from gym_tpu.serve.__main__ import create_server
+    cfg, model, params = setup
+    handle = create_server(params, cfg, port=0, num_slots=2, page_size=8,
+                           warmup=False, metrics_dir=str(tmp_path))
+    threading.Thread(target=handle.httpd.serve_forever, daemon=True).start()
+
+    def stats():
+        with urllib.request.urlopen(
+                f"http://127.0.0.1:{handle.port}/stats", timeout=60) as r:
+            return json.loads(r.read())
+
+    try:
+        plain = [handle.scheduler.submit(_prompt(6, 1), SamplingParams(
+                     max_new_tokens=8, seed=1)),
+                 handle.scheduler.submit(_prompt(5, 2), SamplingParams(
+                     max_new_tokens=8, top_k=1))]
+        for req in plain:
+            req.result(timeout=120)
+        got = stats()
+        assert got["decode_steps"] >= 7
+        assert got["sampler_sorted_steps"] == 0
+        handle.scheduler.submit(_prompt(7, 3), SamplingParams(
+            max_new_tokens=6, top_p=0.9, seed=3)).result(timeout=120)
+        assert stats()["sampler_sorted_steps"] == 5
+    finally:
+        handle.close(drain_deadline_s=5.0)

@@ -108,6 +108,37 @@ def test_health_reports_pids_and_load_observables(fleet):
     assert "backlog_tokens" in snap and "tokens_per_s" in snap
 
 
+def test_health_carries_the_samplers_sorted_steps(fleet):
+    """The worker's health frame has ``sampler_sorted_steps`` beside
+    ``decode_steps``: a ``top_k = 7`` request of 8 tokens is 7 decode
+    steps whose sampler sorted, on the worker that served it."""
+    cfg, params, router, _m = fleet
+
+    def counted():
+        live = [r for r in router.status()["replicas"] if not r["retired"]]
+        assert all(0 <= r["sampler_sorted_steps"] <= r["decode_steps"]
+                   for r in live)
+        return sum(r["sampler_sorted_steps"] for r in live)
+
+    # nothing is running: let the frames of earlier requests come in (one
+    # a worker every half second)
+    before = counted()
+    for _ in range(3):
+        time.sleep(1.2)
+        before, was = counted(), before
+        if before == was:
+            break
+    prompt = [2, 4, 6, 8]
+    pr = router.submit(prompt, SamplingParams(
+        max_new_tokens=8, temperature=0.9, top_k=7, seed=4))
+    assert pr.result(timeout=120) == _ref(params, cfg, prompt, 8,
+                                          temperature=0.9, top_k=7, seed=4)
+    deadline = time.monotonic() + 30
+    while counted() < before + 7 and time.monotonic() < deadline:
+        time.sleep(0.2)
+    assert counted() == before + 7
+
+
 def test_proc_reload_rolls_through_workers(fleet):
     """Rolling hot-swap across the process boundary: both workers
     drain, rebuild from the new snapshot, and post-swap generations

@@ -219,23 +219,26 @@ def test_the_scrub_writes_the_null_blocks_zeros_over_both_arrays(
 
 
 # sha256 of ``lower().as_text()`` of the page models' programs at their
-# rehearsal sizes (page 4, 40 pages, 2 slots; CPU), taken on the parent
-# commit of PR 33 (461f5df) by this file's own recipe
+# rehearsal sizes (page 4, 40 pages, 2 slots; CPU), by this file's own
+# recipe. The copy-on-write programs' are the parent commit of PR 33's
+# (461f5df); the decode and prefill programs' were taken again at PR 36,
+# which changed the one thing they share, the sampler (``sample_rows``:
+# a conditional around the sorts, and ``sorted`` in a step's download)
 PARENT_TEXT = {
     "keye-vl2-30b-a3b/decode":
-        "9231202b05efe6c5a36b96c453f7b7511ba4ae0c6cdaa0a99d400ffc351b36e3",
+        "37c2a997e0f2929ac99c79880ba1b9d4e6d40bd1d42c6c96e27923673eb1e6d4",
     "keye-vl2-30b-a3b/prefill16":
-        "18453c88fd81caa4fc6e8c699637e2d4941d8232d234c9da52db93c31be3eb12",
+        "38c66025f31522eaa37ab99c0e9faabb576f7eb1df2e91fb6b7ebce6f16d92b3",
     "keye-vl2-30b-a3b/prefill64":
-        "481a0d6f0cedde27ccde753078e376bd9e2f3f5eb5f74034fcb12d4fd1e5563b",
+        "0b5351a0ca3da73335b43c190c539d64facf4cdbb3820387c1dedae2191116e0",
     "keye-vl2-30b-a3b/cow":
         "aa64e65774b9263745cae5c5c88df6e1149028305a83b0ec3d45892e2fdaa208",
     "command-a-plus/decode":
-        "ea69b8c0c8b63e7de08f56e4d11caaf3adb7d4587c71fbd869b2c8bfa02b61b2",
+        "f775416503389b12c33db5fc8c8afb0a6ef752015fe62076b4dc6509f5839336",
     "command-a-plus/prefill16":
-        "cb98c0dd9435cbf8fec99d46d631b7bad7b5424a87c44781dc479e006d97c10e",
+        "c0bc2c2529f86cb356f589c43d93fdf94219eede8817f2efe9f3cb859ce4a592",
     "command-a-plus/prefill64":
-        "fd963a34c23f266d8c228d357f77604ea2e8124b82ec65cdbd499f7d01f8333f",
+        "446e41d89516ea5879bb2b057b3e821dc2d8d005d5c5e50ecd88e8a278fe6250",
     "command-a-plus/cow":
         "d17dfaeaba0c733fcb1123392341728f2f67ce0a2f4e6b2ca4180265c3079043",
 }
@@ -246,7 +249,9 @@ def test_the_page_models_programs_lower_to_the_parents_text(name):
     """``keye_vl2.py`` now takes its norm, projections, rotation and head
     from ``decoder_parts.py`` (shared with ``brumby.py``) and the engine
     asks every config whether its cache is a block a row: neither moved
-    an operation of the page models' programs."""
+    an operation of the page models' programs. (Whoever changes what
+    every served program runs takes the texts again, and says so
+    above.)"""
     model, prog = name.split("/")
     make = {"keye-vl2-30b-a3b": closed_keye.model_config,
             "command-a-plus": closed_model.model_config}[model]
