@@ -557,8 +557,12 @@ def create_server(params, cfg, *, host: str = "127.0.0.1", port: int = 0,
                 # a warm disk tier) — plus background-warmup progress
                 "programs_compiled": programs_mod.xla_compile_counter(),
                 # the program's spans so far: {name: [count, total_s,
-                # max_s]} (utils/trace.py; names in PERF.md §3)
+                # max_s, cpu_s]} (utils/trace.py; names in PERF.md §3)
                 "spans": trace.totals(),
+                # CPU seconds of every thread of this process so far:
+                # beside the spans' cpu_s it says what the threads
+                # outside any span ran
+                "process_cpu_s": time.process_time(),
                 "readback_bytes": sum(s.readback_bytes for s in stats),
                 # host arrays of the decode state handed to dispatches,
                 # and decode dispatches that were handed none (all
@@ -650,63 +654,68 @@ def create_server(params, cfg, *, host: str = "127.0.0.1", port: int = 0,
             if self.path != "/generate":
                 self._reply(404, {"error": f"unknown path {self.path}"})
                 return
-            # to the last byte written; `request` joins it once known
-            with trace.span("http.generate") as http_span:
+            # to the last byte written; `request` joins it once known.
+            # Recorder only, like every span of a handler thread: these
+            # threads do not feed the device (utils/trace.py). Its cpu
+            # is all this thread ran for the request; there is no span a
+            # server-sent event: the thread clock is a system call
+            with trace.span("http.generate", annotate=False) as http_span:
                 self._generate(http_span)
 
         def _generate(self, http_span):
             try:
-                fault_point("serve.http")
-                n = int(self.headers.get("Content-Length", 0))
-                raw = self.rfile.read(n) or b"{}"
-                try:
-                    body = json.loads(raw)
-                except json.JSONDecodeError as e:
-                    raise ValueError(f"malformed JSON body: {e}")
-                if not isinstance(body, dict):
-                    raise ValueError(
-                        f"JSON body must be an object, got "
-                        f"{type(body).__name__}")
-                if "prompt" in body:
-                    prompt = np.asarray(body["prompt"], np.int32)
-                elif "text" in body and char_level:
-                    prompt = encode_text(body["text"])
-                elif "text" in body:
-                    raise ValueError(
-                        "text prompts need a char-level vocab; this model "
-                        f"has vocab_size={cfg.vocab_size} — send token "
-                        "ids as 'prompt'")
-                else:
-                    raise ValueError("body needs 'prompt' (token ids) "
-                                     "or 'text'")
-                sp = SamplingParams(
-                    max_new_tokens=int(body.get("max_new_tokens", 64)),
-                    temperature=float(body.get("temperature", 1.0)),
-                    top_k=(None if body.get("top_k") is None
-                           else int(body["top_k"])),
-                    top_p=(None if body.get("top_p") is None
-                           else float(body["top_p"])),
-                    eos_token=(None if body.get("eos_token") is None
-                               else int(body["eos_token"])),
-                    seed=int(body.get("seed", 0)))
-                # body field wins over the X-Deadline-S header; both win
-                # over the server-wide default
-                deadline = body.get("deadline_s",
-                                    self.headers.get("X-Deadline-S"))
-                deadline = (default_deadline if deadline is None
-                            else float(deadline))
-                stream = bool(body.get("stream", False))
-                # multi-tenant tags (ISSUE 17): body field wins over
-                # the header; both optional — absent = the default
-                # tenant/class (single-tenant behavior)
-                tenant = body.get("tenant",
-                                  self.headers.get("X-Tenant"))
-                slo_class = body.get("slo_class",
-                                     self.headers.get("X-SLO-Class"))
-                if tenant is not None:
-                    tenant = str(tenant)
-                if slo_class is not None:
-                    slo_class = str(slo_class)
+                with trace.span("http.parse", annotate=False):
+                    fault_point("serve.http")
+                    n = int(self.headers.get("Content-Length", 0))
+                    raw = self.rfile.read(n) or b"{}"
+                    try:
+                        body = json.loads(raw)
+                    except json.JSONDecodeError as e:
+                        raise ValueError(f"malformed JSON body: {e}")
+                    if not isinstance(body, dict):
+                        raise ValueError(
+                            f"JSON body must be an object, got "
+                            f"{type(body).__name__}")
+                    if "prompt" in body:
+                        prompt = np.asarray(body["prompt"], np.int32)
+                    elif "text" in body and char_level:
+                        prompt = encode_text(body["text"])
+                    elif "text" in body:
+                        raise ValueError(
+                            "text prompts need a char-level vocab; this model "
+                            f"has vocab_size={cfg.vocab_size} — send token "
+                            "ids as 'prompt'")
+                    else:
+                        raise ValueError("body needs 'prompt' (token ids) "
+                                         "or 'text'")
+                    sp = SamplingParams(
+                        max_new_tokens=int(body.get("max_new_tokens", 64)),
+                        temperature=float(body.get("temperature", 1.0)),
+                        top_k=(None if body.get("top_k") is None
+                               else int(body["top_k"])),
+                        top_p=(None if body.get("top_p") is None
+                               else float(body["top_p"])),
+                        eos_token=(None if body.get("eos_token") is None
+                                   else int(body["eos_token"])),
+                        seed=int(body.get("seed", 0)))
+                    # body field wins over the X-Deadline-S header; both win
+                    # over the server-wide default
+                    deadline = body.get("deadline_s",
+                                        self.headers.get("X-Deadline-S"))
+                    deadline = (default_deadline if deadline is None
+                                else float(deadline))
+                    stream = bool(body.get("stream", False))
+                    # multi-tenant tags (ISSUE 17): body field wins over
+                    # the header; both optional — absent = the default
+                    # tenant/class (single-tenant behavior)
+                    tenant = body.get("tenant",
+                                      self.headers.get("X-Tenant"))
+                    slo_class = body.get("slo_class",
+                                         self.headers.get("X-SLO-Class"))
+                    if tenant is not None:
+                        tenant = str(tenant)
+                    if slo_class is not None:
+                        slo_class = str(slo_class)
             except (ValueError, KeyError, TypeError) as e:
                 self._reply(400, {"error": str(e)})
                 return
@@ -721,9 +730,11 @@ def create_server(params, cfg, *, host: str = "127.0.0.1", port: int = 0,
                 submit_kw = ({"stream": stream}
                              if getattr(router, "kind", "") == "process"
                              else {})
-                req = router.submit(prompt, sp, timeout=30.0,
-                                    deadline_s=deadline, tenant=tenant,
-                                    slo_class=slo_class, **submit_kw)
+                with trace.span("http.submit", annotate=False) as sub:
+                    req = router.submit(prompt, sp, timeout=30.0,
+                                        deadline_s=deadline, tenant=tenant,
+                                        slo_class=slo_class, **submit_kw)
+                    sub.ids["request"] = req.id
             except AdmissionRejectedError as e:
                 self._reply(429, {"error": str(e)},
                             retry_after_s=e.retry_after_s)

@@ -1029,9 +1029,8 @@ class InferenceEngine:
         self._top_k[slot] = top_k
         self._top_p[slot] = top_p
         self._base_keys[slot] = key
-        with span("serve.prefill.readback"):
-            # no wait: the token is read with the next step's download
-            self._firsts.append((slot, tok))
+        # no wait: the token is read with the next step's download
+        self._firsts.append((slot, tok))
         self.stats.active_slots = int(self._active.sum())
         self.stats.prefill_buckets = tuple(sorted(self._seen_buckets))
         return slot
@@ -1071,7 +1070,7 @@ class InferenceEngine:
         # an admission that fails its request must not shrink the pool
         held: List[int] = []
         try:
-            with span("serve.prefill.args") as sp_args:
+            with span("serve.prefill.plan") as sp_plan:
                 hit_pages, chain, cow_src, cid, start, suffix, bucket, \
                     n_new, need = self._plan_paged(prompt,
                                                    sp.max_new_tokens)
@@ -1111,7 +1110,7 @@ class InferenceEngine:
                 padded[0, :suffix] = prompt[start:]
                 state, ups = self._state_args(self._state_names)
                 if ups:
-                    sp_args.ids["uploads"] = ups
+                    sp_plan.ids["uploads"] = ups
                 # NumPy as it is: the executable's own argument path
                 # transfers the batch
                 args = (state, np.int32(slot), self._bt[slot][None],

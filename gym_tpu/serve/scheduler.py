@@ -546,6 +546,11 @@ class Scheduler:
                 self._queued_deadlines += 1
         return req
 
+    @property
+    def round(self) -> int:
+        """The ``round`` id the last ``step`` gave its spans."""
+        return self._round
+
     def queue_depth(self) -> int:
         with self._lock:
             return len(self._queue)

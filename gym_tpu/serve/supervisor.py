@@ -38,6 +38,7 @@ import traceback
 from typing import Callable, Optional
 
 from ..utils.resilience import Watchdog, dump_thread_stacks
+from ..utils.trace import span
 from .engine import InferenceEngine
 from .scheduler import EngineFailedError, Scheduler
 
@@ -123,15 +124,22 @@ class Supervisor:
                     f"engine raised {type(e).__name__}: {e}"),
                     wedged=False)
                 return
-            with self._lock:
-                # re-check AFTER the step: a thread that was failed over
-                # past while wedged inside the dispatch must not tick
-                # metrics against the new generation's engine
-                if self._gen != gen:
-                    return
-            if self.metrics is not None:
-                self.metrics.engine_tick(
-                    sched.engine.stats, queue_depth=sched.queue_depth())
+            # what the driver thread does between two rounds, under a
+            # span of its own so that its loop is covered from one
+            # round's start to the next's; kept, like the round's, only
+            # if the round produced
+            with span("serve.tick", hold=True, round=sched.round) as tick:
+                tick.keep = produced > 0
+                with self._lock:
+                    # re-check AFTER the step: a thread that was failed
+                    # over past while wedged inside the dispatch must not
+                    # tick metrics against the new generation's engine
+                    if self._gen != gen:
+                        return
+                if self.metrics is not None:
+                    self.metrics.engine_tick(
+                        sched.engine.stats,
+                        queue_depth=sched.queue_depth())
             if produced == 0:
                 self._stop.wait(self.idle_wait_s)
         wd.close()

@@ -11,6 +11,7 @@ import json
 import os
 import re
 import threading
+import time
 import urllib.request
 
 import jax
@@ -73,7 +74,7 @@ def _per_thread():
 
 
 def _bounded():
-    before = trace.totals().get("t.flood", (0, 0.0, 0.0))[0]
+    before = trace.totals().get("t.flood", (0,))[0]
     for _ in range(trace.CAPACITY + 50):
         with trace.span("t.flood"):
             pass
@@ -118,8 +119,103 @@ def _late_ids():
     assert _since(mark, "t.late", request=41)
 
 
+def _spin(seconds):
+    end = time.perf_counter() + seconds
+    while time.perf_counter() < end:
+        pass
+
+
+def _cpu_of(name, work, **kw):
+    """The record and the totals row's growth of one span around ``work``."""
+    before = trace.totals().get(name, (0, 0.0, 0.0, 0.0))
+    with trace.span(name, **kw) as sp:
+        work()
+    rec, = [r for r in trace.records(name) if r.seq == sp.seq]
+    after = trace.totals()[name]
+    return rec, [a - b for a, b in zip(after, before)]
+
+
+def _cpu_sleeps():
+    rec, grew = _cpu_of("t.cpu.sleeps", lambda: time.sleep(0.05))
+    assert rec.seconds >= 0.05 and 0 <= rec.cpu * 1e-9 < 0.01
+    assert grew[3] == pytest.approx(rec.cpu * 1e-9)
+
+
+def _cpu_spins():
+    # a machine that runs six workers of this suite may take the core
+    # away for a while: the best of a few tries
+    for _ in range(5):
+        rec, grew = _cpu_of("t.cpu.spins", lambda: _spin(0.05))
+        # the two clocks are read at the same moments, to REUSE_NS
+        assert 0 < rec.cpu <= rec.t1 - rec.t0 + trace.REUSE_NS
+        if rec.cpu * 1e-9 > 0.8 * rec.seconds:
+            break
+    assert rec.cpu * 1e-9 > 0.8 * rec.seconds
+    assert grew[3] == pytest.approx(rec.cpu * 1e-9)
+
+
+def _cpu_contended():
+    """One interpreter lock: beside four threads that spin, the span's
+    thread runs about a fifth of its wall time."""
+    stop = threading.Event()
+
+    def other():
+        while not stop.is_set():
+            pass
+    threads = [threading.Thread(target=other, daemon=True)
+               for _ in range(4)]
+    for th in threads:
+        th.start()
+    try:
+        rec, _ = _cpu_of("t.cpu.contended", lambda: _spin(0.3))
+    finally:
+        stop.set()
+        for th in threads:
+            th.join(timeout=30)
+    assert not any(th.is_alive() for th in threads)
+    assert rec.cpu * 1e-9 < 0.6 * rec.seconds
+
+
+def _cpu_reading_shared():
+    """The thread clock is a system call: where one span closes and the
+    next opens both take one reading, at most one every ``REUSE_NS``."""
+    real, calls = time.thread_time_ns, []
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(time, "thread_time_ns",
+                      lambda: calls.append(1) or real())
+        t0 = time.perf_counter_ns()
+        for _ in range(200):
+            with trace.span("t.cpu.shared"):
+                pass
+        wall = time.perf_counter_ns() - t0
+    assert 1 <= len(calls) <= wall // trace.REUSE_NS + 2
+    assert len(calls) < 200                 # of 400 boundaries
+
+
+def _totals_rows():
+    name = f"t.rows.{_mark()}"              # a row nobody else wrote
+    recs = []
+    for nap in (0.01, 0.03):
+        with trace.span(name) as sp:
+            time.sleep(nap)
+            _spin(0.002)
+        recs += [r for r in trace.records(name) if r.seq == sp.seq]
+    trace.record(name, 1.0, 1.5)            # after the fact: no CPU stamp
+    row = trace.totals()[name]
+    assert len(row) == 4
+    assert row[0] == 3
+    assert row[1] == pytest.approx(sum(r.seconds for r in recs) + 0.5)
+    assert row[2] == pytest.approx(0.5)
+    assert row[3] == pytest.approx(sum(r.cpu for r in recs) * 1e-9)
+    assert trace.records(name)[-1].cpu == 0
+    # a record made by hand, as the benchmark's tests make them
+    assert trace.Record(1, "x", 0, 1, None, {}).cpu == 0
+
+
 @pytest.mark.parametrize("case", [_nested, _per_thread, _bounded, _raises,
-                                  _held, _late_ids],
+                                  _held, _late_ids, _cpu_sleeps, _cpu_spins,
+                                  _cpu_contended, _cpu_reading_shared,
+                                  _totals_rows],
                          ids=lambda f: f.__name__.strip("_"))
 def test_recorder(case):
     case()
@@ -264,9 +360,8 @@ ROUND_LEAVES = {"serve.shed", "serve.pick", "serve.admit",
                 "serve.decode.args", "serve.decode.dispatch",
                 "serve.decode.readback", "serve.decode.events",
                 "serve.deliver"}
-PREFILL_LEAVES = ["serve.prefill.args", "serve.prefill.args",
-                  "serve.prefill.dispatch", "serve.prefill.readback",
-                  "request.queue"]
+PREFILL_LEAVES = ["serve.prefill.args", "serve.prefill.plan",
+                  "serve.prefill.dispatch", "request.queue"]
 
 
 @pytest.fixture(scope="module")
@@ -346,9 +441,21 @@ def _serve_readback_carries_its_bytes(reqs, recs, eng):
     assert sum(r.ids["uploads"] for r in args) == eng.stats.upload_arrays
 
 
+def _serve_prefill_readback_where_it_waits(reqs, recs, eng):
+    """A prefill's token comes down with the next step's read; only where
+    no step is in flight to read it with (the first round here) does a
+    ``serve.prefill.readback`` wait for it, inside ``.events``."""
+    reads = [r for r in recs if r.name == "serve.prefill.readback"]
+    events = {r.seq for r in recs if r.name == "serve.decode.events"}
+    assert reads and all(r.parent in events for r in reads)
+    assert "request" not in reads[0].ids
+    assert len(reads) < len(reqs)
+
+
 @pytest.mark.parametrize("check", [_serve_leaves_tile_the_round,
                                    _serve_one_admit_and_queue_a_request,
-                                   _serve_readback_carries_its_bytes],
+                                   _serve_readback_carries_its_bytes,
+                                   _serve_prefill_readback_where_it_waits],
                          ids=lambda f: f.__name__.strip("_"))
 def test_serving_round(served, check):
     check(*served)
@@ -383,17 +490,115 @@ def test_request_id_runs_from_http_to_prefill(tiny_gpt, tmp_path, stream):
         th.join(timeout=60)
     http, = _since(mark, "http.generate")
     rid = http.ids["request"]
-    for name in ("request.queue", "serve.admit", "serve.prefill.dispatch",
-                 "serve.prefill.readback"):
+    for name in ("request.queue", "serve.admit", "serve.prefill.plan",
+                 "serve.prefill.dispatch", "http.submit"):
         assert len(_since(mark, name, request=rid)) == 1, name
     admit, = _since(mark, "serve.admit", request=rid)
     assert http.t0 <= admit.t0 and admit.t1 <= http.t1
+    # the handler's leaves lie under the request's span, in order; the
+    # body is parsed before any id is known
+    parse, = _since(mark, "http.parse")
+    submit, = _since(mark, "http.submit")
+    assert parse.parent == submit.parent == http.seq
+    assert http.t0 <= parse.t0 <= parse.t1 <= submit.t0 <= admit.t0
     # /stats serves the totals, and the bytes the decode steps read back
-    for name in ("serve.round", "serve.admit", "serve.decode.readback",
-                 "request.queue"):
-        count, total_s, max_s = stats["spans"][name]
+    for name in ("serve.round", "serve.tick", "serve.admit",
+                 "serve.decode.readback", "request.queue", "http.parse"):
+        count, total_s, max_s, cpu_s = stats["spans"][name]
         assert count >= 1 and total_s >= max_s >= 0
+        assert 0 <= cpu_s <= total_s + count * trace.REUSE_NS * 1e-9
+    assert stats["spans"]["request.queue"][3] == 0      # never stamped
     assert stats["readback_bytes"] > 0
+
+
+# -- who annotates, and what the handlers leave -----------------------------
+
+
+class _CountingAnnotation:
+    """Stands where ``TraceAnnotation`` does, says a profiler session is
+    on, and lists what entered it."""
+    entered = []
+
+    def __init__(self, name, **ids):
+        self.name = name
+
+    @staticmethod
+    def is_enabled():
+        return True
+
+    def __enter__(self):
+        self.entered.append(self.name)
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+
+@pytest.fixture(scope="module")
+def streamed(tiny_gpt, tmp_path_factory):
+    """One streamed ``/generate`` against the tiny in-process server with
+    every annotation counted: ``(names annotated, /stats after,
+    records)``."""
+    from gym_tpu.serve.__main__ import create_server
+    cfg, params = tiny_gpt
+    handle = create_server(params, cfg, port=0, num_slots=2, replicas=1,
+                           page_size=8, warmup=False,
+                           metrics_dir=str(tmp_path_factory.mktemp("m")))
+    th = threading.Thread(target=handle.httpd.serve_forever, daemon=True)
+    th.start()
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(trace, "TraceAnnotation", _CountingAnnotation)
+        _CountingAnnotation.entered.clear()
+        try:
+            mark = _mark()
+            body = _post(handle.port, {"prompt": [3, 1, 4, 1, 5],
+                                       "max_new_tokens": 6, "seed": 4,
+                                       "stream": True})
+            assert body.count(b"data: ") >= 2   # token chunks, the summary
+            with urllib.request.urlopen(
+                    f"http://127.0.0.1:{handle.port}/stats",
+                    timeout=60) as r:
+                stats = json.loads(r.read())
+        finally:
+            handle.close()
+            th.join(timeout=60)
+        assert not th.is_alive()
+        return list(_CountingAnnotation.entered), stats, _since(mark)
+
+
+@pytest.mark.parametrize("name,annotated", [
+    ("serve.tick", True), ("serve.decode.dispatch", True),
+    ("serve.round", False), ("http.generate", False), ("http.parse", False),
+    ("http.submit", False)])
+def test_only_the_thread_that_feeds_the_device_annotates(streamed, name,
+                                                         annotated):
+    entered, stats, _recs = streamed
+    assert stats["spans"][name][0] >= 1         # it did fire
+    assert (name in entered) == annotated
+
+
+def test_handler_cpu_is_the_requests_and_stats_has_the_processes(streamed):
+    _entered, stats, recs = streamed
+    http, = [r for r in recs if r.name == "http.generate"]
+    leaves = [r for r in recs if r.parent == http.seq]
+    assert [r.name for r in leaves] == ["http.parse", "http.submit"]
+    # all the handler thread ran for the request, its leaves and every
+    # event it wrote included; there is no span an event (the thread
+    # clock is a system call)
+    assert sum(r.cpu for r in leaves) < http.cpu < (http.t1 - http.t0)
+    assert set(stats["spans"]) >= {"http.generate", "http.parse",
+                                   "http.submit"}
+    assert not [n for n in stats["spans"] if n.startswith("http.write")]
+    assert 0 < stats["process_cpu_s"] <= time.process_time()
+
+
+def test_tick_follows_its_round_on_the_driver_thread(streamed):
+    _entered, _stats, recs = streamed
+    rounds = {r.ids["round"]: r for r in recs if r.name == "serve.round"}
+    ticks = [r for r in recs if r.name == "serve.tick"]
+    assert ticks and all(r.parent is None for r in ticks)
+    for tick in ticks:                          # kept with its round
+        assert rounds[tick.ids["round"]].t1 <= tick.t0 <= tick.t1
 
 
 # -- names in the step program ------------------------------------------------
