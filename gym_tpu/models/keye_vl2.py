@@ -126,7 +126,8 @@ class KeyeVL2Config:
 
 def write_index_keys(pool, ki, block_table, cache_pos, page: int):
     """The index keys ``ki`` [b, t, d] of every row's ``t`` new positions
-    (the first at ``cache_pos``) into ``pool`` [pages, page * d], whole
+    (the first at ``cache_pos``) into ``pool`` (a page's ``page * d``
+    values side by side, ``sparse_attention.index_pool_shape``), whole
     pages at a time: the pages the positions fall on are read, the new
     keys laid over their places and the pages written back (a scatter of
     whole rows; a window of 64 lanes a position is a loop of as many
@@ -142,7 +143,7 @@ def write_index_keys(pool, ki, block_table, cache_pos, page: int):
     rows = jax.vmap(
         lambda r, new, at: jax.lax.dynamic_update_slice(r, new, (at, 0)))(
             pool[phys].reshape(b, n * page, d), ki, cache_pos % page)
-    return pool.at[phys].set(rows.reshape(b, n, page * d))
+    return pool.at[phys].set(rows.reshape((b, n) + pool.shape[1:]))
 
 
 class SparsePagedAttention(nn.Module):
@@ -190,11 +191,12 @@ class SparsePagedAttention(nn.Module):
                            lambda: jnp.zeros((P, page, KV * hd), kv_dt))
         cv = self.variable("cache", "v",
                            lambda: jnp.zeros((P, page, KV * hd), kv_dt))
-        # a page's index keys side by side on one row: a minor dimension
-        # of 64 would be laid out with the page index on the lanes and
-        # the whole pool copied to and fro around every scatter
-        ci = self.variable("cache", "ki",
-                           lambda: jnp.zeros((P, page * di), kv_dt))
+        # a page's index keys side by side on whole lane rows: a minor
+        # dimension of 64 would be laid out with the page index on the
+        # lanes and the whole pool copied to and fro around every scatter
+        ci = self.variable(
+            "cache", "ki",
+            lambda: jnp.zeros(sa.index_pool_shape(P, page, di), kv_dt))
         k_pool = ck.value.at[phys, off].set(
             k.reshape(b, t, KV * hd).astype(kv_dt))
         v_pool = cv.value.at[phys, off].set(v.astype(kv_dt))

@@ -4,9 +4,10 @@ attends over those alone (DeepSeek Sparse Attention's published form).
 
 A layer keeps three things a position in the engine's pools: the key and
 the value (``[pages, page, kv_heads * head_dim]``, as every paged layer)
-and ONE index key shared by the index's heads (``[pages, page *
-index_dim]``: a page's index keys side by side, whole lane tiles). For
-query ``t`` of a row and resident position ``s <= t``::
+and ONE index key shared by the index's heads (``index_pool_shape``: a
+page's index keys side by side in rows of 128 lanes, ``[pages, 8, 128]``
+at 16 keys of 64, so that a page is one whole tile). For query ``t`` of a
+row and resident position ``s <= t``::
 
     I[t, s] = sum_j w[t, j] * relu(qI[t, j] . kI[s])        (float32)
     S_t     = the topk positions of largest I[t, s]; ties to the lower s
@@ -19,12 +20,18 @@ attention. Nothing approximates: the kept set is the exact one.
 Two implementations, by the number of queries a row brings:
 
 * ``attend_rows`` (a decode step, a speculative step, a prefill of a few
-  tokens): the row's index keys are gathered through its block table,
-  the kept positions are found (``kept_mask``) and listed (``compact``)
-  without a sort (``lax.top_k`` of 2,048 from 36,864 is a sort on the
-  chip: 4.4 ms a layer measured, PERF.md) and only THEIR keys and values
-  are gathered out of the pools, ``topk`` rows of a kilobyte a query,
-  whatever the row holds.
+  tokens): on a TPU the index scores are the Pallas kernel
+  ``index_keys_paged`` (``sparse_index_decode``), which walks a row's
+  LIVE pages of index keys where they lie in the pool, a chunk of pages
+  copied into VMEM while the chunk before is scored, and writes only the
+  scores' order-preserving integers (``index_path`` decides from backend,
+  dtype and shape; elsewhere every row's whole table is gathered and
+  scored by ``index_scores_paged``, the kernel's reference, whatever the
+  rows hold). The kept positions are found (``kept_mask``) and listed
+  (``compact``) without a sort (``lax.top_k`` of 2,048 from 36,864 is a
+  sort on the chip: 4.4 ms a layer measured, PERF.md) and only THEIR keys
+  and values are gathered out of the pools, ``topk`` rows of a kilobyte a
+  query, whatever the row holds.
 * ``attend_block`` (a prefill's block of queries): every query has its
   own set, so the set is a mask. The index scores of the block are laid
   down as order-preserving integers a block of keys at a time, the
@@ -37,8 +44,9 @@ Two implementations, by the number of queries a row brings:
   costs what its causal triangle costs and no ``[t, t]`` array is ever
   whole.
 
-Device scopes: ``attn.index`` (scores), ``attn.select`` (the selection),
-``attn.sparse`` (the attend over the kept keys).
+Device scopes: ``attn.index`` (scores; the decode step's kernel carries
+it), ``attn.select`` (the selection), ``attn.sparse`` (the attend over
+the kept keys).
 """
 
 from __future__ import annotations
@@ -59,15 +67,27 @@ KERNEL_TK = 512         # key positions a step of it
 _VMEM = 64 << 20
 ROWS_MAX_T = 16         # queries a row up to which ``attend_rows`` runs
 _BITS = 2               # bits of the threshold found a pass (divides 32)
+LANES = 128
+INDEX_CHUNK = 128       # pages of index keys a step of the decode index
+INDEX_SLOTS = 3         # chunks whose copies are in flight or being scored
+INDEX_UNROLL = 16       # of a whole chunk's loop of page-copy starts
+INDEX_KERNEL = "sparse_index_kernel"
+INDEX_GATHER = "sparse_index_gather"
+
+
+def _sortable_i32(x):
+    """``sortable``'s uint32 as the int32 of the same bits (what a
+    kernel computes in)."""
+    x = jnp.where(x == 0, 0.0, x.astype(jnp.float32))
+    bits = jax.lax.bitcast_convert_type(x, jnp.int32)
+    return jnp.where(bits < 0, ~bits, bits | jnp.int32(-2 ** 31))
 
 
 def sortable(x):
     """float32 -> uint32 that orders as the numbers do (``-0.0`` with
     ``0.0``); every finite value and both infinities map above 0, which
     is left for "no key here"."""
-    x = jnp.where(x == 0, 0.0, x.astype(jnp.float32))
-    bits = jax.lax.bitcast_convert_type(x, jnp.uint32)
-    return jnp.where(bits >> 31 == 1, ~bits, bits | jnp.uint32(1 << 31))
+    return jax.lax.bitcast_convert_type(_sortable_i32(x), jnp.uint32)
 
 
 def index_scores(qi, wi, ki):
@@ -81,8 +101,8 @@ def index_scores(qi, wi, ki):
 
 def index_scores_paged(qi, wi, ki_pages, page: int):
     """``index_scores`` of a few queries a row against the row's pages
-    of index keys as the pool holds them, ``ki_pages`` [b, pages, page *
-    d] (a page's keys side by side), without taking the pages apart
+    of index keys, ``ki_pages`` [b, pages, page * d] (a page's keys side
+    by side, as the pool holds them), without taking the pages apart
     (that is a relayout of the gathered 75 MB a layer): a page's row
     times the queries laid out block-diagonally gives the page's
     ``page x J`` products on the lanes, and the weighted sum over heads
@@ -206,10 +226,17 @@ def attend_rows(q, qi, wi, k_pool, v_pool, ki_pool, block_table, cache_pos,
     S = mb * page
     K = min(int(topk), S)
     qpos = cache_pos[:, None] + jnp.arange(t)[None, :]            # [b, t]
+    path = index_path(qi, ki_pool, page, mb)
+    _pa.report_path(path, tuple(qi.shape), str(qi.dtype))
     with jax.named_scope("attn.index"):
-        scores = index_scores_paged(qi, wi, ki_pool[block_table], page)
-        seen = jnp.arange(S)[None, None, :] <= qpos[:, :, None]
-        keys = jnp.where(seen, sortable(scores), 0)
+        if path == INDEX_KERNEL:
+            keys = index_keys_paged(qi, wi, ki_pool, block_table, cache_pos,
+                                    page)
+        else:
+            scores = index_scores_paged(
+                qi, wi, ki_pool[block_table].reshape(b, mb, -1), page)
+            seen = jnp.arange(S)[None, None, :] <= qpos[:, :, None]
+            keys = jnp.where(seen, sortable(scores), 0)
     with jax.named_scope("attn.select"):
         idx, kept = compact(kept_mask(keys, K), K)             # [b, t, K]
     with jax.named_scope("attn.sparse"):
@@ -467,3 +494,198 @@ def _masked_attend(q, k_row, v_row, keys, thr, pos, last, interpret):
         name="sparse_masked_prefill",
     )(pos.astype(jnp.int32), last.astype(jnp.int32), q, k_row, v_row, keys,
       thr)
+
+
+# -- the index of a decode step, as a kernel ---------------------------------
+
+
+def index_pool_shape(pages: int, page: int, d: int) -> tuple:
+    """The shape of a layer's pool of index keys: a page's ``page * d``
+    values side by side, cut into rows of ``LANES`` where that is whole
+    rows (``[pages, 8, 128]`` at 16 keys of 64: a page is one whole tile,
+    2 KB in one place, which a kernel may copy alone; of a ``[pages,
+    1024]`` array Mosaic slices only aligned groups of 8 rows), else one
+    row a page."""
+    width = page * d
+    lanes = LANES if width % LANES == 0 else width
+    return (pages, width // lanes, lanes)
+
+
+def index_path(qi, ki_pool, page: int, mb: int) -> str:
+    """Which implementation ``attend_rows`` takes for the index scores of
+    queries ``qi`` [b, t, J, d], from what the code can observe:
+    ``INDEX_KERNEL`` (``index_keys_paged``) on a TPU, or under
+    ``paged_attention.INTERPRET``, when queries and pool share one of
+    float32 and bfloat16, a lane row of the pool holds whole positions
+    and the table is whole chunks of ``INDEX_CHUNK`` pages; on a TPU also
+    only when a page is whole (8, 128) tiles, a chunk's lane rows whole
+    lane tiles and the heads whole sublane tiles. Else ``INDEX_GATHER``:
+    the row's table gathered and ``index_scores_paged``."""
+    _, sub, lanes = ki_pool.shape
+    heads, d = qi.shape[2:]
+    ok = (qi.dtype == ki_pool.dtype
+          and ki_pool.dtype in (jnp.float32, jnp.bfloat16)
+          and lanes % d == 0 and mb % INDEX_CHUNK == 0)
+    if not _pa.INTERPRET:
+        ok = (ok and _pa._on_tpu() and lanes == LANES and sub % 8 == 0
+              and (INDEX_CHUNK * sub) % LANES == 0 and heads % 8 == 0)
+    return INDEX_KERNEL if ok else INDEX_GATHER
+
+
+def _index_kernel(bt_ref, pos_ref, w1_ref, w2_ref, ki_hbm, o_ref, kbuf, sem,
+                  *, t, page, d, heads, ppc, mb, slots):
+    r = pl.program_id(0)
+    sub, lanes = kbuf.shape[2:]
+    per = lanes // d            # positions a lane row of the pool holds
+    rows = ppc * sub            # lane rows a chunk
+    pos0 = pos_ref[r]
+    kv_len = jnp.minimum(pos0 + t, mb * page)
+    # a row redirected to the null page (inactive slot) reads one page
+    kv_len = jnp.where(bt_ref[r * mb] == 0, jnp.minimum(kv_len, page),
+                       kv_len)
+    n_pages = pl.cdiv(kv_len, page)
+    n_chunks = pl.cdiv(n_pages, ppc)
+    unroll = math.gcd(ppc, INDEX_UNROLL)
+
+    def copy(phys, slot, p):
+        return pltpu.make_async_copy(ki_hbm.at[phys], kbuf.at[slot, p],
+                                     sem.at[slot])
+
+    # The scalar core starts a page's copy in some tens of cycles, and
+    # that, not the bytes, is what a chunk costs (PERF.md §6, PR 38): a
+    # whole chunk's copies are started from an unrolled loop and waited
+    # for once, as one copy of the chunk's size; only a row's last chunk
+    # takes page-by-page loops over the pages it holds.
+    def start(c, slot):
+        live = jnp.minimum(ppc, n_pages - c * ppc)
+
+        def one(p, carry):
+            copy(bt_ref[r * mb + c * ppc + p], slot, p).start()
+            return carry
+
+        def several(g, carry):
+            for u in range(unroll):
+                one(g * unroll + u, carry)
+            return carry
+
+        pl.when(live == ppc)(lambda: jax.lax.fori_loop(
+            0, ppc // unroll, several, None))
+        pl.when(live < ppc)(lambda: jax.lax.fori_loop(0, live, one, None))
+
+    def wait(c, slot):
+        live = jnp.minimum(ppc, n_pages - c * ppc)
+
+        def one(p, carry):
+            copy(0, slot, p).wait()
+            return carry
+
+        pl.when(live == ppc)(pltpu.make_async_copy(
+            ki_hbm.at[pl.ds(0, ppc)], kbuf.at[slot], sem.at[slot]).wait)
+        pl.when(live < ppc)(lambda: jax.lax.fori_loop(0, live, one, None))
+
+    o_ref[...] = jnp.zeros(o_ref.shape, jnp.int32)
+    for i in range(slots - 1):
+        pl.when(i < n_chunks)(functools.partial(start, i, i))
+
+    def body(c, carry):
+        ahead = c + slots - 1
+        pl.when(ahead < n_chunks)(
+            lambda: start(ahead, jax.lax.rem(ahead, slots)))
+        slot = jax.lax.rem(c, slots)
+        wait(c, slot)
+
+        # positions past the row's last share lane rows with resident
+        # ones and hold what a recycled page held: 0 * NaN is NaN, so
+        # they are cleared before the products (a lane row of a page
+        # that was not copied reaches only its own places of the output,
+        # which the select below leaves 0)
+        @pl.when(c == n_chunks - 1)
+        def _():
+            row = jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
+            lane = jax.lax.broadcasted_iota(jnp.int32, (rows, lanes), 1)
+            resident = (kv_len - (c * rows + row) * per) * d
+            kbuf[slot] = jnp.where(
+                lane < resident, kbuf[slot].reshape(rows, lanes), 0
+            ).reshape(ppc, sub, lanes)
+
+        k = kbuf[slot].reshape(rows, lanes)
+        # place (h, n) of a chunk's result: position h of lane row n
+        at = ((c * rows
+               + jax.lax.broadcasted_iota(jnp.int32, (per, rows), 1)) * per
+              + jax.lax.broadcasted_iota(jnp.int32, (per, rows), 0))
+        for j in range(t):
+            # [per * heads, rows]: every head's product with each of a
+            # lane row's positions (the queries laid out block-diagonally
+            # on the lanes), the lane rows on the lanes of the result
+            s = jax.lax.dot_general(w1_ref[0, j], k, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+            scores = (jax.nn.relu(s) * w2_ref[0, j]).reshape(
+                per, heads, rows).sum(axis=1)
+            seen = (at <= pos0 + j) & (at < kv_len)
+            o_ref[0, j, :, pl.ds(pl.multiple_of(c * rows, rows), rows)] = (
+                jnp.where(seen, _sortable_i32(scores), 0))
+        return carry
+
+    jax.lax.fori_loop(0, n_chunks, body, None)
+
+
+def index_keys_paged(qi, wi, ki_pool, block_table, cache_pos, page: int):
+    """What ``attend_rows`` selects from, ``[b, t, S]`` uint32:
+    ``sortable(I[t, s])`` of index queries ``qi`` [b, t, J, d] with head
+    weights ``wi`` [b, t, J] float32 against the index keys of the pages
+    ``block_table`` [b, S // page] names in ``ki_pool``
+    (``index_pool_shape``), where ``s`` is at or before the query's own
+    position (``cache_pos`` the first's), and 0 elsewhere. The pool is
+    walked where it lies: for row ``r`` pages ``block_table[r, 0 ..
+    ceil((cache_pos[r] + t) / page) - 1]`` are copied ``INDEX_CHUNK`` at
+    a time into VMEM while the chunk before is scored
+    (``index_scores_paged``'s arithmetic: products in the operands'
+    dtype, float32 sums); nothing past a row's last page is copied or
+    scored, an inactive slot costs one page, and of the keys only the
+    result leaves VMEM. ``index_path`` says whether the shapes qualify."""
+    return _index_keys_paged(qi, wi, ki_pool, block_table, cache_pos,
+                             int(page), INDEX_CHUNK, INDEX_SLOTS,
+                             _pa.INTERPRET)
+
+
+@functools.partial(jax.jit, static_argnums=(5, 6, 7, 8))
+def _index_keys_paged(qi, wi, ki_pool, block_table, cache_pos, page, ppc,
+                      slots, interpret):
+    b, t, heads, d = qi.shape
+    _, sub, lanes = ki_pool.shape
+    mb = block_table.shape[1]
+    per = lanes // d
+    # w1[(h, j), l] = qi[j, l % d] where lane l holds position h of its
+    # lane row, else 0; w2[(h, j)] = wi[j]
+    own = (jnp.arange(per)[:, None] == jnp.arange(lanes) // d)[:, None, :]
+    w1 = jnp.where(own, jnp.tile(qi, per)[:, :, None], 0).reshape(
+        b, t, per * heads, lanes)
+    w2 = jnp.tile(wi, per)[..., None]
+    kernel = functools.partial(_index_kernel, t=t, page=page, d=d,
+                               heads=heads, ppc=ppc, mb=mb, slots=slots)
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(b,),
+            in_specs=[
+                pl.BlockSpec((1, t, per * heads, lanes),
+                             lambda r, *_: (r, 0, 0, 0)),
+                pl.BlockSpec((1, t, per * heads, 1),
+                             lambda r, *_: (r, 0, 0, 0)),
+                pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((1, t, per, mb * sub),
+                                   lambda r, *_: (r, 0, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((slots, ppc, sub, lanes), ki_pool.dtype),
+                pltpu.SemaphoreType.DMA((slots,))]),
+        out_shape=jax.ShapeDtypeStruct((b, t, per, mb * sub), jnp.int32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",), vmem_limit_bytes=_VMEM),
+        interpret=interpret,
+        name="sparse_index_decode",
+    )(block_table.reshape(-1).astype(jnp.int32), cache_pos.astype(jnp.int32),
+      w1, w2, ki_pool)
+    # the kernel's place (h, n) is position n * per + h of the row
+    return jax.lax.bitcast_convert_type(
+        jnp.swapaxes(out, 2, 3), jnp.uint32).reshape(b, t, mb * page)

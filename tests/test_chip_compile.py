@@ -379,9 +379,13 @@ def test_keye_programs_fit_the_chip_and_copy_no_pool(v5e_chip, monkeypatch,
     products are XLA's grouped-matmul kernel; and besides the in-place
     writes of the new positions no instruction's result is the size of a
     key or value pool array, and the index keys' pool (a page's keys side
-    by side on one row, whole lane tiles) is neither copied, transposed
-    nor converted. The prefill's attend over its masks is the Pallas
-    kernel (``ops/sparse_attention.py:masked_attend``)."""
+    by side, one whole tile) is neither copied, transposed nor converted.
+    The prefill's attend over its masks is the Pallas kernel
+    (``ops/sparse_attention.py:masked_attend``). The decode step's index
+    is the Pallas walk of the rows' live pages (``index_keys_paged``,
+    which a bucket never takes): nothing gathers out of the index keys'
+    pool and nothing moved is as large as the 16 rows' whole tables of
+    them."""
     import re
     from gym_tpu.programs import serve_defs
     monkeypatch.setattr(paged_attention, "_on_tpu", lambda: True)
@@ -401,7 +405,9 @@ def test_keye_programs_fit_the_chip_and_copy_no_pool(v5e_chip, monkeypatch,
     hlo = compiled.as_text()
     assert "ragged-dot" in hlo
     assert ("sparse_masked_prefill" in hlo) == (program != "decode")
-    pool_elems = cfg.kv_pages * 16 * 512
+    assert ("sparse_index_decode" in hlo) == (program == "decode")
+    pool_elems = (16 * 2304 * 1024 if program == "decode"
+                  else cfg.kv_pages * 16 * 512)
     big = []
     for m in re.finditer(
             r"= (\w+)\[([\d,]+)\]\S* (copy|gather|transpose|"
@@ -412,12 +418,38 @@ def test_keye_programs_fit_the_chip_and_copy_no_pool(v5e_chip, monkeypatch,
         if n >= pool_elems:
             big.append(m.group(0))
     assert not big, big[:3]
-    index_pool = f"bf16[{cfg.kv_pages},1024]"
+    index_pool = f"bf16[{cfg.kv_pages},8,128]"
+    assert index_pool in hlo
     moved = [line for line in hlo.splitlines()
              if re.search(re.escape(index_pool)
                           + r"\S* (copy|transpose|convert)\(", line)]
     assert not moved, moved[:2]
+    if program == "decode":
+        # pages of index keys (whole [8, 128] tiles) are gathered only
+        # where the new positions are laid over them, two a row
+        gathered = re.findall(r"= (bf16\[[\d,]*8,128\])\S* gather\(", hlo)
+        assert set(gathered) == {"bf16[16,2,8,128]"}, gathered
     assert re.search(r"input_output_alias=\{.*may-alias", hlo)
+
+
+@pytest.mark.parametrize("t,dtype", [(4, jnp.bfloat16), (16, jnp.bfloat16),
+                                     (1, jnp.float32)],
+                         ids=["spec4", "spec16", "float32"])
+def test_sparse_index_kernel_compiles_for_v5e(v5e_chip, t, dtype):
+    """The decode index's page walk at the cell's sizes beyond what the
+    decode program above holds (one bfloat16 query a row): a speculative
+    step's 4 and 16 queries a row, the pages fetched once for all of
+    them, and a float32 pool."""
+    from gym_tpu.ops import sparse_attention
+    hlo = _compile(
+        functools.partial(sparse_attention._index_keys_paged, page=16,
+                          ppc=sparse_attention.INDEX_CHUNK,
+                          slots=sparse_attention.INDEX_SLOTS,
+                          interpret=False),
+        v5e_chip, ((16, t, 16, 64), dtype), ((16, t, 16), jnp.float32),
+        (sparse_attention.index_pool_shape(32768, 16, 64), dtype),
+        ((16, 2304), jnp.int32), ((16,), jnp.int32))
+    assert "tpu_custom_call" in hlo and "sparse_index_decode" in hlo
 
 
 # -- the fold's H-gate (ISSUE 32) -------------------------------------------

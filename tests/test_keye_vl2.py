@@ -41,6 +41,9 @@ import pytest
 from gym_tpu.models import serving
 from gym_tpu.models.keye_vl2 import KeyeVL2Config, rotate_half
 from gym_tpu.ops import paged_attention as pa
+from gym_tpu.ops import sparse_attention as sa
+from gym_tpu.programs.registry import ProgramRegistry
+from gym_tpu.serve import engine as engine_mod
 from gym_tpu.serve.engine import InferenceEngine, SamplingParams
 from gym_tpu.serve.scheduler import RequestStatus, Scheduler
 from perfbench import weights_keye
@@ -88,6 +91,28 @@ def bf16():
     return sizes, _config(sizes), weights_keye.make_params(sizes, 7)
 
 
+@pytest.fixture(params=["gather", "kernel"])
+def index(request, monkeypatch):
+    """Both paths of a decode step's index scores
+    (``sparse_attention.index_path``): the gather of the row's table, as
+    everywhere off the TPU, and the Pallas walk of the row's live pages
+    under the interpreter, in chunks of 8 pages so that the rehearsal's
+    table of 32 is whole chunks. The programs are the test's own (a fresh
+    registry: the path is decided when a program is traced). Returns the
+    list the kernel's calls are noted in."""
+    calls = []
+    if request.param == "kernel":
+        monkeypatch.setattr(pa, "INTERPRET", True)
+        monkeypatch.setattr(sa, "INDEX_CHUNK", 8)
+        reg = ProgramRegistry()
+        monkeypatch.setattr(engine_mod, "default_registry", lambda: reg)
+        walk = sa.index_keys_paged
+        monkeypatch.setattr(
+            sa, "index_keys_paged",
+            lambda *a: calls.append(a[0].shape) or walk(*a))
+    return request.param, calls
+
+
 def _engine(cfg, params, slots=2, kv_pages=80, page=PAGE):
     return InferenceEngine(params, cfg, num_slots=slots, page_size=page,
                            kv_pages=kv_pages)
@@ -122,11 +147,12 @@ ROW_IDS = ["under_topk", "across_topk", "past_topk", "several_blocks"]
 
 
 @pytest.mark.parametrize("plen,n_new", ROWS, ids=ROW_IDS)
-def test_prefill_then_paged_decode_equals_the_reference_f32(f32, plen,
-                                                            n_new):
+def test_prefill_then_paged_decode_equals_the_reference_f32(f32, index,
+                                                            plen, n_new):
     """Float32 weights and pools: every decoded position's logits equal
     the full forward's to rounding (so every query kept the keys the
-    reference kept); the prefill's token is the reference's best."""
+    reference kept); the prefill's token is the reference's best. Through
+    the gathered table and through the kernel alike."""
     sizes, cfg, params = f32
     prompt = _prompt(plen, plen)
     eng = _engine(cfg, params)
@@ -136,6 +162,7 @@ def test_prefill_then_paged_decode_equals_the_reference_f32(f32, plen,
     assert toks[0] == int(want[0].argmax())
     assert np.abs(logits - want[1:]).max() < F32_TOL
     assert want.std() > 0.5          # logits worth comparing
+    assert bool(index[1]) == (index[0] == "kernel")
 
 
 @pytest.mark.parametrize("plen,n_new", ROWS[1:], ids=ROW_IDS[1:])
@@ -153,10 +180,11 @@ def test_prefill_then_paged_decode_equals_the_reference_bf16(bf16, plen,
     assert np.abs(fp8 - want).mean() > 1.5 * mean
 
 
-def test_scheduler_serves_rows_of_mixed_length_as_the_reference(f32):
+def test_scheduler_serves_rows_of_mixed_length_as_the_reference(f32, index):
     """Five greedy requests of mixed length through three slots and one
     pool (admissions between decode steps, a step always in flight):
-    every served token is the reference's best at its position."""
+    every served token is the reference's best at its position, whichever
+    path the decode step's index takes."""
     sizes, cfg, params = f32
     eng = _engine(cfg, params, slots=3, kv_pages=120)
     sched = Scheduler(eng, max_queue=8)
@@ -176,6 +204,7 @@ def test_scheduler_serves_rows_of_mixed_length_as_the_reference(f32):
                                pad_multiple=32)
         assert gaps.max() < F32_TOL
     assert eng.stats.kv_blocks_in_use == 0
+    assert bool(index[1]) == (index[0] == "kernel")
 
 
 # -- planted faults ---------------------------------------------------------

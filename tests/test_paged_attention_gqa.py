@@ -169,7 +169,8 @@ def _sparse_case(rng, b, t, dtype):
     wi = jnp.asarray(rng.standard_normal((b, t, J)), jnp.float32)
     kp = jnp.asarray(rng.standard_normal((POOL, KPAGE, KVH * HD)), dtype)
     vp = jnp.asarray(rng.standard_normal((POOL, KPAGE, KVH * HD)), dtype)
-    kip = jnp.asarray(rng.standard_normal((POOL, KPAGE * DI)), dtype)
+    kip = jnp.asarray(rng.standard_normal(
+        sa.index_pool_shape(POOL, KPAGE, DI)), dtype)
     bt = jnp.asarray(1 + rng.permutation(POOL - 1)[:b * MB].reshape(b, MB),
                      jnp.int32)
     return q, qi, wi, kp, vp, kip, bt

@@ -62,7 +62,7 @@ def test_the_cache_tree_has_a_third_pool_array_and_its_bytes_count(setup):
     eng = _engine(cfg, params)
     c = _layer0(eng)
     assert c["k"].shape == c["v"].shape == (80, PAGE, 2 * 16)
-    assert c["ki"].shape == (80, PAGE * 8)
+    assert c["ki"].shape == (80, 1, PAGE * 8)
     leaves = jax.tree.leaves(eng._cache)
     assert eng.kv_pool_bytes() == {
         "payload": sum(int(x.nbytes) for x in leaves), "scales": 0}

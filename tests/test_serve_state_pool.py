@@ -223,16 +223,20 @@ def test_the_scrub_writes_the_null_blocks_zeros_over_both_arrays(
 # recipe. The copy-on-write programs' are the parent commit of PR 33's
 # (461f5df); the decode and prefill programs' were taken again at PR 36,
 # which changed the one thing they share, the sampler (``sample_rows``:
-# a conditional around the sorts, and ``sorted`` in a step's download)
+# a conditional around the sorts, and ``sorted`` in a step's download);
+# all four of ``keye_vl2.py``'s were taken again at PR 38, which gave its
+# pool of index keys a third axis (``sparse_attention.index_pool_shape``:
+# ``[pages, 1, 32]`` here where it was ``[pages, 32]``) and wrote
+# ``sortable`` in the int32 arithmetic its decode kernel shares
 PARENT_TEXT = {
     "keye-vl2-30b-a3b/decode":
-        "37c2a997e0f2929ac99c79880ba1b9d4e6d40bd1d42c6c96e27923673eb1e6d4",
+        "98323de8917ceb9a10fd9c19d48ebadac29d513225f5c2c32c9744384b267dfb",
     "keye-vl2-30b-a3b/prefill16":
-        "38c66025f31522eaa37ab99c0e9faabb576f7eb1df2e91fb6b7ebce6f16d92b3",
+        "a69ed4dde0fdb55cf1fd636e5b522e311f70e0becf65a6335a39a2390c962e53",
     "keye-vl2-30b-a3b/prefill64":
-        "0b5351a0ca3da73335b43c190c539d64facf4cdbb3820387c1dedae2191116e0",
+        "faebfa5a91e6f5d9fbdd5b8dfd99c6be94d3c7d92f6c3a30dd12a3a2796f6362",
     "keye-vl2-30b-a3b/cow":
-        "aa64e65774b9263745cae5c5c88df6e1149028305a83b0ec3d45892e2fdaa208",
+        "79f28282bbd654508c8319fcdc470543bada6d781229464c79db2389d06edd11",
     "command-a-plus/decode":
         "f775416503389b12c33db5fc8c8afb0a6ef752015fe62076b4dc6509f5839336",
     "command-a-plus/prefill16":
