@@ -136,12 +136,15 @@ class Cohere2MoeConfig:
         return jax.tree.map(lambda x: jnp.asarray(x, dt), params)
 
 
-def rotate_interleaved(x, pos, theta: float):
+def rotate_interleaved(x, pos, theta: float, inv=None):
     """Rotary embedding over the whole last axis in pairs ``(2i, 2i+1)``
     (``rope_gptj``, ``rotary_pct`` 1): ``x`` [..., t, *, d] float32 with
-    ``pos`` broadcastable to ``x``'s leading axes up to ``t``."""
+    ``pos`` broadcastable to ``x``'s leading axes up to ``t``. ``inv``
+    [d / 2]: the pairs' frequencies where they are not ``theta``'s own
+    (yarn: ``ops/latent_attention.py:yarn_inv_freq``)."""
     d = x.shape[-1]
-    inv = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    if inv is None:
+        inv = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
     ang = pos[..., None].astype(jnp.float32) * inv           # [..., d/2]
     cos = jnp.repeat(jnp.cos(ang), 2, axis=-1)
     sin = jnp.repeat(jnp.sin(ang), 2, axis=-1)

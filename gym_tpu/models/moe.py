@@ -393,7 +393,10 @@ class HeldExperts(nn.Module):
     Routing runs over all ``n_experts``: scores in float32 (``score_fn``:
     ``sigmoid`` of each router output, or ``softmax`` over all of them),
     the ``topk`` largest, their weights renormalised to sum to one
-    (``norm_topk``). The chip holds the routed experts ``held = (lo,
+    (``norm_topk``). With ``select_bias`` a float32 vector
+    ``e_score_correction_bias`` [n_experts] is added to the scores for
+    the CHOICE only (``noaux_tc``): the chosen experts' weights are their
+    own scores, the bias never enters a weight. The chip holds the routed experts ``held = (lo,
     hi)`` and computes their part of the sum alone: token-picks are
     sorted by expert, the picks on held experts run through
     ``ops.grouped_matmul.grouped_dot`` (groups = the held experts, none
@@ -424,6 +427,7 @@ class HeldExperts(nn.Module):
     chunk_rows: int = 8192
     param_dtype: Any = jnp.bfloat16
     score_fn: str = "sigmoid"
+    select_bias: bool = False
 
     @nn.compact
     def __call__(self, h, live=None):
@@ -450,7 +454,13 @@ class HeldExperts(nn.Module):
             scores = _SCORE_FNS[self.score_fn](jnp.dot(
                 h, router.astype(jnp.float32),
                 precision=jax.lax.Precision.HIGHEST))
-            top_s, top_i = jax.lax.top_k(scores, K)              # [S, K]
+            if self.select_bias:
+                bias = self.param("e_score_correction_bias",
+                                  nn.initializers.zeros, (E,), jnp.float32)
+                _, top_i = jax.lax.top_k(scores + bias, K)
+                top_s = jnp.take_along_axis(scores, top_i, axis=-1)
+            else:
+                top_s, top_i = jax.lax.top_k(scores, K)          # [S, K]
             w = (top_s / top_s.sum(-1, keepdims=True) if self.norm_topk
                  else top_s)
             local = top_i - lo

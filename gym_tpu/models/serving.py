@@ -37,6 +37,23 @@ answers:
   positions past ``last_pos``) leaves the block as it is; a row whose
   table entry is 0 is not live and touches nothing.
 
+A model whose page holds something other than heads but still ONE
+position a row of the page (``models/kimi_k2.py``: a latent that all heads
+share, one array a layer) needs no flag and nothing of the engine: page
+plans, the prefix cache, copy-on-write, the scrub, parking and
+``kv_pool_bytes`` are ``tree.map``s over whatever the ``cache`` collection
+holds, indexed by page on the first axis. What such a MODEL owes: every
+``cache`` leaf is ``[kv_pages, page_size, ...]``; a position's row is
+written whole by the call that computes it (a prefill bucket's padding
+too: it lies past the cursor, masked until overwritten); a call WITHOUT
+``last_pos`` may hold several tokens a row (a speculative verify) and
+must see each at ``cache_pos + j``; a call WITH ``last_pos`` may start at
+``cache_pos > 0`` on pages another request wrote (a prefix hit), so its
+attend reads the past from the pages and not from what the call itself
+computed; and the pool's minor dimension is whole lane tiles (a row of
+576 is copied, the whole pool, around every scatter on the chip, and a
+kernel cannot copy part of a tile: ``ops/latent_attention.py:pool_lanes``).
+
 A family's key starts with its ``model_type``; GPT-2's is its plain field
 tuple, as it always was (its programs' keys and names did not move).
 """
@@ -49,11 +66,12 @@ from typing import Any, Dict
 from .brumby import FAMILY as BRUMBY, BrumbyConfig
 from .cohere2_moe import FAMILY as COHERE2_MOE, Cohere2MoeConfig
 from .keye_vl2 import FAMILY as KEYE_VL2, KeyeVL2Config
+from .kimi_k2 import FAMILY as KIMI_K2, KimiK2Config
 from .nanogpt import (GPTConfig, sample_logits,  # noqa: F401 — re-exported
                       sample_rows)
 
 FAMILIES = {COHERE2_MOE: Cohere2MoeConfig, KEYE_VL2: KeyeVL2Config,
-            BRUMBY: BrumbyConfig}
+            BRUMBY: BrumbyConfig, KIMI_K2: KimiK2Config}
 
 
 def config_from_key(key: tuple):
