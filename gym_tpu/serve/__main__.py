@@ -549,6 +549,11 @@ def create_server(params, cfg, *, host: str = "127.0.0.1", port: int = 0,
                                         for s in stats),
                 "prefix_hit_blocks": sum(s.prefix_hit_blocks
                                          for s in stats),
+                # pages evicted from the prefix cache (a full pool's
+                # admissions take their pages there), and the entries of
+                # the allocator's recency heap those evictions looked at
+                "kv_evictions": sum(s.kv_evictions for s in stats),
+                "kv_evict_visits": sum(s.kv_evict_visits for s in stats),
                 "spec_accept_rate": (accepted / drafted
                                      if drafted else None),
                 # device-program registry: XLA compiles this process has

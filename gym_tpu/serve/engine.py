@@ -110,6 +110,7 @@ until something asks for ``last_logits``.
 from __future__ import annotations
 
 import dataclasses
+import heapq
 from typing import Any, Dict, List, Optional, Tuple
 
 import jax
@@ -281,6 +282,12 @@ class EngineStats:
     # page-pool observables
     kv_blocks_in_use: int = 0            # pages referenced by live slots
     kv_blocks_cached: int = 0            # resident reusable prefix blocks
+    kv_evictions: int = 0                # cumulative pages evicted from the
+    #                                      prefix cache: a full pool's
+    #                                      admissions take their pages there
+    kv_evict_visits: int = 0             # cumulative entries of the recency
+    #                                      heap those evictions looked at
+    #                                      (a few a page, never the cache)
     prefix_hit_blocks: int = 0           # cumulative blocks served from the
     #                                      prefix cache instead of prefilled
     # speculative-decoding counters (0 with speculation off)
@@ -380,6 +387,16 @@ class BlockAllocator:
     entry. A cached page at refcount 0 stays RESIDENT (that is the
     point: the next request with the same prefix reuses it copy-free)
     and is evicted LRU only when the free list runs dry.
+
+    **Recency** is the cache's, not the users': ``register`` stamps an
+    entry, ``lookup`` and ``touch`` stamp it anew, a release does not.
+    The victim is the refcount-0 cached page with the oldest stamp.
+    What every call pays is its own pages, never the pool's
+    (ISSUE 40): the evictable pages stand in a heap of ``(stamp,
+    page)``, pushed when a cached page falls to refcount 0 or is
+    stamped anew there; an entry whose page was pinned, stamped anew,
+    condemned or evicted since is dropped when it surfaces. Supply and
+    use are counters.
     """
 
     def __init__(self, num_pages: int, page_size: int):
@@ -391,18 +408,22 @@ class BlockAllocator:
         self.page_size = int(page_size)
         self._free = list(range(num_pages - 1, 0, -1))   # pop() → low ids
         self._ref: Dict[int, int] = {}
-        # chain key → (page, content id); insertion order is LRU order
-        # (lookup hits refresh recency)
-        from collections import OrderedDict
-        self._cache: "OrderedDict[Tuple[int, bytes], Tuple[int, int]]" = \
-            OrderedDict()
+        # chain key → (page, content id)
+        self._cache: Dict[Tuple[int, bytes], Tuple[int, int]] = {}
         self._key_of: Dict[int, Tuple[int, bytes]] = {}
         self._cid = 0
+        self._stamp: Dict[int, int] = {}     # cached page → recency
+        self._clock = 0
+        self._heap: List[Tuple[int, int]] = []
+        self._evictable = 0                  # cached pages at refcount 0
+        self.evictions = 0                   # pages evicted from the cache
+        self.evict_visits = 0                # heap entries those looked at
 
     # -- observables ------------------------------------------------------
 
     def in_use(self) -> int:
-        return sum(1 for r in self._ref.values() if r > 0)
+        # ``_ref`` holds the pages in use and, at 0, the evictable ones
+        return len(self._ref) - self._evictable
 
     def cached(self) -> int:
         return len(self._cache)
@@ -412,11 +433,10 @@ class BlockAllocator:
         plus evictable (refcount-0 cached) pages. ``exclude`` treats the
         given pages as unavailable — a planned admission must not count
         the very prefix blocks it is about to pin as evictable slack."""
-        ex = set(exclude)
-        n = len(self._free)
-        for _key, (pg, _cid) in self._cache.items():
-            if self._ref.get(pg, 0) == 0 and pg not in ex:
-                n += 1
+        n = len(self._free) + self._evictable
+        for pg in set(exclude):
+            if pg in self._key_of and not self._ref.get(pg):
+                n -= 1
         return n
 
     # -- allocation -------------------------------------------------------
@@ -424,37 +444,83 @@ class BlockAllocator:
     def alloc(self) -> int:
         """Allocate a page at refcount 1, evicting the LRU refcount-0
         cached page when the free list is empty."""
-        if self._free:
-            pg = self._free.pop()
-        else:
-            pg = self._evict_one()
-        self._ref[pg] = 1
-        return pg
+        return self.alloc_many(1)[0]
+
+    def alloc_many(self, n: int) -> List[int]:
+        """``n`` pages at refcount 1, the pages ``n`` calls of ``alloc``
+        would give in their order; all of them or, where the pool cannot
+        supply them, none and ``NoFreeBlocksError``."""
+        if n > len(self._free) + self._evictable:
+            raise NoFreeBlocksError(
+                f"paged KV pool cannot supply {n} blocks right now — "
+                f"retry after running requests release")
+        cut = max(len(self._free) - n, 0)
+        pages = self._free[cut:][::-1]       # pop() by pop()
+        del self._free[cut:]
+        pages.extend(self._evict_one() for _ in range(n - len(pages)))
+        self._ref.update(dict.fromkeys(pages, 1))
+        return pages
 
     def _evict_one(self) -> int:
-        for key, (pg, _cid) in self._cache.items():      # oldest first
-            if self._ref.get(pg, 0) == 0:
-                del self._cache[key]
-                del self._key_of[pg]
-                self._ref.pop(pg, None)
-                return pg
+        heap, stamp, ref = self._heap, self._stamp, self._ref
+        while heap:
+            st, pg = heapq.heappop(heap)
+            self.evict_visits += 1
+            if stamp.get(pg) != st or ref.get(pg):
+                continue                     # stamped anew, pinned or gone
+            del self._cache[self._key_of.pop(pg)]
+            del stamp[pg]
+            ref.pop(pg, None)
+            self._evictable -= 1
+            self.evictions += 1
+            return pg
         raise NoFreeBlocksError(
             f"paged KV pool exhausted: all {self.num_pages - 1} pages "
             f"are referenced by running requests")
 
+    def _queue(self, page: int) -> None:
+        """``page`` became evictable, or was stamped anew while it was:
+        it goes on the heap under its stamp. The heap is made anew from
+        the evictable pages when stale entries outnumber the cache (a
+        pool that never evicts would keep one for every release of a
+        shared page)."""
+        if len(self._heap) > 2 * len(self._key_of) + 64:
+            self._heap = [(st, pg) for pg, st in self._stamp.items()
+                          if not self._ref.get(pg)]
+            heapq.heapify(self._heap)
+        else:
+            heapq.heappush(self._heap, (self._stamp[page], page))
+
     def incref(self, page: int) -> None:
-        self._ref[page] = self._ref.get(page, 0) + 1
+        r = self._ref.get(page, 0)
+        self._ref[page] = r + 1
+        if r == 0 and page in self._key_of:
+            self._evictable -= 1
+
+    def incref_many(self, pages) -> None:
+        for pg in pages:
+            self.incref(pg)
 
     def decref(self, page: int) -> None:
         r = self._ref.get(page, 0) - 1
         if r < 0:
             raise ValueError(f"page {page} double-freed")
         self._ref[page] = r
-        if r == 0 and page not in self._key_of:
-            # plain owned page → straight back to the free list; cached
-            # pages stay resident (evictable) for future prefix hits
-            self._ref.pop(page)
+        if r:
+            return
+        if page in self._key_of:
+            # cached pages stay resident (evictable) for future prefix
+            # hits
+            self._evictable += 1
+            self._queue(page)
+        else:
+            # plain owned page → straight back to the free list
+            del self._ref[page]
             self._free.append(page)
+
+    def decref_many(self, pages) -> None:
+        for pg in pages:
+            self.decref(pg)
 
     def condemn(self, page: int) -> bool:
         """``page`` belongs to a quarantined row, so its content is no
@@ -467,26 +533,31 @@ class BlockAllocator:
         key = self._key_of.pop(page, None)
         if key is not None:
             del self._cache[key]
+            del self._stamp[page]
         return True
 
     # -- prefix cache -----------------------------------------------------
 
+    def _restamp(self, page: int) -> None:
+        self._clock += 1
+        self._stamp[page] = self._clock
+        if not self._ref.get(page):
+            self._queue(page)
+
     def lookup(self, parent_cid: int, block: bytes):
         """Resident ``(page, cid)`` for this chain link, or None. A hit
         refreshes the entry's LRU recency."""
-        key = (parent_cid, block)
-        ent = self._cache.get(key)
+        ent = self._cache.get((parent_cid, block))
         if ent is not None:
-            self._cache.move_to_end(key)
+            self._restamp(ent[0])
         return ent
 
     def touch(self, page: int) -> None:
         """Refresh a cached page's LRU recency by page id — admission
         commits touch their hit pages so a hot prefix is not the
         eviction victim just because planning probes never counted."""
-        key = self._key_of.get(page)
-        if key is not None:
-            self._cache.move_to_end(key)
+        if page in self._key_of:
+            self._restamp(page)
 
     def probe(self, parent_cid: int, block: bytes):
         """``lookup`` without the LRU touch — for capacity planning and
@@ -505,6 +576,7 @@ class BlockAllocator:
         self._cid += 1
         self._cache[key] = (page, self._cid)
         self._key_of[page] = key
+        self._restamp(page)          # owned: evictable at its release
         return self._cid
 
 
@@ -908,8 +980,9 @@ class InferenceEngine:
             # a state is not addressed by position: no prefix of a
             # prompt is served from one, and none is registered
             return hit_pages, chain
+        buf, step = prompt.tobytes(), page * prompt.itemsize
         for b in range(len(prompt) // page):
-            ent = al.probe(cid, prompt[b * page:(b + 1) * page].tobytes())
+            ent = al.probe(cid, buf[b * step:(b + 1) * step])
             if ent is None:
                 break
             hit_pages.append(ent[0])
@@ -1069,6 +1142,7 @@ class InferenceEngine:
         # compile/dispatch error in CoW or prefill) unwinds it exactly —
         # an admission that fails its request must not shrink the pool
         held: List[int] = []
+        evicted = al.evictions
         try:
             with span("serve.prefill.plan") as sp_plan:
                 hit_pages, chain, cow_src, cid, start, suffix, bucket, \
@@ -1076,34 +1150,24 @@ class InferenceEngine:
                                                    sp.max_new_tokens)
                 # pin before the capacity check: a pinned page is neither
                 # evictable nor double-counted as supply
-                for pg in hit_pages:
-                    al.incref(pg)
-                    held.append(pg)
+                pinned = hit_pages + ([] if cow_src is None else [cow_src])
+                al.incref_many(pinned)
+                held += pinned
+                # all of them or none (``NoFreeBlocksError``), in the
+                # order a page at a time would come: the copy-on-write's
+                # first
+                fresh = al.alloc_many(need)
+                held += fresh
                 if cow_src is not None:
-                    al.incref(cow_src)
-                    held.append(cow_src)
-                if al.available() < need:
-                    raise NoFreeBlocksError(
-                        f"paged KV pool cannot supply {need} blocks right "
-                        f"now — retry after running requests release")
-                row = np.zeros(self.max_blocks, np.int32)
-                row[:len(hit_pages)] = hit_pages
-                next_b = len(hit_pages)
-                if cow_src is not None:
-                    dst = al.alloc()
-                    held.append(dst)
-                    row[next_b] = dst
-                    next_b += 1
                     self._cache = self._cow_prog(
-                        self._cache, np.int32(cow_src), np.int32(dst))
+                        self._cache, np.int32(cow_src), np.int32(fresh[0]))
                     al.decref(cow_src)       # pinned only for the copy
                     held.remove(cow_src)
-                for k in range(n_new):
-                    pg = al.alloc()
-                    held.append(pg)
-                    row[next_b + k] = pg
+                row = hit_pages + fresh
                 self._bt[slot] = 0
-                self._bt[slot, :next_b + n_new] = row[:next_b + n_new]
+                self._bt[slot, :len(row)] = row
+                sp_plan.ids["pages"] = need
+                sp_plan.ids["evicted"] = al.evictions - evicted
                 self._seen_buckets.add(bucket)
                 prefill = self._prefill_prog(bucket)
                 padded = np.zeros((1, bucket), np.int32)
@@ -1129,8 +1193,7 @@ class InferenceEngine:
             self._stale.clear()
             self.stats.paged_kernel_dispatches += self._kernel_attend
         except BaseException:
-            for pg in held:
-                al.decref(pg)
+            al.decref_many(held)
             self._bt[slot] = 0
             raise
         # only a COMMITTING admission refreshes hit recency — planning
@@ -1142,10 +1205,10 @@ class InferenceEngine:
             # content is immutable — decode writes start past them);
             # the CoW path has nothing new: every block was cached
             reg_cid = cid
+            buf, step = prompt.tobytes(), page * prompt.itemsize
             for b in range(len(hit_pages), full):
                 reg_cid = al.register(
-                    reg_cid, prompt[b * page:(b + 1) * page].tobytes(),
-                    int(row[b]))
+                    reg_cid, buf[b * step:(b + 1) * step], row[b])
         self._pos[slot] = n
         self._hist[slot] = 0
         self._hist[slot, :n] = prompt
@@ -1154,23 +1217,27 @@ class InferenceEngine:
                                          + (1 if cow_src is not None
                                             else 0))
         self.stats.prefill_tokens += bucket
+        self._pool_stats()
+        return tok
+
+    def _pool_stats(self) -> None:
+        al = self._alloc
         self.stats.kv_blocks_in_use = al.in_use()
         self.stats.kv_blocks_cached = al.cached()
-        return tok
+        self.stats.kv_evictions = al.evictions
+        self.stats.kv_evict_visits = al.evict_visits
 
     def _release_pages(self, slot: int) -> None:
         """Drop this slot's block-table references (idempotent: an
         already-cleared row is a no-op). Cached prefix blocks stay
         resident at refcount 0; plain owned blocks return to the free
         list."""
-        for pg in self._bt[slot]:
-            if pg:
-                self._alloc.decref(int(pg))
+        row = self._bt[slot]
+        self._alloc.decref_many(row[row != 0].tolist())
         # the mirror's row only: the programs clear the device's when the
         # row stops, and read no inactive row's table before that
         self._bt[slot] = 0
-        self.stats.kv_blocks_in_use = self._alloc.in_use()
-        self.stats.kv_blocks_cached = self._alloc.cached()
+        self._pool_stats()
 
     def _scrub_pages(self, slot: int) -> None:
         """Write over the pages only this quarantined row holds, before
@@ -1283,11 +1350,9 @@ class InferenceEngine:
         if parked.released:
             return
         parked.released = True
-        for pg in parked.block_table:
-            if pg:
-                self._alloc.decref(int(pg))
-        self.stats.kv_blocks_in_use = self._alloc.in_use()
-        self.stats.kv_blocks_cached = self._alloc.cached()
+        row = parked.block_table
+        self._alloc.decref_many(row[row != 0].tolist())
+        self._pool_stats()
 
     def step(self, override_tokens: Optional[Dict[int, int]] = None,
              ahead: bool = False) -> List[TokenEvent]:

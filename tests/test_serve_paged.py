@@ -1096,3 +1096,341 @@ def test_stats_serve_the_samplers_sorted_steps(setup, tmp_path):
         assert stats()["sampler_sorted_steps"] == 5
     finally:
         handle.close(drain_deadline_s=5.0)
+
+
+# -- the allocator's bookkeeping (ISSUE 40) ---------------------------------
+
+
+class _WalkingAllocator:
+    """The allocator as it stood before ISSUE 40, the plain reference of
+    the differential tests below: the victim is found by walking the
+    cache from its oldest entry past every pinned page, supply and use
+    by scanning it. ``visits`` counts the entries those walks look at;
+    the ``*_many`` calls are the loops the engine used to make."""
+
+    def __init__(self, num_pages, page_size):
+        from collections import OrderedDict
+        self.num_pages, self.page_size = int(num_pages), int(page_size)
+        self._free = list(range(num_pages - 1, 0, -1))
+        self._ref = {}
+        self._cache = OrderedDict()
+        self._key_of = {}
+        self._cid = 0
+        self.evictions = self.visits = 0
+
+    evict_visits = property(lambda self: self.visits)
+
+    def in_use(self):
+        return sum(1 for r in self._ref.values() if r > 0)
+
+    def cached(self):
+        return len(self._cache)
+
+    def available(self, exclude=()):
+        ex = set(exclude)
+        n = len(self._free)
+        for _key, (pg, _cid) in self._cache.items():
+            if self._ref.get(pg, 0) == 0 and pg not in ex:
+                n += 1
+        return n
+
+    def alloc(self):
+        if self._free:
+            pg = self._free.pop()
+        else:
+            pg = self._evict_one()
+        self._ref[pg] = 1
+        return pg
+
+    def _evict_one(self):
+        for key, (pg, _cid) in self._cache.items():
+            self.visits += 1
+            if self._ref.get(pg, 0) == 0:
+                del self._cache[key]
+                del self._key_of[pg]
+                self._ref.pop(pg, None)
+                self.evictions += 1
+                return pg
+        raise NoFreeBlocksError("pool exhausted")
+
+    def alloc_many(self, n):
+        if self.available() < n:
+            raise NoFreeBlocksError("pool cannot supply")
+        return [self.alloc() for _ in range(n)]
+
+    def incref(self, page):
+        self._ref[page] = self._ref.get(page, 0) + 1
+
+    def incref_many(self, pages):
+        for pg in pages:
+            self.incref(pg)
+
+    def decref(self, page):
+        r = self._ref.get(page, 0) - 1
+        if r < 0:
+            raise ValueError(f"page {page} double-freed")
+        self._ref[page] = r
+        if r == 0 and page not in self._key_of:
+            self._ref.pop(page)
+            self._free.append(page)
+
+    def decref_many(self, pages):
+        for pg in pages:
+            self.decref(pg)
+
+    def condemn(self, page):
+        if self._ref.get(page, 0) != 1:
+            return False
+        key = self._key_of.pop(page, None)
+        if key is not None:
+            del self._cache[key]
+        return True
+
+    def lookup(self, parent_cid, block):
+        key = (parent_cid, block)
+        ent = self._cache.get(key)
+        if ent is not None:
+            self._cache.move_to_end(key)
+        return ent
+
+    def touch(self, page):
+        key = self._key_of.get(page)
+        if key is not None:
+            self._cache.move_to_end(key)
+
+    def probe(self, parent_cid, block):
+        return self._cache.get((parent_cid, block))
+
+    def register(self, parent_cid, block, page):
+        key = (parent_cid, block)
+        ent = self._cache.get(key)
+        if ent is not None:
+            return ent[1]
+        self._cid += 1
+        self._cache[key] = (page, self._cid)
+        self._key_of[page] = key
+        return self._cid
+
+
+def _both(old, new, name, *args):
+    """One call on both allocators: the same answer or the same error."""
+    def call(al):
+        try:
+            return getattr(al, name)(*args)
+        except (NoFreeBlocksError, ValueError) as e:
+            return type(e)
+    want, got = call(old), call(new)
+    assert got == want, (name, args, got, want)
+    return want
+
+
+@pytest.mark.parametrize("seed", range(10))
+def test_allocator_hands_out_what_the_walking_one_did(seed):
+    """3,000 seeded operations on a pool of 23 pages that runs dry, on
+    the walking allocator and on the one that keeps a heap and counters:
+    every page handed out, every chain id, every ``available``,
+    ``in_use`` and ``cached`` equal, and ``NoFreeBlocksError`` at the
+    same operation. ``held`` has an entry a reference the test owns."""
+    rng = np.random.default_rng(seed)
+    old, new = _WalkingAllocator(24, 4), BlockAllocator(24, 4)
+    held, cids, keys = [], [0], []
+    pick = lambda seq: seq[int(rng.integers(len(seq)))]  # noqa: E731
+    ran_dry = 0
+    ops = ["alloc"] * 4 + ["alloc_many", "register", "register", "lookup",
+                           "probe", "pin", "incref", "decref", "decref",
+                           "decref_many", "touch", "condemn", "available"]
+    for _ in range(3000):
+        op = pick(ops)
+        if op == "alloc":
+            pg = _both(old, new, "alloc")
+            if pg is NoFreeBlocksError:
+                ran_dry += 1
+            else:
+                held.append(pg)
+        elif op == "alloc_many":
+            pages = _both(old, new, "alloc_many", int(rng.integers(1, 7)))
+            if pages is NoFreeBlocksError:
+                ran_dry += 1
+            else:
+                held.extend(pages)
+        elif op == "register":
+            plain = [pg for pg in set(held) if pg not in old._key_of]
+            if plain:
+                # few contents under few parents: a key is met again,
+                # cached (the entry wins) and evicted
+                key = (pick(cids[-6:]), bytes([int(rng.integers(3))]))
+                cids.append(_both(old, new, "register", *key, pick(plain)))
+                keys.append(key)
+        elif op in ("lookup", "probe") and keys:
+            _both(old, new, op, *pick(keys[-40:]))
+        elif op == "pin" and old._key_of:
+            # a prefix hit: a resident page, in use or evictable
+            pg = pick(sorted(old._key_of))
+            _both(old, new, "incref", pg)
+            held.append(pg)
+        elif op == "incref" and held:
+            pg = pick(held)
+            _both(old, new, "incref", pg)
+            held.append(pg)
+        elif op == "decref" and held:
+            _both(old, new, "decref",
+                  held.pop(int(rng.integers(len(held)))))
+        elif op == "decref_many" and held:
+            rng.shuffle(held)
+            k = int(rng.integers(1, 9))
+            _both(old, new, "decref_many", held[:k])
+            del held[:k]
+        elif op == "touch":
+            _both(old, new, "touch", int(rng.integers(1, 24)))
+        elif op == "condemn" and held:
+            _both(old, new, "condemn", pick(held))
+        elif op == "available":
+            some = rng.integers(1, 24, int(rng.integers(0, 8))).tolist()
+            _both(old, new, "available", some)
+        for name in ("available", "in_use", "cached"):
+            _both(old, new, name)
+        assert new.evictions == old.evictions
+    assert ran_dry > 20 and old.evictions > 100
+    # the heap holds no more than a few entries a page of the pool
+    assert len(new._heap) <= 2 * 23 + 65
+    _both(old, new, "decref_many", held)
+    assert _both(old, new, "available") == 23
+    assert _both(old, new, "decref", 1) is ValueError     # double-freed
+
+
+def test_allocator_heap_is_made_anew_where_nothing_evicts():
+    """A pool that never runs dry never pops its heap: a shared page
+    that is hit, stamped and released a thousand times leaves one live
+    entry, not a thousand stale ones."""
+    al = BlockAllocator(64, 4)
+    pages = al.alloc_many(8)
+    cid = 0
+    for i, pg in enumerate(pages):
+        cid = al.register(cid, bytes([i]), pg)
+    al.decref_many(pages)
+    for _ in range(1000):
+        al.incref_many(pages)
+        for pg in pages:
+            al.touch(pg)
+        al.decref_many(pages)
+    assert len(al._heap) <= 2 * 8 + 65 and al.evictions == 0
+    assert al.available() == 63 and al.in_use() == 0
+    taken = al.alloc_many(63)                  # the free list, then the 8
+    assert taken[-8:] == pages and al.evictions == 8 and al.cached() == 0
+
+
+def test_an_admissions_pages_cost_a_page_each_not_a_walk_of_the_pool():
+    """32 running rows of 500 registered pages each, an empty free
+    list, and one finished row's 600 pages to evict: an admission of 500
+    pages looks at 500 entries, where the walking allocator passes the
+    16,000 pinned entries before every victim (over a million entries
+    for the first 70 pages alone). No clock: the count is the test."""
+    rows, each, spare = 32, 500, 600
+
+    def full_pool(al):
+        cid = 0
+        for r in range(rows + 1):
+            pages = al.alloc_many(spare if r == rows else each)
+            for i, pg in enumerate(pages):
+                cid = al.register(cid, b"%d.%d" % (r, i), pg)
+        al.decref_many(pages)                  # the last row ended
+        assert al.available() == spare and not al._free
+        return al
+
+    n = 1 + rows * each + spare
+    new, old = full_pool(BlockAllocator(n, 16)), \
+        full_pool(_WalkingAllocator(n, 16))
+    assert new.available(exclude=range(1, 100)) == spare
+    got = new.alloc_many(each)
+    assert new.evictions == each and new.evict_visits <= 2 * each
+    assert new.in_use() == (rows + 1) * each
+    assert got[:70] == old.alloc_many(70)
+    assert old.visits > 1_000_000
+
+
+def test_engine_through_a_pool_that_evicts_is_the_walking_allocators(setup):
+    """40 seeded admissions, most of them behind one of three shared
+    prefixes, through a pool too small to keep them all: with the
+    walking allocator in the engine's place for the reference, the
+    tokens, the block tables and the prefix hits are the same, so the
+    same pages were evicted, hit and copied on write."""
+    cfg, model, params = setup
+
+    def run(allocator):
+        eng = InferenceEngine(params, cfg, num_slots=3, page_size=4,
+                              kv_pages=28)
+        if allocator is not None:
+            eng._alloc = allocator(eng.kv_pages, eng.page_size)
+        rng = np.random.default_rng(40)
+        prefixes = [_prompt(int(n), 900 + i)
+                    for i, n in enumerate((16, 12, 24))]
+        out, slots = [], {}
+        for i in range(40):
+            kind = int(rng.integers(6))
+            head = prefixes[kind % 3]
+            prompt = (head if kind == 3 else       # whole: copy-on-write
+                      _prompt(int(rng.integers(9, 30)), 500 + i)
+                      if kind > 3 else
+                      np.concatenate([head, _prompt(
+                          int(rng.integers(1, 9)), 700 + i)]))
+            sp = SamplingParams(max_new_tokens=int(rng.integers(2, 12)),
+                                top_k=4, seed=i)
+            while True:
+                try:
+                    slot = eng.admit_nowait(prompt, sp)
+                    break
+                except NoFreeBlocksError:
+                    assert slots                   # someone will release
+                    for ev in eng.step():
+                        slots[ev.slot].append(ev.token)
+                        if ev.finished:
+                            out.append(slots.pop(ev.slot))
+            slots[slot] = [i, eng._bt[slot].tolist(),
+                           eng.stats.prefix_hit_blocks]
+            while len(slots) == 3 or (i == 39 and slots):
+                for ev in eng.step():
+                    slots[ev.slot].append(ev.token)
+                    if ev.finished:
+                        out.append(slots.pop(ev.slot))
+        st = eng.stats
+        assert st.kv_evictions == eng._alloc.evictions
+        assert st.kv_blocks_in_use == 0
+        return sorted(out), (st.prefix_hit_blocks, st.kv_evictions,
+                             st.kv_blocks_cached, st.prefill_tokens)
+
+    want, got = run(_WalkingAllocator), run(None)
+    assert got == want
+    hits, evictions = got[1][:2]
+    assert hits > 20 and evictions > 40
+
+
+def test_stats_carries_the_evictions_and_the_plan_span_its_pages(setup,
+                                                                 tmp_path):
+    """``/stats`` has ``kv_evictions`` (and the heap entries they looked
+    at), and a ``serve.prefill.plan`` span says how many pages its
+    admission took and how many of them it evicted."""
+    import json
+    import urllib.request
+    from gym_tpu.serve.__main__ import create_server
+    from gym_tpu.utils import trace
+    cfg, model, params = setup
+    handle = create_server(params, cfg, port=0, num_slots=1, page_size=8,
+                           kv_pages=10, warmup=False,
+                           metrics_dir=str(tmp_path))
+    threading.Thread(target=handle.httpd.serve_forever, daemon=True).start()
+    try:
+        for i in range(3):                  # 4 pages each, 3 registered
+            handle.scheduler.submit(_prompt(24, 40 + i), SamplingParams(
+                max_new_tokens=4, seed=i)).result(timeout=120)
+        with urllib.request.urlopen(
+                f"http://127.0.0.1:{handle.port}/stats", timeout=60) as r:
+            got = json.loads(r.read())
+        plans = [r for r in trace.records()
+                 if r.name == "serve.prefill.plan"][-3:]
+        assert [r.ids["pages"] for r in plans] == [4, 4, 4]
+        evicted = [r.ids["evicted"] for r in plans]
+        assert evicted[0] == 0 and sum(evicted) == got["kv_evictions"] > 0
+        assert got["kv_evictions"] <= got["kv_evict_visits"] \
+            <= 2 * got["kv_evictions"]
+    finally:
+        handle.close(drain_deadline_s=5.0)
