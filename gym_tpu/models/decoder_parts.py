@@ -17,10 +17,16 @@ import jax
 import jax.numpy as jnp
 
 
-def rotate_half(x, pos, theta: float):
+def rotate_half(x, pos, theta: float, rotary_dim=None):
     """Rotary embedding over the whole last axis, lane ``i`` paired with
     lane ``i + d/2``: ``x`` [..., t, *, d] float32 with ``pos``
-    broadcastable to ``x``'s leading axes up to ``t``."""
+    broadcastable to ``x``'s leading axes up to ``t``. ``rotary_dim``
+    given: over the first ``rotary_dim`` lanes only (lane ``i`` paired
+    with lane ``i + rotary_dim/2``), the others pass."""
+    if rotary_dim is not None and rotary_dim < x.shape[-1]:
+        return jnp.concatenate(
+            [rotate_half(x[..., :rotary_dim], pos, theta),
+             x[..., rotary_dim:]], axis=-1)
     d = x.shape[-1]
     inv = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
     ang = pos[..., None].astype(jnp.float32) * inv            # [..., d/2]

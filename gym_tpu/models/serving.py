@@ -54,6 +54,31 @@ computed; and the pool's minor dimension is whole lane tiles (a row of
 576 is copied, the whole pool, around every scatter on the chip, and a
 kernel cannot copy part of a tile: ``ops/latent_attention.py:pool_lanes``).
 
+A model that keeps BOTH kinds of cache in one row (``models/
+qwen3_next.py``: pages of keys and values in some layers, a recurrent
+state in the others) says ``row_state = True``, names the entries of its
+``cache`` collection that are indexed by STATE BLOCK and not by page
+(``row_state_names()``) and carries a field ``state_blocks`` that the
+engine sets beside ``kv_pages``. The engine's one pool manager then gives
+a row ONE state block beside its run of pages: the block table grows a
+last column that names it (so the decode state carries it on the device
+as it carries the pages, a stopped row's is cleared with them, and a
+parked row's snapshot keeps it); an admission is planned, and succeeds,
+only with both to be had (``admit_probe`` says so before); ``release``,
+``release_parked`` and the scrub of a quarantined row free or zero both;
+no prefix of a prompt is served to such a row and none is registered
+(the state at a prefix's end is not kept), so its pages go back to the
+free list at release; ``spec_tokens > 0`` is refused as for
+``fixed_row_cache``; ``kv_pool_bytes`` counts the state apart. What such
+a MODEL owes: the named entries' leaves are indexed by state block on
+their first axis and block 0 stays zeros; every other leaf is
+``[kv_pages, page_size, ...]`` under the page models' rules, except that
+a call with ``last_pos`` never starts on pages another request wrote; a
+row at cursor 0 has no past, so a state block another row left reads as
+zeros to it; a prefill bucket's padding leaves the state as it is; a row
+whose state block is 0 is not live and touches nothing; several tokens a
+row without ``last_pos`` are refused.
+
 A family's key starts with its ``model_type``; GPT-2's is its plain field
 tuple, as it always was (its programs' keys and names did not move).
 """
@@ -69,9 +94,11 @@ from .keye_vl2 import FAMILY as KEYE_VL2, KeyeVL2Config
 from .kimi_k2 import FAMILY as KIMI_K2, KimiK2Config
 from .nanogpt import (GPTConfig, sample_logits,  # noqa: F401 — re-exported
                       sample_rows)
+from .qwen3_next import FAMILY as QWEN3_NEXT, Qwen3NextConfig
 
 FAMILIES = {COHERE2_MOE: Cohere2MoeConfig, KEYE_VL2: KeyeVL2Config,
-            BRUMBY: BrumbyConfig, KIMI_K2: KimiK2Config}
+            BRUMBY: BrumbyConfig, KIMI_K2: KimiK2Config,
+            QWEN3_NEXT: Qwen3NextConfig}
 
 
 def config_from_key(key: tuple):

@@ -93,13 +93,13 @@ GATHER = "gather"
 # learned sparse attention (``ops/sparse_attention.py``): index scores,
 # the exact ``topk`` selection, an attend over the kept keys only
 SPARSE = "sparse_topk"
-# ``RETENTION`` (imported above): power retention, no keys and values
-# are kept, a row's one block of state is decayed, updated and read
+# ``RETENTION`` (imported above), ``GATED_DELTA`` (below): no keys and
+# values are kept, a row's block of state is decayed, updated and read
 
 
 def paged_attend_path(n_embd: int, page_size: int, dtype, kv_dtype,
-                      head_dim: int = 0, window: int = 0,
-                      sparse_topk: int = 0, retention: bool = False) -> str:
+                      head_dim: int = 0, window: int = 0, sparse_topk: int = 0,
+                      retention: bool = False, gated_delta=False) -> str:
     """Which implementation the paged attend takes, from what the code
     can observe. ``n_embd`` is the pool row's width (all key-value heads
     of a position). THE dispatch point — the model and the engine's
@@ -125,11 +125,11 @@ def paged_attend_path(n_embd: int, page_size: int, dtype, kv_dtype,
     backend and dtype; it reads the kept positions where they lie and
     never builds a row's window.
 
-    ``retention`` (a layer whose cache is a recurrent state of fixed
-    size, ``ops/power_retention.py``): ``RETENTION`` on every backend
-    and dtype; there are no pages of keys to walk or gather."""
-    if retention:
-        return RETENTION
+    ``retention`` / ``gated_delta`` (a layer whose cache is a recurrent
+    state, ``ops/power_retention.py`` / ``ops/gated_delta.py``): that id
+    on every backend and dtype; there are no pages of keys to walk."""
+    if retention or gated_delta:
+        return RETENTION if retention else GATED_DELTA
     if sparse_topk:
         return SPARSE
     dtype, kv_dtype = jnp.dtype(dtype), jnp.dtype(kv_dtype)
@@ -449,3 +449,9 @@ def _paged_attention_gqa(q, k_pool, v_pool, block_table, cache_pos, window,
     )(block_table.reshape(-1).astype(jnp.int32),
       cache_pos.astype(jnp.int32), qx, k_pool, v_pool)
     return out.reshape(b, kvh, t_pad, group, hd)[:, :, :t]
+
+
+# at the END of this file on purpose: a kernel's compiled body carries the
+# file and line of the frames it was traced under, so no line above may
+# move (``programs/serve_defs.py`` says more)
+from .gated_delta import GATED_DELTA  # noqa: E402

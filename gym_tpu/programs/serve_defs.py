@@ -102,7 +102,7 @@ def _templates(cfg_tuple: tuple, batch: int):
     process serves."""
     cfg, model = _model(cfg_tuple)
     dummy = jnp.zeros((batch, 1), jnp.int32)
-    mb = cfg.block_size // cfg.page_size
+    mb = table_width(cfg)
     shapes = jax.eval_shape(
         lambda: model.init(
             {"params": jax.random.PRNGKey(0)}, dummy, train=False,
@@ -404,7 +404,7 @@ def _paged_cfg(cfg_tuple: tuple):
         raise ValueError(
             "paged program defs need a config with page_size/kv_pages "
             "set (the engine's dataclasses.replace'd decode config)")
-    mb = cfg.block_size // cfg.page_size
+    mb = table_width(cfg)
     pcfg = {"config": cfg_tuple, "page_size": cfg.page_size,
             "kv_pages": cfg.kv_pages}
     return cfg, mb, pcfg
@@ -477,3 +477,61 @@ def spec_decode_def(cfg_tuple: tuple, num_slots: int, chunk: int,
               _state_tpl(SPEC_STATE, s, mb, cfg.block_size)),
         donate_args=(1,),
         builder=lambda: build_spec_decode(cfg_tuple, s, chunk, gamma))
+
+
+# -- a row that holds a state block beside its pages ------------------------
+#
+# Everything below stands at the END of this file on purpose, and nothing
+# above it may gain or lose a line: a Pallas kernel's compiled body carries
+# the file and LINE of every frame it was traced under (this module's
+# ``decode`` / ``prefill`` among them), so a line that moves up there moves
+# the compile-cache key of every kernel-bearing program of every served
+# model (``tests/test_serve_hybrid_pool.py`` holds the lines where they
+# are).
+
+
+def row_state(config) -> bool:
+    """Whether a row of this model holds ONE state block beside its run
+    of pages (``models/serving.py``: ``row_state``). Its block table then
+    has one column more than the row has pages, the state block's: the
+    decode state's ``bt`` is ``table_width`` columns wide, so the programs
+    above carry it, clear it when a row stops and hand it to the model
+    with the pages, and ask nothing else of it."""
+    return bool(getattr(config, "row_state", False))
+
+
+def table_width(config) -> int:
+    """Columns of a row's block table: its pages and, for a model with
+    ``row_state``, its state block."""
+    return config.block_size // config.page_size + int(row_state(config))
+
+
+def build_row_cow(cfg_tuple: tuple, state: bool):
+    """``build_cow`` for a model with ``row_state``: the copy of entry
+    ``src`` over ``dst`` runs over the leaves indexed by page only, or with
+    ``state`` over those indexed by state block only (the scrub of a
+    quarantined row's block): an index of one kind means nothing to the
+    other kind's leaves."""
+    names = set(config_from_key(cfg_tuple).row_state_names())
+
+    @functools.partial(jax.jit, donate_argnums=(0,))
+    def cow(cache, src, dst):
+        return {name: jax.tree.map(lambda c: c.at[dst].set(c[src]), sub)
+                if (name in names) == state else sub
+                for name, sub in cache.items()}
+
+    return cow
+
+
+def row_cow_def(cfg_tuple: tuple, state: bool) -> ProgramDef:
+    """``cow_def`` for a model with ``row_state``: its pages' copy, or
+    with ``state`` its state blocks'."""
+    cfg, _mb, pcfg = _paged_cfg(cfg_tuple)
+    _, pool_tpl = _templates(cfg_tuple, 1)
+    what = "state" if state else f"page={cfg.page_size}"
+    return ProgramDef(
+        name=f"serve.cow[{what}{_qtag(cfg_tuple)}]", family="serve.cow",
+        config={**pcfg, "state": state},
+        args=(pool_tpl, _scalar(np.int32), _scalar(np.int32)),
+        donate_args=(0,),
+        builder=lambda: build_row_cow(cfg_tuple, state))

@@ -554,6 +554,12 @@ def create_server(params, cfg, *, host: str = "127.0.0.1", port: int = 0,
                 # the allocator's recency heap those evictions looked at
                 "kv_evictions": sum(s.kv_evictions for s in stats),
                 "kv_evict_visits": sum(s.kv_evict_visits for s in stats),
+                # a model whose rows hold one state block beside their
+                # pages: how many there are (0: no such model), and how
+                # many live and parked rows hold
+                "state_blocks": sum(s.state_blocks for s in stats),
+                "state_blocks_in_use": sum(s.state_blocks_in_use
+                                           for s in stats),
                 "spec_accept_rate": (accepted / drafted
                                      if drafted else None),
                 # device-program registry: XLA compiles this process has

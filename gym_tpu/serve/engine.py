@@ -51,6 +51,17 @@ set once and runs every request through it:
   back to the allocator while its neighbors keep decoding, no
   drain-the-batch barrier, and no wait before the next step is queued.
 
+- **Two kinds of row beside the run of pages** (``models/serving.py``
+  says what each model owes): a row that is ONE block of fixed size (a
+  recurrent state in every layer: ``row_cache``; a page is then the whole
+  row) and a row that holds one STATE BLOCK beside its run of pages (a
+  state in some layers, pages in the others: ``row_state``; the block
+  table's last column names the block, so it rides in the decode state
+  on the device as the pages do, is cleared with them when a row stops
+  and is pinned with them while a row is parked). Such rows share no
+  prefix, need no copy-on-write page and refuse speculation; the second
+  kind is admitted only when pages AND a state block are to be had.
+
 Parity oracle (tests/test_serve.py): for a single request the engine's
 token stream is IDENTICAL to ``generate_fast`` with the same sampling
 config and seed off the TPU — both use the shared ``sample_logits``
@@ -122,7 +133,7 @@ from ..ops.paged_attention import GATHER
 from ..programs import default_registry
 from ..programs.serve_defs import (PAGED_STATE, SPEC_STATE, cow_def,
                                    paged_decode_def, paged_prefill_def,
-                                   spec_decode_def)
+                                   row_cow_def, row_state, spec_decode_def)
 from ..utils.resilience import fault_point
 from ..utils.trace import span
 
@@ -195,7 +206,8 @@ class SamplingParams:
 @dataclasses.dataclass
 class ParkedSlot:
     """Host-side snapshot of one preempted slot.
-    The block-table REFERENCES move into the snapshot — pages stay
+    The block-table REFERENCES move into the snapshot — pages (and a
+    ``row_state`` model's state block, the table's last entry) stay
     pinned in the pool at their current refcounts, exactly like the
     slot-owned write blocks the spec-decode rewind masks — so a later
     ``resume`` continues the generation byte-identical to an
@@ -288,6 +300,11 @@ class EngineStats:
     kv_evict_visits: int = 0             # cumulative entries of the recency
     #                                      heap those evictions looked at
     #                                      (a few a page, never the cache)
+    state_blocks: int = 0                # state blocks of a model whose rows
+    #                                      hold one beside their pages (the
+    #                                      null one too; 0: no such model)
+    state_blocks_in_use: int = 0         # of them, held by live and parked
+    #                                      rows
     prefix_hit_blocks: int = 0           # cumulative blocks served from the
     #                                      prefix cache instead of prefilled
     # speculative-decoding counters (0 with speculation off)
@@ -595,7 +612,8 @@ class InferenceEngine:
                  num_slots: int = 8, decode_chunk: int = 1,
                  paged: bool = True, page_size: int = 16,
                  kv_pages: Optional[int] = None, spec_tokens: int = 0,
-                 weights_tag: Optional[str] = None):
+                 weights_tag: Optional[str] = None,
+                 state_blocks: Optional[int] = None):
         """``decode_chunk``: decode steps fused into one dispatch (a
         device-side scan with on-device EOS/max-token bookkeeping).
         1 = purest continuous batching — admission/eviction can happen
@@ -610,6 +628,10 @@ class InferenceEngine:
         for callers that still name it, and ``False`` is refused.
         ``spec_tokens=γ > 0`` drafts γ tokens a decode iteration and
         verifies them in one model call (the module docstring has both).
+        ``state_blocks``: for a model whose rows hold one state block
+        beside their pages (``row_state``), how many there are (default:
+        the config's, else the null block + one a slot + one spare for a
+        parked row).
 
         ``weights_tag`` names the parameter set this engine serves (e.g.
         ``"step-120"``) — pure observability for the fleet router's
@@ -649,16 +671,24 @@ class InferenceEngine:
         # whole row (one table entry a row, ``kv_pages`` counts blocks),
         # nothing of it can be shared, copied on write or rewound
         self.row_cache = row_cache(config)
+        # a model whose rows hold ONE state block beside their pages: the
+        # block table's last column names it, both are planned, parked,
+        # freed and scrubbed as one, and as with ``row_cache`` nothing of
+        # such a row can be shared, copied on write or rewound
+        self.row_state = row_state(config)
         if self.row_cache:
             page_size = self.block_size
-            if self.spec_tokens:
-                raise ValueError(
-                    "spec_tokens > 0 with a model whose cache is a "
-                    "recurrent state: rejected drafts cannot be rewound "
-                    "out of a state without a copy of it")
+        # either kind of row with state shares no prefix, needs no
+        # copy-on-write page and cannot be rewound
+        self._stateful = self.row_cache or self.row_state
+        if self._stateful and self.spec_tokens:
+            raise ValueError(
+                "spec_tokens > 0 with a model whose cache is a "
+                "recurrent state: rejected drafts cannot be rewound "
+                "out of a state without a copy of it")
         # pages an admission may need beyond its own: the copy-on-write
         # of a shared last block
-        self._cow_room = 0 if self.row_cache else 1
+        self._cow_room = 0 if self._stateful else 1
         if page_size < 1 or self.block_size % page_size:
             raise ValueError(
                 f"page_size must be >= 1 and divide block_size "
@@ -680,6 +710,18 @@ class InferenceEngine:
         self.config = dataclasses.replace(
             base_cfg, page_size=self.page_size, kv_pages=self.kv_pages)
         self._alloc = BlockAllocator(self.kv_pages, self.page_size)
+        self.state_blocks = 0
+        self._state_free: List[int] = []
+        if self.row_state:
+            n = int(state_blocks or getattr(config, "state_blocks", 0)
+                    or 2 + self.num_slots)
+            if n < 2:
+                raise ValueError(
+                    f"state_blocks={n} too small: need the null block + "
+                    f"one row's")
+            self.state_blocks = n
+            self._state_free = list(range(n - 1, 0, -1))     # pop() → low
+            self.config = dataclasses.replace(self.config, state_blocks=n)
         # the model's own dispatch point, asked with what its layers
         # will ask: the id on the dispatch spans and what /stats counts
         self.attend_path = attend_path_id(self.config)
@@ -699,7 +741,10 @@ class InferenceEngine:
         decode_def = paged_decode_def(self._cfg_tuple, self.num_slots,
                                       self.decode_chunk)
         self._decode_prog = self._acquire(decode_def)
-        self._cow_prog = self._acquire(cow_def(self._cfg_tuple))
+        # the page copy; under ``row_state`` over the page leaves alone,
+        # with a twin over the state blocks (the scrub's)
+        self._cow_prog, self._state_cow_prog = (
+            d and self._acquire(d) for d in self._cow_defs())
         self._spec_prog = (
             self._acquire(spec_decode_def(
                 self._cfg_tuple, self.num_slots, self.decode_chunk,
@@ -715,7 +760,8 @@ class InferenceEngine:
         self._cache = jax.tree.map(
             lambda sh: jnp.zeros(sh.shape, sh.dtype), decode_def.args[1])
         s = self.num_slots
-        self._bt = np.zeros((s, self.max_blocks), np.int32)
+        # a row's pages and, under ``row_state``, its state block last
+        self._bt = np.zeros((s, self.max_blocks + self.row_state), np.int32)
         self._pos = np.zeros(s, np.int32)          # per-slot KV cursor
         self._hist = np.zeros((s, self.block_size), np.int32)
         self._prompt_len = np.zeros(s, np.int32)
@@ -751,7 +797,8 @@ class InferenceEngine:
         self._logits_host: Optional[np.ndarray] = None
         self.stats = EngineStats(num_slots=s,
                                  weights_dtype=self.weights_dtype,
-                                 kv_dtype=self.kv_dtype)
+                                 kv_dtype=self.kv_dtype,
+                                 state_blocks=self.state_blocks)
 
     @property
     def last_logits(self) -> Optional[np.ndarray]:
@@ -788,27 +835,41 @@ class InferenceEngine:
     def kv_pool_bytes(self) -> Dict[str, int]:
         """Actual device bytes of the KV cache, split into the K/V
         payload and the quantization-scale sidecar (0 at f32) — the
-        honest-accounting observable behind the 4x capacity claim."""
-        payload = scales = 0
+        honest-accounting observable behind the 4x capacity claim. A
+        ``row_state`` model's state blocks are counted apart, under
+        ``state``."""
+        out = {"payload": 0, "scales": 0}
+        state_names = ()
+        if self.row_state:
+            out["state"] = 0
+            state_names = set(self.config.row_state_names())
 
-        def walk(node):
-            nonlocal payload, scales
+        def walk(node, state):
             if hasattr(node, "items"):
                 for name, sub in node.items():
                     if hasattr(sub, "items"):
-                        walk(sub)
+                        walk(sub, state or name in state_names)
+                    elif state:
+                        out["state"] += int(sub.nbytes)
                     elif name.endswith("_scale"):
-                        scales += int(sub.nbytes)
+                        out["scales"] += int(sub.nbytes)
                     else:       # keys, values, whatever else a layer keeps
-                        payload += int(sub.nbytes)
+                        out["payload"] += int(sub.nbytes)
 
-        walk(self._cache)
-        return {"payload": payload, "scales": scales}
+        walk(self._cache, False)
+        return out
 
     # -- device programs (registry-backed) --------------------------------
 
     def _acquire(self, pdef):
         return self._registry.acquire(pdef, pin_owner=self)
+
+    def _cow_defs(self):
+        """``(page copy, state-block copy or None)``."""
+        if not self.row_state:
+            return cow_def(self._cfg_tuple), None
+        return (row_cow_def(self._cfg_tuple, False),
+                row_cow_def(self._cfg_tuple, True))
 
     def _prefill_prog(self, bucket: int):
         """Registry handle for this bucket's prefill program, ensured
@@ -844,7 +905,7 @@ class InferenceEngine:
         defs = [paged_decode_def(cfg, s, chunk)]
         if self.spec_tokens:
             defs.append(spec_decode_def(cfg, s, chunk, self.spec_tokens))
-        defs.append(cow_def(cfg))
+        defs.extend(d for d in self._cow_defs() if d is not None)
         if chunk != 1 or self.spec_tokens:
             # the lazy chunk-1 twin (teacher forcing / eval harnesses)
             # is part of the family too — without it a warmed or
@@ -976,9 +1037,11 @@ class InferenceEngine:
         hit_pages: List[int] = []
         chain: List[int] = []
         cid = 0
-        if self.row_cache:
+        if self._stateful:
             # a state is not addressed by position: no prefix of a
-            # prompt is served from one, and none is registered
+            # prompt is served from one (nor, beside one, from pages:
+            # the state at the prefix's end is not kept), and none is
+            # registered
             return hit_pages, chain
         buf, step = prompt.tobytes(), page * prompt.itemsize
         for b in range(len(prompt) // page):
@@ -1050,7 +1113,10 @@ class InferenceEngine:
             _n_new, need = self._plan_paged(p, sp.max_new_tokens)
         pinned = hit_pages + ([cow_src] if cow_src is not None else [])
         score = len(hit_pages) + (1 if cow_src is not None else 0)
-        return self._alloc.available(exclude=pinned) >= need, score
+        fits = self._alloc.available(exclude=pinned) >= need
+        if self.row_state:                   # both, or not admitted
+            fits = fits and bool(self._state_free)
+        return fits, score
 
     def admit(self, prompt: np.ndarray,
               sp: SamplingParams) -> Tuple[int, TokenEvent]:
@@ -1142,6 +1208,7 @@ class InferenceEngine:
         # compile/dispatch error in CoW or prefill) unwinds it exactly —
         # an admission that fails its request must not shrink the pool
         held: List[int] = []
+        state_block = 0
         evicted = al.evictions
         try:
             with span("serve.prefill.plan") as sp_plan:
@@ -1158,6 +1225,13 @@ class InferenceEngine:
                 # first
                 fresh = al.alloc_many(need)
                 held += fresh
+                if self.row_state:
+                    if not self._state_free:
+                        raise NoFreeBlocksError(
+                            "no state block is free right now — retry "
+                            "after running requests release")
+                    state_block = self._state_free.pop()
+                    sp_plan.ids["state_block"] = state_block
                 if cow_src is not None:
                     self._cache = self._cow_prog(
                         self._cache, np.int32(cow_src), np.int32(fresh[0]))
@@ -1166,6 +1240,8 @@ class InferenceEngine:
                 row = hit_pages + fresh
                 self._bt[slot] = 0
                 self._bt[slot, :len(row)] = row
+                if self.row_state:
+                    self._bt[slot, -1] = state_block
                 sp_plan.ids["pages"] = need
                 sp_plan.ids["evicted"] = al.evictions - evicted
                 self._seen_buckets.add(bucket)
@@ -1194,13 +1270,15 @@ class InferenceEngine:
             self.stats.paged_kernel_dispatches += self._kernel_attend
         except BaseException:
             al.decref_many(held)
+            if state_block:
+                self._state_free.append(state_block)
             self._bt[slot] = 0
             raise
         # only a COMMITTING admission refreshes hit recency — planning
         # probes must not keep a never-admitted prefix artificially hot
         for pg in hit_pages:
             al.touch(pg)
-        if cow_src is None and not self.row_cache:
+        if cow_src is None and not self._stateful:
             # register the freshly-prefilled full PROMPT blocks (their
             # content is immutable — decode writes start past them);
             # the CoW path has nothing new: every block was cached
@@ -1226,14 +1304,29 @@ class InferenceEngine:
         self.stats.kv_blocks_cached = al.cached()
         self.stats.kv_evictions = al.evictions
         self.stats.kv_evict_visits = al.evict_visits
+        if self.row_state:
+            self.stats.state_blocks_in_use = (
+                self.state_blocks - 1 - len(self._state_free))
+
+    def _free_row(self, row: np.ndarray) -> None:
+        """Give back what a block-table row references: its pages and,
+        under ``row_state``, its state block (the last entry)."""
+        pages = row[:self.max_blocks]
+        self._alloc.decref_many(pages[pages != 0].tolist())
+        if self.row_state and row[-1]:
+            self._state_free.append(int(row[-1]))
+
+    def state_block(self, slot: int) -> int:
+        """The state block slot ``slot`` holds (0: none, or no such
+        model)."""
+        return int(self._bt[slot, -1]) if self.row_state else 0
 
     def _release_pages(self, slot: int) -> None:
         """Drop this slot's block-table references (idempotent: an
         already-cleared row is a no-op). Cached prefix blocks stay
         resident at refcount 0; plain owned blocks return to the free
-        list."""
-        row = self._bt[slot]
-        self._alloc.decref_many(row[row != 0].tolist())
+        list, and a ``row_state`` row's state block to its own."""
+        self._free_row(self._bt[slot])
         # the mirror's row only: the programs clear the device's when the
         # row stops, and read no inactive row's table before that
         self._bt[slot] = 0
@@ -1247,10 +1340,15 @@ class InferenceEngine:
         or every row would be poisoned through its unallocated table
         entries; the copy is the admit path's own program. Rare path:
         one small dispatch a page."""
-        for pg in self._bt[slot]:
+        for pg in self._bt[slot, :self.max_blocks]:
             if pg and self._alloc.condemn(int(pg)):
                 self._cache = self._cow_prog(
                     self._cache, np.int32(0), np.int32(pg))
+        block = self.state_block(slot)
+        if block:
+            # the row's state block: the null block's zeros over it
+            self._cache = self._state_cow_prog(
+                self._cache, np.int32(0), np.int32(block))
 
     def release(self, slot: int) -> None:
         """Free a slot (a cancelled request, a deadline): the slot's
@@ -1350,8 +1448,7 @@ class InferenceEngine:
         if parked.released:
             return
         parked.released = True
-        row = parked.block_table
-        self._alloc.decref_many(row[row != 0].tolist())
+        self._free_row(parked.block_table)
         self._pool_stats()
 
     def step(self, override_tokens: Optional[Dict[int, int]] = None,

@@ -809,6 +809,10 @@ class Scheduler:
                     # the padded tokens this prefill dispatched
                     admit_span.ids["bucket"] = (
                         engine.stats.prefill_tokens - padded)
+                    block = engine.state_block(slot)
+                    if block:
+                        # a row that holds a state block beside its pages
+                        admit_span.ids["state_block"] = block
                     record("request.queue", req.submit_t, req.admit_t,
                            request=req.id)
                 except NoFreeBlocksError:
