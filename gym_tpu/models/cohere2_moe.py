@@ -21,11 +21,11 @@ After the last layer ``LN``, then ``logits = logit_scale * y E^T`` over
 the rows of the tied embedding this chip holds (``vocab_size`` here is
 that count: a sliced vocabulary is a smaller vocabulary).
 
-Serving only, paged only: keys and values live in the engine's page
-pools, ``[kv_pages, page_size, num_key_value_heads * head_dim]`` a layer
-in ``kv_dtype``; weights are stored in ``weights_dtype`` (bfloat16) and
-multiplied as stored with float32 accumulation; the residual stream, the
-norms' statistics, the router, the softmax and the logits are float32.
+Serving only, paged only: keys and values in the engine's page pools,
+``[kv_pages, page_size, num_key_value_heads * head_dim]`` a layer in
+``kv_dtype``; weights as stored (``weights_dtype``) with float32 sums;
+residual, norms, router, softmax and logits float32. A prefill runs its
+bucket ``prefill_rows`` positions a pass, padding-only passes skipped.
 """
 
 from __future__ import annotations
@@ -69,8 +69,8 @@ class Cohere2MoeConfig:
     norm_topk_prob: bool = True
     # the routed experts [lo, hi) this chip holds of every layer
     held_experts: Tuple[int, int] = (0, 128)
-    # positions a row may reach (the block table's length times a page)
-    block_size: int = 16384
+    prefill_rows: int = 4096          # positions a pass of a prefill
+    block_size: int = 16384           # positions a row may reach
     moe_chunk_rows: int = 8192
     attn_query_block: int = 2048      # queries a paged attend of a prefill
     decode: bool = False
@@ -332,7 +332,7 @@ class Cohere2Moe(nn.Module):
     """``__call__(tokens [b, t], train=False, block_table=, cache_pos=,
     last_pos=None)`` -> float32 logits [b, t, V], or [b, V] at position
     ``last_pos`` of every row when that is given (a prefill wants one
-    position's logits of a bucket of up to 16k)."""
+    position's logits of a bucket of up to 16k: ``in_passes``)."""
 
     config: Cohere2MoeConfig
 
@@ -348,16 +348,16 @@ class Cohere2Moe(nn.Module):
             raise ValueError("this decoder runs through the paged cache "
                              "only: decode=True and page_size > 0")
         if block_table is None or cache_pos is None:
-            raise ValueError("paged decode needs block_table and "
-                             "cache_pos")
+            raise ValueError("paged decode needs block_table and cache_pos")
         for name in ("weights_dtype", "kv_dtype"):
             if getattr(cfg, name) not in _DTYPES:
-                raise ValueError(f"{name} must be one of "
-                                 f"{sorted(_DTYPES)}, got "
-                                 f"{getattr(cfg, name)!r}")
+                raise ValueError(f"{name} must be one of {sorted(_DTYPES)}, "
+                                 f"got {getattr(cfg, name)!r}")
         dt = _DTYPES[cfg.weights_dtype]
         embed = self.param("embed_tokens", nn.initializers.normal(0.02),
                            (cfg.vocab_size, cfg.hidden_size), dt)
+        if last_pos is not None and tokens.shape[1] > cfg.prefill_rows:
+            return self.in_passes(tokens, block_table, cache_pos, last_pos)
         x = embed[tokens].astype(jnp.float32)
         for i in range(cfg.num_hidden_layers):
             x = ParallelBlock(cfg, i, name=f"layers_{i}")(x, block_table,
@@ -365,7 +365,98 @@ class Cohere2Moe(nn.Module):
         if last_pos is not None:
             x = jax.lax.dynamic_index_in_dim(x, last_pos, axis=1,
                                              keepdims=False)
+        return self.head(x, embed)
+
+    def head(self, x, embed):
+        """The final norm and the tied embedding's rows: float32 logits
+        of ``x`` [..., hidden]."""
+        cfg = self.config
+        dt = _DTYPES[cfg.weights_dtype]
         y = LayerNormNoBias(cfg.layer_norm_eps, dt, name="norm")(x)
         with jax.named_scope("head"):
             return cfg.logit_scale * jnp.dot(
                 y.astype(dt), embed.T, preferred_element_type=jnp.float32)
+
+    def in_passes(self, tokens, block_table, cache_pos, last_pos):
+        """The prefill of a bucket longer than ``prefill_rows``: logits
+        [b, V] at ``last_pos`` (``prefill_in_passes``). It stands below
+        ``__call__`` and is entered there in two lines, so that the line
+        of ``__call__`` a decode step's kernels were traced under is the
+        parent's."""
+        cfg = self.config
+        x = prefill_in_passes(
+            self, [ParallelBlock(cfg, i)
+                   for i in range(cfg.num_hidden_layers)],
+            tokens, block_table, cache_pos, last_pos,
+            cfg.prefill_pass(tokens.shape[1]))
+        return self.head(x, self.variables["params"]["embed_tokens"])
+
+
+# -- a prefill in passes ----------------------------------------------------
+# Below everything else: the functions above stand in the call stacks of
+# kernels whose compiled bodies carry file and line, so nothing above may
+# move (tests/test_serve_hybrid_pool.py). The config class takes its
+# answer to ``prefill_pass`` from here for the same reason.
+
+
+def prefill_pass(config, t: int) -> int:
+    """Positions a pass of a prefill of ``t`` (``config.prefill_pass(t)``:
+    what the serving engine asks a config, to count the positions a
+    dispatched prefill runs): ``t`` itself where the bucket is no longer
+    than ``prefill_rows`` and runs whole, else the largest count that
+    divides both (a bucket capped at the row's extent need not be a power
+    of two)."""
+    rows = config.prefill_rows
+    return math.gcd(t, rows) if t > rows else t
+
+
+def prefill_in_passes(mod: nn.Module, blocks, tokens, block_table,
+                      cache_pos, last_pos, step: int):
+    """A prefill bucket ``tokens`` [b, t], ``step`` positions at a time
+    through ALL of ``blocks`` (unbound layer modules, ``__call__(x,
+    block_table, cache_pos)``, whose parameters and ``cache`` entries
+    ``mod`` holds as ``layers_<i>``, beside ``embed_tokens``): the
+    hidden row [b, hidden] of position ``last_pos`` after the last layer.
+    A pass's keys and values are in the pools before the next pass
+    attends, so every position sees what it saw in one run of the whole
+    bucket. A pass that lies wholly past ``last_pos`` is the bucket's
+    padding and is skipped (a real ``cond``): it costs nothing and writes
+    nothing, and what its pages held before lies past the row's cursor,
+    where a decode step writes a position before it attends to it. The
+    pools ride the loop's carry and are updated in place."""
+    b, t = tokens.shape
+    p, caches = mod.variables["params"], mod.variables["cache"]
+    names = [f"layers_{i}" for i in range(len(blocks))]
+    n_valid = jnp.broadcast_to(last_pos + 1, (b,))
+
+    def one_pass(carry, lo):
+        def run(carry):
+            pools, x_last = carry
+            tok = jax.lax.dynamic_slice_in_dim(tokens, lo, step, axis=1)
+            x = p["embed_tokens"][tok].astype(jnp.float32)
+            out = []
+            for name, block, pool in zip(names, blocks, pools):
+                x, new = block.apply({"params": p[name], "cache": pool}, x,
+                                     block_table, cache_pos + lo,
+                                     mutable=["cache"])
+                out.append(new["cache"])
+            here = jnp.clip(last_pos - lo, 0, step - 1)
+            row = jax.lax.dynamic_index_in_dim(x, here, axis=1,
+                                               keepdims=False)
+            return tuple(out), jnp.where(last_pos - lo == here, row, x_last)
+
+        # a pass past every row's prompt is a bucket's padding
+        return jax.lax.cond(lo < n_valid.max(), run, lambda c: c,
+                            carry), None
+
+    hidden = p["embed_tokens"].shape[1]
+    (pools, x_last), _ = jax.lax.scan(
+        one_pass, (tuple(caches[name] for name in names),
+                   jnp.zeros((b, hidden), jnp.float32)),
+        jnp.arange(0, t, step))
+    for name, pool in zip(names, pools):
+        mod.put_variable("cache", name, pool)
+    return x_last
+
+
+Cohere2MoeConfig.prefill_pass = prefill_pass

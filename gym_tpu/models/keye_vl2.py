@@ -23,13 +23,13 @@ the rows of the vocabulary this chip holds.
 
 The norm, the grouped projections with their q/k norms and rotation, the
 embedding and the head are ``decoder_parts.py``'s, shared with
-``brumby.py``. Serving only, paged only (as ``cohere2_moe.py``, whose
-pool arithmetic and query blocks it shares): a layer keeps ``k``, ``v`` and
-``kI`` (after norm and rotary) a position in the engine's pools, ``[kv_pages,
-page_size, kv_heads * head_dim]`` twice and ``[kv_pages, page_size *
-indexer_head_dim]`` (a page's index keys side by side on one row). Weights and pools in ``weights_dtype`` /
-``kv_dtype``; residual, norms, softmaxes, router and index scores in
-float32.
+``brumby.py``. Serving only, paged only (as ``cohere2_moe.py``, whose pool
+arithmetic, query blocks and prefill in passes it shares: a bucket runs
+``prefill_rows`` positions a pass, padding-only passes skipped): a layer
+keeps ``k``, ``v`` and ``kI`` (after norm and rotary) a position in the
+pools, ``[kv_pages, page_size, kv_heads * head_dim]`` twice and a page's
+index keys side by side on one row. Weights and pools in ``weights_dtype``
+/ ``kv_dtype``; residual, norms, softmaxes, router, index scores float32.
 """
 
 from __future__ import annotations
@@ -73,8 +73,8 @@ class KeyeVL2Config:
     sparse_topk: int = 2048             # sa_config.topk: keys a query keeps
     # the routed experts [lo, hi) this chip holds of every layer
     held_experts: Tuple[int, int] = (0, 128)
-    # positions a row may reach (the block table's length times a page)
-    block_size: int = 36864
+    prefill_rows: int = 1024          # positions a pass of a prefill
+    block_size: int = 36864           # positions a row may reach
     moe_chunk_rows: int = 8192
     attn_query_block: int = 1024      # queries a sparse attend of a prefill
     attn_key_block: int = 2048        # keys a step of its loops
@@ -285,7 +285,7 @@ class Block(nn.Module):
 class KeyeVL2(nn.Module):
     """``__call__(tokens [b, t], train=False, block_table=, cache_pos=,
     last_pos=None)`` -> float32 logits [b, t, V], or [b, V] at position
-    ``last_pos`` of every row when that is given."""
+    ``last_pos`` of every row when that is given (``in_passes``)."""
 
     config: KeyeVL2Config
 
@@ -301,16 +301,44 @@ class KeyeVL2(nn.Module):
             raise ValueError("this decoder runs through the paged cache "
                              "only: decode=True and page_size > 0")
         if block_table is None or cache_pos is None:
-            raise ValueError("paged decode needs block_table and "
-                             "cache_pos")
+            raise ValueError("paged decode needs block_table and cache_pos")
         for name in ("weights_dtype", "kv_dtype"):
             if getattr(cfg, name) not in _DTYPES:
-                raise ValueError(f"{name} must be one of "
-                                 f"{sorted(_DTYPES)}, got "
-                                 f"{getattr(cfg, name)!r}")
+                raise ValueError(f"{name} must be one of {sorted(_DTYPES)}, "
+                                 f"got {getattr(cfg, name)!r}")
         dt = _DTYPES[cfg.weights_dtype]
+        if last_pos is not None and tokens.shape[1] > cfg.prefill_rows:
+            return self.in_passes(tokens, block_table, cache_pos, last_pos)
         x = embed_tokens(self, tokens, cfg.vocab_size, cfg.hidden_size, dt)
         for i in range(cfg.num_hidden_layers):
             x = Block(cfg, name=f"layers_{i}")(x, block_table, cache_pos)
         return untied_head(self, x, last_pos, cfg.vocab_size,
                            cfg.rms_norm_eps, dt)
+
+    def in_passes(self, tokens, block_table, cache_pos, last_pos):
+        """The prefill of a bucket longer than ``prefill_rows``: logits
+        [b, V] at ``last_pos``, the bucket taken ``prefill_rows``
+        positions at a time through all layers and the passes that hold
+        only its padding skipped (``cohere2_moe.py:prefill_in_passes``).
+        A pass is whole ``attn_query_block``s and more than
+        ``sparse_attention.ROWS_MAX_T`` queries, so its attend is the
+        whole bucket's, block for block; each layer gathers the row's
+        window (``k_row``, ``v_row``, ``ki_row``) once a pass. It stands
+        below ``__call__`` and is entered there in two lines, so that the
+        line of ``__call__`` a decode step's index kernel was traced under
+        is the parent's."""
+        cfg = self.config
+        x = prefill_in_passes(
+            self, [Block(cfg)] * cfg.num_hidden_layers, tokens, block_table,
+            cache_pos, last_pos, cfg.prefill_pass(tokens.shape[1]))
+        return untied_head(self, x[:, None], None, cfg.vocab_size,
+                           cfg.rms_norm_eps,
+                           _DTYPES[cfg.weights_dtype])[:, 0]
+
+
+# Down here, and the config's answer to the serving engine's question with
+# it: no line at or above ``SparsePagedAttention``'s last may move, a
+# decode step's index kernel carries them (tests/test_serve_hybrid_pool.py)
+from .cohere2_moe import prefill_in_passes, prefill_pass  # noqa: E402
+
+KeyeVL2Config.prefill_pass = prefill_pass

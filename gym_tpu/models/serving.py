@@ -19,6 +19,14 @@ answers:
 * ``prepare_params()``   a parameter tree as this config serves it;
 * the fields ``block_size`` (positions a row may reach), ``vocab_size``,
   ``page_size``, ``kv_pages``, ``weights_dtype``, ``kv_dtype``;
+* ``prefill_pass(t)`` (optional): the positions a pass of a prefill of a
+  bucket of ``t`` runs, for a model that takes a bucket in passes through
+  all its layers and skips the passes that hold only the bucket's padding
+  (``models/cohere2_moe.py:prefill_in_passes``; ``t`` itself where the
+  bucket runs whole). The engine asks it to count the positions a
+  dispatched prefill ran, ``EngineStats.prefill_tokens_run`` beside the
+  padded ``prefill_tokens`` (``serve/engine.py:prefill_positions_run``); a
+  config that does not answer is counted as running its buckets;
 * ``fixed_row_cache`` (optional, default false): true where a row's
   cache is ONE block of fixed size whatever the row's length (a
   recurrent state: ``models/brumby.py``) and not a run of pages that
@@ -44,8 +52,7 @@ plans, the prefix cache, copy-on-write, the scrub, parking and
 ``kv_pool_bytes`` are ``tree.map``s over whatever the ``cache`` collection
 holds, indexed by page on the first axis. What such a MODEL owes: every
 ``cache`` leaf is ``[kv_pages, page_size, ...]``; a position's row is
-written whole by the call that computes it (a prefill bucket's padding
-too: it lies past the cursor, masked until overwritten); a call WITHOUT
+written whole by the call that computes it; a call WITHOUT
 ``last_pos`` may hold several tokens a row (a speculative verify) and
 must see each at ``cache_pos + j``; a call WITH ``last_pos`` may start at
 ``cache_pos > 0`` on pages another request wrote (a prefix hit), so its
@@ -53,6 +60,22 @@ attend reads the past from the pages and not from what the call itself
 computed; and the pool's minor dimension is whole lane tiles (a row of
 576 is copied, the whole pool, around every scatter on the chip, and a
 kernel cannot copy part of a tile: ``ops/latent_attention.py:pool_lanes``).
+
+What a prefill (a call WITH ``last_pos``) owes, whatever the pages hold:
+the logits of position ``last_pos`` and every position up to it in the
+pools. The positions past ``last_pos`` are the bucket's padding: a model
+may write them (they lie past the row's cursor, masked until a decode
+step overwrites them) or, where it takes the bucket in passes, skip the
+passes that hold nothing else and leave their pages as they were. So what
+lies past a row's cursor is NEVER read as a number, by any attend: a page
+comes to a row with what its last holder left (the engine zeroes nothing
+at admission), a decode step writes its position before it attends, and
+the kernels and the sparse attend select a masked position's value away
+(``paged_attention``'s ``vok``, ``sparse_attention``'s ``last`` and
+``kept``; ``tests/test_prefill_passes.py`` plants NaN there). The gather
+of a row's window, the path off the TPU, gives it a zero weight instead:
+sound because a freed page holds finite numbers (a quarantined row's are
+written over first, ``engine._scrub_pages``).
 
 A model that keeps BOTH kinds of cache in one row (``models/
 qwen3_next.py``: pages of keys and values in some layers, a recurrent

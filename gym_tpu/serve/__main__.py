@@ -529,6 +529,8 @@ def create_server(params, cfg, *, host: str = "127.0.0.1", port: int = 0,
                 "prefills": sum(s.prefills for s in stats),
                 "prefill_buckets": buckets,
                 "prefill_tokens": sum(s.prefill_tokens for s in stats),
+                "prefill_tokens_run": sum(s.prefill_tokens_run
+                                          for s in stats),
                 "paged": True,          # the only cache there is
                 "page_size": int(eng0.page_size),
                 "kv_pages": int(eng0.kv_pages),

@@ -264,6 +264,11 @@ class EngineStats:
     prefill_tokens: int = 0              # padded tokens dispatched through
     #                                      prefill — the prefix-sharing
     #                                      work-elision observable
+    prefill_tokens_run: int = 0          # of them, the positions the
+    #                                      prefills' passes ran: a model
+    #                                      that takes a bucket in passes
+    #                                      skips those that hold only its
+    #                                      padding (``prefill_positions_run``)
     active_slots: int = 0
     num_slots: int = 0
     readback_bytes: int = 0              # cumulative bytes decode steps read
@@ -340,6 +345,20 @@ def prompt_bucket(n: int, block_size: int) -> int:
         raise ValueError("empty prompt")
     b = 1 << (n - 1).bit_length()
     return min(b, block_size)
+
+
+def prefill_positions_run(config: Any, bucket: int, suffix: int) -> int:
+    """Positions a dispatched prefill of ``suffix`` tokens padded to
+    ``bucket`` runs on the device: the bucket, unless the model takes a
+    bucket in passes through all its layers and skips the passes that
+    hold only padding. ``config.prefill_pass(bucket)`` says how long a
+    pass is (``models/serving.py``); a config that does not answer runs,
+    or is counted as running, its whole bucket."""
+    ask = getattr(config, "prefill_pass", None)
+    if ask is None:
+        return bucket
+    step = ask(bucket)
+    return min(bucket, -(-suffix // step) * step)
 
 
 def row_cache(config: Any) -> bool:
@@ -1295,6 +1314,8 @@ class InferenceEngine:
                                          + (1 if cow_src is not None
                                             else 0))
         self.stats.prefill_tokens += bucket
+        self.stats.prefill_tokens_run += prefill_positions_run(
+            self.config, bucket, suffix)
         self._pool_stats()
         return tok
 

@@ -420,6 +420,30 @@ PARENT_LINES = {
         "_gqa_kernel": (304, "a1fa94b28f88340c"),
         "paged_attention_gqa": (398, "5b22bbbd26ed4d86"),
         "_paged_attention_gqa": (413, "f06f18a7d5e6f640")},
+    # PR 44 (a prefill in passes) edits these two: what stands in the
+    # stacks of ``qwen3-next``'s kernels and of the two models' own decode
+    # programs, on PR 44's parent commit (eee31ca); a method as
+    # ``Class.method``. The additions stand at the files' ends and below
+    # the models' ``__call__``
+    "gym_tpu/models/cohere2_moe.py": {
+        "pool_slots": (158, "98830a9bbad3f7df"),
+        "by_query_block": (171, "1fa993cb95b63949"),
+        "GroupedPagedAttention.__call__": (212, "0c203b0b656bc634"),
+        "ParallelBlock.__call__": (312, "b234a304f457c618")},
+    "gym_tpu/models/keye_vl2.py": {
+        "write_index_keys": (127, "09cfd5507f06b46c"),
+        "SparsePagedAttention.__call__": (157, "61cdc4635754d84b"),
+        "Block.__call__": (266, "806af717de8e5a7b")},
+}
+# and the one line of each model's own ``__call__`` (edited above it, to
+# enter ``in_passes``) that a decode step's kernels were traced under
+PARENT_CALLS = {
+    "gym_tpu/models/cohere2_moe.py": (
+        363, '            x = ParallelBlock(cfg, i, name=f"layers_{i}")'
+        '(x, block_table,'),
+    "gym_tpu/models/keye_vl2.py": (
+        314, '            x = Block(cfg, name=f"layers_{i}")'
+        '(x, block_table, cache_pos)'),
 }
 
 
@@ -429,13 +453,20 @@ def test_no_line_under_a_kernels_call_stack_moved(path):
     with open(os.path.join(ROOT, path)) as f:
         src = f.read()
     lines, found = src.splitlines(), {}
-    for node in ast.parse(src).body:
-        if isinstance(node, ast.FunctionDef) and node.name in \
-                PARENT_LINES[path]:
-            body = "\n".join(lines[node.lineno - 1:node.end_lineno])
-            found[node.name] = (node.lineno, hashlib.sha256(
-                body.encode()).hexdigest()[:16])
+    for top in ast.parse(src).body:
+        inner = [(top.name + ".", m) for m in top.body] \
+            if isinstance(top, ast.ClassDef) else [("", top)]
+        for prefix, node in inner:
+            name = prefix + getattr(node, "name", "")
+            if isinstance(node, ast.FunctionDef) and name in \
+                    PARENT_LINES[path]:
+                body = "\n".join(lines[node.lineno - 1:node.end_lineno])
+                found[name] = (node.lineno, hashlib.sha256(
+                    body.encode()).hexdigest()[:16])
     assert found == PARENT_LINES[path]
+    if path in PARENT_CALLS:
+        at, text = PARENT_CALLS[path]
+        assert lines[at - 1] == text
 
 
 # the modules this PR adds or edits (``git diff --stat`` against the
