@@ -38,9 +38,9 @@ zeros to it.
   scattered, and a block no live row owns is not touched. Elsewhere (the
   CPU's tests) the same pass a row at a time in ``jax.numpy``.
 * ``prefill``: a row's prompt in chunks: within a chunk the masked scores
-  ``(scale q.k)^2`` under the gates' differences, across chunks ``phi(Q)
-  S`` and ``S <- decay S + [V; 1]^T phi(K)``. Positions at or past the
-  prompt's length leave the state as it is (gate 1, feature 0).
+  under the gates' differences, across chunks ``phi(Q) S`` (on a TPU the
+  kernel ``retention_prefill_read``) and ``S <- decay S + [V; 1]^T
+  phi(K)``. Padding leaves the state as it is (gate 1, feature 0).
 """
 
 from __future__ import annotations
@@ -267,6 +267,9 @@ def prefill(S0, z0, q, k, v, gam, valid, eps: float, scale: float,
     n = T // C
     mm = jnp.dtype(mm_dtype)
     prec = HIGHEST if mm == jnp.float32 else None
+    from .paged_attention import report_path   # it imports this module
+    path = prefill_read_path(S0)
+    report_path(f"retention_read_{path}", (b, KV, G, C, hd), str(mm))
     keep = valid[:, None, :]
     gam = jnp.where(keep, gam, 0.0)
     k = jnp.where(keep[..., None], k, 0).astype(mm)
@@ -302,10 +305,14 @@ def prefill(S0, z0, q, k, v, gam, valid, eps: float, scale: float,
                              preferred_element_type=jnp.float32)
             den = w.sum(-1)
         with jax.named_scope("attn.retention.state"):
-            fq = phi(q_c, scale).astype(mm)               # [b,KV,G,C,D]
-            read = into[:, :, None, :, None] * jnp.einsum(
-                "bkgtd,bkvd->bkgtv", fq, S.astype(mm), precision=prec,
-                preferred_element_type=jnp.float32)
+            if path == "kernel":
+                read = into[:, :, None, :, None] * _kernel_read(
+                    q_c, S, scale, prec)
+            else:
+                fq = phi(q_c, scale).astype(mm)           # [b,KV,G,C,D]
+                read = into[:, :, None, :, None] * jnp.einsum(
+                    "bkgtd,bkvd->bkgtv", fq, S.astype(mm), precision=prec,
+                    preferred_element_type=jnp.float32)
             num, den = num + read[..., :hd], den + read[..., hd]
             fk = phi(k_c, scale) * out_of[..., None]        # [b,KV,C,D]
             S = jnp.exp(total)[..., None, None] * S + jnp.concatenate(
@@ -335,3 +342,69 @@ def attend(q, k, v, gam, eps: float, scale: float):
     num = jnp.einsum("kgts,ksv->kgtv", w, v.astype(jnp.float32),
                      precision=HIGHEST)
     return num / (w.sum(-1)[..., None] + eps)
+
+
+def prefill_read_path(S0) -> str:
+    """``"kernel"`` or ``"rows"``: how a prefill's chunk reads the state,
+    by ``state_pass_path``'s test on the rows' states ``S0`` [b, KV, hd,
+    D] (a TPU, or ``INTERPRET``; float32, head and feature axes whole
+    tiles). ``"rows"`` is ``phi(Q)`` written out and one einsum."""
+    return state_pass_path(S0)
+
+
+def _read_kernel(q_ref, s_ref, out_ref, sb_ref, *, hd: int, scale: float,
+                 prec):
+    """One (row, key-value head) of a chunk's read: the head's state
+    ``[hd + 1, D]`` (the normaliser its last row) against ``phi`` of the
+    group's ``G x C`` query rows, which is never whole: for each shift
+    ``r`` the rows' lanes rotated by ``r``, times the rows and ``scale
+    c_r`` in float32, rounded to the product's dtype as
+    ``phi(q).astype(mm)`` rounds, and the state's lanes ``[r hd, (r + 1)
+    hd)`` times them, summed in float32. The features are the product's
+    stationary side and the state's ``hd + 1`` rows stream past them, so
+    the output is ``[values, query rows]`` and the normaliser's row costs
+    the matrix unit an eighth more, not a second tile. The state is cast
+    to the product's dtype once, into ``sb_ref``, whose rows from ``hd``
+    are the normaliser and zeros (a whole tile of the packed dtype)."""
+    mm, wide = sb_ref.dtype, sb_ref.shape[0]
+    sb_ref[0:hd, :] = s_ref[0, 0, 0:hd, :].astype(mm)
+    z = s_ref[0, 0, hd:hd + 1, :]                              # [1, D]
+    first = jax.lax.broadcasted_iota(
+        jnp.int32, (wide - hd, z.shape[1]), 0) == 0
+    sb_ref[hd:wide, :] = jnp.where(first, z, 0.0).astype(mm)
+    q = q_ref[0, 0].astype(jnp.float32)                        # [G C, hd]
+    acc = jnp.zeros(out_ref.shape[2:], jnp.float32)
+    for r in range(hd // 2 + 1):
+        c = np.float32(1.0 if r in (0, hd // 2) else math.sqrt(2.0))
+        rot = pltpu.roll(q, hd - r, 1) if r else q
+        acc = acc + jax.lax.dot_general(
+            sb_ref[:, r * hd:(r + 1) * hd],
+            ((np.float32(scale) * c) * q * rot).astype(mm),
+            (((1,), (1,)), ((), ())), precision=prec,
+            preferred_element_type=jnp.float32)
+    out_ref[0, 0] = acc
+
+
+def _kernel_read(q_c, S, scale: float, prec):
+    """``einsum("bkgtd,bkvd->bkgtv", phi(q_c, scale).astype(mm),
+    S.astype(mm))`` as one Pallas kernel that never writes ``phi(q_c)``
+    out: ``q_c`` [b, KV, G, C, hd] in the product's dtype, ``S`` [b, KV,
+    hd + 1, D] float32 -> float32 [b, KV, G, C, hd + 16], of which the
+    first ``hd + 1`` columns are the read. A key-value head's state
+    comes in once for its group's ``G x C`` query rows."""
+    b, KV, G, C, hd = q_c.shape
+    D, wide = S.shape[-1], hd + 16
+    at_head = lambda i, k: (i, k, 0, 0)                     # noqa: E731
+    out = pl.pallas_call(
+        functools.partial(_read_kernel, hd=hd, scale=scale, prec=prec),
+        grid=(b, KV),
+        in_specs=[pl.BlockSpec((1, 1, G * C, hd), at_head),
+                  pl.BlockSpec((1, 1, hd + 1, D), at_head)],
+        out_specs=pl.BlockSpec((1, 1, wide, G * C), at_head),
+        out_shape=jax.ShapeDtypeStruct((b, KV, wide, G * C), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((wide, D), q_c.dtype)],
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_VMEM),
+        interpret=INTERPRET,
+        name="retention_prefill_read",
+    )(q_c.reshape(b, KV, G * C, hd), S)
+    return jnp.moveaxis(out.reshape(b, KV, wide, G, C), 2, -1)

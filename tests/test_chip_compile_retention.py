@@ -1,12 +1,14 @@
 """The served programs of the power-retention decoder (``models/brumby.py``)
 compiled for a described v5e at the benchmark cell's sizes, without the
 chip: that they fit, that the decode step's pass over the state is the
-Pallas kernel in place on the donated pool, and that nothing of a state
-array's size is moved beside it. ``tests/test_chip_compile.py``'s rule
-for the page pools, for the pool of state blocks; a file of its own so
-that the two compiles (a minute each) run beside that file's five
-minutes and not after them."""
+Pallas kernel in place on the donated pool, that nothing of a state
+array's size is moved beside it, and that a prefill's chunks read the
+state through their kernel with no ``phi(Q)`` written out.
+``tests/test_chip_compile.py``'s rule for the page pools, for the pool of
+state blocks; a file of its own so that the two compiles (a minute each)
+run beside that file's five minutes and not after them."""
 
+import math
 import os
 
 import jax
@@ -118,6 +120,34 @@ def test_brumby_programs_fit_the_chip_and_move_no_state(brumby_served,
     pool = f"f32[{cfg.kv_pages},8,128,{D}]"
     assert hlo.count(pool) >= 8
     assert re.search(r"input_output_alias=\{.*may-alias", hlo)
+
+
+def test_brumby_prefill_reads_the_state_without_writing_phi_q(brumby_served):
+    """The longest prefill bucket: each layer's chunk loop reads the
+    state through the Pallas kernel ``retention_prefill_read``, which
+    builds ``phi(Q)`` a lane tile at a time in VMEM, and no instruction
+    of the program writes an array along the feature axis (a minor
+    dimension of 8,320, or 16,640 for the lanes beside their rotations)
+    as large as ``phi(Q)`` of a chunk (``KV x G x C x D`` elements, 85 MB
+    in bfloat16) but the pools themselves: the key side's ``[8, 128,
+    8,320]`` and ``[8, 128, 16,640]`` stay (34 and 68 MB in float32)."""
+    import re
+    from gym_tpu.ops import power_retention
+    cfg, compiled = brumby_served("prefill16384")
+    hlo = compiled.as_text()
+    assert "retention_prefill_read" in hlo
+    D = power_retention.feature_dim(cfg.head_dim)
+    least = cfg.num_attention_heads * cfg.retention_chunk * D
+    pool = f"{cfg.kv_pages},{cfg.num_key_value_heads},{cfg.head_dim},{D}"
+    big = set()
+    for m in re.finditer(r"= \w+\[([\d,]+)\]\S* [\w\-]+\(", hlo):
+        dims = [int(d) for d in m.group(1).split(",")]
+        if (dims[-1] in (D, 2 * D) and math.prod(dims) >= least
+                and m.group(1) != pool):
+            big.add(m.group(0))
+    assert not big, sorted(big)[:3]
+    # the key side is still there to be found: the check can see
+    assert re.search(rf"= f32\[[\d,]*{cfg.retention_chunk},{2 * D}\]", hlo)
 
 
 @pytest.mark.parametrize("program", ["decode", "prefill16384"])

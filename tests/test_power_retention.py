@@ -191,3 +191,84 @@ def test_the_state_kernel_is_the_pass_a_row_at_a_time(monkeypatch):
         np.testing.assert_array_equal(got[1][blk], S[blk])
     assert np.isfinite(np.asarray(got[1][4])).all()
     assert not np.asarray(got[0][1]).any()
+
+
+def _draw_served(b, kv, g, T, seed, mm):
+    """A prefill's arguments at the served head (128 values by 8,320
+    features): queries, keys and values in the products' dtype, gates
+    that remember, and for each row a state some past left."""
+    hd = 128
+    D = pr.feature_dim(hd)
+    ks = jax.random.split(jax.random.PRNGKey(seed), 6)
+    q = jax.random.normal(ks[0], (b, kv, g, T, hd)).astype(mm)
+    k = jax.random.normal(ks[1], (b, kv, T, hd)).astype(mm)
+    v = jax.random.normal(ks[2], (b, kv, T, hd)).astype(mm)
+    gam = jax.nn.log_sigmoid(jax.random.normal(ks[3], (b, kv, T)) + 3.0)
+    S0 = jax.random.normal(ks[4], (b, kv, hd, D))
+    z0 = 30.0 + jnp.abs(jax.random.normal(ks[5], (b, kv, D)))
+    return q, k, v, gam, S0, z0
+
+
+@pytest.mark.parametrize("tail", [0, 5])
+@pytest.mark.parametrize("fresh", [False, True])
+@pytest.mark.parametrize("chunk", [8, 16])
+@pytest.mark.parametrize("group", [1, 5])
+def test_the_read_kernel_is_the_read_with_phi_written_out(
+        monkeypatch, group, chunk, fresh, tail):
+    """``retention_prefill_read`` (interpreted on the CPU) at the served
+    head and in the served products' dtype: a chunk's read of a state is
+    the einsum over ``phi(Q)`` written out, and a prefill through it
+    (two chunks or four, the last ``tail`` positions a bucket's padding,
+    the row fresh or with a past) gives the outputs of the prefill
+    through the einsum and the SAME state, bit for bit: the kernel reads,
+    it does not write."""
+    hd, T, mm = 128, 32, jnp.bfloat16
+    q, k, v, gam, S0, z0 = _draw_served(1, 2, group, T, 7 * chunk + group,
+                                        mm)
+    if fresh:
+        S0, z0 = jnp.zeros_like(S0), jnp.zeros_like(z0)
+    scale = 1.0 / math.sqrt(hd)
+    valid = (jnp.arange(T) < T - tail)[None]
+    args = (S0, z0, q, k, v, gam, valid, 1e-6, scale, chunk)
+    assert pr.prefill_read_path(S0) == "rows"
+    want = pr.prefill(*args, mm_dtype=mm)
+    S = jnp.concatenate([S0, z0[:, :, None, :]], axis=2)
+    q_c = q[:, :, :, :chunk]
+    read = jnp.einsum("bkgtd,bkvd->bkgtv", pr.phi(q_c, scale).astype(mm),
+                      S.astype(mm), preferred_element_type=jnp.float32)
+    monkeypatch.setattr(pr, "INTERPRET", True)
+    assert pr.prefill_read_path(S0) == "kernel"
+    assert pr.prefill_read_path(S0.astype(jnp.bfloat16)) == "rows"
+    got = pr._kernel_read(q_c, S, scale, None)
+    assert got.shape[:-1] == read.shape[:-1]
+    # sums of 8,320 products of either sign, in another order
+    np.testing.assert_allclose(got[..., :hd + 1], read, rtol=1e-4,
+                               atol=1e-3 * float(jnp.abs(read).max() + 1))
+    got = pr.prefill(*args, mm_dtype=mm)
+    np.testing.assert_allclose(got[0], want[0], rtol=1e-3, atol=1e-3)
+    np.testing.assert_array_equal(got[1], want[1])
+    np.testing.assert_array_equal(got[2], want[2])
+
+
+@pytest.mark.parametrize("chunk", [8, 16, 32])
+def test_a_prefill_through_the_read_kernel_agrees_with_attention(
+        monkeypatch, caplog, chunk):
+    """``prefill`` whole through ``retention_prefill_read`` (float32
+    products, as ``test_recurrence_chunked_and_attention_forms_agree``
+    holds the plain form) against the attention form, at the served
+    head; the path is logged once a shape on the served kernels'
+    logger. The rehearsal's head of 16 (144 features: no whole lane
+    tiles) keeps the ``jax.numpy`` read whatever the backend."""
+    T, kv, g = 32, 2, 2
+    q, k, v, gam, S0, z0 = _draw_served(1, kv, g, T, chunk, jnp.float32)
+    scale = 1.0 / math.sqrt(128)
+    want = pr.attend(q[0], k[0], v[0], gam[0], EPS, scale)
+    monkeypatch.setattr(pr, "INTERPRET", True)
+    assert pr.prefill_read_path(jnp.zeros((1, 2, 16, 144))) == "rows"
+    with caplog.at_level("INFO", logger="gym_tpu.ops.paged_attention"):
+        y, S, z = pr.prefill(jnp.zeros_like(S0), jnp.zeros_like(z0), q, k,
+                             v, gam, jnp.ones((1, T), bool), EPS, scale,
+                             chunk)
+    np.testing.assert_allclose(y[0], want, rtol=2e-4, atol=2e-5)
+    assert (f"attention path retention_read_kernel for q(1, {kv}, {g}, "
+            f"{chunk}, 128) float32") in caplog.text
