@@ -29,8 +29,8 @@ COPY pyproject.toml .
 COPY gym_tpu/ gym_tpu/
 COPY tests/ tests/
 COPY examples/ examples/
-COPY benchmarks/ benchmarks/
-COPY bench.py chip_smoke.py ./
+COPY scripts/ scripts/
+COPY chip_smoke.py ./
 RUN pip install --no-cache-dir -e .
 
 # default: prove the build works (8 virtual CPU devices, same as CI)

@@ -244,7 +244,7 @@ def _ring_kernel_blocks(q, k, v, axis_name: str) -> jnp.ndarray:
         # device runs the full kernel every ring step, so the src > my
         # steps — whose merge weight is zeroed below — are dead compute
         # (~half the invocations). The zig-zag schedules above fix this
-        # (measured 2.0–3.1× at cp=8, BENCHMARKS.md) and are the default
+        # (about half the work of a ring step at cp=8) and are the default
         # through the GPT integration; this path remains for
         # layout='contiguous' and odd-chunk fallbacks.
         o_b, lse_b = fused_block_attention(q, kc, vc, False)

@@ -14,7 +14,7 @@ keyed store with three perf layers:
    exactly ONE build: the first holds the per-key build lock, the rest
    block on it and share the result.  Hits, builds, XLA compiles, disk
    hits and compile-seconds are counted and exported (``/stats``,
-   ``serve.csv``, ``bench.py``).
+   ``serve.csv``, the benchmark's ``window`` line).
 
 2. **Persistent executable tier.**  ``enable_disk_tier`` points JAX's
    persistent compilation cache at a directory (owning what

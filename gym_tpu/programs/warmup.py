@@ -30,7 +30,7 @@ from .registry import ProgramDef, ProgramRegistry, default_registry
 class WarmupThread(threading.Thread):
     """Daemon thread precompiling ``defs`` through ``registry``.  Query
     ``stats()`` for progress (``/stats`` exports it) or ``wait()`` to
-    block until done (tests, the warmed bench arm)."""
+    block until done (tests, a server that warms before it listens)."""
 
     def __init__(self, defs: List[ProgramDef],
                  registry: Optional[ProgramRegistry] = None,

@@ -43,8 +43,8 @@ latency (tail latency is the serving observable; a mean hides a wedged
 tail completely).
 
 ``headline()`` aggregates the run into the one-line JSON surface
-``bench.py --serve-only`` / ``--chaos-only`` and the HTTP ``/stats``
-endpoint report; ``read_headline(path)`` recomputes the same aggregate
+that the HTTP ``/stats`` endpoint and the server's shutdown line
+report; ``read_headline(path)`` recomputes the same aggregate
 from a ``serve.csv`` on disk (post-hoc analysis, tests on synthetic
 files).
 """

@@ -30,7 +30,7 @@ What it models — and what it deliberately shares with the live stack:
 What it does NOT model (the stated sim-vs-live tolerance absorbs
 these): prefill cost (folded into the calibrated rate on average),
 prefix-cache hits, dispatch/wire overhead, and GIL/host scheduling
-noise. The tracesim bench (``bench.py --tracesim-only``) asserts the
+noise. ``tests/test_servesim.py::test_sim_vs_live_smoke`` asserts the
 model's p99 TTFT and shed rate against a real replay of the same trace
 within explicit tolerances — the agreement contract that makes sweep
 results trustworthy.

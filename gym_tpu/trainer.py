@@ -54,8 +54,8 @@ class FitResult:
     history: Dict[str, List]
     mfu: Optional[float] = None   # model-FLOPs utilization (GPT models)
     # throughput excluding the first dispatch (compile/warmup): the number
-    # an A/B of loop mechanics (e.g. bench.py's host_overlap ablation)
-    # should compare. None when the run had fewer than two dispatches.
+    # the benchmark's ``train_tokens_per_s`` is made from (perfbench/)
+    # and an A/B should compare. None under two dispatches.
     steps_per_second_steady: Optional[float] = None
     # True when the run was cut short by SIGTERM/SIGINT: an emergency
     # checkpoint was taken (when checkpointing is configured) and `steps`
@@ -1136,8 +1136,8 @@ class Trainer:
                     "of the state; saving synchronously")
             if sync or not ckpt_overlap:
                 # serial save: multi-process lockstep write, the
-                # async_checkpoint=False escape hatch (and the bench
-                # ablation's overlap-off arm), or the preemption
+                # async_checkpoint=False escape hatch (the example's
+                # --sync_checkpoint, the kill harness), or the preemption
                 # handler's emergency save — ckpt.save waits out any
                 # in-flight async write first
                 ckpt.save(at_step, canon if canon is not None else state,

@@ -1,9 +1,9 @@
 """Persistent XLA compilation cache wiring (registry-owned).
 
 The fit loop's warmup cost is dominated by XLA compiles of the node
-program (~40 s for the bench workload); JAX's persistent compilation
-cache makes repeated invocations of the same program — re-running
-``bench.py``, iterating on a training script, resuming from a checkpoint
+program (a cell's ``compile_s``, PERF.md); JAX's persistent compilation
+cache makes repeated invocations of the same program — re-running a
+cell, iterating on a training script, resuming from a checkpoint
 — skip straight to execution.
 
 Since ISSUE 9 the knob is OWNED by the unified device-program registry
@@ -11,8 +11,8 @@ Since ISSUE 9 the knob is OWNED by the unified device-program registry
 persistent executable tier and this helper are the same JAX compilation
 cache, configured in one place, with hit/miss monitoring installed so
 ``programs.xla_compile_counter()`` can attribute deserializations vs
-real compiles.  This module stays as the stable ``Trainer.fit`` /
-``bench.py`` entry point and simply delegates.
+real compiles.  This module stays as the stable entry point of
+``Trainer.fit`` and simply delegates.
 """
 
 from __future__ import annotations

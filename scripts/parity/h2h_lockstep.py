@@ -22,14 +22,14 @@ average/outer-Nesterov round + final node average on both sides — to
 cover the outer loop too. Measured: per-step math identical (1-node,
 ≤1.1e-4/100 steps); the 4-node trajectory is chaotic with an
 fp-reassociation floor of ~±0.01 final-eval across batch seeds with NO
-systematic sign (seed 17: +0.0124, seed 18: −0.0009). Full resolution
-chain in BENCHMARKS.md "Identical-init GPT row".
+systematic sign (seed 17: +0.0124, seed 18: −0.0009). The head-to-head
+row of DEMONSTRATION.md sums the chain up.
 
 Writes logs/h2h_lockstep.json (adam) /
 logs/h2h_lockstep_diloco*.json (diloco):
     {"step_abs_diff": {...}, "final_eval_ref": ..., "final_eval_ours": ...}
 
-Usage: python benchmarks/h2h_lockstep.py [--mode adam|diloco]
+Usage: python scripts/parity/h2h_lockstep.py [--mode adam|diloco]
            [--steps 100] [--batch 8] [--seed 17] [--out PATH]
        (CPU-only: pins jax to the host backend; torch is CPU anyway.)
 """

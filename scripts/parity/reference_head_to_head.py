@@ -18,7 +18,7 @@ Configs (BASELINE.md tracked trio + one GPT config):
   digits  2n SimpleReduce · 8n DiLoCo(H=50) · 8n SPARTA(p=0.005)
   docs-char 4n DiLoCo(H=50) GPT "small" (block 64)
 
-Usage:  python benchmarks/reference_head_to_head.py
+Usage:  python scripts/parity/reference_head_to_head.py
             [--steps N] [--gpt_steps N] [--only substr] [--out PATH]
 """
 
@@ -29,7 +29,8 @@ import json
 import os
 import sys
 
-REPO = os.path.join(os.path.dirname(os.path.abspath(__file__)), os.pardir)
+REPO = os.path.join(os.path.dirname(os.path.abspath(__file__)), os.pardir,
+                    os.pardir)
 REF = "/root/reference"
 sys.path.insert(0, REPO)
 if REF not in sys.path:
@@ -314,7 +315,7 @@ def torch_eval_loss_gpt(model, ds, block):
 
 def main():
     ap = argparse.ArgumentParser()
-    # defaults reproduce BENCHMARKS.md "Head-to-head" exactly
+    # defaults reproduce logs/head_to_head.json (DEMONSTRATION.md's row)
     ap.add_argument("--steps", type=int, default=150)
     ap.add_argument("--gpt_steps", type=int, default=100)
     ap.add_argument("--band_seeds", type=int, default=2,

@@ -12,7 +12,7 @@ and writes one JSON line per config plus `<log_dir>/baselines.json`
 loss + it/s of the exact example configurations — convergence, not unit
 asserts.
 
-Usage: python benchmarks/run_baselines.py [--steps N] [--device tpu|cpu]
+Usage: python scripts/parity/run_baselines.py [--steps N] [--device tpu|cpu]
            [--log_dir /tmp/smoke]   # keep smoke runs out of logs/
 """
 
@@ -24,7 +24,7 @@ import os
 import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                                os.pardir))
+                                os.pardir, os.pardir))
 
 import numpy as np
 

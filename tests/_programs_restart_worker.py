@@ -3,8 +3,9 @@
 
 Builds a tiny serving engine with the device-program registry's
 persistent executable tier pointed at ``argv[1]``, warms the COMPLETE
-program family, serves one request, and prints the registry counters as
-one JSON line.  The parent test runs this twice against the same cache
+program family (unless ``argv[2]`` is ``nowarm``: the request then pays
+its builds itself), serves one request, and prints the registry counters
+as one JSON line.  The parent test runs this twice against the same cache
 directory: the first (cold-disk) run must compile, the second
 (warm-disk "process restart") must report ``xla_compiles == 0`` — every
 build answered by deserializing a persisted executable, zero XLA on the
@@ -35,9 +36,12 @@ params = model.init({"params": jax.random.PRNGKey(0)},
                     np.zeros((1, 4), np.int64), train=False)["params"]
 
 eng = InferenceEngine(params, cfg, num_slots=2, decode_chunk=2)
-warm = programs.warm_engine_programs(eng, start=True)
-assert warm.wait(timeout=600), "warmup did not finish"
+warmup = sys.argv[2:] != ["nowarm"]
+warm = programs.warm_engine_programs(eng, start=warmup)
+if warmup:
+    assert warm.wait(timeout=600), "warmup did not finish"
 
+builds0 = programs.default_registry().counters()["builds"]
 sched = Scheduler(eng, max_queue=4)
 h = sched.submit(np.array([1, 2, 3]),
                  SamplingParams(max_new_tokens=4, temperature=0.9,
@@ -47,8 +51,10 @@ while h.status.value in ("queued", "running"):
 tokens = h.result(timeout=10)
 assert len(tokens) == 4
 
+counters = programs.default_registry().counters()
 print(json.dumps({
-    "counters": programs.default_registry().counters(),
+    "counters": counters,
+    "on_path_builds": counters["builds"] - builds0,
     "xla_compiles": programs.xla_compile_counter(),
     "warm": warm.stats(),
     "tokens": tokens,

@@ -296,8 +296,8 @@ def test_warmed_engine_serves_with_zero_builds(tiny_serving):
     """After background warmup finishes, NO request — any prompt
     length, any sampling — triggers a build: the ≤⌈log2(block)⌉+1
     compile bound is paid entirely off the request path (the cold-p99
-    TTFT mechanism, pinned here structurally; measured in
-    ``bench.py --coldstart-only``)."""
+    TTFT mechanism, pinned here structurally; its seconds on the chip
+    are a cell's ``setup_s`` and ``compile_s``, PERF.md)."""
     cfg, params = tiny_serving
     eng = InferenceEngine(params, cfg, num_slots=2, decode_chunk=2)
     warm = warm_engine_programs(eng, start=True)
@@ -365,19 +365,26 @@ def test_trainer_to_server_handoff_zero_recompile(tmp_path):
 
 
 @pytest.mark.slow
-def test_process_restart_zero_xla_compiles(tmp_path):
+@pytest.mark.parametrize("warmup", [True, False],
+                         ids=["warmed", "on_request_path"])
+def test_process_restart_zero_xla_compiles(tmp_path, warmup):
     """The restart drill's pin, at the python level: two processes, same
     config, same program-cache dir. The first compiles and persists;
     the second — a server restart — reports ``xla_compiles == 0``:
-    every program deserialized, zero XLA on the hot path."""
+    every program deserialized, zero XLA on the hot path. Warmed, the
+    request meets no build at all; un-warmed it pays every build of its
+    own, each a compile in the first process and a read in the second."""
     cache_dir = str(tmp_path / "progcache")
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
     env.pop("XLA_FLAGS", None)               # plain 1-device subprocess
+    # the worker's argument names the directory: the variable would win
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
     env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
 
     def run():
-        p = subprocess.run([sys.executable, RESTART_WORKER, cache_dir],
+        p = subprocess.run([sys.executable, RESTART_WORKER, cache_dir]
+                           + ([] if warmup else ["nowarm"]),
                            env=env, cwd=REPO, capture_output=True,
                            text=True, timeout=600)
         assert p.returncode == 0, p.stderr[-2000:]
@@ -394,6 +401,9 @@ def test_process_restart_zero_xla_compiles(tmp_path):
     # the deserializing restart is also measurably cheaper
     assert (warm["counters"]["compile_seconds"]
             < cold["counters"]["compile_seconds"])
+    for regime in (cold, warm):
+        assert regime["on_path_builds"] == (
+            0 if warmup else regime["counters"]["builds"])
 
 
 # -- satellite: generate_fast cache collision audit ------------------------

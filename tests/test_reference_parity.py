@@ -147,17 +147,17 @@ def test_demo_dct_basis_parity():
 def test_cnn_loss_parity_with_ported_weights():
     """The head-to-head's identical-init premise (VERDICT r3 #3): the
     torch CNN's state_dict ported through
-    ``benchmarks.reference_head_to_head.port_torch_cnn`` computes the
-    SAME loss in flax — conv HWIO transposes, the NCHW/NHWC flatten-
-    boundary permutation on the first Linear, and fresh BN stats all
-    line up. Without this pin the 'same init' in the benchmark would be
-    unverified."""
+    ``port_torch_cnn`` of ``scripts/parity/reference_head_to_head.py``
+    computes the SAME loss in flax — conv HWIO transposes, the NCHW/NHWC
+    flatten-boundary permutation on the first Linear, and fresh BN stats
+    all line up. Without this pin the 'same init' in the head-to-head
+    would be unverified."""
     import jax
 
-    bench_dir = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "benchmarks")
-    if bench_dir not in sys.path:
-        sys.path.insert(0, bench_dir)
+    parity_dir = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "scripts", "parity")
+    if parity_dir not in sys.path:
+        sys.path.insert(0, parity_dir)
     from reference_head_to_head import port_torch_cnn, torch_cnn
 
     from gym_tpu.models import MnistLossModel

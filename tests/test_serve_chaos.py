@@ -96,6 +96,28 @@ def test_serve_fault_sites_registered():
     faults.reset()
 
 
+def test_a_requests_decode_dispatches_are_the_gates_arithmetic(setup):
+    """``@N`` in a fault spec counts REAL decode dispatches, and a lone
+    request of ``n`` tokens makes ``n`` of them: ``n - 1`` for the tokens
+    after the prefill's and the one the round keeps in flight ahead of
+    its reads. ``scripts/ci_chaos.sh`` aims its hangs by this count
+    (request A of 4 tokens takes dispatches 1-4, so ``hang@5`` and
+    ``hang@7`` land in request B); the script failed from the PR that
+    put a step in flight until PR 46 read the count again."""
+    cfg, model, params = setup
+    sched = Scheduler(InferenceEngine(params, cfg, num_slots=2),
+                      max_queue=8)
+    faults.configure("serve.decode:delay=0")
+    for n, hits in ((4, 4), (8, 12)):
+        h = sched.submit(np.array([1, 2, 3]),
+                         SamplingParams(max_new_tokens=n, top_k=4))
+        _drain(sched, [h])
+        for _ in range(3):                # idle rounds dispatch nothing
+            sched.step()
+        assert len(h.result(timeout=1)) == n
+        assert faults.hits("serve.decode") == hits
+
+
 def test_prefill_fault_fails_only_its_request(setup):
     """An injected IO error at the prefill site fails THAT request typed
     and the loop keeps serving — isolation, not collapse."""
