@@ -102,6 +102,15 @@ zeros to it; a prefill bucket's padding leaves the state as it is; a row
 whose state block is 0 is not live and touches nothing; several tokens a
 row without ``last_pos`` are refused.
 
+A model whose residual is SEVERAL streams a token (``models/xing4.py``:
+four, mixed around every sub-layer by a hyper-connection) owes the engine
+nothing and asks nothing of it: the streams are a value inside a program,
+born from the embedding in the first block and summed before the head in
+the last, so a program's arguments, its pools, its logits and its counters
+are a one-stream model's. What grows is what a program holds at once (a
+prefill pass carries ``hc_mult`` float32 copies of its positions'
+hidden), which ``prefill_rows`` bounds.
+
 A family's key starts with its ``model_type``; GPT-2's is its plain field
 tuple, as it always was (its programs' keys and names did not move).
 """
@@ -118,10 +127,11 @@ from .kimi_k2 import FAMILY as KIMI_K2, KimiK2Config
 from .nanogpt import (GPTConfig, sample_logits,  # noqa: F401 — re-exported
                       sample_rows)
 from .qwen3_next import FAMILY as QWEN3_NEXT, Qwen3NextConfig
+from .xing4 import FAMILY as XING4, Xing4Config
 
 FAMILIES = {COHERE2_MOE: Cohere2MoeConfig, KEYE_VL2: KeyeVL2Config,
             BRUMBY: BrumbyConfig, KIMI_K2: KimiK2Config,
-            QWEN3_NEXT: Qwen3NextConfig}
+            QWEN3_NEXT: Qwen3NextConfig, XING4: Xing4Config}
 
 
 def config_from_key(key: tuple):

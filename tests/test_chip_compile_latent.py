@@ -138,3 +138,49 @@ def test_a_pool_row_of_576_lanes_is_copied_whole_around_a_scatter(v5e_chip):
         temps[lanes] = compiled.memory_analysis().temp_size_in_bytes
     assert temps[640] < 1 << 20
     assert temps[576] > PAGES * 16 * 576 * 2
+
+
+# the Xing4.0 cell as served (perfbench/configs/xing4.0-29b-a4b.json,
+# perfbench/traffic/serve-closed-reason.json): 1 dense + 5 expert layers
+# at the published widths, all 64 experts and the whole vocabulary held,
+# four float32 residual streams a token, 32 slots, 16,384 pages
+XING4_PAGES = 16384
+
+
+def test_xing4_decode_unrolls_the_sinkhorn_iterations_and_moves_no_pool(
+        v5e_chip):
+    """The decode program of ``models/xing4.py`` as the cell's engine
+    compiles it (``kimi_k2.py``'s latent layer at 32 heads inside a block
+    that carries four streams): 9.6 GB of weights and 2.0 GB of pages fit
+    with room; the attend is the Pallas walk; every layer's pool is
+    aliased from argument to result and nothing has a pool's shape but
+    the pools; and the twelve hyper-connections' 20 Sinkhorn iterations
+    are unrolled operations under ``hc.sinkhorn``: the program holds no
+    ``while`` at all."""
+    import dataclasses
+    from gym_tpu.models.xing4 import Xing4Config
+    from gym_tpu.ops import latent_attention
+    from gym_tpu.programs import serve_defs
+    cfg = dataclasses.replace(
+        Xing4Config(num_hidden_layers=6,
+                    first_k_dense_replace=1).decode_config(),
+        page_size=16, kv_pages=XING4_PAGES)
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(latent_attention, "_on_tpu", lambda: True)
+        compiled = compile_def(
+            serve_defs.paged_decode_def(cfg.program_key(), SLOTS, 1),
+            v5e_chip)
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    assert 10.0 * 1024 ** 3 < total < 12.0 * 1024 ** 3, total / 2 ** 30
+    hlo = compiled.as_text()
+    assert "latent_paged_decode" in hlo
+    assert not re.findall(r" while\(", hlo)
+    assert len(re.findall(r"hc\.sinkhorn/div", hlo)) >= 12 * 40
+    assert "hc.coef" in hlo and "hc.mix" in hlo
+    pool = rf"bf16\[{XING4_PAGES},16,640\]"
+    moved = re.findall(
+        rf"= {pool}\S* (copy|transpose|convert|gather|copy-start)\(", hlo)
+    assert not moved, moved[:3]
+    assert len(re.findall(r"may-alias", hlo.split("\n", 1)[0])) >= 6
