@@ -460,3 +460,45 @@ def prefill_in_passes(mod: nn.Module, blocks, tokens, block_table,
 
 
 Cohere2MoeConfig.prefill_pass = prefill_pass
+
+
+def grouped_prefill_chunks(config, windows, step: int, bucket: int,
+                           start: int, suffix: int):
+    """``{"layers_<i>/self_attn/gqa_chunks": [run, unmasked]}`` of one
+    dispatched prefill (``suffix`` tokens from position ``start``, padded
+    to ``bucket``, ``step`` positions a pass): the chunks of keys the
+    grouped kernel walked in layer ``i`` and how many of them took its
+    body without masks (``ops/paged_attention.py:gqa_chunks``), over the
+    passes that ran and ``by_query_block``'s calls of each. ``windows``
+    ``{i: window or 0}`` names the layers whose attend is that kernel.
+    On the host, from the engine (``config.prefill_counted``): a prefill
+    program returns no counters."""
+    import numpy as np
+
+    from ..ops.paged_attention import gqa_chunks
+    qc = config.attn_query_block
+    tc = step if step <= qc or step % qc else qc
+    ran = min(bucket, -(-suffix // step) * step)
+    pos = start + np.arange(0, ran, tc)
+    kvh = config.num_key_value_heads
+    by_window = {w: gqa_chunks(
+        pos, tc, config.num_attention_heads // kvh, kvh, config.page_size,
+        config.block_size // config.page_size, w)
+        for w in set(windows.values())}
+    return {f"layers_{i}/self_attn/gqa_chunks": by_window[w]
+            for i, w in windows.items()}
+
+
+def prefill_counted(config, bucket: int, start: int, suffix: int):
+    """``grouped_prefill_chunks`` of the layers whose attend is the
+    grouped kernel (none off the TPU): ``config.prefill_counted``."""
+    from ..ops.paged_attention import KERNEL, KERNEL_WINDOW
+    windows = {i: w for i, ((_h, _d, w), path) in enumerate(zip(
+        config.kv_layout(), config.attend_paths()))
+        if path in (KERNEL, KERNEL_WINDOW)}
+    return grouped_prefill_chunks(config, windows,
+                                  config.prefill_pass(bucket), bucket,
+                                  start, suffix)
+
+
+Cohere2MoeConfig.prefill_counted = prefill_counted

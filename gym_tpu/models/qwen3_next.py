@@ -610,3 +610,18 @@ class Qwen3Next(nn.Module):
                     cache = gd.store_rows(*take(i), sb, *cache)
             store[i].value = put(i, cache)
         return head(x_last[:, None])[:, 0]
+
+
+def prefill_counted(config, bucket: int, start: int, suffix: int):
+    """The full layers' ``gqa_chunks`` of one dispatched prefill
+    (``models/cohere2_moe.py:grouped_prefill_chunks``)."""
+    from ..ops.paged_attention import KERNEL
+    from .cohere2_moe import grouped_prefill_chunks
+    windows = {i: 0 for i, path in enumerate(config.attend_paths())
+               if path == KERNEL}
+    step = pass_rows(bucket, config.prefill_rows, config.delta_chunk)
+    return grouped_prefill_chunks(config, windows, step, bucket, start,
+                                  suffix)
+
+
+Qwen3NextConfig.prefill_counted = prefill_counted

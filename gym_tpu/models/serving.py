@@ -27,6 +27,12 @@ answers:
   dispatched prefill ran, ``EngineStats.prefill_tokens_run`` beside the
   padded ``prefill_tokens`` (``serve/engine.py:prefill_positions_run``); a
   config that does not answer is counted as running its buckets;
+* ``prefill_counted(bucket, start, suffix)`` (optional): what a
+  dispatched prefill of ``suffix`` tokens from position ``start``, padded
+  to ``bucket``, would have counted: ``{"<layer>/<module>/<name>":
+  integers}``, added to the decode steps' ``counters`` (a prefill program
+  returns none). ``models/cohere2_moe.py:grouped_prefill_chunks``: the
+  chunks the grouped kernel walked and how many without masks;
 * ``fixed_row_cache`` (optional, default false): true where a row's
   cache is ONE block of fixed size whatever the row's length (a
   recurrent state: ``models/brumby.py``) and not a run of pages that

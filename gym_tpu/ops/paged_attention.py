@@ -49,9 +49,9 @@ whose head dimension fills a lane tile (128) needs no lane masking. Its
 pool row is ``kv_heads * head_dim`` lanes, key-value head ``g`` the
 lane tile(s) ``g * head_dim ..``, and the ``group`` query heads that read
 it are rows of one product against that slice. One program takes a block
-of query positions with ALL heads, copies each page whole (one
-contiguous ``[page, kv_heads * head_dim]`` transfer) and walks the
-key-value heads in turn inside a chunk of 256 positions. Operands keep
+of query positions with ALL heads (``gqa_tile`` says how many), copies
+each page whole (one ``[page, kv_heads * head_dim]`` transfer) and walks
+the key-value heads in turn inside a chunk of 256 or 512. Operands keep
 the pool's dtype (bfloat16 pools are multiplied as bfloat16, float32
 accumulation and statistics). With a ``window``, query ``i`` sees key
 ``j`` iff ``0 <= i - j < window``: pages wholly older than the block's
@@ -83,8 +83,8 @@ INTERPRET = False
 _CHUNK = 128        # key/value positions folded per inner step
 _ROWS = 256         # expanded query rows (position x head) per program
 
-_GQA_CHUNK = 256    # the same for the grouped kernel
-_GQA_TQ = 32        # query positions (all their heads) per program
+_GQA_CHUNKS = (256, 512)  # the same for the grouped kernel: ``gqa_tile``
+_GQA_ROWS, _GQA_SCORES = 1024, 1 << 20    # a head's rows, a chunk's scores
 _GQA_VMEM = 64 << 20
 
 KERNEL = "pallas_paged"
@@ -137,7 +137,7 @@ def paged_attend_path(n_embd: int, page_size: int, dtype, kv_dtype,
         ok = dtype == kv_dtype and dtype in (jnp.float32, jnp.bfloat16)
         tiles = (head_dim % 128 == 0
                  and page_size % (32 // dtype.itemsize) == 0
-                 and _GQA_CHUNK % page_size == 0)
+                 and _GQA_CHUNKS[0] % page_size == 0)
         kernel = KERNEL_WINDOW if window else KERNEL
         if INTERPRET:
             return kernel if ok else GATHER
@@ -301,9 +301,26 @@ def _paged_attention(q, k_pool, v_pool, block_table, cache_pos, n_head,
 # -- grouped heads, bfloat16, a window --------------------------------------
 
 
+def gqa_tile(t, group, kvh, page):
+    """``(tq, chunk)`` of the grouped kernel for a call of ``t`` positions
+    a row: query positions a program (all their heads: ``tq * group``
+    rows a key-value head) and key positions a chunk. About 1,024 rows a
+    head where ``t`` allows and 512 keys a chunk once a head has 512
+    rows, both within 2**20 scores a chunk over all heads (a body's
+    length, which the compiler unrolls: 1,024 vregs of scores compile in
+    seconds, four times that in a minute); below that PR 27's 256 keys,
+    and for a decode step or a speculative verify all of a row's
+    positions in one program."""
+    rows = min(_GQA_ROWS, _GQA_SCORES // (kvh * _GQA_CHUNKS[0]))
+    tq = max(1, min(t, rows // group))
+    wide = _GQA_CHUNKS[1] <= tq * group <= _GQA_SCORES // (
+        kvh * _GQA_CHUNKS[1])
+    return tq, max(page, _GQA_CHUNKS[wide] // page * page)
+
+
 def _gqa_kernel(bt_ref, pos_ref, q_ref, k_hbm, v_hbm, o_ref,
                 kbuf, vbuf, sem, m_ref, l_ref, acc_ref, *,
-                kvh, group, hd, t, tq, page, ppc, mb, window, scale):
+                kvh, group, hd, t, tq, page, ppc, mb, window):
     r, qb = pl.program_id(0), pl.program_id(1)
     rows, ch = tq * group, ppc * page
     pos0 = pos_ref[r]
@@ -321,6 +338,11 @@ def _gqa_kernel(bt_ref, pos_ref, q_ref, k_hbm, v_hbm, o_ref,
     c0 = lo // ch
     n_pages = pl.cdiv(kv_len, page)
     n_chunks = pl.cdiv(kv_len, ch)
+    # chunks [u0, u1) lie wholly at or below the block's first position,
+    # wholly inside the last position's window and wholly in copied
+    # pages: every row sees every key of them
+    u0, u1 = _gqa_interior(pos0 + j0, pos0 + j_hi, kv_len, c0, n_chunks,
+                           ch, window, t, jnp)
 
     def page_copies(c, slot):
         for p in range(ppc):
@@ -355,6 +377,39 @@ def _gqa_kernel(bt_ref, pos_ref, q_ref, k_hbm, v_hbm, o_ref,
                        * (1.0 / group)).astype(jnp.int32)
     qpos = pos0 + jnp.minimum(j, t - 1)                       # [rows, 1]
 
+    def fold(c, slot, masked):
+        """Chunk ``c`` into the running softmax; ``masked`` unless every
+        row sees every key of it and all its pages were copied."""
+        if masked:
+            col = c * ch + jax.lax.broadcasted_iota(jnp.int32, (rows, ch), 1)
+            seen = col <= qpos
+            if window:
+                seen = seen & (col > qpos - window)
+            # pages not copied and positions past this block's last are
+            # stale buffer or a recycled page's old contents: 0 * NaN is
+            # NaN, so select, don't rely on p
+            vrow = c * ch + jax.lax.broadcasted_iota(jnp.int32, (ch, 1), 0)
+            vok = (vrow >= first_page * page) & (vrow < kv_len)
+        for g in range(kvh):
+            lanes = pl.ds(g * hd, hd)
+            s = jax.lax.dot_general(
+                q_ref[0, g], kbuf[slot, :, lanes],
+                (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            if masked:
+                s = jnp.where(seen, s, NEG)
+            m_prev = m_ref[g]
+            m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s - m_new)
+            v = vbuf[slot, :, lanes]
+            if masked:
+                p, v = jnp.where(seen, p, 0.0), jnp.where(vok, v, 0)
+            l_ref[g] = alpha * l_ref[g] + p.sum(axis=1, keepdims=True)
+            acc_ref[g] = alpha * acc_ref[g] + jnp.dot(
+                p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+            m_ref[g] = m_new
+
     def body(c, carry):
         slot = jax.lax.rem(c - c0, 2)
 
@@ -363,31 +418,12 @@ def _gqa_kernel(bt_ref, pos_ref, q_ref, k_hbm, v_hbm, o_ref,
             start(c + 1, 1 - slot)
 
         wait(c, slot)
-        col = c * ch + jax.lax.broadcasted_iota(jnp.int32, (rows, ch), 1)
-        seen = col <= qpos
-        if window:
-            seen = seen & (col > qpos - window)
-        # pages not copied and positions past this block's last are stale
-        # buffer or a recycled page's old contents: 0 * NaN is NaN, so
-        # select, don't rely on p
-        vrow = c * ch + jax.lax.broadcasted_iota(jnp.int32, (ch, 1), 0)
-        vok = (vrow >= first_page * page) & (vrow < kv_len)
-        for g in range(kvh):
-            lanes = pl.ds(g * hd, hd)
-            s = jax.lax.dot_general(
-                q_ref[0, g], kbuf[slot, :, lanes],
-                (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            s = jnp.where(seen, s * scale, NEG)
-            m_prev = m_ref[g]
-            m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
-            alpha = jnp.exp(m_prev - m_new)
-            p = jnp.where(seen, jnp.exp(s - m_new), 0.0)
-            l_ref[g] = alpha * l_ref[g] + p.sum(axis=1, keepdims=True)
-            v = jnp.where(vok, vbuf[slot, :, lanes], 0)
-            acc_ref[g] = alpha * acc_ref[g] + jnp.dot(
-                p.astype(v.dtype), v, preferred_element_type=jnp.float32)
-            m_ref[g] = m_new
+        if t == 1:
+            fold(c, slot, True)
+        else:
+            edge = (c < u0) | (c >= u1)
+            pl.when(edge)(lambda: fold(c, slot, True))
+            pl.when(~edge)(lambda: fold(c, slot, False))
         return carry
 
     jax.lax.fori_loop(c0, n_chunks, body, None)
@@ -414,15 +450,17 @@ def _paged_attention_gqa(q, k_pool, v_pool, block_table, cache_pos, window,
                          interpret):
     b, kvh, t, group, hd = q.shape
     page, mb = k_pool.shape[1], block_table.shape[1]
-    ppc = max(1, _GQA_CHUNK // page)
-    tq = min(_GQA_TQ, t)
+    tq, chunk = gqa_tile(t, group, kvh, page)
+    ppc = chunk // page
     t_pad = -(-t // tq) * tq
     rows = tq * group
-    qx = jnp.pad(q, ((0, 0), (0, 0), (0, t_pad - t), (0, 0), (0, 0)))
+    # the scores' scale goes onto the queries, once
+    qx = (q.astype(jnp.float32) * (1.0 / math.sqrt(hd))).astype(q.dtype)
+    qx = jnp.pad(qx, ((0, 0), (0, 0), (0, t_pad - t), (0, 0), (0, 0)))
     qx = qx.reshape(b, kvh, t_pad * group, hd)
     kernel = functools.partial(
         _gqa_kernel, kvh=kvh, group=group, hd=hd, t=t, tq=tq, page=page,
-        ppc=ppc, mb=mb, window=window, scale=1.0 / math.sqrt(hd))
+        ppc=ppc, mb=mb, window=window)
     block = pl.BlockSpec((1, kvh, rows, hd), lambda r, qb, *_: (r, 0, qb, 0))
     out = pl.pallas_call(
         kernel,
@@ -451,7 +489,42 @@ def _paged_attention_gqa(q, k_pool, v_pool, block_table, cache_pos, window,
     return out.reshape(b, kvh, t_pad, group, hd)[:, :, :t]
 
 
+def _gqa_interior(first, last, kv_len, c0, n_chunks, ch, window, t, xp):
+    """``(u0, u1)``: of the chunks ``[c0, n_chunks)`` a block of queries
+    at positions ``first .. last`` walks, those ``[u0, u1)`` need no
+    mask: every key of them lies at or below ``first``, inside ``last``'s
+    window and below ``kv_len``. A decode step (``t`` 1) masks every
+    chunk. The kernel (``xp`` is ``jnp``) and ``gqa_chunks`` (``numpy``)
+    both ask here."""
+    if t == 1:
+        return n_chunks, n_chunks
+    u1 = xp.minimum(first + 1, kv_len) // ch
+    u0 = -(-xp.maximum(last - window + 1, 0) // ch) if window else c0
+    u0 = xp.clip(u0, c0, n_chunks)
+    return u0, xp.clip(u1, u0, n_chunks)
+
+
 # at the END of this file on purpose: a kernel's compiled body carries the
 # file and line of the frames it was traced under, so no line above may
 # move (``programs/serve_defs.py`` says more)
 from .gated_delta import GATED_DELTA  # noqa: E402
+import numpy as np  # noqa: E402
+
+
+def gqa_chunks(pos, t, group, kvh, page, mb, window=0):
+    """``[run, unmasked]``: the chunks the programs of ONE call of the
+    grouped kernel walk, ``t`` positions a row from the cursors ``pos``
+    [b] of live rows over tables of ``mb`` pages, and how many of them
+    take the body without masks; summed over rows and query blocks. The
+    kernel's own arithmetic on the host, for a counter: the kernel cannot
+    say which body it ran."""
+    tq, ch = gqa_tile(t, group, kvh, page)
+    j0 = np.arange(0, t, tq, dtype=np.int64)
+    first = np.asarray(pos, np.int64).reshape(-1, 1) + j0
+    last = first + np.minimum(tq - 1, t - 1 - j0)
+    kv_len = np.minimum(last + 1, mb * page)
+    c0 = (np.maximum(first - window + 1, 0) if window else 0 * first) // ch
+    n_chunks = -(-kv_len // ch)
+    u0, u1 = _gqa_interior(first, last, kv_len, c0, n_chunks, ch, window,
+                           t, np)
+    return np.asarray([(n_chunks - c0).sum(), (u1 - u0).sum()], np.int64)

@@ -324,9 +324,10 @@ class EngineStats:
     # metrics/serve.csv/stats report them without reaching into config
     weights_dtype: str = "f32"
     kv_dtype: str = "f32"
-    # what the model counted in its decode steps, summed since the
-    # engine was built: {"<layer>/<module>/<name>": integers}; empty for
-    # a model that counts nothing (models/serving.py: ``counters``)
+    # what the model counted in its decode steps (and its config of a
+    # dispatched prefill: ``prefill_counted``), summed since the engine
+    # was built: {"<layer>/<module>/<name>": integers}; empty for a
+    # model that counts nothing (models/serving.py: ``counters``)
     model_counters: Dict[str, Any] = dataclasses.field(default_factory=dict)
 
     def spec_accept_rate(self) -> Optional[float]:
@@ -1316,6 +1317,9 @@ class InferenceEngine:
         self.stats.prefill_tokens += bucket
         self.stats.prefill_tokens_run += prefill_positions_run(
             self.config, bucket, suffix)
+        counted = getattr(self.config, "prefill_counted", None)
+        if counted is not None:
+            self._count(counted(bucket, start, suffix))
         self._pool_stats()
         return tok
 

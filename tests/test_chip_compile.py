@@ -282,22 +282,42 @@ def _command_a_plus_cfg(kv_pages=12288):
         page_size=16, kv_pages=kv_pages)
 
 
-@pytest.mark.parametrize("t,window", [(1, 0), (1, 4096), (4096, 4096),
-                                      (16384, 0), (16384, 4096)],
-                         ids=["decode_full", "decode_window",
-                              "prefill4k_window", "prefill16k_full",
-                              "prefill16k_window"])
-def test_grouped_paged_kernel_compiles_for_v5e(v5e_chip, t, window):
-    """The grouped page walk at the cell's sizes: 16 query heads a
-    key-value head, head dimension 128, a bfloat16 pool row of 1,024
-    lanes, with and without a window."""
+# ``command-a-plus`` (8 key-value heads of 16 query heads, head size 128,
+# a pool row of 1,024 lanes, 12,288 pages) and ``qwen3-next`` (2 of 8,
+# head size 256, a row of 512 lanes, 65,536 pages): q's shape less the
+# batch, pool pages, a table's pages
+CMDA = ((8, 16, 128), 12288, 1024)
+QWEN = ((2, 8, 256), 65536, 3168)
+
+
+@pytest.mark.parametrize("model,t,window,tile", [
+    (CMDA, 1, 0, (1, 256)), (CMDA, 1, 4096, (1, 256)),
+    (CMDA, 16, 0, (16, 256)),                        # speculative verify
+    (CMDA, 4096, 4096, (32, 256)), (CMDA, 16384, 0, (32, 256)),
+    (CMDA, 16384, 4096, (32, 256)),
+    (QWEN, 1, 0, (1, 256)), (QWEN, 2048, 0, (128, 512)),
+    (QWEN, 4096, 0, (128, 512))],
+    ids=["decode_full", "decode_window", "verify16", "prefill4k_window",
+         "prefill16k_full", "prefill16k_window", "qwen_decode",
+         "qwen_prefill2k", "qwen_prefill4k"])
+def test_grouped_paged_kernel_compiles_for_v5e(v5e_chip, model, t, window,
+                                               tile):
+    """The grouped page walk at the two cells' sizes, with and without a
+    window: a decode step and a speculative verify keep PR 27's tile (all
+    of a row's ``t`` positions a program, 256 keys a chunk), a prefill
+    of two key-value heads takes 1,024 rows a head by 512 keys and one of
+    eight what it had (512 by 256: 2**20 scores a chunk either way), and
+    all fit the kernel's scoped VMEM."""
+    (kvh, group, hd), pages, mb = model
+    assert paged_attention.gqa_tile(t, group, kvh, 16) == tile
     b = 32 if t == 1 else 1
     hlo = _compile(
         functools.partial(paged_attention.paged_attention_gqa,
                           window=window),
-        v5e_chip, ((b, 8, t, 16, 128), jnp.bfloat16),
-        ((12288, 16, 1024), jnp.bfloat16), ((12288, 16, 1024), jnp.bfloat16),
-        ((b, 1024), jnp.int32), ((b,), jnp.int32))
+        v5e_chip, ((b, kvh, t, group, hd), jnp.bfloat16),
+        ((pages, 16, kvh * hd), jnp.bfloat16),
+        ((pages, 16, kvh * hd), jnp.bfloat16),
+        ((b, mb), jnp.int32), ((b,), jnp.int32))
     name = ("paged_gqa_" + ("decode" if t == 1 else "prefill")
             + ("_window" if window else "_full"))
     assert "tpu_custom_call" in hlo and name in hlo

@@ -5,6 +5,9 @@ gathered window, and its dispatch point. Through the engine against the
 model's reference: ``tests/test_cohere2_moe.py``; compiled for the chip at
 the served sizes: ``tests/test_chip_compile.py``."""
 
+import dataclasses
+import os
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -32,19 +35,42 @@ def _gathered(q, kp, vp, bt, pos, window):
     return jnp.einsum("bktgs,bskd->bktgd", att, v)
 
 
-@pytest.mark.parametrize("t,pos", [(1, [0, 5, 40, 95]), (8, [0, 8, 30, 88]),
-                                   (40, [0, 3, 50, 56])],
-                         ids=["decode", "chunk8", "prefill40"])
-@pytest.mark.parametrize("window", [0, 20], ids=["full", "window20"])
+# a tile of 8 positions (32 rows a head) by chunks of 32 keys in place of
+# 256 by 512: a row of 96 positions then has what a 20 k prompt has at the
+# served tile, several query tiles a call and several chunks a tile
+SMALL = {"_GQA_ROWS": 32, "_GQA_CHUNKS": (16, 32)}
+
+
+def _set_tile(monkeypatch, tile):
+    for name, value in (tile or {}).items():
+        monkeypatch.setattr(pa, name, value)
+
+
+@pytest.mark.parametrize("t,pos,tile", [
+    (1, [0, 5, 40, 95], None), (8, [0, 8, 30, 88], None),
+    (40, [0, 3, 50, 56], None),
+    # three query tiles, the last ragged; a row from 0, one whose tiles
+    # all lie in chunk 0, one (70) whose tiles have interior AND edge
+    # chunks, one (37) whose tiles straddle a chunk's end
+    (20, [0, 5, 70, 37], SMALL),
+    # a tile that ends on a chunk's last key and one that starts on a
+    # chunk's first; the row's end inside the last tile (75 + 21 = 96)
+    (21, [24, 32, 64, 75], SMALL)],
+    ids=["decode", "chunk8", "prefill40", "tiles_ragged", "tiles_aligned"])
+@pytest.mark.parametrize("window", [0, 20, 72],
+                         ids=["full", "window20", "window72"])
 @pytest.mark.parametrize("dtype,tol", [(jnp.float32, 1e-5),
                                        (jnp.bfloat16, 3e-2)],
                          ids=["f32", "bf16"])
 def test_grouped_kernel_equals_a_gathered_window(monkeypatch, dtype, tol,
-                                                 window, t, pos):
+                                                 window, t, pos, tile):
     """Four query heads a key-value head, rows at different depths of
     their tables (the first page, mid-page, past the window, the last
-    page), under the Pallas interpreter."""
+    page), under the Pallas interpreter. With the small tile a window of
+    20 cuts a chunk at its lower edge and leaves no chunk unmasked; one
+    of 72 leaves a chunk between its lower edge and the diagonal."""
     monkeypatch.setattr(pa, "INTERPRET", True)
+    _set_tile(monkeypatch, tile)
     rng = np.random.default_rng(t + window)
     b = len(pos)
     q = jnp.asarray(rng.standard_normal((b, KVH, t, G, HD)), dtype)
@@ -52,6 +78,9 @@ def test_grouped_kernel_equals_a_gathered_window(monkeypatch, dtype, tol,
     vp = jnp.asarray(rng.standard_normal((POOL, KPAGE, KVH * HD)), dtype)
     bt = jnp.asarray(1 + rng.permutation(POOL - 1)[:b * MB].reshape(b, MB),
                      jnp.int32)
+    if tile:
+        run, unmasked = pa.gqa_chunks(pos, t, G, KVH, KPAGE, MB, window)
+        assert run > unmasked and bool(unmasked) == (window != 20)
     pos = jnp.asarray(pos, jnp.int32)
     out = pa.paged_attention_gqa(q, kp, vp, bt, pos, window=window)
     assert out.shape == q.shape and out.dtype == q.dtype
@@ -59,28 +88,162 @@ def test_grouped_kernel_equals_a_gathered_window(monkeypatch, dtype, tol,
     assert float(jnp.abs(out.astype(jnp.float32) - want).max()) < tol
 
 
-def test_grouped_kernel_never_reads_pages_before_the_window(monkeypatch):
+@pytest.mark.parametrize("t,pos,window,tile", [
+    (1, 90, 20, None),            # keys 71..90
+    # tiles of 8 from 64, 72 and 80: to the first, chunk 0 (keys 0..31)
+    # is its window's lower edge (pages 0..2 are not copied), chunk 1
+    # runs without masks beside it and chunk 2 holds the diagonal
+    (20, 64, 40, SMALL)], ids=["decode", "tiles"])
+def test_grouped_kernel_never_reads_pages_before_the_window(
+        monkeypatch, t, pos, window, tile):
     """A window layer of a long row: poison (NaN) in every page wholly
-    older than the window, as a recycled page may hold, changes nothing;
-    the same poison inside the window does."""
+    older than the window, as a recycled page may hold, and in every
+    position past the call's last changes nothing; the same poison inside
+    the window does."""
     monkeypatch.setattr(pa, "INTERPRET", True)
+    _set_tile(monkeypatch, tile)
     rng = np.random.default_rng(0)
-    q = jnp.asarray(rng.standard_normal((1, KVH, 1, G, HD)), jnp.float32)
+    q = jnp.asarray(rng.standard_normal((1, KVH, t, G, HD)), jnp.float32)
     kp = jnp.asarray(rng.standard_normal((POOL, KPAGE, KVH * HD)),
                      jnp.float32)
     vp = jnp.asarray(rng.standard_normal((POOL, KPAGE, KVH * HD)),
                      jnp.float32)
     bt = jnp.arange(1, MB + 1, dtype=jnp.int32)[None]
-    pos = jnp.asarray([90], jnp.int32)      # window 20: keys 71..90
-    want = pa.paged_attention_gqa(q, kp, vp, bt, pos, window=20)
-    old = jnp.arange(1, 1 + 71 // KPAGE)    # pages of positions 0..63
-    got = pa.paged_attention_gqa(q, kp.at[old].set(jnp.nan),
-                                 vp.at[old].set(jnp.nan), bt, pos,
-                                 window=20)
+    last = pos + t - 1
+    want = pa.paged_attention_gqa(q, kp, vp, bt, jnp.asarray([pos]),
+                                  window=window)
+    # pages wholly before the FIRST query's window, and the positions
+    # past the last query in its page and in the pages after it
+    old = jnp.arange(1, 1 + (pos - window + 1) // KPAGE)
+    kn = kp.at[old].set(jnp.nan).at[2 + last // KPAGE:].set(jnp.nan)
+    vn = vp.at[old].set(jnp.nan).at[2 + last // KPAGE:].set(jnp.nan)
+    kn = kn.at[1 + last // KPAGE, last % KPAGE + 1:].set(jnp.nan)
+    vn = vn.at[1 + last // KPAGE, last % KPAGE + 1:].set(jnp.nan)
+    assert len(old) and bool(jnp.isnan(kn[1 + last // KPAGE]).any())
+    got = pa.paged_attention_gqa(q, kn, vn, bt, jnp.asarray([pos]),
+                                 window=window)
     np.testing.assert_array_equal(got, want)
-    inside = pa.paged_attention_gqa(q, kp.at[10].set(jnp.nan), vp, bt, pos,
-                                    window=20)
+    inside = pa.paged_attention_gqa(q, kp.at[1 + pos // KPAGE].set(jnp.nan),
+                                    vp, bt, jnp.asarray([pos]),
+                                    window=window)
     assert np.isnan(np.asarray(inside)).any()
+
+
+@pytest.mark.parametrize("t,window,tile", [
+    (1, 0, None), (1, 20, None), (20, 0, SMALL), (20, 20, SMALL),
+    (21, 72, SMALL), (2048, 0, None), (2048, 4096, None)],
+    ids=["decode", "decode_window", "tiles", "tiles_window20",
+         "tiles_window72", "served_full", "served_window"])
+def test_gqa_chunks_equals_a_count_by_a_plain_loop(monkeypatch, t, window,
+                                                   tile):
+    """``gqa_chunks`` against a loop over rows, query tiles, chunks,
+    queries and keys: a chunk is walked if it holds a key some query of
+    the tile may see or a page the tile copies, and runs without masks if
+    every query of the tile sees every key of it."""
+    _set_tile(monkeypatch, tile)
+    page, mb = (KPAGE, MB) if tile or t == 1 else (16, 1024)
+    tq, ch = pa.gqa_tile(t, G, KVH, page)
+    rng = np.random.default_rng(t + window)
+    rows = [0, 5] + rng.integers(0, mb * page - t, 4).tolist()
+    run = unmasked = 0
+    for pos in rows:
+        for j0 in range(0, t, tq):
+            qs = [pos + j for j in range(j0, min(j0 + tq, t))]
+            lo = max(qs[0] - window + 1, 0) // page * page if window else 0
+            hi = min(qs[-1] + 1, mb * page)
+            for c in range(-(-mb * page // ch)):
+                keys = range(c * ch, (c + 1) * ch)
+                if c * ch >= hi or (c + 1) * ch <= lo // ch * ch:
+                    continue
+                run += 1
+                # the queries' extremes decide for all of them
+                unmasked += t > 1 and all(
+                    k <= q and k < hi and (not window or k > q - window)
+                    for q in (qs[0], qs[-1]) for k in keys)
+    got = pa.gqa_chunks(rows, t, G, KVH, page, mb, window)
+    assert got.tolist() == [run, unmasked]
+    if t == 2048:
+        assert (tq, ch) == (256, 512) and 0 < unmasked < run
+
+
+def _served_config(model):
+    if model == "qwen3-next":
+        from gym_tpu.models.qwen3_next import Qwen3NextConfig
+        return dataclasses.replace(
+            Qwen3NextConfig(num_hidden_layers=8).decode_config(),
+            page_size=16, kv_pages=65536)
+    from gym_tpu.models.cohere2_moe import Cohere2MoeConfig
+    return dataclasses.replace(
+        Cohere2MoeConfig(num_hidden_layers=4).decode_config(),
+        page_size=16, kv_pages=12288)
+
+
+@pytest.mark.parametrize("bucket,start,suffix", [
+    (16384, 0, 12000), (8192, 0, 4097), (2048, 0, 1500), (16, 4096, 9)],
+    ids=["three_passes", "two_passes", "one_call", "a_suffix"])
+@pytest.mark.parametrize("model", ["qwen3-next", "command-a-plus"])
+def test_a_config_counts_a_prefills_chunks_call_by_call(monkeypatch, model,
+                                                        bucket, start,
+                                                        suffix):
+    """``config.prefill_counted``: for every layer whose attend is the
+    grouped kernel, the sum of ``gqa_chunks`` over the passes that ran
+    (4,096 positions each, the passes of padding skipped) and the calls
+    of 2,048 queries a pass makes; nothing where the gather path runs
+    (off the TPU)."""
+    cfg = _served_config(model)
+    assert cfg.prefill_counted(bucket, start, suffix) == {}
+    monkeypatch.setattr(pa, "_on_tpu", lambda: True)
+    got = cfg.prefill_counted(bucket, start, suffix)
+    kernel = [i for i, path in enumerate(cfg.attend_paths())
+              if path in (pa.KERNEL, pa.KERNEL_WINDOW)]
+    assert kernel and sorted(got) == sorted(
+        f"layers_{i}/self_attn/gqa_chunks" for i in kernel)
+    group = cfg.num_attention_heads // cfg.num_key_value_heads
+    step = min(bucket, 4096)
+    for i in kernel:
+        window = cfg.window(i) if model == "command-a-plus" else 0
+        want = np.zeros(2, np.int64)
+        for lo in range(0, bucket, step):
+            if lo >= suffix:
+                continue            # a pass of padding
+            tc = min(step, 2048)
+            for c in range(0, step, tc):
+                want += pa.gqa_chunks(
+                    [start + lo + c], tc, group, cfg.num_key_value_heads,
+                    16, cfg.block_size // 16, window)
+        np.testing.assert_array_equal(
+            got[f"layers_{i}/self_attn/gqa_chunks"], want)
+        assert 0 <= want[1] < want[0]
+
+
+@pytest.mark.parametrize("counters,value", [
+    ({"layers_3/self_attn/gqa_chunks": [400, 372],
+      "layers_7/self_attn/gqa_chunks": [400, 372],
+      "layers_3/self_attn/pages": [9000, 0]}, 93.0),
+    ({"layers_0/self_attn/gqa_chunks": [90, 30],
+      "layers_1/self_attn/gqa_chunks": [300, 270]}, 100 * 300 / 390),
+    ({"layers_3/self_attn/pages": [9000, 0]}, None),    # the parent's
+    ({"layers_3/self_attn/gqa_chunks": [0, 0]}, None),  # no prefill
+    ({}, None)], ids=["full_layers", "window_and_full", "no_counter",
+                      "no_prefill", "no_counters"])
+def test_serve_gqa_prefill_unmasked_chunks_pct(counters, value):
+    """The per-layer metric's reader on ``/stats``' ``model_counters``
+    over a window; listed last in ``BENCHMARK.json`` for the two cells
+    whose models run the grouped kernel."""
+    from perfbench import harness
+    read = harness.load_reader(os.path.join(harness.ROOT, "perfbench"),
+                               "serve_gqa_prefill_unmasked_chunks_pct")
+    got = read({"kind": "closed", "model_counters": counters})
+    assert got == (pytest.approx(value) if value is not None else None)
+    assert read({"kind": "closed"}) is None
+    entry = harness.load_json(os.path.join(harness.ROOT,
+                                           "BENCHMARK.json"))["per_layer"][-1]
+    assert entry == {
+        "name": "serve_gqa_prefill_unmasked_chunks_pct", "unit": "%",
+        "better": "higher", "source": "program_counter", "layer": "Kernels",
+        "moves": "serve_tokens_per_s",
+        "workloads": ["qwen3-next-80b-a3b.serve-closed-longctx",
+                      "command-a-plus.serve-closed-rag"]}
 
 
 # -- the dispatch point ------------------------------------------------------

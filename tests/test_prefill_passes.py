@@ -286,6 +286,30 @@ def test_the_engine_counts_the_positions_the_passes_ran(served, n):
     assert toks[0] == toks[1]
 
 
+@pytest.mark.parametrize("n", [70, 160])
+def test_the_engine_adds_what_a_config_counts_of_a_prefill(monkeypatch, n):
+    """An admission adds ``config.prefill_counted(bucket, start, suffix)``
+    to the model's counters, beside what the decode steps count; on the
+    gather path (off the TPU) the grouped kernel's count is empty."""
+    cfg, params = _served("command-a-plus", 16)
+    assert cfg.prefill_counted(prompt_bucket(n, 256), 0, n) == {}
+    asked = []
+    monkeypatch.setattr(
+        type(cfg), "prefill_counted", lambda _cfg, *a: asked.append(a) or {
+            "layers_1/self_attn/gqa_chunks": np.asarray([7, 5])})
+    eng = InferenceEngine(params, cfg, num_slots=2, paged=True,
+                          page_size=PAGE, kv_pages=PAGES)
+    for k in (1, 2):
+        eng.admit(_prompt(n, n + k), SamplingParams(max_new_tokens=2,
+                                                    top_k=1))
+        eng.step()
+        counted = eng.stats.model_counters
+        assert counted["layers_1/self_attn/gqa_chunks"].tolist() == [
+            7 * k, 5 * k]
+    assert asked == [(prompt_bucket(n, 256), 0, n)] * 2
+    assert counted["layers_0/self_attn/pages"][0] > 0
+
+
 @pytest.mark.parametrize("n", [3, 11, 40])
 def test_a_model_that_does_not_say_counts_its_buckets(n):
     """GPT-2's config has no ``prefill_pass``: its prefills run, and are

@@ -417,9 +417,14 @@ PARENT_LINES = {
         "_kernel": (163, "ac08d12742bba020"),
         "paged_attention": (242, "573809f61b05873a"),
         "_paged_attention": (257, "8a7ac96326e0d185"),
-        "_gqa_kernel": (304, "a1fa94b28f88340c"),
-        "paged_attention_gqa": (398, "5b22bbbd26ed4d86"),
-        "_paged_attention_gqa": (413, "f06f18a7d5e6f640")},
+        # PR 48 (the grouped kernel's tile and its unmasked body) edits
+        # what follows, on PR 48's own tree: ``command-a-plus``'s and
+        # ``qwen3-next``'s programs are the only ones that hold them
+        "gqa_tile": (304, "af704735725b0154"),
+        "_gqa_kernel": (321, "5ff68fb9ae8b72d2"),
+        "paged_attention_gqa": (434, "5b22bbbd26ed4d86"),
+        "_paged_attention_gqa": (449, "d49318341d52c741"),
+        "_gqa_interior": (492, "0e019adf952ba331")},
     # PR 44 (a prefill in passes) edits these two: what stands in the
     # stacks of ``qwen3-next``'s kernels and of the two models' own decode
     # programs, on PR 44's parent commit (eee31ca); a method as
