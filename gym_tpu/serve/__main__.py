@@ -587,6 +587,10 @@ def create_server(params, cfg, *, host: str = "127.0.0.1", port: int = 0,
                 # out what was in flight
                 "steps_ahead": sum(s.steps_ahead for s in stats),
                 "drains": sum(s.drains for s in stats),
+                # requests that progressed in a read of the scheduler's,
+                # each woken once
+                "wakes": sum(rep.scheduler.wakes
+                             for rep in router.replicas),
                 # decode steps whose sampler sorted the vocabulary (a
                 # live row filtered by top_k or top_p)
                 "sampler_sorted_steps": sum(s.sampler_sorted_steps

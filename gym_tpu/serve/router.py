@@ -227,6 +227,7 @@ class FleetRequest:
         wait_deadline = (None if timeout is None
                          else time.perf_counter() + timeout)
         yielded: List[int] = []
+        seen = 0          # the cursor into the CURRENT attempt's tokens
         while True:
             inner = self._inner
             rem = (None if wait_deadline is None
@@ -241,16 +242,25 @@ class FleetRequest:
                     f"request {inner.id} still {inner.status.value} "
                     f"after {timeout}s ({len(yielded)} tokens streamed)")
             step = poll_s if rem is None else min(poll_s, rem)
-            snapshot, terminal = inner.wait_progress(len(yielded), step)
-            if len(snapshot) > len(yielded):
-                if snapshot[:len(yielded)] != yielded:
+            new, terminal = inner.wait_progress(seen, step)
+            if new:
+                # the failover splice guard: what a NEW attempt replays
+                # of the yielded prefix is compared token by token (an
+                # attempt that never failed over replays nothing: its
+                # cursor is the prefix's length) and only what follows
+                # is yielded
+                replayed = min(len(yielded) - seen, len(new))
+                if (replayed > 0
+                        and new[:replayed] != yielded[seen:seen + replayed]):
                     raise EngineFailedError(
                         f"stream splice mismatch after failover: "
                         f"replayed prefix diverged at request "
                         f"{inner.id} — non-deterministic replica?")
-                chunk = snapshot[len(yielded):]
-                yielded.extend(chunk)
-                yield chunk
+                seen += len(new)
+                chunk = new[replayed:]
+                if chunk:
+                    yielded.extend(chunk)
+                    yield chunk
             if terminal:
                 if inner.status is RequestStatus.DONE:
                     return
@@ -261,9 +271,11 @@ class FleetRequest:
                     raise exc
                 # replica died mid-stream: re-dispatch under the
                 # remaining deadline; the new attempt replays the
-                # yielded prefix, which the loop above suppresses
+                # yielded prefix from its first token, which the loop
+                # above checks and suppresses
                 self._router._failover_redispatch(self, exc,
                                                   wait_deadline)
+                seen = 0
 
 
 class Router:

@@ -230,10 +230,13 @@ class RequestStatus(enum.Enum):
     FAILED = "failed"
 
 
-@dataclasses.dataclass
+@dataclasses.dataclass(eq=False)
 class Request:
     """A submitted generation request. ``tokens`` accumulates NEW tokens
-    (the prompt is not echoed); timestamps are ``time.perf_counter()``."""
+    (the prompt is not echoed); timestamps are ``time.perf_counter()``.
+    Two requests are never equal: a request compares and hashes by
+    identity (a generated ``__eq__`` would build two tuples of every
+    field a comparison, and compare ``prompt`` arrays)."""
 
     id: int
     prompt: np.ndarray
@@ -269,12 +272,14 @@ class Request:
                       ) -> Tuple[List[int], bool]:
         """Block until the request holds MORE than ``seen`` tokens or
         reaches a terminal state (or ``timeout`` elapses — not an
-        error: streaming pollers re-arm). Returns ``(tokens snapshot,
-        terminal)``. The streaming read surface: a streamer keeps its
-        own cursor, calls with it, and ships ``snapshot[seen:]`` —
-        token chunks arrive at decode-chunk granularity because that is
-        when the driver appends. Terminal FAILED is NOT raised here;
-        the caller branches on ``status``/``exception`` so a streaming
+        error: streaming pollers re-arm). Returns ``(tokens[seen:],
+        terminal)``: the NEW tokens only, so a stream costs what it
+        produced and not that times its events. The streaming read
+        surface: a streamer keeps its own cursor, calls with it, ships
+        what comes back and moves the cursor by its length — token
+        chunks arrive at decode-chunk granularity because that is when
+        the driver appends. Terminal FAILED is NOT raised here; the
+        caller branches on ``status``/``exception`` so a streaming
         failover can splice instead of unwinding."""
         deadline = (None if timeout is None
                     else time.perf_counter() + timeout)
@@ -286,7 +291,10 @@ class Request:
                 if rem is not None and rem <= 0:
                     break
                 self._progress.wait(rem)
-        return list(self.tokens), self._event.is_set()
+        # terminal first: every token is appended before the event is
+        # set, so a tail read under a set event is the whole of it
+        terminal = self._event.is_set()
+        return self.tokens[seen:], terminal
 
     def result(self, timeout: Optional[float] = None) -> List[int]:
         """Block until the request completes; returns the new tokens or
@@ -425,6 +433,8 @@ class Scheduler:
         # deadline cancellations — the single-driver contract means a
         # cancel can NEVER touch the engine from the caller's thread
         self._cancelled: set = set()
+        # (request, read) pairs that progressed, each woken once
+        self.wakes = 0
 
     # -- submit side ------------------------------------------------------
 
@@ -1023,7 +1033,8 @@ class Scheduler:
         now = time.perf_counter()
         completed: List[Request] = []
         failed: List[Tuple[Request, BaseException]] = []
-        progressed: List[Request] = []
+        # by id, one entry a request whatever the read holds for it
+        progressed: Dict[int, Request] = {}
         with self._lock:
             if self._epoch != epoch:
                 return produced        # stale driver: discard the chunk
@@ -1057,8 +1068,7 @@ class Scheduler:
                     req.first_token_t = now      # the prefill's token
                 req.tokens.append(ev.token)
                 produced += 1
-                if req not in progressed:
-                    progressed.append(req)
+                progressed[req.id] = req
                 if ev.finished:
                     del self._by_slot[ev.slot]
                     completed.append(req)
@@ -1110,9 +1120,14 @@ class Scheduler:
             self._complete(req, now)
         for req, exc in failed:
             self._fail(req, exc)
-        if progressed:
-            for req in progressed:
-                req._notify_progress()
+        # a resolution has woken its request's streamers already
+        self.wakes += len(progressed)
+        waiting = [req for req in progressed.values()
+                   if not req._event.is_set()]
+        if waiting:
+            with span("serve.wake", requests=len(waiting)):
+                for req in waiting:
+                    req._notify_progress()
         return produced
 
     def _complete(self, req: Request,

@@ -333,33 +333,32 @@ class WorkerServer:
                     if req._event.wait(timeout=1.0):
                         break
                     continue
-                snapshot, terminal = req.wait_progress(seen, timeout=1.0)
-                if (not terminal and sent_any and coalesce > 0
-                        and len(snapshot) > seen):
+                new, terminal = req.wait_progress(seen, timeout=1.0)
+                if (not terminal and sent_any and coalesce > 0 and new):
                     time.sleep(coalesce)
-                    snapshot, terminal = req.wait_progress(seen, 0.0)
-                if len(snapshot) > seen:
+                    new, terminal = req.wait_progress(seen, 0.0)
+                if new:
                     # failover splice: verify the replayed prefix (the
                     # engine is deterministic — a mismatch means the
                     # fleet is NOT serving one model; fail typed, never
                     # ship a corrupted stream), ship only what follows
-                    for i in range(seen, min(len(snapshot), len(prefix))):
-                        if snapshot[i] != prefix[i]:
+                    replayed = max(0, len(prefix) - seen)
+                    for i, tok in enumerate(new[:replayed], seen):
+                        if tok != prefix[i]:
                             self.scheduler.cancel(
                                 req, reason="splice mismatch")
                             send(wire.exception_to_frame(
-                                rid, _splice_mismatch(i, prefix[i],
-                                                      snapshot[i])))
+                                rid, _splice_mismatch(i, prefix[i], tok)))
                             return
-                    start = max(seen, len(prefix))
-                    if len(snapshot) > start:
+                    fresh = new[replayed:]
+                    if fresh:
                         if not send({"type": "chunk", "id": rid,
-                                     "tokens": snapshot[start:]}):
+                                     "tokens": fresh}):
                             self.scheduler.cancel(
                                 req, reason="router disconnected")
                             return
                         sent_any = True
-                    seen = len(snapshot)
+                    seen += len(new)
                 if terminal:
                     break
             from .scheduler import RequestFailedError, RequestStatus

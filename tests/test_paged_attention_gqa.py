@@ -228,7 +228,7 @@ def test_a_config_counts_a_prefills_chunks_call_by_call(monkeypatch, model,
                       "no_prefill", "no_counters"])
 def test_serve_gqa_prefill_unmasked_chunks_pct(counters, value):
     """The per-layer metric's reader on ``/stats``' ``model_counters``
-    over a window; listed last in ``BENCHMARK.json`` for the two cells
+    over a window; listed in ``BENCHMARK.json`` for the two cells
     whose models run the grouped kernel."""
     from perfbench import harness
     read = harness.load_reader(os.path.join(harness.ROOT, "perfbench"),
@@ -236,8 +236,9 @@ def test_serve_gqa_prefill_unmasked_chunks_pct(counters, value):
     got = read({"kind": "closed", "model_counters": counters})
     assert got == (pytest.approx(value) if value is not None else None)
     assert read({"kind": "closed"}) is None
-    entry = harness.load_json(os.path.join(harness.ROOT,
-                                           "BENCHMARK.json"))["per_layer"][-1]
+    entry, = [m for m in harness.load_json(os.path.join(
+        harness.ROOT, "BENCHMARK.json"))["per_layer"]
+        if m["name"] == "serve_gqa_prefill_unmasked_chunks_pct"]
     assert entry == {
         "name": "serve_gqa_prefill_unmasked_chunks_pct", "unit": "%",
         "better": "higher", "source": "program_counter", "layer": "Kernels",

@@ -21,9 +21,11 @@ import jax
 from gym_tpu.models.nanogpt import GPT, GPTConfig, generate_fast
 from gym_tpu.serve.engine import InferenceEngine, SamplingParams
 from gym_tpu.serve.metrics import HEADER, ServeMetrics, read_headline
-from gym_tpu.serve.router import build_fleet
-from gym_tpu.serve.scheduler import (RequestCancelledError,
+from gym_tpu.serve.router import FleetRequest, build_fleet
+from gym_tpu.serve.scheduler import (EngineFailedError, Request,
+                                     RequestCancelledError,
                                      RequestStatus, Scheduler)
+from gym_tpu.serve.worker import WorkerServer
 
 
 @pytest.fixture(scope="module")
@@ -291,3 +293,218 @@ def test_read_headline_tolerates_pre_pid_csv(tmp_path):
     assert head["requests_disconnected"] == 1
     assert head["requests_failed"] == 0
     assert head["replicas"]["0"]["requests_done"] == 1
+
+
+# -- the streamer reads only its new tokens (ISSUE 49) ----------------------
+
+
+class CountingList(list):
+    """A token list that counts the elements its readers copy out of it:
+    a slice's length, a whole iteration's (``list(tokens)``)."""
+
+    copied = 0
+
+    def __getitem__(self, key):
+        got = super().__getitem__(key)
+        if isinstance(key, slice):
+            CountingList.copied += len(got)
+        return got
+
+    def __iter__(self):
+        CountingList.copied += len(self)
+        return super().__iter__()
+
+
+def _request(rid=0, tokens=()):
+    req = Request(id=rid, prompt=np.arange(4, dtype=np.int32),
+                  sampling=SamplingParams(max_new_tokens=8192),
+                  status=RequestStatus.RUNNING)
+    req.tokens = CountingList(tokens)
+    return req
+
+
+def _resolve(req, exc=None):
+    """What ``Scheduler._complete`` / ``_fail`` do to a request."""
+    if exc is None:
+        req.status = RequestStatus.DONE
+    else:
+        req.status, req.exception = RequestStatus.FAILED, exc
+    req._event.set()
+    req._notify_progress()
+
+
+def _feed(req, tokens, exc=None, pause=0.0):
+    """The driver's side: append and wake, a token an event."""
+    for tok in tokens:
+        req.tokens.append(tok)
+        req._notify_progress()
+        if pause:
+            time.sleep(pause)
+    _resolve(req, exc)
+
+
+class _Attempts:
+    """A router for ``FleetRequest.stream``: its failover installs the
+    next scripted attempt."""
+
+    replicas = ()
+
+    def __init__(self, attempts):
+        self.attempts = list(attempts)
+
+    def _failover_redispatch(self, fr, exc, wait_deadline):
+        fr._inner = self.attempts.pop(0)
+        fr.failovers += 1
+
+
+def _fleet_request(router, inner):
+    fr = FleetRequest(router, inner.prompt, inner.sampling, None,
+                      time.perf_counter())
+    fr._inner = inner
+    return fr
+
+
+def test_wait_progress_returns_the_tail_and_sums_to_the_result():
+    req = _request()
+    assert req.wait_progress(0, timeout=0.0) == ([], False)
+    got, seen = [], 0
+    for burst in ([5], [6, 7, 8], [9], [10, 11]):    # a chunk of 3, of 2
+        req.tokens.extend(burst)
+        new, terminal = req.wait_progress(seen, timeout=0.0)
+        assert new == burst and not terminal         # only what is new
+        got += new
+        seen += len(new)
+    assert req.wait_progress(seen, timeout=0.0) == ([], False)
+    _resolve(req)
+    assert req.wait_progress(seen, timeout=0.0) == ([], True)
+    assert got == req.result(timeout=0) == [5, 6, 7, 8, 9, 10, 11]
+    # a cursor of 0 reads everything: what a caller without one expects
+    assert req.wait_progress(0, timeout=0.0) == (got, True)
+
+
+def test_a_stream_of_2000_tokens_copies_linear_elements():
+    """One event a token, 2,000 of them: the wait surface and the
+    router's stream copy a few elements an event, not the tokens so far
+    (that was 2,000 x 2,001 / 2 = 2 million twice over)."""
+    n = 2000
+    req = _request()
+    stream = _fleet_request(_Attempts([]), req).stream(timeout=60)
+    CountingList.copied = 0
+    got = []
+    for tok in range(n):
+        req.tokens.append(tok)
+        got.extend(next(stream))         # returns at once: a token waits
+    _resolve(req)
+    assert list(stream) == []
+    assert got == list(range(n))
+    assert CountingList.copied <= 2 * n
+    CountingList.copied = 0
+    assert req.result(timeout=0) == got  # the one whole copy, at the end
+    assert CountingList.copied == n
+
+
+REPLAY = list(range(100, 112))           # what the first attempt streamed
+
+
+@pytest.mark.parametrize("fed", ["at_once", "token_by_token"])
+@pytest.mark.parametrize("diverges_at", [None, 0, 5, len(REPLAY) - 1])
+def test_splice_guard_compares_a_new_attempts_replay(diverges_at, fed):
+    """A failover's replay is held to what was yielded, position by
+    position: an honest one is suppressed and the stream goes on
+    byte-identical; one that diverges anywhere raises typed."""
+    first = _request(0, REPLAY)
+    _resolve(first, EngineFailedError("test: the replica died"))
+    replay = list(REPLAY)
+    if diverges_at is not None:
+        replay[diverges_at] += 1
+    rest = [7, 8, 9]
+    second = _request(1)
+    fr = _fleet_request(_Attempts([second]), first)
+    feeder = threading.Thread(
+        target=_feed, args=(second, replay + rest),
+        kwargs={"pause": 0.0 if fed == "at_once" else 0.002})
+    if fed == "at_once":
+        feeder.start()
+        feeder.join()
+    CountingList.copied = 0
+    got = []
+    try:
+        stream = fr.stream(timeout=60)
+        got.extend(next(stream))         # the first attempt's tokens
+        assert got == REPLAY
+        if fed != "at_once":
+            feeder.start()
+        if diverges_at is None:
+            for chunk in stream:
+                got.extend(chunk)
+            assert got == REPLAY + rest  # no dupes, no gaps
+            assert fr.failovers == 1
+        else:
+            with pytest.raises(EngineFailedError, match="splice mismatch"):
+                for chunk in stream:
+                    got.extend(chunk)
+            assert got == REPLAY         # nothing of the bad attempt
+    finally:
+        feeder.join()
+    # the guard reads each replayed position once, not the prefix an event
+    assert CountingList.copied <= 4 * (len(REPLAY) + len(rest))
+
+
+class _OneRequestScheduler:
+    def __init__(self, req):
+        self.req, self.cancelled = req, []
+
+    def submit(self, prompt, sp, **kw):
+        return self.req
+
+    def cancel(self, req, reason=""):
+        self.cancelled.append(reason)
+        return True
+
+
+@pytest.mark.parametrize("fed", ["at_once", "token_by_token"])
+@pytest.mark.parametrize("diverges_at", [None, 0, 5, len(REPLAY) - 1])
+def test_worker_frame_splice_guard(diverges_at, fed):
+    """The process fleet's half: a submit frame's ``prefix`` is verified
+    against the replay token by token and only what follows is shipped;
+    a divergence cancels the request and sends the typed error frame."""
+    rest = [7, 8, 9]
+    replay = list(REPLAY)
+    if diverges_at is not None:
+        replay[diverges_at] += 1
+    req = _request(3)
+    feeder = threading.Thread(
+        target=_feed, args=(req, replay + rest),
+        kwargs={"pause": 0.0 if fed == "at_once" else 0.002})
+    worker = WorkerServer.__new__(WorkerServer)
+    worker.scheduler = _OneRequestScheduler(req)
+    frames = []
+    if fed == "at_once":
+        feeder.start()
+        feeder.join()
+    else:
+        feeder.start()
+    CountingList.copied = 0
+    try:
+        worker._stream_request(
+            {"type": "submit", "id": "r1", "prompt": [1, 2, 3],
+             "prefix": REPLAY, "coalesce_s": 0.0},
+            lambda frame: frames.append(frame) or True,
+            {}, set(), threading.Lock())
+    finally:
+        feeder.join()
+    assert frames[0] == {"type": "accepted", "id": "r1"}
+    shipped = [t for f in frames if f["type"] == "chunk"
+               for t in f["tokens"]]
+    if diverges_at is None:
+        assert shipped == rest
+        assert frames[-1]["type"] == "done"
+        assert frames[-1]["new_tokens"] == len(rest)
+        assert not worker.scheduler.cancelled
+    else:
+        assert shipped == []
+        assert frames[-1]["type"] == "error"
+        assert frames[-1]["error_type"] == "EngineFailedError"
+        assert f"replayed token {diverges_at} is" in frames[-1]["message"]
+        assert worker.scheduler.cancelled == ["splice mismatch"]
+    assert CountingList.copied <= 4 * (len(REPLAY) + len(rest))

@@ -408,6 +408,11 @@ def _serve_leaves_tile_the_round(reqs, recs, eng):
         assert {r.name for r in leaves} <= ROUND_LEAVES
         assert leaves[0].name == "serve.shed"
         assert leaves[-1].name == "serve.deliver"
+        # the wake-ups (ISSUE 49) are a leaf of ``serve.deliver``'s own
+        assert not any(r.name == "serve.wake" for r in leaves)
+        assert all(r.parent == leaves[-1].seq for r in recs
+                   if r.name == "serve.wake"
+                   and r.ids["round"] == rnd.ids["round"])
         assert all(r.ids["round"] == rnd.ids["round"] for r in leaves)
         edges = [rnd.t0] + [t for r in leaves for t in (r.t0, r.t1)] \
             + [rnd.t1]
